@@ -239,7 +239,6 @@ def run_fleet_ab(metric: str, requests: int = 64,
     platform = os.environ.get("RLT_FLEET_PLATFORM", "cpu")
     root = os.environ.get("RLT_FLEET_DIR") or tempfile.mkdtemp(
         prefix="rlt_bench_fleet_")
-    cache = os.path.join(root, "compile_cache")
 
     if trace_path and os.path.exists(trace_path):
         trace = load_trace(trace_path)
@@ -258,7 +257,7 @@ def run_fleet_ab(metric: str, requests: int = 64,
     server_kw = dict(
         num_workers=num_workers, platform=platform, buckets=BUCKETS,
         max_batch_slots=SLOTS, max_new_tokens=MAX_NEW,
-        compile_cache=cache, telemetry=False)
+        telemetry=False)
 
     legs: dict = {}
     # -- single Server: the reference fleet AND the parity oracle ------
@@ -442,9 +441,9 @@ def run_fleet_ab(metric: str, requests: int = 64,
         "platform": platform,
         "slots": SLOTS,
         "page_size": PAGE_SIZE,
-        # env-resolved decode kernel (ops/flash_decode.py); paging is on
-        # and page-aligned here, so engines see the same resolution
-        "decode_kernel": resolve_decode_impl(None),
+        # the REQUESTED decode impl (env or "auto"); what an engine
+        # lowered is in its own stats()["decode_kernel"]
+        "decode_impl": resolve_decode_impl(None),
         "tokens_per_sec": headline["tokens_per_sec"],
         "ttft_p99_ms": headline["ttft_p99_ms"],
         "multipliers": {

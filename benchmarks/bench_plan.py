@@ -29,7 +29,7 @@ import sys
 import tempfile
 
 
-def _fit(cfg, batch: int, steps: int, root: str, cache: str, **kw):
+def _fit(cfg, batch: int, steps: int, root: str, **kw):
     from ray_lightning_tpu import Trainer
     from ray_lightning_tpu.models.gpt import GPTLightningModule
 
@@ -38,7 +38,7 @@ def _fit(cfg, batch: int, steps: int, root: str, cache: str, **kw):
     trainer = Trainer(max_steps=steps, max_epochs=10**6, seed=0,
                       default_root_dir=root, enable_checkpointing=False,
                       num_sanity_val_steps=0, limit_val_batches=0,
-                      log_every_n_steps=10**9, compile_cache=cache, **kw)
+                      log_every_n_steps=10**9, **kw)
     trainer.fit(module)
     return trainer
 
@@ -53,14 +53,13 @@ def main() -> None:
     batch, steps = 8, 4
 
     with tempfile.TemporaryDirectory() as td:
-        cache = os.path.join(td, "compile_cache")
-        auto = _fit(cfg, batch, steps, os.path.join(td, "auto"), cache,
+        auto = _fit(cfg, batch, steps, os.path.join(td, "auto"),
                     strategy="auto")
         report = auto._plan_report or {}
         # manual baseline: the same plan hand-picked (DDP over every
         # chip is the measured-best manual config for these sizes)
         manual = _fit(cfg, batch, steps, os.path.join(td, "manual"),
-                      cache, strategy="ddp")
+                      strategy="ddp")
         result = {
             "metric": "plan",
             "candidates": report.get("enumerated", 0),

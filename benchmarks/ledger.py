@@ -5,7 +5,7 @@ The repo accumulates one measured JSON blob per round (the driver's
 regression was something a human noticed diffing them.  This module
 compares two rounds record-by-record and exits nonzero when a tracked
 figure regresses past its band — the pre-merge perf gate
-(``python bench.py --compare BENCH_r05.json`` or
+(``python bench.py --compare prev.json`` or
 ``python -m benchmarks.ledger prev.json curr.json``).
 
 Accepted inputs, auto-detected per file:

@@ -10,7 +10,7 @@ full training run:
 - the RLT_COMM* env knobs (codec, hierarchy, buckets included)
   round-trip through ``worker_env()`` → ``resolve()`` unchanged;
 - the compressed collectives LOWER without error on a CPU mesh (every
-  codec: int8 / bf16 / fp8 / int4, via the shard_map compat wrapper),
+  codec: int8 / bf16 / fp8 / int4, under shard_map),
   the two-level hierarchical psum lowers with its grouped collectives,
   and the quantizer round-trips exactly-representable payloads
   bit-exactly;
@@ -37,7 +37,6 @@ def _main(argv) -> int:   # noqa: ARG001 - argv kept for parity
     from ray_lightning_tpu.comm.collectives import compressed_psum
     from ray_lightning_tpu.comm.quant import (blockwise_dequantize,
                                               blockwise_quantize)
-    from ray_lightning_tpu.parallel.mesh import shard_map_compat
     from ray_lightning_tpu.parallel.pipeline import PipelineStrategy
     from ray_lightning_tpu.parallel.strategy import (_STRATEGIES,
                                                      resolve_strategy)
@@ -99,8 +98,8 @@ def _main(argv) -> int:   # noqa: ARG001 - argv kept for parity
         def body(x, mode=mode):
             return compressed_psum(x[0], "data", world, mode=mode,
                                    mean=True)[None]
-        fn = shard_map_compat(body, mesh, in_specs=P("data"),
-                              out_specs=P("data"))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                           out_specs=P("data"), check_vma=False)
         try:
             jax.jit(fn).lower(
                 jax.ShapeDtypeStruct((world, 300), np.float32)).compile()
@@ -112,8 +111,8 @@ def _main(argv) -> int:   # noqa: ARG001 - argv kept for parity
         return hierarchical_psum(x[0], "data", 2, world // 2,
                                  mode="int8", mean=True)[None]
     try:
-        fn = shard_map_compat(hier_body, mesh, in_specs=P("data"),
-                              out_specs=P("data"))
+        fn = jax.shard_map(hier_body, mesh=mesh, in_specs=P("data"),
+                           out_specs=P("data"), check_vma=False)
         jax.jit(fn).lower(
             jax.ShapeDtypeStruct((world, 300), np.float32)).compile()
     except Exception as e:   # noqa: BLE001

@@ -5,8 +5,9 @@ per-process tax (the biggest framework-controlled wall-clock cost once
 steady-state step time sits at raw-JAX parity):
 
 - ``cache.py`` — lifecycle of JAX's persistent compilation cache:
-  config/env resolution, topology-namespaced directories, hit/miss and
-  compile-seconds accounting surfaced through the metrics plane.
+  config/env resolution, the one cache directory
+  (``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``), hit/miss
+  and compile-seconds accounting surfaced through the metrics plane.
 - ``aot.py`` — background lower+compile of the step programs from their
   ``eval_shape`` avals, overlapped with state init, the rendezvous and
   the device-resident dataset upload.
@@ -16,17 +17,16 @@ steady-state step time sits at raw-JAX parity):
 Wired through ``core/trainer.py`` (activation + AOT submission +
 time-to-first-step), ``core/loop_engine.py`` (cached-step programs
 submit when their shapes become known), ``plugins/xla.py`` (worker env
-+ seeding), and ``tune/runner.py`` (one shared cache per experiment).
++ seeding); tune trials share the same directory as everything else.
 """
 
 from ray_lightning_tpu.compile.cache import (  # noqa: F401
     CacheStats,
     CompileCacheConfig,
-    DEFAULT_ROOT,
+    DEFAULT_DIR,
     activate,
     active_dir,
     deactivate,
-    namespace_dir,
     note_first_step,
     publish_metrics,
     reset_stats,
@@ -42,11 +42,10 @@ from ray_lightning_tpu.compile.aot import (  # noqa: F401
 __all__ = [
     "CacheStats",
     "CompileCacheConfig",
-    "DEFAULT_ROOT",
+    "DEFAULT_DIR",
     "activate",
     "active_dir",
     "deactivate",
-    "namespace_dir",
     "note_first_step",
     "publish_metrics",
     "reset_stats",

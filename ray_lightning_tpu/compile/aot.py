@@ -58,7 +58,7 @@ class AotPrecompiler:
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
         self.results: dict[str, Any] = {}   # name -> seconds | exception
-        self._queue: list[tuple[str, Any, tuple]] = []
+        self._queue: list[tuple[str, Any, tuple, Any]] = []
         self._pending = 0
         self._cond = threading.Condition()
         self._thread: Optional[threading.Thread] = None
@@ -78,8 +78,18 @@ class AotPrecompiler:
         ``name``.  No-op when disabled."""
         if not self.enabled:
             return
+        # the submitter's mesh rides along: it is thread-local
+        # (parallel/mesh.py), and mesh-aware ops traced on the compile
+        # thread (auto_attention's sharded flash kernel, ring attention)
+        # must see what the dispatching thread would have seen.  jax
+        # caches the TRACE per avals, so a program traced here without
+        # the mesh is also the program the fit then runs — on a
+        # multi-chip mesh that silently swapped the Pallas kernel for
+        # the XLA dot path.
+        from ray_lightning_tpu.parallel.mesh import get_current_mesh
         with self._cond:
-            self._queue.append((name, jitted, abstract_args))
+            self._queue.append((name, jitted, abstract_args,
+                                get_current_mesh()))
             self._pending += 1
             if self._thread is None or not self._thread.is_alive():
                 self._thread = threading.Thread(
@@ -87,13 +97,15 @@ class AotPrecompiler:
                 self._thread.start()
 
     def _run(self) -> None:
+        from ray_lightning_tpu.parallel.mesh import set_current_mesh
         while True:
             with self._cond:
                 if not self._queue:
                     return
-                name, jitted, args = self._queue.pop(0)
+                name, jitted, args, mesh = self._queue.pop(0)
             t0 = time.monotonic()
             try:
+                set_current_mesh(mesh)
                 jitted.lower(*args).compile()
                 dt = time.monotonic() - t0
                 self.results[name] = dt

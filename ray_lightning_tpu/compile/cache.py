@@ -11,15 +11,17 @@ is this module:
 
 - :class:`CompileCacheConfig` — picklable settings carried on the
   Trainer (like ``TelemetryConfig``), resolved from the ``compile_cache=``
-  argument, the ``RLT_COMPILE_CACHE*`` env knobs, or the live builtin
-  tune session (tune/runner.py points every trial of an experiment at
-  one shared cache under the experiment dir).
-- :func:`activate` — enables JAX's persistent cache at a *namespaced*
-  subdirectory of the configured root
-  (``<root>/jax<version>-<platform>-<device kind>-d<devices>-p<procs>``),
-  so entries from a different jax version, device kind or topology can
-  never collide with this run's, and a shared root stays safe to point
-  heterogeneous jobs at.
+  argument and the ``RLT_COMPILE_CACHE*`` env knobs.  The cache is ON by
+  default.
+- One directory, placed from outside: where ``JAX_COMPILATION_CACHE_DIR``
+  is set the cache lives THERE and nothing in this package points jax
+  anywhere else (workers inherit the variable); unset, it lives at the
+  fixed in-checkout :data:`DEFAULT_DIR`.  jax keys the entries by
+  program, jax version, platform and device kind, so one directory is
+  safe to share between runs, tune experiments and device kinds; what
+  must not happen is a path that moves (time, pid, temp name) — it
+  never hits.
+- :func:`activate` — enables JAX's persistent cache at that directory.
 - Cache accounting: listeners on JAX's monitoring events count cache
   hits / misses and accumulate real backend-compile seconds; the
   metrics plane (telemetry/metrics.py) exposes them as
@@ -36,16 +38,21 @@ from __future__ import annotations
 
 import logging
 import os
-import re
 import threading
 from dataclasses import dataclass, field, replace
 from typing import Any, Optional
 
 _log = logging.getLogger(__name__)
 
-#: default cache root when enabled without an explicit directory
-DEFAULT_ROOT = os.path.join(
-    os.path.expanduser("~"), ".cache", "ray_lightning_tpu", "xla")
+#: jax's own variable: when set it is THE cache directory — it outranks
+#: every argument and knob below and is never overridden in code
+ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+#: the one fixed cache directory otherwise: inside the checkout (next to
+#: the package, git-ignored), so a second run from the same tree hits
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 #: the user-facing env knobs (README "Compilation cache"; validated by
 #: compile/selfcheck.py so docs and code can't drift)
@@ -61,9 +68,9 @@ class CompileCacheConfig:
     """Picklable compile-cache settings carried on the Trainer (the
     trainer ships to workers, so the config rides along for free)."""
 
-    enabled: bool = False
-    #: cache ROOT; the topology namespace is appended at activation.
-    #: None = :data:`DEFAULT_ROOT`.
+    enabled: bool = True
+    #: explicit cache directory; outranked by ``JAX_COMPILATION_CACHE_DIR``
+    #: (see :attr:`root`).  None = :data:`DEFAULT_DIR`.
     dir: Optional[str] = None
     #: persist entries at least this large (bytes; 0 = everything —
     #: jax's own default of 0 kept, the floor exists for shared NFS
@@ -79,19 +86,20 @@ class CompileCacheConfig:
     def resolve(cls, value: Any) -> "CompileCacheConfig":
         """Trainer's ``compile_cache=`` argument → a config.
 
-        ``None`` defers to the environment and the live builtin tune
-        session; ``True``/``False`` force (default root); a string is an
-        explicit cache root; a dict supplies field overrides (enabled
-        unless it says otherwise).  Precedence for ``None``:
-        ``RLT_COMPILE_CACHE=0`` kills everything; an env-provided dir
-        wins over the tune session's per-experiment dir (a user pointing
-        every job at one root beats per-experiment isolation); bare
-        ``RLT_COMPILE_CACHE=1`` enables the default root.
+        ``None`` defers to the environment; ``True``/``False`` force;
+        a string is an explicit cache directory; a dict supplies field
+        overrides (enabled unless it says otherwise).  For ``None``:
+        ``RLT_COMPILE_CACHE=0`` turns the cache off, anything else
+        leaves it on, with ``RLT_COMPILE_CACHE_DIR`` (or
+        ``RLT_COMPILE_CACHE=/path``) as the explicit directory.
+        Whatever directory is resolved here, a set
+        ``JAX_COMPILATION_CACHE_DIR`` outranks it (:attr:`root`).
         """
         if isinstance(value, cls):
             return value
         if isinstance(value, bool):
-            return cls(enabled=value)._with_env_knobs() if value else cls()
+            return (cls()._with_env_knobs() if value
+                    else cls(enabled=False))
         if isinstance(value, str):
             return cls(enabled=True, dir=value)._with_env_knobs()
         if isinstance(value, dict):
@@ -104,15 +112,11 @@ class CompileCacheConfig:
                 f"CompileCacheConfig; got {type(value).__name__}")
         enable = os.environ.get(ENV_ENABLE, "").strip()
         if enable == "0":
-            return cls()
+            return cls(enabled=False)
         env_dir = os.environ.get(ENV_DIR, "").strip() or None
-        if enable not in ("", "0", "1") and env_dir is None:
-            env_dir = enable          # RLT_COMPILE_CACHE=/path/to/root
-        if env_dir is None:
-            env_dir = _session_cache_dir()
-        if env_dir is None and enable != "1":
-            return cls()
-        return cls(enabled=True, dir=env_dir)._with_env_knobs()
+        if enable not in ("", "1") and env_dir is None:
+            env_dir = enable          # RLT_COMPILE_CACHE=/path/to/dir
+        return cls(dir=env_dir)._with_env_knobs()
 
     def _with_env_knobs(self) -> "CompileCacheConfig":
         out = self
@@ -134,49 +138,28 @@ class CompileCacheConfig:
 
     @property
     def root(self) -> str:
-        return self.dir or DEFAULT_ROOT
+        """THE cache directory: ``JAX_COMPILATION_CACHE_DIR`` where the
+        environment sets it (read at use, so a worker that inherits the
+        variable agrees with its driver), else the explicit ``dir``,
+        else the fixed in-checkout :data:`DEFAULT_DIR`."""
+        return (os.environ.get(ENV_JAX_DIR, "").strip()
+                or self.dir or DEFAULT_DIR)
 
     def worker_env(self) -> dict[str, str]:
         """Env replicating this config in a spawned worker — belt and
         braces alongside the pickled trainer (covers worker-side code
-        that consults the env before the payload arrives)."""
+        that consults the env before the payload arrives).
+        ``JAX_COMPILATION_CACHE_DIR`` itself is inherited, not set."""
         if not self.enabled:
-            return {}
-        return {
+            return {ENV_ENABLE: "0"}
+        env = {
             ENV_ENABLE: "1",
-            ENV_DIR: self.root,
             ENV_MIN_ENTRY: str(self.min_entry_bytes),
             ENV_MIN_COMPILE: str(self.min_compile_secs),
         }
-
-
-def _session_cache_dir() -> Optional[str]:
-    """Shared per-experiment cache dir of the live builtin tune trial
-    (tune/runner.py sets it so all same-shape trials warm-start from
-    trial 0's compiles), or None outside a trial."""
-    try:
-        from ray_lightning_tpu.tune.session import get_compile_cache_dir
-        return get_compile_cache_dir()
-    except Exception:
-        return None
-
-
-def namespace_dir(root: str) -> str:
-    """Topology-namespaced subdirectory of ``root``.
-
-    JAX's cache key already covers the program; the namespace keeps one
-    shared root safe across jax versions / device kinds / mesh sizes
-    (stale or foreign entries live in sibling dirs, never this one) and
-    makes ``du``-level hygiene possible per topology.
-    """
-    import jax
-    dev = jax.devices()[0]
-    kind = re.sub(r"[^A-Za-z0-9_.+-]+", "-",
-                  str(getattr(dev, "device_kind", dev.platform) or
-                      dev.platform))
-    name = (f"jax{jax.__version__}-{dev.platform}-{kind}"
-            f"-d{jax.device_count()}-p{jax.process_count()}")
-    return os.path.join(root, name)
+        if self.dir:
+            env[ENV_DIR] = self.dir
+        return env
 
 
 # -- activation -----------------------------------------------------------
@@ -186,46 +169,47 @@ _activate_lock = threading.Lock()
 
 
 def activate(config: CompileCacheConfig) -> Optional[str]:
-    """Point JAX's persistent compilation cache at the config's
-    namespaced directory (idempotent; re-activating with a different
-    root resets jax's cache handle so the switch takes effect — the
-    tune runner re-targets one process across experiments this way).
-    Returns the active namespaced dir, or None when disabled."""
+    """Turn JAX's persistent compilation cache on at ``config.root``
+    (idempotent).  ``root`` is ``JAX_COMPILATION_CACHE_DIR`` whenever
+    that is set, so the one ``jax_compilation_cache_dir`` update below
+    can only restate the variable, never override it.  Returns the
+    active directory, or None when disabled."""
     global _active_dir
     if config is None or not config.enabled:
         return None
     import jax
     with _activate_lock:
-        ns = namespace_dir(config.root)
-        os.makedirs(ns, exist_ok=True)
-        if _active_dir != ns:
+        target = config.root
+        os.makedirs(target, exist_ok=True)
+        if _active_dir != target:
             # unconditionally drop jax's memoized cache state: jax
             # latches "cache unused" at the first compile of a process,
             # so activating AFTER any compile has happened (tests, a
             # warmup jit, a prior experiment) would otherwise be ignored
             _reset_jax_cache()
             jax.config.update("jax_enable_compilation_cache", True)
-            jax.config.update("jax_compilation_cache_dir", ns)
-            _active_dir = ns
-            _log.info("persistent XLA compilation cache at %s", ns)
+            jax.config.update("jax_compilation_cache_dir", target)
+            _active_dir = target
+            _log.info("persistent XLA compilation cache at %s", target)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                           int(config.min_entry_bytes))
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(config.min_compile_secs))
         _install_listeners()
-        return ns
+        return target
 
 
 def deactivate() -> None:
-    """Restore jax's no-persistent-cache default (tests use this so one
-    module's cache dir never leaks into the next)."""
+    """Switch the persistent cache off again (tests use this so one
+    module's cache never leaks into the next).  The directory setting
+    is left alone — only :func:`activate` ever writes it."""
     global _active_dir
     with _activate_lock:
         if _active_dir is None:
             return
         import jax
         _reset_jax_cache()
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_enable_compilation_cache", False)
         _active_dir = None
 
 
@@ -235,12 +219,9 @@ def active_dir() -> Optional[str]:
 
 def _reset_jax_cache() -> None:
     """Drop jax's live cache handle so the next compile re-reads the
-    (changed) cache-dir config."""
-    try:
-        from jax._src import compilation_cache as _jcc
-        _jcc.reset_cache()
-    except Exception:   # pragma: no cover - jax internals moved
-        _log.debug("could not reset jax compilation cache", exc_info=True)
+    (changed) cache config."""
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 # -- accounting -----------------------------------------------------------
@@ -302,20 +283,14 @@ def _on_duration(event: str, duration: float, **_kw: Any) -> None:
 
 
 def _install_listeners() -> None:
-    """Register jax monitoring listeners once per process.  Monitoring
-    is a private-but-stable jax surface; failure degrades to zeroed
-    stats, never to a broken cache."""
+    """Register jax monitoring listeners once per process."""
     global _listeners_installed
     if _listeners_installed:
         return
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        _listeners_installed = True
-    except Exception:   # pragma: no cover - jax internals moved
-        _log.warning("jax monitoring unavailable; compile-cache hit/miss "
-                     "accounting disabled", exc_info=True)
+    from jax import monitoring
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    _listeners_installed = True
 
 
 def stats() -> CacheStats:
@@ -367,12 +342,12 @@ def note_first_step(seconds: float) -> None:
 
 __all__ = [
     "CompileCacheConfig",
-    "DEFAULT_ROOT",
+    "DEFAULT_DIR",
+    "ENV_JAX_DIR",
     "ENV_KNOBS",
     "activate",
     "deactivate",
     "active_dir",
-    "namespace_dir",
     "stats",
     "reset_stats",
     "status_word",

@@ -44,17 +44,24 @@ def run_selfcheck() -> list[str]:
             problems.append(str(e))
 
     # 2. env-knob round-trip: a config built from env reproduces itself
-    #    through worker_env (what the plugin ships to workers)
-    saved = {k: os.environ.get(k) for k in cache.ENV_KNOBS}
+    #    through worker_env (what the plugin ships to workers); the
+    #    default is ON at the fixed in-checkout directory; and a set
+    #    JAX_COMPILATION_CACHE_DIR outranks every knob without ever
+    #    being restated to workers (they inherit it)
+    names = cache.ENV_KNOBS + (cache.ENV_JAX_DIR,)
+    saved = {k: os.environ.get(k) for k in names}
     try:
-        for k in cache.ENV_KNOBS:
+        for k in names:
             os.environ.pop(k, None)
+        cfg0 = cache.CompileCacheConfig.resolve(None)
+        if not (cfg0.enabled and cfg0.root == cache.DEFAULT_DIR):
+            problems.append(f"default is not ON at DEFAULT_DIR: {cfg0}")
         os.environ[cache.ENV_ENABLE] = "1"
-        os.environ[cache.ENV_DIR] = "/tmp/rlt-selfcheck-cache"
+        os.environ[cache.ENV_DIR] = "/explicit/cache"
         os.environ[cache.ENV_MIN_ENTRY] = "1024"
         os.environ[cache.ENV_MIN_COMPILE] = "0.25"
         cfg = cache.CompileCacheConfig.resolve(None)
-        if not (cfg.enabled and cfg.root == "/tmp/rlt-selfcheck-cache"
+        if not (cfg.enabled and cfg.root == "/explicit/cache"
                 and cfg.min_entry_bytes == 1024
                 and cfg.min_compile_secs == 0.25):
             problems.append(f"env resolution broken: {cfg}")
@@ -66,9 +73,21 @@ def run_selfcheck() -> list[str]:
         if cfg2 != cfg:
             problems.append(
                 f"worker_env round-trip drifted: {cfg} -> {cfg2}")
+        os.environ[cache.ENV_JAX_DIR] = "/placed/from/outside"
+        if cfg2.root != "/placed/from/outside":
+            problems.append(
+                f"{cache.ENV_JAX_DIR} did not outrank {cache.ENV_DIR}")
+        if "/placed/from/outside" in cfg2.worker_env().values():
+            problems.append(
+                f"worker_env restates {cache.ENV_JAX_DIR}; workers "
+                f"inherit it")
         os.environ[cache.ENV_ENABLE] = "0"
-        if cache.CompileCacheConfig.resolve(None).enabled:
+        off = cache.CompileCacheConfig.resolve(None)
+        if off.enabled:
             problems.append(f"{cache.ENV_ENABLE}=0 failed to disable")
+        if off.worker_env().get(cache.ENV_ENABLE) != "0":
+            problems.append("an off config does not switch its workers "
+                            "off (the default is ON)")
     finally:
         for k, v in saved.items():
             if v is None:

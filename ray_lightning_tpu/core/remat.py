@@ -121,10 +121,8 @@ def saved_activation_bytes(fn, *args) -> int:
     block input — excluded: their residency is charged as state/batch
     elsewhere in the cost model).  ``args`` may be ShapeDtypeStructs;
     this only traces."""
-    try:
-        from jax.ad_checkpoint import saved_residuals
-    except ImportError:   # this jax ships it under _src only
-        from jax._src.ad_checkpoint import saved_residuals
+    # jax 0.9.0 exports only ``print_saved_residuals`` publicly
+    from jax._src.ad_checkpoint import saved_residuals
     return sum(_aval_bytes(aval) for aval, src in saved_residuals(
         fn, *args) if "argument" not in src)
 

@@ -132,7 +132,6 @@ def build_train_step(module, tx,
         reduction under shard_map (comm plane module docstring)."""
         from jax.sharding import PartitionSpec as P
 
-        from ray_lightning_tpu.parallel.mesh import shard_map_compat
 
         residual = grad_sync.residual_of(state.opt_state)
         comm_key = None
@@ -162,10 +161,10 @@ def build_train_step(module, tx,
         batch_specs = jax.tree_util.tree_map(
             lambda x: grad_sync.batch_spec(getattr(x, "ndim", 0)), batch)
         res_specs = grad_sync.residual_specs(residual)
-        mapped = shard_map_compat(
-            local_fn, grad_sync.mesh,
+        mapped = jax.shard_map(
+            local_fn, mesh=grad_sync.mesh,
             in_specs=(P(), P(), P(), P(), batch_specs, res_specs),
-            out_specs=(P(), P(), P(), P(), res_specs))
+            out_specs=(P(), P(), P(), P(), res_specs), check_vma=False)
         return mapped(state.params, state.model_state, step_rng,
                       comm_key, batch, residual)
 

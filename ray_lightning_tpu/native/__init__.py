@@ -7,16 +7,22 @@ and prefetches them on a background thread, so host batch assembly
 overlaps device compute instead of serializing with it.
 
 Build model: compiled on first use with the system ``g++`` into
-``_build/librlt_native.so`` (mtime-checked against the source, per-pid
-temp + atomic rename so concurrent worker processes race safely).  If no
-toolchain is available the library degrades to ``None`` and callers fall
-back to the pure-Python path — the same optional-dependency gating the
-framework applies to Ray and Tune (utils/imports.py).
+``_build/librlt_native-<digest>.so``, the digest taken over the source
+and the compiler flags — so a tree copied to another machine (where
+file times mean nothing) rebuilds exactly when the source differs, and
+the flags name no host CPU (no ``-march=native``), so an artefact that
+does travel with the tree still runs.  Per-pid temp + atomic rename
+lets concurrent worker processes race safely.  If no toolchain is
+available the library degrades to ``None`` and callers fall back to
+the pure-Python path (``native_available()`` says which ran) — the same
+optional-dependency gating the framework applies to Ray and Tune
+(utils/imports.py).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -30,24 +36,32 @@ _log = logging.getLogger(__name__)
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "prefetch.cpp")
 _BUILD_DIR = os.path.join(_HERE, "_build")
-_LIB = os.path.join(_BUILD_DIR, "librlt_native.so")
+_CXXFLAGS = ["-O3", "-shared", "-fPIC", "-pthread", "-std=c++17"]
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
 
 
-def _compile() -> bool:
+def _lib_path() -> str:
+    """The artefact for THIS source and these flags (module docstring)."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_BUILD_DIR,
+                        f"librlt_native-{h.hexdigest()[:16]}.so")
+
+
+def _compile(lib_path: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-pthread",
-           "-std=c++17", _SRC, "-o", tmp]
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_CXXFLAGS, _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     except (OSError, subprocess.SubprocessError) as e:
         _log.warning("native build failed (%s); using pure-Python path", e)
         return False
-    os.replace(tmp, _LIB)
+    os.replace(tmp, lib_path)
     return True
 
 
@@ -81,12 +95,11 @@ def load_library() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            fresh = (os.path.exists(_LIB) and
-                     os.path.getmtime(_LIB) >= os.path.getmtime(_SRC))
-            if not fresh and not _compile():
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path) and not _compile(lib_path):
                 _lib_failed = True
                 return None
-            _lib = _bind(ctypes.CDLL(_LIB))
+            _lib = _bind(ctypes.CDLL(lib_path))
         except OSError as e:
             _log.warning("native library unusable (%s)", e)
             _lib_failed = True

@@ -85,8 +85,7 @@ def sharded_flash_attention(q, k, v, *, mesh, causal: bool = True,
     collectives are needed — attention mixes only T and D, which stay
     unsharded here (sequence sharding is ring attention's job)."""
     from ray_lightning_tpu.ops.flash_attention import flash_attention
-    from ray_lightning_tpu.parallel.mesh import (data_and_tensor_axes,
-                                                 shard_map_compat)
+    from ray_lightning_tpu.parallel.mesh import data_and_tensor_axes
     from jax.sharding import PartitionSpec as P
 
     dp, tensor = data_and_tensor_axes(mesh)
@@ -96,8 +95,8 @@ def sharded_flash_attention(q, k, v, *, mesh, causal: bool = True,
         return flash_attention(ql, kl, vl, causal=causal, dtype=dtype,
                                **kw)
 
-    fn = shard_map_compat(inner, mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 
@@ -130,8 +129,10 @@ def cached_attention(q, k_cache, v_cache, positions, *,
     the length-aware Pallas kernel (ops/flash_decode.py) that reads only
     live KV blocks; ``paged`` additionally walks ``page_table``
     ([S, pages_per_slot] int32, serve/fleet/pages.py) so the fetch is
-    page-indirect.  Unsupported geometry falls back to dense — same
-    numbers, no surprise crash on odd head shapes.
+    page-indirect.  Under ``auto`` the choice follows platform and
+    geometry (``select_decode_kernel``); an explicit ``flash_decode`` /
+    ``paged`` that the geometry cannot lower raises instead of quietly
+    becoming the dense einsum.
 
     Multi-query form (speculative-decode verify, core/steps.py
     ``build_verify_step``): ``q`` [S, T, H, D] with ``positions``
@@ -144,8 +145,8 @@ def cached_attention(q, k_cache, v_cache, positions, *,
     decode, which is what makes greedy parity exact by construction.
     """
     from ray_lightning_tpu.ops.flash_decode import (
-        NEG_INF, decode_kernel_supported, flash_decode_attention,
-        resolve_decode_impl)
+        NEG_INF, flash_decode_attention, note_decode_kernel,
+        select_decode_kernel)
 
     if positions.ndim == 2:
         if q.shape[1] == 1:
@@ -157,21 +158,15 @@ def cached_attention(q, k_cache, v_cache, positions, *,
                                   page_table=page_table)
                  for j in range(q.shape[1])], axis=1)
 
-    impl = resolve_decode_impl(impl)
-    if impl == "paged" and page_table is None:
-        impl = "flash_decode"  # no table plumbed: slot-contiguous kernel
-    if impl in ("flash_decode", "paged"):
-        S, _, H, D = q.shape
-        L = k_cache.shape[1]
-        bk = (L // page_table.shape[1] if impl == "paged"
-              else None)
-        from ray_lightning_tpu.ops.flash_decode import _pick_block_k
-        if decode_kernel_supported(L, H, D,
-                                   block_k=bk or _pick_block_k(L),
-                                   dtype=q.dtype):
-            return flash_decode_attention(
-                q, k_cache, v_cache, positions, dtype=dtype,
-                page_table=page_table if impl == "paged" else None)
+    _, _, H, D = q.shape
+    kernel = select_decode_kernel(
+        k_cache.shape[1], H, D, dtype=q.dtype, impl=impl,
+        n_pages=None if page_table is None else page_table.shape[1])
+    note_decode_kernel(kernel)
+    if kernel != "dense":
+        return flash_decode_attention(
+            q, k_cache, v_cache, positions, dtype=dtype,
+            page_table=page_table if kernel == "paged" else None)
     d = q.shape[-1]
     scores = jnp.einsum("sqhd,slhd->shql", q, k_cache,
                         preferred_element_type=jnp.float32)

@@ -26,9 +26,13 @@ the win is not FLOPs, it is *bytes not read*.  This kernel:
   table.
 
 Heads are packed on the lane axis (``C = H*D``) and looped in-kernel
-with static column slices, mirroring the packed flash kernels.  On
-non-TPU backends everything runs under the Pallas interpreter so the
-tier-1 suite executes the real kernel on CPU.
+with static column slices, mirroring the packed flash kernels; per head
+the scores sit on the lane axis too (``[1, block_k]``), so both
+products are MXU matmuls.  On non-TPU backends everything runs under
+the Pallas interpreter so the tier-1 suite executes the real kernel
+body on CPU — which says nothing about what Mosaic accepts:
+tests/test_chip_compile.py compiles flat and paged, bf16 and f32, for a
+described v5e.
 
 Numerics: fp32 softmax statistics, ``NEG_INF = -1e30`` masking (NaN-free
 under exp, ops/flash_attention.py idiom), output in the caller's compute
@@ -38,8 +42,10 @@ dtype — parity with the dense einsum within the documented bf16 2e-2 bar
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -58,19 +64,83 @@ _KERNEL_NAME = "flash_decode_kernel"
 
 
 def resolve_decode_impl(value=None) -> str:
-    """Decode attention impl: explicit value > ``RLT_DECODE_IMPL`` env >
-    ``auto`` (TPU → flash_decode, like ``auto_attention``; elsewhere the
-    dense einsum stays the default so CPU serving is untouched unless a
-    caller opts in)."""
+    """The REQUESTED decode attention impl: explicit value >
+    ``RLT_DECODE_IMPL`` env > ``auto``.  ``auto`` is returned as such —
+    :func:`select_decode_kernel` turns it into a kernel from the
+    platform and the cache geometry."""
     v = (value or os.environ.get("RLT_DECODE_IMPL") or "auto").lower()
     if v not in VALID_DECODE_IMPLS:
         raise ValueError(
             f"RLT_DECODE_IMPL must be one of {VALID_DECODE_IMPLS}, "
             f"got {v!r}")
-    if v == "auto":
-        return ("flash_decode"
-                if jax.devices()[0].platform == "tpu" else "dense")
     return v
+
+
+def select_decode_kernel(L: int, H: int, D: int, *, dtype, impl=None,
+                         n_pages=None) -> str:
+    """The kernel :func:`~ray_lightning_tpu.ops.attention.cached_attention`
+    lowers for this cache geometry: ``dense``, ``flash_decode`` or
+    ``paged``.
+
+    ``auto`` follows what the code can observe: the Pallas kernel on TPU
+    when the geometry lowers (:func:`decode_kernel_supported`), the
+    dense einsum otherwise (CPU serving stays untouched unless a caller
+    opts in).  An EXPLICIT ``flash_decode``/``paged`` request that the
+    geometry cannot lower raises — it never silently becomes the dense
+    einsum.  ``paged`` without a page table (``n_pages=None``) is the
+    slot-contiguous kernel: same Pallas body, identity fetch."""
+    req = resolve_decode_impl(impl)
+    if req == "dense":
+        return "dense"
+    if req == "auto":
+        if jax.devices()[0].platform != "tpu":
+            return "dense"
+        want = "flash_decode"
+    else:
+        want = req
+    if want == "paged" and n_pages is None:
+        want = "flash_decode"
+    bk = L // n_pages if want == "paged" else _pick_block_k(L)
+    if decode_kernel_supported(L, H, D, block_k=bk, dtype=dtype):
+        return want
+    if req == "auto":
+        return "dense"
+    raise ValueError(
+        f"decode impl {req!r} was requested explicitly but the cache "
+        f"geometry L={L}, H={H}, D={D}, block_k={bk}, "
+        f"dtype={jnp.dtype(dtype).name} cannot lower on this platform "
+        f"(needs H*D % 128 == 0 and block_k a sublane multiple that "
+        f"tiles L); use impl='auto' or 'dense'")
+
+
+# -- which kernel a program actually lowered --------------------------------
+#
+# The serve engine reports the kernel its decode program LOWERED, not the
+# one requested: cached_attention notes its choice here while a program
+# traces, and the engine collects the notes around each trace
+# (serve/engine.py ``_counted``).  Thread-local because the AOT
+# precompiler traces on its own thread.
+
+_trace_notes = threading.local()
+
+
+@contextlib.contextmanager
+def record_decode_kernels():
+    """Collect the kernel names ``cached_attention`` lowers while
+    tracing on this thread inside the block (yields the set)."""
+    prev = getattr(_trace_notes, "seen", None)
+    seen: set = set()
+    _trace_notes.seen = seen
+    try:
+        yield seen
+    finally:
+        _trace_notes.seen = prev
+
+
+def note_decode_kernel(kernel: str) -> None:
+    seen = getattr(_trace_notes, "seen", None)
+    if seen is not None:
+        seen.add(kernel)
 
 
 def kv_block_bound(kb: int, pos, block_k: int):
@@ -121,29 +191,38 @@ def _decode_body(pos, kb, nk, logical_base,
 
     @pl.when(kb * block_k <= pos)
     def _compute():
-        rows = (jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+        # scores live on the LANE axis ([1, block_k]): both products are
+        # plain MXU matmuls (q·kᵀ, p·v) with fp32 accumulation.  Scores
+        # on the sublane axis (k·qᵀ -> [block_k, 1]) would be a matvec,
+        # which Mosaic lowers as a broadcast-multiply and refuses for
+        # bf16 operands with an fp32 result ('vector.broadcast'
+        # element-type verification) — tests/test_chip_compile.py
+        # compiles this body for a described v5e.
+        cols = (jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
                 + logical_base)
-        valid = rows <= pos
+        valid = cols <= pos
         for h in range(n_head):
             sl = slice(h * head_dim, (h + 1) * head_dim)
             q = q_ref[0, :, sl]                       # [1, D]
             k = k_ref[0, :, sl]                       # [block_k, D]
             v = v_ref[0, :, sl]                       # [block_k, D]
             s = jax.lax.dot_general(
-                k, q, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # [bk, 1]
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [1, bk]
             s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_ref[h, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s))
-            alpha = jnp.exp(m_prev - m_new)           # [1]
-            p = jnp.exp(s - m_new[0])                 # [bk, 1]
-            l_ref[h, :] = alpha[0] * l_ref[h, :]
-            l_ref[h, :1] = l_ref[h, :1] + jnp.sum(p)
+            m_prev = m_ref[h:h + 1, :1]                         # [1, 1]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)                     # [1, 1]
+            p = jnp.exp(s - m_new)                              # [1, bk]
+            l_ref[h:h + 1, :] = (alpha * l_ref[h:h + 1, :]
+                                 + jnp.sum(p, axis=1, keepdims=True))
             pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)   # [1, D]
-            acc_ref[h, :] = alpha[0] * acc_ref[h, :] + pv[0]
-            m_ref[h, :] = jnp.full_like(m_ref[h, :], m_new[0])
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)             # [1, D]
+            acc_ref[h:h + 1, :] = alpha * acc_ref[h:h + 1, :] + pv
+            m_ref[h:h + 1, :] = jnp.broadcast_to(
+                m_new, (1, m_ref.shape[1]))
 
     @pl.when(kb == nk - 1)
     def _final():
@@ -151,8 +230,8 @@ def _decode_body(pos, kb, nk, logical_base,
             sl = slice(h * head_dim, (h + 1) * head_dim)
             # l > 0 always: logical row 0 satisfies ``0 <= pos`` for any
             # non-negative position, so at least one key is live
-            o_ref[0, :, sl] = (acc_ref[h, :] / l_ref[h, 0])[None, :] \
-                .astype(o_ref.dtype)
+            o_ref[0, :, sl] = (acc_ref[h:h + 1, :]
+                               / l_ref[h:h + 1, :1]).astype(o_ref.dtype)
 
 
 def flash_decode_kernel(positions_ref, q_ref, k_ref, v_ref, o_ref,
@@ -257,7 +336,7 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *,
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, 1, C), dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*scalars, q2, k2, v2)
@@ -270,5 +349,8 @@ __all__ = [
     "decode_kernel_supported",
     "flash_decode_attention",
     "kv_block_bound",
+    "note_decode_kernel",
+    "record_decode_kernels",
     "resolve_decode_impl",
+    "select_decode_kernel",
 ]

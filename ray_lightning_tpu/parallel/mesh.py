@@ -71,26 +71,6 @@ def mesh_axis_size(mesh: Mesh, *names: str) -> int:
     return total
 
 
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across the jax API generations this image may carry:
-    the top-level ``jax.shard_map`` (``check_vma`` keyword) when present,
-    ``jax.experimental.shard_map.shard_map`` (``check_rep``) otherwise.
-    Replication checking is disabled either way — every body routed
-    through here performs manual collectives whose replication the
-    checker cannot see."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _esm
-    return _esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False)
-
-
 # The trainer publishes its mesh here so mesh-aware ops traced *inside*
 # its jitted step (ring attention's shard_map, parallel/ring.py) can
 # reach it without threading a handle through the flax module tree.

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ray_lightning_tpu.parallel.mesh import get_current_mesh, shard_map_compat
+from ray_lightning_tpu.parallel.mesh import get_current_mesh
 from ray_lightning_tpu.parallel.strategy import SpmdStrategy
 from ray_lightning_tpu.telemetry.metrics import note_traced_collective
 from ray_lightning_tpu.parallel.ring import _tensor_bytes
@@ -146,9 +146,8 @@ def pipeline_forward(stage_fn: Callable[[Any, jax.Array], jax.Array],
     inner = functools.partial(
         _pipeline_inner, stage_fn=stage_fn, axis_name=axis_name,
         n_microbatches=n_microbatches, n_stages=S)
-    fn = shard_map_compat(inner, mesh,
-                          in_specs=(param_specs, x_spec),
-                          out_specs=x_spec)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(param_specs, x_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(stacked_params, x)
 
 

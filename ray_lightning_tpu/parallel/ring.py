@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_lightning_tpu.parallel.mesh import get_current_mesh, shard_map_compat
+from ray_lightning_tpu.parallel.mesh import get_current_mesh
 from ray_lightning_tpu.telemetry.metrics import note_traced_collective
 
 NEG_INF = -1e30
@@ -156,6 +156,6 @@ def ring_attention(q, k, v, *, causal: bool = True, dtype=jnp.bfloat16,
     inner = functools.partial(_ring_inner, axis_name=axis_name,
                               causal=causal, scale=scale, dtype=dtype,
                               ring_size=ring)
-    fn = shard_map_compat(inner, mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

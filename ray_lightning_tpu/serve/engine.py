@@ -80,11 +80,13 @@ class ServeEngine:
         #: flag (not default-on) so non-fleet engines keep their exact
         #: pre-existing program count
         self.kvship = bool(kvship)
-        #: which decode attention kernel the compiled program uses —
-        #: dense | flash_decode | paged (resolved at setup from
-        #: RLT_DECODE_IMPL, ops/flash_decode.py); benches emit it so a
-        #: kernel regression is visible in the JSON ledger
-        self.decode_kernel = "dense"
+        #: which decode attention kernel the decode program LOWERED —
+        #: dense | flash_decode | paged, as cached_attention noted it
+        #: while the program traced (ops/flash_decode.py
+        #: record_decode_kernels), not the RLT_DECODE_IMPL request;
+        #: benches emit it so a kernel regression is visible in the
+        #: JSON ledger.  None until the decode program has traced.
+        self.decode_kernel: Optional[str] = None
         self.trace_counts: dict[str, int] = {}
         self.kv_spec: Optional[KVCacheSpec] = None
         self.params = None
@@ -208,25 +210,21 @@ class ServeEngine:
             self._prefills[b] = jit_step(
                 f"prefill_{b}", build_prefill_step(module, b), 3)
 
-        # decode kernel selection (ops/flash_decode.py): the paged
-        # kernel needs a page table whose pages tile the cache; when
-        # paging is off or ragged, "paged" degrades to the
-        # slot-contiguous flash kernel rather than failing setup
+        # the paged kernel needs a page table whose pages tile the
+        # cache; with paging off or ragged no table is plumbed and
+        # "paged" lowers the slot-contiguous flash kernel instead
+        # (ops/flash_decode.py select_decode_kernel)
         from ray_lightning_tpu.ops.flash_decode import resolve_decode_impl
-        impl = resolve_decode_impl(None)
         page_table = suffix_table = None
-        if impl == "paged":
-            if self.paged is not None \
-                    and self.max_seq_len % self.paged.page_size == 0:
-                from ray_lightning_tpu.serve.fleet.pages import (
-                    identity_page_table)
-                page_table = identity_page_table(
-                    self.slots, self.max_seq_len, self.paged.page_size)
-                suffix_table = identity_page_table(
-                    1, self.max_seq_len, self.paged.page_size)
-            else:
-                impl = "flash_decode"
-        self.decode_kernel = impl
+        if resolve_decode_impl(None) == "paged" \
+                and self.paged is not None \
+                and self.max_seq_len % self.paged.page_size == 0:
+            from ray_lightning_tpu.serve.fleet.pages import (
+                identity_page_table)
+            page_table = identity_page_table(
+                self.slots, self.max_seq_len, self.paged.page_size)
+            suffix_table = identity_page_table(
+                1, self.max_seq_len, self.paged.page_size)
         self._decode = jit_step(
             "decode", build_decode_step(module, page_table=page_table), 2)
         if self.paged is not None:
@@ -482,12 +480,21 @@ class ServeEngine:
         """Wrap a step body so every TRACE bumps a host counter (the
         wrapper body only runs while jax traces; cached dispatches never
         re-enter Python)."""
+        from ray_lightning_tpu.ops.flash_decode import (
+            record_decode_kernels)
+
         def wrapped(*args):
             self.trace_counts[name] = self.trace_counts.get(name, 0) + 1
             reg = _metrics.get_registry()
             if reg is not None:
                 reg.counter("rlt_serve_traces_total").inc(1, program=name)
-            return fn(*args)
+            if name != "decode":
+                return fn(*args)
+            with record_decode_kernels() as lowered:
+                out = fn(*args)
+            # one kernel per program: every layer has the same geometry
+            self.decode_kernel = "+".join(sorted(lowered)) or None
+            return out
         return wrapped
 
     # -- serving -----------------------------------------------------------
@@ -647,9 +654,16 @@ class ServeEngine:
         """Trace counters + compile-cache counters: the zero-retrace /
         compiled-once evidence surfaced to the driver."""
         from ray_lightning_tpu.compile import cache as compile_cache
+        import jax
         s = compile_cache.stats()
         warm = getattr(self, "trace_counts_at_warmup", {})
+        dev = jax.local_devices()[0]
         out = {
+            # the device THIS process holds, as jax reports it — a
+            # serve record names what it ran on
+            "device": {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": jax.device_count()},
+            "memory_stats": dev.memory_stats(),
             "decode_kernel": self.decode_kernel,
             "traces": dict(self.trace_counts),
             # traces since the warmup snapshot: 0 everywhere = the
@@ -667,6 +681,7 @@ class ServeEngine:
             + len(self._kv_imports),
             "compile_cache": {
                 "active": compile_cache.active_dir() is not None,
+                "dir": compile_cache.active_dir(),
                 "hits": s.hits,
                 "misses": s.misses,
                 "backend_compile_secs": round(s.backend_compile_secs, 3),

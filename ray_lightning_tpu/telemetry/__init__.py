@@ -213,7 +213,8 @@ class TelemetryConfig:
     #: enabled unless RLT_GOODPUT=0 disarms; an explicit bool wins
     goodput: Optional[bool] = None
     #: per-device peak TFLOPs for the MFU denominator; None defers to
-    #: RLT_GOODPUT_TFLOPS, then PlanConfig.device_tflops
+    #: RLT_GOODPUT_TFLOPS, then the device kind's published peak
+    #: (telemetry/goodput.py DEVICE_PEAKS; unknown kind: no MFU)
     goodput_tflops: Optional[float] = None
 
     @classmethod
@@ -307,8 +308,9 @@ class TelemetryConfig:
 
     def resolved_goodput_tflops(self) -> Optional[float]:
         """Per-device peak TFLOPs for MFU: the explicit config field,
-        else ``RLT_GOODPUT_TFLOPS``, else None (the trainer falls back
-        to ``PlanConfig.device_tflops``)."""
+        else ``RLT_GOODPUT_TFLOPS``, else None (the trainer then looks
+        the device kind up in ``goodput.DEVICE_PEAKS``; an unknown kind
+        prices no MFU)."""
         if self.goodput_tflops is not None:
             return float(self.goodput_tflops)
         from ray_lightning_tpu.telemetry import goodput as _goodput

@@ -47,7 +47,8 @@ MFU pairs with the partition: ``flops_per_step`` (the
 ``LightningModule.flops_per_step()`` hook, or the default pricing of
 the train-step jaxpr via the PR 12 dot-counting machinery) divided by
 the measured mean step wall × ``devices`` × ``device_tflops``
-(``PlanConfig.device_tflops`` / ``RLT_GOODPUT_TFLOPS``).
+(``RLT_GOODPUT_TFLOPS``, else the published peak of the device kind
+in :data:`DEVICE_PEAKS`; a kind with no row prices no MFU).
 
 Like every plane here: disabled is the default, entry points are
 one-global-check no-ops, and nothing heavy imports at module load.
@@ -69,8 +70,30 @@ _log = logging.getLogger(__name__)
 #: arm/disarm: goodput is on whenever telemetry is on unless this is 0
 GOODPUT_ENV = "RLT_GOODPUT"
 #: per-device peak TFLOPs override for the MFU denominator (defaults
-#: to PlanConfig.device_tflops / RLT_PLAN_TFLOPS)
+#: to the :data:`DEVICE_PEAKS` entry of the device the fit runs on)
 GOODPUT_TFLOPS_ENV = "RLT_GOODPUT_TFLOPS"
+
+#: published per-chip peaks, keyed by jax's ``device_kind``.  MFU is
+#: priced from here and from nowhere else: a device that is not in the
+#: table has no default.  Source: Google Cloud documentation, "TPU v5e"
+#: (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s per chip).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"tflops_bf16": 197.0, "hbm_gbps": 819.0,
+                    "hbm_gb": 16.0},
+}
+
+
+def device_peak(device_kind: str) -> dict:
+    """The :data:`DEVICE_PEAKS` row of ``device_kind``; an unknown kind
+    is an error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak on record for device kind "
+            f"{device_kind!r} (known: {sorted(DEVICE_PEAKS)}); add its "
+            f"row to telemetry/goodput.py DEVICE_PEAKS with the source"
+        ) from None
 
 #: the partition, per run kind: disjoint, exhaustive (``other`` is the
 #: residual), pinned by telemetry/selfcheck.py

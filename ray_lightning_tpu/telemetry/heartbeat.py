@@ -6,7 +6,7 @@ Two start sites share this one sender:
 
 - ``worker_main`` (built-in backend) starts a process-level sender the
   moment the actor connects — before jax ever imports — so a worker
-  that wedges during backend/tunnel init is already visible to the
+  that wedges during backend (libtpu) init is already visible to the
   watchdog.  Gated by ``RLT_TELEMETRY=1`` in the worker env.
 - ``plugins/xla._worker_run`` starts one under backends with no
   process-level sender (real Ray actors), after the queue proxy exists.

@@ -323,9 +323,9 @@ class MetricsRegistry:
     # -- device sampling -------------------------------------------------
 
     def sample_device_state(self) -> None:
-        """Current/peak HBM per local device.  Profiler-less backends
-        (virtual CPU devices, some tunnels) report 0 — the gauges still
-        exist, so dashboards don't break per platform."""
+        """Current/peak HBM per local device.  Backends whose
+        ``memory_stats()`` is None (virtual CPU devices) report 0 — the
+        gauges still exist, so dashboards don't break per platform."""
         cur = self.gauge("rlt_hbm_bytes")
         peak = self.gauge("rlt_hbm_peak_bytes")
         try:

@@ -246,11 +246,12 @@ def run(
     — the thread-local trial session scopes both the output dir and
     the active driver-side aggregator per trial.
 
-    Compilation: all trials share one persistent XLA compilation cache
-    under ``<exp_dir>/compile_cache`` (``RLT_COMPILE_CACHE=0`` opts
-    out), so same-shape trials after the first — and crash-retried
-    trials under ``max_failures`` — warm-start instead of re-paying
-    XLA compilation (compile/cache.py).
+    Compilation: trials use the one persistent XLA compilation cache
+    every Trainer uses (compile/cache.py: ``JAX_COMPILATION_CACHE_DIR``
+    or the fixed in-checkout directory; ``RLT_COMPILE_CACHE=0`` opts
+    out), so same-shape trials after the first — crash-retried trials
+    and LATER experiments included — warm-start instead of re-paying
+    XLA compilation.
 
     Device isolation: when ``resources_per_trial`` declares a TPU chip
     count (``get_tune_resources(...)`` bundles or ``{"TPU": n}``), the
@@ -300,19 +301,6 @@ def run(
     leaser = _DeviceLeaser(demand) if demand is not None else None
     sem = threading.Semaphore(max(1, max_concurrent_trials))
 
-    # one persistent compilation cache for the WHOLE experiment: trials
-    # of a sweep dispatch byte-identical SPMD programs per shape, so
-    # trial 0 pays each compile once and trial N>0 (and every
-    # max_failures restart) loads the executable from disk instead of
-    # re-paying XLA — multiplied by num_samples, the dominant startup
-    # cost of exactly this workload.  RLT_COMPILE_CACHE=0 opts out;
-    # an explicit RLT_COMPILE_CACHE_DIR (a cross-experiment root)
-    # outranks this per-experiment dir at config resolution
-    # (compile/cache.py precedence).
-    compile_cache_dir = (
-        None if os.environ.get("RLT_COMPILE_CACHE", "").strip() == "0"
-        else os.path.join(exp_dir, "compile_cache"))
-
     def on_report(trial: Trial, metrics: dict) -> None:
         trial.last_result = dict(metrics)
         trial.history.append(dict(metrics))
@@ -336,8 +324,7 @@ def run(
             if abort.is_set():
                 return  # fail_fast tripped; leave trial PENDING
             trial.status = "RUNNING"
-            session = TrialSession(trial, on_report, device_leaser=leaser,
-                                   compile_cache_dir=compile_cache_dir)
+            session = TrialSession(trial, on_report, device_leaser=leaser)
             set_session(session)
             restore_from: Optional[str] = None
             failures = 0

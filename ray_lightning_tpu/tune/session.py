@@ -31,19 +31,12 @@ class TrialSession:
     visible device.
     """
 
-    def __init__(self, trial, on_report, device_leaser=None,
-                 compile_cache_dir=None):
+    def __init__(self, trial, on_report, device_leaser=None):
         self.trial = trial
         self._on_report = on_report
         self._step = 0
         self._leaser = device_leaser
         self.devices = None
-        #: the experiment's SHARED persistent-compilation-cache dir
-        #: (tune/runner.py): every same-shape trial, and every
-        #: max_failures restart of this trial, warm-starts from the
-        #: programs earlier trials already compiled (compile/cache.py
-        #: resolves it when the trial's Trainer is constructed)
-        self.compile_cache_dir = compile_cache_dir
 
     def acquire_devices(self):
         if self._leaser is not None and self.devices is None:
@@ -170,15 +163,6 @@ def get_trial_id() -> str:
 def get_trial_dir() -> Optional[str]:
     s = _get()
     return s.trial.logdir if s else None
-
-
-def get_compile_cache_dir() -> Optional[str]:
-    """The experiment-wide shared compilation-cache dir, or None outside
-    a builtin tune trial (or when the runner disabled sharing).
-    ``CompileCacheConfig.resolve`` consults this so a Trainer built
-    inside a trial points at the experiment's cache by default."""
-    s = _get()
-    return getattr(s, "compile_cache_dir", None) if s is not None else None
 
 
 def get_trial():

@@ -128,7 +128,9 @@ class ShardedCheckpointer:
             return None
         try:
             md = self._mgr.item_metadata(int(step))
-            return getattr(md, "state", None)
+            # orbax 0.11.32: the item's metadata is a TreeMetadata
+            # whose ``tree`` is the nested dict
+            return md.state.tree
         except Exception:
             return None
 

@@ -76,7 +76,8 @@ def partition_env(
         "TPU_CHIPS_PER_PROCESS_BOUNDS": cpb,
         "TPU_PROCESS_BOUNDS": pb,
         "TPU_VISIBLE_CHIPS": chips,
-        "TPU_VISIBLE_DEVICES": chips,  # older libtpu spelling
+        # libtpu 0.0.34 lists this name beside TPU_VISIBLE_CHIPS
+        "TPU_VISIBLE_DEVICES": chips,
         "TPU_PROCESS_ADDRESSES": ",".join(
             f"{node_ip}:{p}" for p in ports),
         "TPU_PROCESS_PORT": str(ports[local_rank]),

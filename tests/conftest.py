@@ -17,6 +17,16 @@ if not _REAL_HW and "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
+# The persistent compilation cache is ON by default and lives in the
+# checkout (compile/cache.py).  The CPU test session keeps it off — and
+# forgets a directory the environment names — so tier-1 is hermetic: no
+# run warms the next, no entry lands outside tmp_path.  Tests of the
+# cache pass ``compile_cache=`` explicitly or set the variables
+# themselves; worker subprocesses inherit this environment.
+if not _REAL_HW:
+    os.environ.setdefault("RLT_COMPILE_CACHE", "0")
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+
 import jax  # noqa: E402
 
 if not _REAL_HW:

@@ -1,0 +1,319 @@
+"""What can be checked about the chip without one.
+
+1. Compile-for-the-chip: the Pallas kernels of the main paths are
+   compiled at gpt2-small widths for a DESCRIBED v5e (the TPU compiler is
+   installed here; the chip is not attached).  Interpret mode cannot see
+   what Mosaic refuses — the decode kernel passed every interpret-mode
+   test while Mosaic rejected it for bf16 caches.  Nothing runs, so
+   these say nothing about results or times; a compile that passes is
+   not a chip run.  Skipped where the topology cannot be described.
+2. The decisions around the chip that are plain Python: which decode
+   kernel lowers, which process may start workers, which peak prices
+   MFU, which native artefact loads.
+3. ``chip_smoke.py`` itself: it must fail fast without an accelerator,
+   its phases are rehearsed at ``tiny`` on the CPU by patching its
+   constants from here (the program has no option for that), and its
+   parent must stay off JAX.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs stay out of /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# gpt2-small serve/train geometry
+H, D, L, S, T, B = 12, 64, 1024, 8, 1024, 8
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described (not attached) v5e device, with the persistent
+    compilation cache off around the compiles: a described-device entry
+    cannot be read back without a chip, and the next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiles_with_kernel(fn, *args) -> None:
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_flash_attention_compiles_for_v5e(v5e, grad):
+    from ray_lightning_tpu.ops.flash_attention import flash_attention
+    x = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=v5e)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    _compiles_with_kernel(bwd if grad else fwd, x, x, x)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["flat", "paged"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_flash_decode_compiles_for_v5e(v5e, dtype, paged):
+    from ray_lightning_tpu.ops.flash_decode import flash_decode_attention
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    args = [sds((S, 1, H, D), dtype), sds((S, L, H, D), dtype),
+            sds((S, L, H, D), dtype), sds((S,), jnp.int32)]
+    if paged:
+        args.append(sds((S, L // 128), jnp.int32))
+
+    def decode(q, k, v, pos, table=None):
+        return flash_decode_attention(q, k, v, pos, dtype=dtype,
+                                      page_table=table, interpret=False)
+
+    _compiles_with_kernel(decode, *args)
+
+
+# -- which decode kernel lowers ---------------------------------------------
+
+@pytest.mark.parametrize("impl,n_pages,want", [
+    ("dense", None, "dense"),
+    ("auto", None, "dense"),            # CPU: auto never opts in
+    ("flash_decode", None, "flash_decode"),
+    ("paged", 8, "paged"),
+    ("paged", None, "flash_decode"),    # no table plumbed: same kernel
+])
+def test_select_decode_kernel(monkeypatch, impl, n_pages, want):
+    from ray_lightning_tpu.ops.flash_decode import select_decode_kernel
+    monkeypatch.delenv("RLT_DECODE_IMPL", raising=False)
+    assert select_decode_kernel(L, H, D, dtype=jnp.bfloat16, impl=impl,
+                                n_pages=n_pages) == want
+
+
+@pytest.mark.parametrize("impl", ["flash_decode", "paged"])
+def test_explicit_decode_kernel_never_falls_back(monkeypatch, impl):
+    """A geometry the kernel cannot lower on the chip (H*D not a lane
+    multiple): ``auto`` follows the shape to dense, an EXPLICIT request
+    raises instead of quietly lowering the dense einsum."""
+    from ray_lightning_tpu.ops import flash_decode as fd
+    from ray_lightning_tpu.ops.attention import cached_attention
+    monkeypatch.setattr(fd, "_use_interpret", lambda: False)  # as on TPU
+    q = jnp.zeros((2, 1, 3, 24), jnp.bfloat16)
+    kv = jnp.zeros((2, 128, 3, 24), jnp.bfloat16)
+    pos = jnp.zeros((2,), jnp.int32)
+    table = jnp.zeros((2, 8), jnp.int32)
+    with pytest.raises(ValueError, match="requested explicitly"):
+        cached_attention(q, kv, kv, pos, impl=impl, page_table=table)
+    with fd.record_decode_kernels() as lowered:
+        cached_attention(q, kv, kv, pos, impl="dense")
+    assert lowered == {"dense"}
+
+
+# -- one process for each chip ----------------------------------------------
+
+class _FakeDevice:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+
+def test_driver_holding_the_chip_cannot_start_workers(monkeypatch):
+    """Train-then-serve in one script: the driver that already holds the
+    accelerator fails AT ONCE with the reason and the remedy — it does
+    not wait out the serve setup timeout."""
+    from ray_lightning_tpu.models.gpt import GPTLightningModule
+    from ray_lightning_tpu.serve import Server
+    from ray_lightning_tpu.utils import platform as plat
+
+    plat.require_chip_free("x", "y")        # a CPU driver passes
+    jax.devices()                           # this process holds a backend
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeDevice()])
+    with pytest.raises(RuntimeError, match="one process at a time") as e:
+        Server(GPTLightningModule("tiny"), use_tpu=True).start()
+    assert "checkpoint" in str(e.value) and "fresh process" in str(e.value)
+
+
+def test_actor_plugin_refuses_a_driver_holding_the_chip(monkeypatch):
+    from ray_lightning_tpu import RayXlaPlugin, Trainer
+    from ray_lightning_tpu.models import BoringModel
+    jax.devices()
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeDevice()])
+    trainer = Trainer(plugins=[RayXlaPlugin(num_workers=2, use_tpu=True)],
+                      max_steps=1, enable_checkpointing=False)
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        trainer.fit(BoringModel())
+
+
+# -- peaks, native artefact --------------------------------------------------
+
+def test_device_peak_unknown_kind_is_an_error():
+    from ray_lightning_tpu.telemetry.goodput import device_peak
+    v5e_peak = device_peak("TPU v5 lite")
+    assert (v5e_peak["tflops_bf16"], v5e_peak["hbm_gbps"],
+            v5e_peak["hbm_gb"]) == (197.0, 819.0, 16.0)
+    with pytest.raises(ValueError, match="no published peak"):
+        device_peak("cpu")
+
+
+def test_native_artefact_is_keyed_by_source_not_mtime(monkeypatch, tmp_path):
+    """A tree copied to another machine rebuilds exactly when the source
+    (or the flags) differ; the flags name no host CPU."""
+    from ray_lightning_tpu import native
+    assert "-march=native" not in native._CXXFLAGS
+    here = native._lib_path()
+    src = tmp_path / "prefetch.cpp"
+    src.write_bytes(open(native._SRC, "rb").read())
+    monkeypatch.setattr(native, "_SRC", str(src))
+    assert native._lib_path() == here        # same bytes, other mtime
+    src.write_bytes(src.read_bytes() + b"\n// changed\n")
+    assert native._lib_path() != here
+
+
+# -- chip_smoke.py -----------------------------------------------------------
+
+def _load_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_fails_fast_without_an_accelerator(tmp_path):
+    """The script as the driver runs it, on the CPU: a quick non-zero
+    exit and no result line."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run([sys.executable, os.path.join(REPO,
+                                                       "chip_smoke.py")],
+                         capture_output=True, text=True, timeout=120,
+                         env=env, cwd=REPO)
+    assert out.returncode != 0, out.stdout
+    assert '"ok": true' not in out.stdout
+    assert "no tpu device" in out.stdout.lower()
+
+
+def test_chip_smoke_parent_stays_off_jax_and_fails_with_its_phases(tmp_path):
+    """The parent, in a clean interpreter with its children faked: it
+    prints the result line only when every phase passed and reported the
+    accelerator, and it never imports jax (so it cannot hold the chip)."""
+    driver = tmp_path / "drive.py"
+    driver.write_text(f"""
+import json, sys
+sys.path.insert(0, {REPO!r})
+import chip_smoke as cs
+dev = {{"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+fail = sys.argv[1]
+def fake(name, workdir):
+    if name == fail:
+        return 1, {{"phase": name, "ok": False, "error": "boom"}}
+    return 0, {{"phase": name, "ok": True,
+               "device": dict(dev, platform="cpu") if fail == "cpu" else dev}}
+cs.run_child = fake
+rc = cs.main([])
+assert "jax" not in sys.modules, "the parent imported jax"
+sys.exit(rc)
+""")
+    def run(fail):
+        return subprocess.run([sys.executable, str(driver), fail],
+                              capture_output=True, text=True, timeout=60,
+                              cwd=str(tmp_path))
+    good = run("none")
+    assert good.returncode == 0, good.stdout + good.stderr
+    assert json.loads(good.stdout.strip().splitlines()[-1]) == {
+        "ok": True, "device": {"platform": "tpu", "kind": "TPU v5 lite",
+                               "count": 1}}
+    for fail in ("train", "serve", "cpu"):
+        bad = run(fail)
+        assert bad.returncode != 0, (fail, bad.stdout)
+        assert '"ok": true' not in bad.stdout, (fail, bad.stdout)
+
+
+def test_chip_smoke_phases_rehearsed_at_tiny(monkeypatch, tmp_path):
+    """Both one-chip phases, end to end, on the CPU at ``tiny``: one
+    fit, its checkpoint handed to two servers, the token comparison —
+    and each refusal the script owes: no accelerator, a requested
+    kernel that was not the one lowered, tokens that differ."""
+    cs = _load_smoke()
+    monkeypatch.setattr(cs, "MODEL", "tiny")
+    monkeypatch.setattr(cs, "TRAIN_STEPS", 8)
+    monkeypatch.setattr(cs, "PROMPT_LENS", (5, 40))
+    monkeypatch.setattr(cs, "NEW_TOKENS", 6)
+    monkeypatch.setattr(cs, "SLOTS", 2)
+    monkeypatch.setattr(cs, "SERVER_KW", {"platform": "cpu"})
+    monkeypatch.setattr(cs, "DECODE_ENV",
+                        {"RLT_DECODE_IMPL": "flash_decode"})
+    # the expensive parts run once (keyed by their name argument); the
+    # refusals replay them
+    def once(real):
+        memo: dict = {}
+
+        def call(workdir, name, *a, **k):
+            if name not in memo:
+                memo[name] = real(workdir, name, *a, **k)
+            return memo[name]
+        return call
+
+    for fn in ("_fit", "_serve_once"):
+        monkeypatch.setattr(cs, fn, once(getattr(cs, fn)))
+    work = str(tmp_path)
+
+    # the process that should hold the chip sees the CPU
+    with pytest.raises(cs.NoAccelerator):
+        cs.phase_train(work)
+    monkeypatch.setattr(cs, "PLATFORM", "cpu")
+    from ray_lightning_tpu.telemetry import goodput
+    monkeypatch.setitem(goodput.DEVICE_PEAKS, "cpu",
+                        {"tflops_bf16": 1.0, "hbm_gbps": 1.0, "hbm_gb": 1.0})
+    # the flash kernel was asked for and is not in the program (no
+    # Mosaic on the CPU)
+    with pytest.raises(cs.SmokeFailure, match="tpu_custom_call"):
+        cs.phase_train(work)
+    monkeypatch.setattr(cs, "KERNEL_MARKER", "fusion")
+    assert cs.run_phase("train", work) == 0
+    train = json.load(open(os.path.join(work, "train.json")))
+    assert train["ok"] and len(train["losses"]) == 8
+    assert os.path.exists(train["checkpoint"])
+
+    assert cs.run_phase("serve", work) == 0
+    serve = json.load(open(os.path.join(work, "serve.json")))
+    assert serve["ok"] and serve["tokens_equal_dense"]
+    assert serve["decode_kernel"] == "flash_decode"
+    assert serve["retraces_after_warmup"] == 0
+    assert serve["device"]["platform"] == "cpu"
+
+    # the decode kernel asked for was not the one lowered
+    monkeypatch.setattr(cs, "DECODE_KERNEL", "paged")
+    with pytest.raises(cs.SmokeFailure, match="lowered 'flash_decode'"):
+        cs.phase_serve(work)
+    monkeypatch.setattr(cs, "DECODE_KERNEL", "flash_decode")
+    # tokens that differ from the dense engine's
+    outs, stats = cs._serve_once(work, "serve_dense", None, {})
+    monkeypatch.setattr(
+        cs, "_serve_once", lambda w, name, *a, _f=cs._serve_once:
+        ([[t + 1 for t in o] for o in outs], stats)
+        if name == "serve_dense" else _f(w, name, *a))
+    with pytest.raises(cs.SmokeFailure, match="tokens differ"):
+        cs.phase_serve(work)

@@ -26,7 +26,6 @@ from ray_lightning_tpu.comm import (
 )
 from ray_lightning_tpu.comm.quant import payload_bytes
 from ray_lightning_tpu.models import BoringModel
-from ray_lightning_tpu.parallel.mesh import shard_map_compat
 from ray_lightning_tpu.parallel.strategy import resolve_strategy
 
 from tests.utils import get_trainer
@@ -159,8 +158,8 @@ def test_compressed_psum_matches_mean(mode, seed):
         return compressed_psum(xl[0], "data", WORLD, mode=mode,
                                mean=True)[None]
 
-    fn = shard_map_compat(body, mesh, in_specs=P("data"),
-                          out_specs=P("data"))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                       out_specs=P("data"), check_vma=False)
     xg = jax.device_put(x, NamedSharding(mesh, P("data")))
     out = np.asarray(jax.jit(fn)(xg))
     ref = x.mean(0)
@@ -187,8 +186,9 @@ def test_hierarchical_psum_matches_mean(mode, seed):
                                      mean=True, with_error=True)
         return res[None], err[None]
 
-    fn = shard_map_compat(body, mesh, in_specs=P("data"),
-                          out_specs=(P("data"), P("data")))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                       out_specs=(P("data"), P("data")),
+                       check_vma=False)
     xg = jax.device_put(x, NamedSharding(mesh, P("data")))
     out, err = jax.jit(fn)(xg)
     out, err = np.asarray(out), np.asarray(err)
@@ -218,8 +218,9 @@ def test_compressed_psum_error_feedback_term(seed):
                                    mean=True, with_error=True)
         return res[None], err[None]
 
-    fn = shard_map_compat(body, mesh, in_specs=P("data"),
-                          out_specs=(P("data"), P("data")))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                       out_specs=(P("data"), P("data")),
+                       check_vma=False)
     xg = jax.device_put(x, NamedSharding(mesh, P("data")))
     _, err = jax.jit(fn)(xg)
     err = np.asarray(err)

@@ -44,20 +44,29 @@ def _isolate_cache_state():
 # ---------------------------------------------------------------------------
 
 def _clear_env(monkeypatch):
-    for k in cc.ENV_KNOBS:
+    for k in cc.ENV_KNOBS + (cc.ENV_JAX_DIR,):
         monkeypatch.delenv(k, raising=False)
 
 
-def test_config_default_disabled(monkeypatch):
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_config_default_on_at_fixed_checkout_dir(monkeypatch):
+    """Unset environment: the cache is ON at ONE fixed path inside the
+    checkout — not under $HOME, not a temp, pid or time-stamped name."""
     _clear_env(monkeypatch)
-    assert not cc.CompileCacheConfig.resolve(None).enabled
+    cfg = cc.CompileCacheConfig.resolve(None)
+    assert cfg.enabled
+    assert cfg.root == cc.DEFAULT_DIR == os.path.join(_REPO_ROOT,
+                                                      ".jax_cache")
+    assert cc.CompileCacheConfig.resolve(None).root == cfg.root
 
 
 def test_config_env_enable_forms(monkeypatch, tmp_path):
     _clear_env(monkeypatch)
     monkeypatch.setenv(cc.ENV_ENABLE, "1")
     cfg = cc.CompileCacheConfig.resolve(None)
-    assert cfg.enabled and cfg.root == cc.DEFAULT_ROOT
+    assert cfg.enabled and cfg.root == cc.DEFAULT_DIR
 
     monkeypatch.setenv(cc.ENV_ENABLE, str(tmp_path / "root"))
     cfg = cc.CompileCacheConfig.resolve(None)
@@ -99,17 +108,43 @@ def test_worker_env_round_trip(monkeypatch, tmp_path):
     for k, v in cfg.worker_env().items():
         monkeypatch.setenv(k, v)
     assert cc.CompileCacheConfig.resolve(None) == cfg
-    assert cc.CompileCacheConfig(enabled=False).worker_env() == {}
+    # the default is ON, so an off driver must say so to its workers
+    _clear_env(monkeypatch)
+    for k, v in cc.CompileCacheConfig(enabled=False).worker_env().items():
+        monkeypatch.setenv(k, v)
+    assert not cc.CompileCacheConfig.resolve(None).enabled
 
 
-def test_namespace_dir_components(tmp_path):
-    ns = cc.namespace_dir(str(tmp_path))
-    base = os.path.basename(ns)
-    assert os.path.dirname(ns) == str(tmp_path)
-    assert jax.__version__ in base
-    assert f"-d{jax.device_count()}-p{jax.process_count()}" in base
-    # path-safe: nothing but the sanctioned characters
-    assert "/" not in base and " " not in base
+@pytest.mark.parametrize("how", ["arg", "rlt_dir", "rlt_enable_path",
+                                 "default"])
+def test_jax_env_dir_outranks_everything(monkeypatch, tmp_path, how):
+    """``JAX_COMPILATION_CACHE_DIR`` set: the cache lives THERE — no
+    argument, knob or default re-points it, the worker env never
+    restates it (workers inherit the variable), and activation hands
+    jax exactly that directory."""
+    _clear_env(monkeypatch)
+    outside = str(tmp_path / "placed_from_outside")
+    other = str(tmp_path / "other")
+    monkeypatch.setenv(cc.ENV_JAX_DIR, outside)
+    if how == "arg":
+        cfg = cc.CompileCacheConfig.resolve(other)
+    elif how == "rlt_dir":
+        monkeypatch.setenv(cc.ENV_DIR, other)
+        cfg = cc.CompileCacheConfig.resolve(None)
+    elif how == "rlt_enable_path":
+        monkeypatch.setenv(cc.ENV_ENABLE, other)
+        cfg = cc.CompileCacheConfig.resolve(None)
+    else:
+        cfg = cc.CompileCacheConfig.resolve(None)
+    assert cfg.enabled and cfg.root == outside
+    assert outside not in cfg.worker_env().values()
+    assert cc.activate(cfg) == outside == cc.active_dir()
+    assert jax.config.jax_compilation_cache_dir == outside
+    assert not os.path.exists(other)
+    # switching off leaves the directory setting alone
+    cc.deactivate()
+    assert jax.config.jax_compilation_cache_dir == outside
+    assert not jax.config.jax_enable_compilation_cache
 
 
 # ---------------------------------------------------------------------------
@@ -167,6 +202,33 @@ def test_aot_precompile_and_dispatch():
     np.testing.assert_allclose(np.asarray(out), np.full((4,), 3.0))
 
 
+def test_aot_thread_traces_under_the_submitters_mesh():
+    """The current mesh is thread-local, and jax caches a program's
+    trace per avals: a step traced on the precompile thread WITHOUT the
+    mesh is the step the fit then runs.  (Found on four chips: the
+    sharded fits had silently lost the flash kernel.)"""
+    from ray_lightning_tpu.parallel.mesh import (build_device_mesh,
+                                                 get_current_mesh,
+                                                 set_current_mesh)
+    mesh = build_device_mesh(("data",), {"data": 2}, jax.devices()[:2])
+    seen = []
+
+    def fn(x):
+        seen.append(get_current_mesh())
+        return x + 1
+
+    set_current_mesh(mesh)
+    try:
+        pre = AotPrecompiler()
+        pre.submit("meshy", jax.jit(fn),
+                   (jax.ShapeDtypeStruct((4,), np.float32),))
+        pre.barrier(timeout=60)
+    finally:
+        set_current_mesh(None)
+    assert pre.succeeded("meshy"), pre.results
+    assert seen == [mesh]
+
+
 def test_aot_failure_is_soft():
     pre = AotPrecompiler()
     pre.submit("bad", jax.jit(lambda x: x), ("not-an-aval",))
@@ -215,7 +277,7 @@ def test_fit_records_first_step_and_precompiles(tmp_path):
     assert trainer._precompiler.succeeded("train_step"), \
         trainer._precompiler.results
     ns = cc.active_dir()
-    assert ns and ns.startswith(str(tmp_path / "cache"))
+    assert ns == str(tmp_path / "cache")    # the directory itself
     assert os.listdir(ns)           # entries persisted
     assert cc.stats().requests > 0
 
@@ -296,7 +358,10 @@ def _run_child(tmp_path, cache_dir, batch=2):
     env = {**os.environ,
            "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-           "RLT_COMPILE_CACHE_DIR": str(cache_dir),
+           # placed from outside, the way a chip run places it; the
+           # conftest opt-out must not reach this child
+           "JAX_COMPILATION_CACHE_DIR": str(cache_dir),
+           "RLT_COMPILE_CACHE": "1",
            "PYTHONPATH": repo_root + os.pathsep
            + os.environ.get("PYTHONPATH", "")}
     out = subprocess.run(
@@ -312,7 +377,7 @@ def test_cold_then_warm_across_processes(tmp_path):
     """Same process tree torn down between fits, cache dir retained:
     the warm process must record cache hits, spend a fraction of the
     cold one's XLA compile seconds, and start stepping sooner; a shape
-    change must miss (fresh programs compile, namespacing untouched)."""
+    change must miss (fresh programs compile)."""
     cache_dir = tmp_path / "cache"
     cold = _run_child(tmp_path, cache_dir)
     # fresh dir: every program misses (a stray in-process hit can come
@@ -340,7 +405,17 @@ def _tune_trainable(config, checkpoint_dir=None):
     tune.report(loss=float(trainer.callback_metrics.get("loss", 0.0)))
 
 
-def test_tune_trials_share_compile_cache(tmp_path, seed):
+@pytest.fixture
+def placed_cache(monkeypatch, tmp_path):
+    """The cache placed from outside, as a chip run places it (and the
+    conftest opt-out lifted)."""
+    d = tmp_path / "placed_cache"
+    monkeypatch.setenv(cc.ENV_JAX_DIR, str(d))
+    monkeypatch.setenv(cc.ENV_ENABLE, "1")
+    return d
+
+
+def test_tune_trials_share_compile_cache(tmp_path, seed, placed_cache):
     before = cc.stats()
     analysis = tune.run(_tune_trainable, config={}, num_samples=2,
                         metric="loss", mode="min",
@@ -350,11 +425,14 @@ def test_tune_trials_share_compile_cache(tmp_path, seed):
     # trial 1 rebuilt every jit object; its programs came off trial 0's
     # persistent cache instead of recompiling
     assert after.hits > before.hits
-    assert os.path.isdir(os.path.join(str(tmp_path), "cc_exp",
-                                      "compile_cache"))
+    # experiments share THE cache directory: nothing per-experiment
+    # (a directory named after the experiment never hits again)
+    assert os.listdir(placed_cache)
+    assert not os.path.exists(os.path.join(str(tmp_path), "cc_exp",
+                                           "compile_cache"))
 
 
-def test_tune_restart_resumes_warm(tmp_path, seed):
+def test_tune_restart_resumes_warm(tmp_path, seed, placed_cache):
     attempts = []
 
     def flaky(config, checkpoint_dir=None):
@@ -372,13 +450,12 @@ def test_tune_restart_resumes_warm(tmp_path, seed):
     assert attempts[1] > attempts[0]
 
 
-def test_tune_cache_optout(tmp_path, monkeypatch, seed):
+def test_tune_cache_optout(tmp_path, monkeypatch, seed, placed_cache):
     monkeypatch.setenv("RLT_COMPILE_CACHE", "0")
     tune.run(_tune_trainable, config={}, num_samples=1,
              metric="loss", mode="min",
              local_dir=str(tmp_path), name="cc_off")
-    assert not os.path.isdir(os.path.join(str(tmp_path), "cc_off",
-                                          "compile_cache"))
+    assert not os.path.exists(placed_cache)
 
 
 # ---------------------------------------------------------------------------

@@ -191,12 +191,18 @@ def _round(value=10.0, goodput=None, extra=None):
     return [rec]
 
 
-def test_ledger_bootstraps_against_pre_goodput_blob():
-    """Comparing a goodput-bearing round against a REAL pre-goodput
-    driver blob (BENCH_r05.json) must skip-with-note, never KeyError
-    and never gate (satellite 1)."""
+def test_ledger_bootstraps_against_pre_goodput_blob(tmp_path):
+    """Comparing a goodput-bearing round against a pre-goodput record
+    (the last one the driver took before the goodput plane existed; an
+    older claim, its file since removed) must skip-with-note, never
+    KeyError and never gate (satellite 1)."""
+    import json
     from benchmarks import ledger
-    prev_path = os.path.join(_REPO_ROOT, "BENCH_r05.json")
+    prev_path = str(tmp_path / "pre_goodput.json")
+    with open(prev_path, "w") as f:
+        json.dump({"metric": "gpt2s_train_steps_per_sec_tpu",
+                   "value": 18.998, "unit": "steps/sec",
+                   "vs_baseline": 1.9, "device_ms": 49.35}, f)
     prev_by = ledger.load_records(prev_path)
     assert prev_by and not any(
         isinstance(r.get("goodput"), dict) for r in prev_by.values()), \

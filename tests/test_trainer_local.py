@@ -498,7 +498,7 @@ def test_donation_decision_table(seed, monkeypatch):
         GPTLightningModule("gpt2-1p3b", dataset_size=8, batch_size=8),
         "zero1", 16 * GB)
     assert got is True
-    # unknown budget (virtual CPU, profiler-less tunnels): donate
+    # unknown budget (virtual CPU: memory_stats() is None): donate
     t = Trainer(enable_checkpointing=False, logger=False)
     t._device_memory_budget = lambda: None
     _, abstract, sh = abstract_and_shardings(
