@@ -81,7 +81,7 @@ def _bench_impl(impl: str, L: int, steps: int, platform: str) -> dict:
     except Exception as e:  # profiler-less backends still get wall time
         sys.stderr.write(f"trace skipped: {e}\n")
         trace_dir = None
-    dev_ms = trace_tools.dominant_module_ms_or_none(trace_dir)
+    dev_ms = trace_tools.dominant_module_ms(trace_dir)
 
     return {
         "metric": f"decode_micro_{impl}_L{L}",

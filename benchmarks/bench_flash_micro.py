@@ -66,7 +66,7 @@ def main() -> None:
     except Exception as e:  # profiler-less backends still get wall time
         sys.stderr.write(f"trace skipped: {e}\n")
         trace_dir = None
-    dev_ms = trace_tools.dominant_module_ms_or_none(trace_dir)
+    dev_ms = trace_tools.dominant_module_ms(trace_dir)
 
     print(json.dumps({
         "metric": f"flash_fwdbwd_T{t}",

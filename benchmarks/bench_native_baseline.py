@@ -115,7 +115,7 @@ def _emit_device_ms(run, side: str) -> "float | None":
     except Exception as e:  # profiler unavailable on some backends
         sys.stderr.write(f"device-time capture skipped: {e}\n")
         return None
-    med = trace_tools.dominant_module_ms_or_none(d)
+    med = trace_tools.dominant_module_ms(d)
     if med is None:
         return None
     _emit(f"{_CURRENT_WORKLOAD}_{side}_device_ms", med, unit="ms/step")
@@ -136,7 +136,7 @@ def _emit_framework_device(result: dict) -> "float | None":
     the wall clock measured — a fresh Trainer would recompile inside
     the trace window)."""
     from benchmarks import trace_tools
-    med = trace_tools.dominant_module_ms_or_none(result.get("trace_dir"))
+    med = trace_tools.dominant_module_ms(result.get("trace_dir"))
     if med is None:
         return None
     _emit(f"{_CURRENT_WORKLOAD}_framework_device_ms", med, unit="ms/step")

@@ -169,11 +169,9 @@ def run_steps_per_sec(module, metric: str, *, warmup: int = 3,
         # ici/dcn link) / trace-measured exposed comm / host gap.
         # Parsed before the device_ms path below consumes the dir.
         from ray_lightning_tpu.telemetry.anatomy import (
-            parse_anatomy_or_none,
+            parse_trace_anatomy,
         )
-        anatomy = parse_anatomy_or_none(timer.trace_dir)
-        if anatomy is not None:
-            result["anatomy"] = anatomy
+        result["anatomy"] = parse_trace_anatomy(timer.trace_dir).as_dict()
     # goodput plane (telemetry/goodput.py): the run's wall-clock
     # partition + measured MFU, compacted to the fields the ledger
     # gates on (benchmarks/ledger.py goodput-fraction / MFU bands)
@@ -197,10 +195,9 @@ def run_steps_per_sec(module, metric: str, *, warmup: int = 3,
             result["collective_gibs"] = summary["collective_gibs"]
     if inline_device_ms and timer.trace_dir is not None:
         from benchmarks import trace_tools
-        med = trace_tools.dominant_module_ms_or_none(timer.trace_dir)
+        result["device_ms"] = round(
+            trace_tools.dominant_module_ms(timer.trace_dir), 2)
         timer.trace_dir = None
-        if med is not None:
-            result["device_ms"] = round(med, 2)
     if callable(extra_fields):
         # derived fields (e.g. bench_comm's exposed_comm_seconds need
         # the measured value): compute from the assembled result

@@ -136,23 +136,18 @@ def dominant_module(trace_dir: str) -> tuple[str, float, int]:
     return name, float(statistics.median(durs)), len(durs)
 
 
-def dominant_module_ms_or_none(trace_dir: "str | None",
-                               *, consume: bool = True) -> "float | None":
-    """Median device ms of the dominant module, or None when the trace
-    is missing/unparseable (profiler-less backends) — the shared
-    capture-and-fallback recipe for benches that must still emit wall
-    numbers without a profiler.  ``consume`` removes the trace dir."""
+def dominant_module_ms(trace_dir: "str | None",
+                       *, consume: bool = True) -> "float | None":
+    """Median device ms of the dominant module.  None only when no
+    capture was made (``trace_dir`` is None: a profiler-less backend,
+    and the caller said so on stderr); a capture that is there and
+    cannot be parsed raises.  ``consume`` removes the trace dir."""
     import shutil
-    import sys
 
     if not trace_dir:
         return None
     try:
-        _, med, _ = dominant_module(trace_dir)
-        return med
-    except Exception as e:
-        sys.stderr.write(f"device-time capture skipped: {e}\n")
-        return None
+        return dominant_module(trace_dir)[1]
     finally:
         if consume:
             shutil.rmtree(trace_dir, ignore_errors=True)

@@ -36,7 +36,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Optional
 
-from ray_lightning_tpu.telemetry import counter as _tcounter
+from ray_lightning_tpu.telemetry import span
 
 _log = logging.getLogger(__name__)
 
@@ -106,13 +106,18 @@ class AotPrecompiler:
             t0 = time.monotonic()
             try:
                 set_current_mesh(mesh)
-                jitted.lower(*args).compile()
+                # this thread's own span stack (telemetry/spans.py): its
+                # spans are roots beside the main thread's, and say
+                # which program's trace or load a set-up waited for.
+                # ``thread`` marks them as overlapping the main thread's
+                # time, for readers that add set-up up
+                with span("aot", program=name, thread="aot"):
+                    with span("lower"):
+                        lowered = jitted.lower(*args)
+                    with span("backend_compile"):
+                        lowered.compile()
                 dt = time.monotonic() - t0
                 self.results[name] = dt
-                # counter, not span: spans share the recorder's open-span
-                # stack with the main thread, and a cross-thread push
-                # would corrupt its nesting depth
-                _tcounter("precompile_seconds", dt, program=name)
             except Exception as e:   # noqa: BLE001 - soft fallback
                 self.results[name] = e
                 _log.info(

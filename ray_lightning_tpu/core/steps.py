@@ -187,10 +187,14 @@ def build_train_step(module, tx,
             loss, new_ms, logged, grads, new_residual = synced_grads(
                 state, step_rng, batch)
 
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        if grad_sync is not None:
-            new_opt = grad_sync.with_residual(new_opt, new_residual)
-        new_params = optax.apply_updates(state.params, updates)
+        # profiler scope (telemetry/scopes.py); the model's own parts
+        # enter theirs in models/ and ops/
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            if grad_sync is not None:
+                new_opt = grad_sync.with_residual(new_opt, new_residual)
+            new_params = optax.apply_updates(state.params, updates)
         if grad_sync is not None:
             new_params = grad_sync.regather_params(new_params)
         metrics = {"loss": loss, **logged}
@@ -232,18 +236,20 @@ def build_prefill_step(module, bucket_len: int, model=None,
             params = dequant(params)
         logits, captured = model.apply({"params": params}, tokens, True,
                                        mutable=["kv_cache"])
-        first = jnp.argmax(
-            jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0,
-                                         keepdims=False),
-            axis=-1).astype(tokens.dtype)
+        with jax.named_scope("sample"):
+            first = jnp.argmax(
+                jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0,
+                                             keepdims=False),
+                axis=-1).astype(tokens.dtype)
         # captured K/V ride the module tree ({'h0': {'attn': {'kv':
         # ((k, v),)}}}); stack to [n_layer, 1, Tb, H, D] and write every
         # layer's block with one dynamic_update_slice at the slot
-        ks, vs = _stacked_kv(captured["kv_cache"])
-        k_caches = jax.lax.dynamic_update_slice(
-            k_caches, ks, (0, slot) + (0,) * (k_caches.ndim - 2))
-        v_caches = jax.lax.dynamic_update_slice(
-            v_caches, vs, (0, slot) + (0,) * (v_caches.ndim - 2))
+        with jax.named_scope("kv_cache"):
+            ks, vs = _stacked_kv(captured["kv_cache"])
+            k_caches = jax.lax.dynamic_update_slice(
+                k_caches, ks, (0, slot) + (0,) * (k_caches.ndim - 2))
+            v_caches = jax.lax.dynamic_update_slice(
+                v_caches, vs, (0, slot) + (0,) * (v_caches.ndim - 2))
         return k_caches, v_caches, first
 
     return step_fn
@@ -303,8 +309,9 @@ def build_decode_step(module, page_table=None) -> Callable:
         logits, new_k, new_v = model.apply(
             {"params": params}, tokens, positions, k_caches, v_caches,
             method="decode", **kw)
-        return new_k, new_v, jnp.argmax(logits, axis=-1).astype(
-            tokens.dtype)
+        with jax.named_scope("sample"):
+            return new_k, new_v, jnp.argmax(logits, axis=-1).astype(
+                tokens.dtype)
 
     return step_fn
 
@@ -476,13 +483,18 @@ def build_eval_step(module, stage: str) -> Callable:
                          for k, v in out.items()}}
         return logged
 
+    # a program's name is its function's: the train step stays
+    # ``jit_step_fn``; these get names of their own, so that a profiler
+    # trace and the scope tables (telemetry/scopes.py, keyed by program
+    # name) tell them apart
+    step_fn.__name__ = step_fn.__qualname__ = f"{stage}_step"
     return step_fn
 
 
 def build_predict_step(module) -> Callable:
-    def step_fn(state: TrainState, batch: Any):
+    def predict_step(state: TrainState, batch: Any):
         ctx = StepContext(module, state.params, state.model_state,
                           rng=None, training=False)
         return module.predict_step(ctx, batch)
 
-    return step_fn
+    return predict_step

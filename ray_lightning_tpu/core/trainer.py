@@ -24,6 +24,7 @@ explicit host-transfer-point discipline flagged in SURVEY.md §7).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import tempfile
@@ -57,6 +58,7 @@ from ray_lightning_tpu.parallel.gather import fetch_tree
 from ray_lightning_tpu.parallel.mesh import set_current_mesh
 from ray_lightning_tpu.parallel.strategy import resolve_strategy
 from ray_lightning_tpu.telemetry import TelemetryConfig, span
+from ray_lightning_tpu.telemetry import spans as _spans
 from ray_lightning_tpu.telemetry import metrics as _metrics
 from ray_lightning_tpu.utils.seed import reset_seed, seed_everything
 
@@ -227,6 +229,8 @@ class Trainer:
         self.time_to_first_step: Optional[float] = None
         self._stage_t0: Optional[float] = None
         self._precompiler: Optional[AotPrecompiler] = None
+        #: the open set-up window and its spans (root, then first_step)
+        self._setup_spans: Optional[contextlib.ExitStack] = None
         self._epoch_metric_acc: dict[str, list] = {}
         self._warned_skip = False
         self._stage = None
@@ -347,6 +351,29 @@ class Trainer:
         self._stage = stage
         self._stage_t0 = time.monotonic()
         self.time_to_first_step = None
+        # set-up is kept whatever the telemetry flag says (dozens of
+        # spans, none in a loop): one root from here to the end of the
+        # first step, read afterwards from telemetry.spans.kept(<root>)
+        self._close_setup_spans()
+        self._setup_spans = contextlib.ExitStack()
+        self._setup_spans.enter_context(_spans.keep(f"{stage}_setup"))
+        self._setup_spans.enter_context(span(f"{stage}_setup"))
+        try:
+            return self._run_stage_spanned(module, datamodule, stage,
+                                           ckpt_path)
+        finally:
+            self._close_setup_spans()
+
+    def _close_setup_spans(self) -> None:
+        """Close ``first_step``, the root and the window (idempotent:
+        the fit closes them at its first step's result, every other
+        path at the stage's end)."""
+        if self._setup_spans is not None:
+            self._setup_spans.close()
+            self._setup_spans = None
+
+    def _run_stage_spanned(self, module, datamodule, stage: str,
+                           ckpt_path: Optional[str]):
         self.lightning_module = module
         module.trainer = self
         self.datamodule = datamodule
@@ -397,26 +424,28 @@ class Trainer:
         compile_cache.activate(self.compile_cache)
 
         # data lifecycle (reference: prepare_data per worker, ray_ddp.py:446)
-        if datamodule is not None:
-            datamodule._call_prepare_data()
-            datamodule._call_setup(stage)
-        module.prepare_data()
-        module.setup(stage)
-        module.setup_model()
+        with span("setup_model"):
+            if datamodule is not None:
+                datamodule._call_prepare_data()
+                datamodule._call_setup(stage)
+            module.prepare_data()
+            module.setup(stage)
+            module.setup_model()
 
         strategy = self.plugin.strategy
         if strategy is None:
             strategy = resolve_strategy(None)
             self.plugin.strategy = strategy
 
-        loaders = self._build_loaders(stage)
-        first_loader = loaders.get(
-            {"fit": "train", "validate": "val", "test": "test",
-             "predict": "predict"}[stage])
-        if first_loader is None:
-            raise ValueError(f"No dataloader available for stage {stage!r}")
-
-        example_batch, replacement = _peek_first_batch(first_loader)
+        with span("loaders"):
+            loaders = self._build_loaders(stage)
+            first_loader = loaders.get(
+                {"fit": "train", "validate": "val", "test": "test",
+                 "predict": "predict"}[stage])
+            if first_loader is None:
+                raise ValueError(
+                    f"No dataloader available for stage {stage!r}")
+            example_batch, replacement = _peek_first_batch(first_loader)
         if replacement is not first_loader:
             key = {"fit": "train", "validate": "val", "test": "test",
                    "predict": "predict"}[stage]
@@ -445,8 +474,11 @@ class Trainer:
                     f"math is identical without a stage split")
             from ray_lightning_tpu.mpmd.engine import run_mpmd_fit
             return run_mpmd_fit(self, module, loaders, example_batch)
-        self._mesh = strategy.build_mesh(self.plugin.local_devices(),
-                                         batch_hint=batch_hint)
+        with span("mesh"):
+            # the first question about devices: JAX starts its backend
+            # here when the caller has not
+            self._mesh = strategy.build_mesh(self.plugin.local_devices(),
+                                             batch_hint=batch_hint)
         set_current_mesh(self._mesh)  # for mesh-aware ops (ring attention)
         # goodput plane (telemetry/goodput.py): one ledger per fit run,
         # backdated to the stage clock so the partition covers every
@@ -518,8 +550,9 @@ class Trainer:
         if self._goodput_ledger is not None:
             self._goodput_ledger.add("init", time.monotonic() - t_init)
 
-        for cb in self.callbacks:
-            cb.setup(self, module, stage)
+        with span("hooks", hook="setup"):
+            for cb in self.callbacks:
+                cb.setup(self, module, stage)
         try:
             if stage == "fit":
                 result = self._fit_loop(module, loaders)
@@ -1205,17 +1238,20 @@ class Trainer:
         self.num_val_batches = self._loader_len(val_loader,
                                                 self.limit_val_batches)
 
-        for cb in self.callbacks:
-            cb.on_fit_start(self, module)
-        module.on_fit_start()
+        with span("hooks", hook="on_fit_start"):
+            for cb in self.callbacks:
+                cb.on_fit_start(self, module)
+            module.on_fit_start()
 
         if val_loader is not None and self.num_sanity_val_steps > 0 \
                 and self.num_val_batches > 0:
-            self._sanity_check(module, val_loader)
+            with span("sanity_val"):
+                self._sanity_check(module, val_loader)
 
-        for cb in self.callbacks:
-            cb.on_train_start(self, module)
-        module.on_train_start()
+        with span("hooks", hook="on_train_start"):
+            for cb in self.callbacks:
+                cb.on_train_start(self, module)
+            module.on_train_start()
 
         start_epoch = self.current_epoch
         epoch = start_epoch
@@ -1329,6 +1365,13 @@ class Trainer:
         ride one dispatch) and the val-interval check after every
         dispatch.  Replaces the round-2 trio of divergent loops.
         """
+        if self.time_to_first_step is None \
+                and self._setup_spans is not None:
+            # from here to the first step's result: the wait for the
+            # AOT thread, the first batches, the dispatch (where a
+            # cached program loads, or a missed one compiles) and the
+            # first run.  _note_first_step closes it, and the root.
+            self._setup_spans.enter_context(span("first_step"))
         source = self._train_source(train_loader, strategy)
         if self._precompiler is not None:
             # close the overlap window: everything submitted (train /
@@ -1336,7 +1379,8 @@ class Trainer:
             # caches before the first dispatch, or a lazy compile on
             # this thread would race the background one for the same
             # program.  Instant from epoch 2 on (nothing pending).
-            self._precompiler.barrier()
+            with span("aot_wait"):
+                self._precompiler.barrier()
         k = self.steps_per_execution
         while not (self.should_stop or self._max_steps_reached()):
             allowed = self._allowed_chunk()
@@ -1417,31 +1461,35 @@ class Trainer:
     def _engine_one(self, module, source, item) -> None:
         invoke, want_batch = self._batch_hook_plan()
         if invoke:
-            batch = item.batch() if want_batch else None
-            for cb in self.callbacks:
-                cb.on_train_batch_start(self, module, batch, item.batch_idx)
+            # user code between two dispatches
+            with span("callbacks", hook="on_train_batch_start"):
+                batch = item.batch() if want_batch else None
+                for cb in self.callbacks:
+                    cb.on_train_batch_start(self, module, batch,
+                                            item.batch_idx)
         t0 = time.monotonic()
         with span("step", step=self.global_step):
             metrics = source.run_one(self, item)
         self.global_step += 1
+        first = self._note_first_step(metrics)
         step_s = time.monotonic() - t0
         _metrics.on_step(step_s, step=self.global_step)
         if self._goodput_ledger is not None:
-            self._goodput_ledger.note_step(step_s)
+            self._goodput_ledger.note_step(step_s, first=first)
         if self._redundancy is not None:
             # parity BEFORE the snapshot: a rank that dies inside the
             # save (snapkill) has already escrowed this step
             self._redundancy.maybe_tick()
         if self._snapshotter is not None:
             self._snapshotter.maybe_snapshot()
-        self._note_first_step(metrics)
         self._accumulate_metrics(metrics)
         if self.global_step % self.log_every_n_steps == 0:
             self._publish_metrics(metrics)
         if invoke:
-            for cb in self.callbacks:
-                cb.on_train_batch_end(self, module, metrics, batch,
-                                      item.batch_idx)
+            with span("callbacks", hook="on_train_batch_end"):
+                for cb in self.callbacks:
+                    cb.on_train_batch_end(self, module, metrics, batch,
+                                          item.batch_idx)
 
     def _engine_chunk(self, module, source, items) -> None:
         """k steps in ONE dispatch; batch-granular callbacks coarsen to
@@ -1449,11 +1497,13 @@ class Trainer:
         stacked metrics and its last batch)."""
         invoke, want_batch = self._batch_hook_plan()
         if invoke:
-            for it in items:
-                for cb in self.callbacks:
-                    cb.on_train_batch_start(
-                        self, module, it.batch() if want_batch else None,
-                        it.batch_idx)
+            with span("callbacks", hook="on_train_batch_start"):
+                for it in items:
+                    for cb in self.callbacks:
+                        cb.on_train_batch_start(
+                            self, module,
+                            it.batch() if want_batch else None,
+                            it.batch_idx)
         before = self.global_step
         # k steps ride one span; the aggregator normalizes per-step time
         # by the "k" attribute when computing percentiles
@@ -1461,10 +1511,12 @@ class Trainer:
         with span("step", step=before, k=len(items)):
             metrics = source.run_chunk(self, items)
         self.global_step += len(items)
+        first = self._note_first_step(metrics)
         step_s = time.monotonic() - t0
         _metrics.on_step(step_s, k=len(items), step=self.global_step)
         if self._goodput_ledger is not None:
-            self._goodput_ledger.note_step(step_s, k=len(items))
+            self._goodput_ledger.note_step(step_s, k=len(items),
+                                           first=first)
         if self._redundancy is not None:
             # chunked dispatch coarsens the parity cadence to chunk
             # boundaries, exactly like the snapshot cadence below
@@ -1473,27 +1525,32 @@ class Trainer:
             # chunked dispatch coarsens the snapshot cadence to chunk
             # boundaries, like the batch-granular callbacks do
             self._snapshotter.maybe_snapshot()
-        self._note_first_step(metrics)
         self._accumulate_metrics(metrics)
         self._publish_if_crossed(before, jax.tree_util.tree_map(
             lambda a: a[-1], metrics))
         if invoke:
-            for cb in self.callbacks:
-                cb.on_train_batch_end(
-                    self, module, metrics,
-                    items[-1].batch() if want_batch else None,
-                    items[-1].batch_idx)
+            with span("callbacks", hook="on_train_batch_end"):
+                for cb in self.callbacks:
+                    cb.on_train_batch_end(
+                        self, module, metrics,
+                        items[-1].batch() if want_batch else None,
+                        items[-1].batch_idx)
 
-    def _note_first_step(self, metrics) -> None:
+    def _note_first_step(self, metrics) -> bool:
         """Record time-to-first-step once per stage: the startup cost
         (compile + init + rendezvous + upload) the compile plane exists
         to shrink.  Blocks on the first step's metrics so the number
-        covers execution, not just async dispatch — one sync, once."""
+        covers execution, not just async dispatch — one sync, once.
+        True for that first step (the goodput ledger books it as
+        compile time, not as a step)."""
         if self.time_to_first_step is not None or self._stage_t0 is None:
-            return
-        jax.block_until_ready(metrics)
+            return False
+        with span("device_wait", what="first_step"):
+            jax.block_until_ready(metrics)
         self.time_to_first_step = time.monotonic() - self._stage_t0
         compile_cache.note_first_step(self.time_to_first_step)
+        self._close_setup_spans()
+        return True
 
     # -- metrics ---------------------------------------------------------
 
@@ -1501,11 +1558,22 @@ class Trainer:
         for k, v in metrics.items():
             self._epoch_metric_acc.setdefault(k, []).append(v)
 
+    def _device_wait(self, what: str, tree):
+        """Fetch ``tree``: the loop blocks here until the steps that
+        made it are done (the host runs ahead of the device), so the
+        seconds are step time: the ledger's mean step wall would else
+        miss the steps still in flight at the end of a fit."""
+        t0 = time.monotonic()
+        with span("device_wait", what=what):
+            out = jax.device_get(tree)
+        if self._goodput_ledger is not None:
+            self._goodput_ledger.add("step", time.monotonic() - t0)
+        return out
+
     def _publish_metrics(self, metrics: dict) -> None:
-        for k, v in metrics.items():
-            val = float(jax.device_get(v))
-            self.callback_metrics[k] = val
-            self.logged_metrics[k] = val
+        vals = self._device_wait("publish_metrics", list(metrics.values()))
+        for k, v in zip(metrics, vals):
+            self.callback_metrics[k] = self.logged_metrics[k] = float(v)
         if self.logger is not None and self.is_global_zero and metrics:
             self.logger.log_metrics(
                 {k: self.logged_metrics[k] for k in metrics},
@@ -1513,12 +1581,13 @@ class Trainer:
 
     def _flush_epoch_metrics(self) -> None:
         flushed = {}
-        for k, vals in self._epoch_metric_acc.items():
+        fetched = self._device_wait("epoch_metrics", self._epoch_metric_acc)
+        for k, vals in fetched.items():
             # entries are scalars (per-step) or [k] vectors (per-chunk,
             # steps_per_execution>1); flatten to one per-step series
             arr = np.concatenate([
                 np.atleast_1d(np.asarray(v, dtype=np.float64))
-                for v in jax.device_get(vals)])
+                for v in vals])
             self.callback_metrics[k] = flushed[k] = float(arr.mean())
             self.logged_metrics[k] = float(arr[-1])
         self._epoch_metric_acc = {}

@@ -149,7 +149,10 @@ class Block(nn.Module):
             n_head=cfg.n_head, causal=True, dropout=cfg.dropout,
             dtype=cfg.dtype, attention_impl=cfg.attention_impl,
             name="attn")
-        h = nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x)
+        # profiler scopes (telemetry/scopes.py): the flax module names
+        # attn / mlp are on the list already; the norms are ln1/ln2/ln_f
+        with jax.named_scope("ln"):
+            h = nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x)
         new_cache = None
         if decode_cache is not None:
             # serve-plane decode: the attention returns the updated slot
@@ -158,9 +161,10 @@ class Block(nn.Module):
                                 decode_cache=decode_cache,
                                 positions=positions,
                                 page_table=page_table)
-            x = x + a
         else:
-            x = x + attn(h, deterministic)
+            a = attn(h, deterministic)
+        with jax.named_scope("attn"):
+            x = x + a    # each residual add goes with its branch
         if self.use_moe:
             from ray_lightning_tpu.ops.moe import MoEMLP
             ffn = MoEMLP(n_experts=cfg.n_experts, d_ff=4 * cfg.n_embd,
@@ -169,8 +173,10 @@ class Block(nn.Module):
                          dtype=cfg.dtype, name="moe")
         else:
             ffn = MLP(cfg, name="mlp")
-        x = x + ffn(nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x),
-                    deterministic)
+        with jax.named_scope("ln"):
+            h = nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x)
+        with jax.named_scope("mlp"):
+            x = x + ffn(h, deterministic)
         return x if new_cache is None else (x, new_cache)
 
 
@@ -221,10 +227,12 @@ class GPT(nn.Module):
         """Pre-head representation ``[B, T, C]`` in the compute dtype."""
         cfg = self.config
         B, T = idx.shape
-        x = self.wte(idx) + self.wpe[:T].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = self.wte(idx) + self.wpe[:T].astype(cfg.dtype)
         for blk in self.blocks:
             x = blk(x, deterministic)
-        return self.ln_f(x)
+        with jax.named_scope("ln"):
+            return self.ln_f(x)
 
     @property
     def embedding_table(self):
@@ -235,7 +243,8 @@ class GPT(nn.Module):
         # tied output head: attend promotes operands to the compute dtype
         # (bf16 on the MXU, fp32 accumulation implicit on TPU); logits
         # upcast to fp32 only for the loss softmax.
-        return self.wte.attend(x).astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            return self.wte.attend(x).astype(jnp.float32)
 
     def decode(self, tokens, positions, k_caches, v_caches,
                page_table=None):
@@ -258,19 +267,26 @@ class GPT(nn.Module):
         keeps the slot-contiguous layout.
         """
         cfg = self.config
-        x = self.wte(tokens[:, None])
-        x = x + jnp.take(self.wpe, positions, axis=0)[:, None, :].astype(
-            cfg.dtype)
+        with jax.named_scope("embed"):
+            x = self.wte(tokens[:, None])
+            x = x + jnp.take(self.wpe, positions,
+                             axis=0)[:, None, :].astype(cfg.dtype)
         new_k, new_v = [], []
         for i, blk in enumerate(self.blocks):
-            x, (k, v) = blk(x, True,
-                            decode_cache=(k_caches[i], v_caches[i]),
+            with jax.named_scope("kv_cache"):
+                layer_cache = (k_caches[i], v_caches[i])
+            x, (k, v) = blk(x, True, decode_cache=layer_cache,
                             positions=positions, page_table=page_table)
             new_k.append(k)
             new_v.append(v)
-        x = self.ln_f(x)
-        logits = self.wte.attend(x).astype(jnp.float32)
-        return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
+        with jax.named_scope("ln"):
+            x = self.ln_f(x)
+        with jax.named_scope("lm_head"):
+            logits = self.wte.attend(x).astype(jnp.float32)
+        with jax.named_scope("kv_cache"):
+            # every layer's slice out and stack back: the padded copy of
+            # K and V that the decode program pays (ROADMAP S4a)
+            return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
 
     def verify(self, tokens, positions, k_caches, v_caches,
                page_table=None):
@@ -292,21 +308,27 @@ class GPT(nn.Module):
         new_v)``.
         """
         cfg = self.config
-        x = self.wte(tokens)
-        # gather clamps out-of-range positions (slots speculating past
-        # the cache end read wpe[-1]; their outputs are truncated by the
-        # scheduler's max_new cap before anything is emitted)
-        x = x + jnp.take(self.wpe, positions, axis=0).astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = self.wte(tokens)
+            # gather clamps out-of-range positions (slots speculating
+            # past the cache end read wpe[-1]; their outputs are
+            # truncated by the scheduler's max_new cap before anything
+            # is emitted)
+            x = x + jnp.take(self.wpe, positions, axis=0).astype(cfg.dtype)
         new_k, new_v = [], []
         for i, blk in enumerate(self.blocks):
-            x, (k, v) = blk(x, True,
-                            decode_cache=(k_caches[i], v_caches[i]),
+            with jax.named_scope("kv_cache"):
+                layer_cache = (k_caches[i], v_caches[i])
+            x, (k, v) = blk(x, True, decode_cache=layer_cache,
                             positions=positions, page_table=page_table)
             new_k.append(k)
             new_v.append(v)
-        x = self.ln_f(x)
-        logits = self.wte.attend(x).astype(jnp.float32)
-        return logits, jnp.stack(new_k), jnp.stack(new_v)
+        with jax.named_scope("ln"):
+            x = self.ln_f(x)
+        with jax.named_scope("lm_head"):
+            logits = self.wte.attend(x).astype(jnp.float32)
+        with jax.named_scope("kv_cache"):
+            return logits, jnp.stack(new_k), jnp.stack(new_v)
 
 
 def gpt_partition_rules(tensor_axis: str = "tensor") -> list[tuple[str, P]]:
@@ -498,6 +520,25 @@ class GPTLightningModule(LightningModule):
         # (ops/optim.py fp32_master) keeps update precision
         return (jnp.bfloat16
                 if os.environ.get("RLT_BF16_PARAMS", "1") != "0" else None)
+
+    def flops_per_step(self):
+        """Goodput-plane hook (core/module.py): the FLOPs one optimizer
+        step requires over the global batch, from the sizes alone: 6 per
+        matmul parameter and token (2 forward, 4 backward; 12 d^2 a
+        block plus the tied table as the head), plus causal attention's
+        score and value products, 6 L T d a token.  Recomputation is not
+        counted.  The trainer's default (every ``dot_general`` of the
+        step's jaxpr) cannot see inside the flash kernels, and counts
+        the dense fallback's full square where it can."""
+        cfg = self.config
+        batch = getattr(self.trainer, "_abstract_batch", None)
+        if cfg.n_experts > 0 or batch is None:
+            return None      # routed FFN / unknown batch: price the jaxpr
+        b, t = jax.tree_util.tree_leaves(batch)[0].shape[:2]
+        matmul_params = 12 * cfg.n_layer * cfg.n_embd ** 2 \
+            + cfg.vocab_size * cfg.n_embd
+        return float(b * t) * (6.0 * matmul_params
+                               + 6.0 * cfg.n_layer * t * cfg.n_embd)
 
     def configure_optimizers(self):
         sched = optax.linear_schedule(0.0, self.lr, self.warmup_steps)

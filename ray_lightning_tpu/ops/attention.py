@@ -240,8 +240,11 @@ class MultiHeadAttention(nn.Module):
             k_cache, v_cache = decode_cache
             slots = jnp.arange(B)
             if T == 1:
-                k_cache = k_cache.at[slots, positions].set(k[:, 0])
-                v_cache = v_cache.at[slots, positions].set(v[:, 0])
+                # profiler scope (telemetry/scopes.py): the cache write
+                # is kv_cache, not the attention around it
+                with jax.named_scope("kv_cache"):
+                    k_cache = k_cache.at[slots, positions].set(k[:, 0])
+                    v_cache = v_cache.at[slots, positions].set(v[:, 0])
             else:
                 # multi-query verify (T = speculation depth k+1,
                 # positions [B, T]): write every query's K/V first,
@@ -252,8 +255,9 @@ class MultiHeadAttention(nn.Module):
                 # and the paging dummy row's +j offsets) are DROPPED by
                 # jax's out-of-bounds scatter semantics — no per-slot
                 # gating, no shape change, no retrace.
-                k_cache = k_cache.at[slots[:, None], positions].set(k)
-                v_cache = v_cache.at[slots[:, None], positions].set(v)
+                with jax.named_scope("kv_cache"):
+                    k_cache = k_cache.at[slots[:, None], positions].set(k)
+                    v_cache = v_cache.at[slots[:, None], positions].set(v)
             y = cached_attention(q, k_cache, v_cache, positions,
                                  dtype=self.dtype, page_table=page_table)
             y = nn.Dense(C, dtype=self.dtype,

@@ -412,6 +412,7 @@ def _fwd_tri_packed(q, k, v, h, sm_scale, bq, nq, interpret):
 
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b * g2, n_tri),
         in_specs=[
             pl.BlockSpec((1, bq, w), q_map),
@@ -447,6 +448,7 @@ def _fwd_packed(q, k, v, h, causal, sm_scale, interpret):
     x_spec = pl.BlockSpec((1, t, w), lambda g: (g // g2, 0, g % g2))
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b * g2,),
         in_specs=[x_spec, x_spec, x_spec],
         out_specs=[
@@ -572,6 +574,7 @@ def _bwd_tri_packed(q, k, v, h, lse, do, delta, sm_scale, bq, nq,
                              n=nq)
     dk, dv = pl.pallas_call(
         dkdv,
+        name="flash_bwd_dkv",
         grid=(b * g2, n_tri),
         in_specs=[
             pl.BlockSpec((1, bq, w), qi_rev_map),               # q
@@ -609,6 +612,7 @@ def _bwd_tri_packed(q, k, v, h, lse, do, delta, sm_scale, bq, nq,
                             block=bq, d=d, pack=pack)
     dq = pl.pallas_call(
         dqk,
+        name="flash_bwd_dq",
         grid=(b * g2, n_tri),
         in_specs=[
             pl.BlockSpec((1, bq, w), q_map),
@@ -731,6 +735,7 @@ def _fwd_rowres(q, k, v, h, sm_scale, bq, nq, interpret):
                                bq=bq, d=d, pack=pack, fold=fold)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b * g2, nq),
         in_specs=[
             pl.BlockSpec((1, bq, w), row_map),
@@ -831,6 +836,7 @@ def _bwd_rowres(q, k, v, h, lse, do, delta, sm_scale, bq, nq, interpret):
                                bq=bq, nq=nq, d=d, pack=pack, fold=fold)
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_bwd_fused",
         grid=(b * g2, nq),
         in_specs=[
             pl.BlockSpec((1, bq, w), row_map),                  # q
@@ -871,6 +877,7 @@ def _bwd_packed(q, k, v, h, o, lse, do, causal, sm_scale, interpret):
     r_spec = pl.BlockSpec((1, 1, t, pack), lambda g: (g // g2, g % g2, 0, 0))
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_bwd_fused",
         grid=(b * g2,),
         in_specs=[x_spec, x_spec, x_spec, x_spec, x_spec, r_spec],
         out_specs=[x_spec, x_spec, x_spec],
@@ -933,6 +940,7 @@ def _fwd(q, k, v, h, causal, sm_scale, block_q, block_k, interpret):
 
         o, lse = pl.pallas_call(
             kernel,
+            name="flash_fwd",
             grid=(bh, n_tri),
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_map),
@@ -962,6 +970,7 @@ def _fwd(q, k, v, h, causal, sm_scale, block_q, block_k, interpret):
                                block_q=bq, block_k=bk, nk=nk)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
@@ -1209,6 +1218,7 @@ def _bwd_fused(q, k, v, lse, do, delta, causal, sm_scale, interpret):
     r_spec = pl.BlockSpec((1, t, 1), lambda g: (g, 0, 0))
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_bwd_fused",
         grid=(bh,),
         in_specs=[x_spec, x_spec, x_spec, x_spec, r_spec, r_spec],
         out_specs=[x_spec, x_spec, x_spec],
@@ -1314,6 +1324,7 @@ def _bwd_tri(q, k, v, o, lse, do, sm_scale, bq, nq, delta, interpret):
                              block=bq, n=nq)
     dk, dv = pl.pallas_call(
         dkdv,
+        name="flash_bwd_dkv",
         grid=(bh, n_tri),
         in_specs=[
             pl.BlockSpec((1, bq, d), qi_rev_map),               # q
@@ -1347,6 +1358,7 @@ def _bwd_tri(q, k, v, o, lse, do, sm_scale, bq, nq, delta, interpret):
     dqk = functools.partial(_bwd_dq_tri_kernel, sm_scale=sm_scale, block=bq)
     dq = pl.pallas_call(
         dqk,
+        name="flash_bwd_dq",
         grid=(bh, n_tri),
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),
@@ -1413,6 +1425,7 @@ def _bwd(q, k, v, h, o, lse, do, causal, sm_scale, block_q, block_k,
                                  nq=nq)
         dk, dv = pl.pallas_call(
             dkdv,
+            name="flash_bwd_dkv",
             grid=(bh, nk, nq),
             in_specs=[
                 q_spec,                                          # q by qi=j
@@ -1442,6 +1455,7 @@ def _bwd(q, k, v, h, o, lse, do, causal, sm_scale, block_q, block_k,
         k_by_j = pl.BlockSpec((1, bk, d), lambda g, i, j: (g, j, 0))
         dq = pl.pallas_call(
             dqk,
+            name="flash_bwd_dq",
             grid=(bh, nq, nk),
             in_specs=[
                 qi_spec,

@@ -334,6 +334,8 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *,
         else "flash_decode_paged_kernel"
     out = pl.pallas_call(
         body,
+        # the custom call's name in the compiled program and the trace
+        name="flash_decode" if not paged else "flash_decode_paged",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, 1, C), dtype),
         compiler_params=pltpu.CompilerParams(

@@ -47,20 +47,26 @@ def fused_lm_cross_entropy(hidden, table, targets):
     hidden: [B, T, D] compute dtype; table: [V, D] tied embedding;
     targets: [B, T] int labels.  Returns mean token CE (fp32 scalar).
     """
-    logits = jax.lax.dot_general(
-        hidden, table.astype(hidden.dtype),
-        (((2,), (1,)), ((), ())))                      # [B, T, V] bf16
-    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1))
-    # upcast BEFORE the max subtraction: both casts are exact (m is one
-    # of the logits) and stay elementwise inside the reduction fusion,
-    # so the exp argument carries full fp32 precision — identical to the
-    # naive path — while still no fp32 [B,T,V] tensor hits HBM
-    shifted = logits.astype(jnp.float32) - m.astype(jnp.float32)[..., None]
-    sumexp = jnp.sum(jnp.exp(shifted), axis=-1)
-    lse = jnp.log(sumexp) + m.astype(jnp.float32)
-    logit_y = jnp.take_along_axis(
-        logits, targets[..., None], axis=-1)[..., 0]
-    return (lse - logit_y.astype(jnp.float32)).mean()
+    # profiler scopes (telemetry/scopes.py): the head's matmul, then the
+    # loss over its logits
+    with jax.named_scope("lm_head"):
+        logits = jax.lax.dot_general(
+            hidden, table.astype(hidden.dtype),
+            (((2,), (1,)), ((), ())))                  # [B, T, V] bf16
+    with jax.named_scope("loss"):
+        m = jax.lax.stop_gradient(jnp.max(logits, axis=-1))
+        # upcast BEFORE the max subtraction: both casts are exact (m is
+        # one of the logits) and stay elementwise inside the reduction
+        # fusion, so the exp argument carries full fp32 precision —
+        # identical to the naive path — while still no fp32 [B,T,V]
+        # tensor hits HBM
+        shifted = logits.astype(jnp.float32) \
+            - m.astype(jnp.float32)[..., None]
+        sumexp = jnp.sum(jnp.exp(shifted), axis=-1)
+        lse = jnp.log(sumexp) + m.astype(jnp.float32)
+        logit_y = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1)[..., 0]
+        return (lse - logit_y.astype(jnp.float32)).mean()
 
 
 def chunked_softmax_cross_entropy(hidden, table, targets,
@@ -93,11 +99,14 @@ def chunked_softmax_cross_entropy(hidden, table, targets,
 
     def body(total, xs):
         hc, yc = xs
-        logits = jax.lax.dot_general(
-            hc, table, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [rows, V] f32
-        ce = optax.softmax_cross_entropy_with_integer_labels(logits, yc)
-        return total + ce.sum(), None
+        with jax.named_scope("lm_head"):
+            logits = jax.lax.dot_general(
+                hc, table, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [rows, V] f32
+        with jax.named_scope("loss"):
+            ce = optax.softmax_cross_entropy_with_integer_labels(
+                logits, yc)
+            return total + ce.sum(), None
 
     total, _ = jax.lax.scan(jax.checkpoint(body),
                             jnp.zeros((), jnp.float32), (h, y))

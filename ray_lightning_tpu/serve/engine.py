@@ -47,6 +47,7 @@ from ray_lightning_tpu.core.steps import (
 )
 from ray_lightning_tpu.serve.kvcache import KVCacheSpec
 from ray_lightning_tpu.telemetry import metrics as _metrics
+from ray_lightning_tpu.telemetry import span
 
 _log = logging.getLogger(__name__)
 
@@ -98,6 +99,7 @@ class ServeEngine:
         self._kv_init = None
         self._k = None
         self._v = None
+        self._k_dtype = None
         # draft plane (spec decode)
         self.draft_kv_spec: Optional[KVCacheSpec] = None
         self.draft_layers = 0
@@ -130,256 +132,267 @@ class ServeEngine:
         module = self.module
         module.setup_model()
         model = module.configure_decode_model()
-        mesh = self.strategy.build_mesh(batch_hint=self.slots)
+        with span("devices"):
+            # the first question about devices: JAX starts and claims
+            # the chip in here
+            mesh = self.strategy.build_mesh(batch_hint=self.slots)
         self._mesh = mesh
         set_current_mesh(mesh)
 
-        # abstract params + cache geometry, no device work: params from
-        # the model's own init avals, K/V head shapes from an abstract
-        # prefill capture on the smallest bucket
-        dummy = jax.ShapeDtypeStruct((1, self.buckets[0]), np.int32)
-        abstract_vars = jax.eval_shape(
-            model.init, jax.random.PRNGKey(0), dummy)
-        abstract_params = abstract_vars["params"]
-        _, cap = jax.eval_shape(
-            lambda p, t: model.apply({"params": p}, t, True,
-                                     mutable=["kv_cache"]),
-            abstract_params, dummy)
-        k_avals = [k for k, _ in kv_layer_pairs(cap["kv_cache"])]
-        self.kv_spec = KVCacheSpec.from_capture(
-            k_avals, self.slots, self.max_seq_len)
-        kv_dtype = k_avals[0].dtype
+        with span("build"):
+            # abstract params + cache geometry, no device work: params from
+            # the model's own init avals, K/V head shapes from an abstract
+            # prefill capture on the smallest bucket
+            dummy = jax.ShapeDtypeStruct((1, self.buckets[0]), np.int32)
+            abstract_vars = jax.eval_shape(
+                model.init, jax.random.PRNGKey(0), dummy)
+            abstract_params = abstract_vars["params"]
+            _, cap = jax.eval_shape(
+                lambda p, t: model.apply({"params": p}, t, True,
+                                         mutable=["kv_cache"]),
+                abstract_params, dummy)
+            k_avals = [k for k, _ in kv_layer_pairs(cap["kv_cache"])]
+            self.kv_spec = KVCacheSpec.from_capture(
+                k_avals, self.slots, self.max_seq_len)
+            kv_dtype = self._k_dtype = k_avals[0].dtype
 
-        param_sh = self.strategy._shardings_with(
-            mesh, abstract_params, self.strategy.param_spec)
-        kv_sh = NamedSharding(mesh, self.strategy.kv_cache_spec(mesh))
-        rep = NamedSharding(mesh, P())
-        multi = mesh.devices.size > 1
+            param_sh = self.strategy._shardings_with(
+                mesh, abstract_params, self.strategy.param_spec)
+            kv_sh = NamedSharding(mesh, self.strategy.kv_cache_spec(mesh))
+            rep = NamedSharding(mesh, P())
+            multi = mesh.devices.size > 1
 
-        # -- params: restored weights or a seeded fresh init --------------
-        if self._weights is not None:
-            from flax import serialization
-            params = self._weights["params"] \
-                if isinstance(self._weights, dict) \
-                and "params" in self._weights else self._weights
-            # normalize checkpoint/state-dict nesting onto the model's
-            # own param tree structure before sharding
-            params = serialization.from_state_dict(abstract_params,
-                                                   params)
-            self.params = jax.device_put(params, param_sh) \
-                if multi else jax.device_put(params)
-        else:
-            def init_fn(rng):
-                import jax.numpy as jnp
-                variables = module.init_params(
-                    rng, np.zeros((1, self.buckets[0]), np.int32))
-                p = dict(variables)["params"]
-                pd = getattr(module, "param_dtype", None)
-                if pd is not None:
-                    p = jax.tree_util.tree_map(
-                        lambda a: a.astype(pd)
-                        if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                        p)
-                return p
-
-            ikw = {"out_shardings": param_sh} if multi else {}
-            self.params = jax.jit(init_fn, **ikw)(
-                jax.random.PRNGKey(self.seed))
-        self._weights = None
-
-        # -- programs ------------------------------------------------------
-        import jax.numpy as jnp
-        shape = self.kv_spec.shape
-
-        def kv_init():
-            z = jnp.zeros(shape, kv_dtype)
-            return z, z
-
-        kkw = {"out_shardings": (kv_sh, kv_sh)} if multi else {}
-        self._kv_init = jax.jit(self._counted("kv_init", kv_init), **kkw)
-
-        def jit_step(name, fn, n_scalars):
-            kw: dict = {"donate_argnums": (1, 2)}
-            if multi:
-                kw["in_shardings"] = (
-                    (param_sh, kv_sh, kv_sh) + (rep,) * n_scalars)
-                kw["out_shardings"] = (kv_sh, kv_sh, rep)
-            return jax.jit(self._counted(name, fn), **kw)
-
-        for b in self.buckets:
-            self._prefills[b] = jit_step(
-                f"prefill_{b}", build_prefill_step(module, b), 3)
-
-        # the paged kernel needs a page table whose pages tile the
-        # cache; with paging off or ragged no table is plumbed and
-        # "paged" lowers the slot-contiguous flash kernel instead
-        # (ops/flash_decode.py select_decode_kernel)
-        from ray_lightning_tpu.ops.flash_decode import resolve_decode_impl
-        page_table = suffix_table = None
-        if resolve_decode_impl(None) == "paged" \
-                and self.paged is not None \
-                and self.max_seq_len % self.paged.page_size == 0:
-            from ray_lightning_tpu.serve.fleet.pages import (
-                identity_page_table)
-            page_table = identity_page_table(
-                self.slots, self.max_seq_len, self.paged.page_size)
-            suffix_table = identity_page_table(
-                1, self.max_seq_len, self.paged.page_size)
-        self._decode = jit_step(
-            "decode", build_decode_step(module, page_table=page_table), 2)
-        if self.paged is not None:
-            # paged-KV programs (serve/fleet/pages.py): a masked page
-            # copy for prefix-cache hits + the single-slot suffix step
-            # that computes only the unmatched tail of a prompt
-            self._suffix = jit_step(
-                "suffix",
-                build_suffix_step(module, page_table=suffix_table), 3)
-            ckw: dict = {"donate_argnums": (0, 1)}
-            if multi:
-                ckw["in_shardings"] = (kv_sh, kv_sh, rep, rep, rep)
-                ckw["out_shardings"] = (kv_sh, kv_sh)
-            self._kv_copy = jax.jit(
-                self._counted("kv_copy", build_kv_copy()), **ckw)
-
-        if self.spec is not None:
-            # -- draft plane (speculative decoding, serve/spec.py) ---------
-            draft_model = module.configure_draft(
-                self.spec.draft_layers or None)
-            if draft_model is None:
-                raise ValueError(
-                    f"spec= requires {type(module).__name__}."
-                    f"configure_draft() to return a draft module "
-                    f"(core/module.py hook); it returned None")
-            self._draft_model = draft_model
-            self.draft_layers = getattr(
-                getattr(draft_model, "config", None), "n_layer", 0)
-            d_abstract = jax.eval_shape(
-                draft_model.init, jax.random.PRNGKey(0), dummy)["params"]
-
-            def _subtree(target, aval, path=""):
-                """Draft params BY PATH out of the target tree — the
-                weight-sharing contract: every draft param is the
-                target's same-named array (zero extra HBM)."""
-                if isinstance(aval, dict):
-                    out = {}
-                    for name, sub in aval.items():
-                        if name not in target:
-                            raise ValueError(
-                                f"draft param {path + name!r} missing "
-                                f"from the target tree: "
-                                f"configure_draft() must share the "
-                                f"target's param naming")
-                        out[name] = _subtree(target[name], sub,
-                                             path + name + "/")
-                    return out
-                if tuple(target.shape) != tuple(aval.shape):
-                    raise ValueError(
-                        f"draft param {path!r}: shape {aval.shape} != "
-                        f"target {target.shape}")
-                return target
-
-            draft_params = _subtree(self.params, d_abstract)
-            self.draft_fp_bytes = int(sum(
-                int(np.prod(a.shape)) * 2
-                for a in jax.tree_util.tree_leaves(d_abstract)))
-            dequant = None
-            if self.spec.draft_quant == "int8":
-                # int8 residency (RLT_DRAFT_QUANT): hold the draft tree
-                # as blockwise (payload, scale) pairs, dequantized
-                # INSIDE the draft programs (comm/quant.py).  Trades
-                # the zero-cost views for a ~2x-smaller standalone copy
-                # whose bytes stay resident even if the target tree is
-                # later offloaded; the measured delta rides stats().
-                from ray_lightning_tpu.comm.quant import (
-                    dequantize_blob, quantize_blob)
-                flat, treedef = jax.tree_util.tree_flatten(draft_params)
-                shapes = [tuple(a.shape) for a in flat]
-                dtypes = [a.dtype for a in flat]
-                qflat = [tuple(quantize_blob(a, "int8")) for a in flat]
-                self._draft_params = qflat
-                self.draft_resident_bytes = int(sum(
-                    p.nbytes + s.nbytes for p, s in qflat))
-
-                def dequant(qleaves):
-                    leaves = [
-                        dequantize_blob(p, s, "int8", shape, dtype=dt)
-                        for (p, s), shape, dt in zip(qleaves, shapes,
-                                                     dtypes)]
-                    return jax.tree_util.tree_unflatten(treedef, leaves)
+        with span("weights"):
+            # -- params: restored weights or a seeded fresh init --------------
+            if self._weights is not None:
+                from flax import serialization
+                params = self._weights["params"] \
+                    if isinstance(self._weights, dict) \
+                    and "params" in self._weights else self._weights
+                # normalize checkpoint/state-dict nesting onto the model's
+                # own param tree structure before sharding
+                params = serialization.from_state_dict(abstract_params,
+                                                       params)
+                self.params = jax.device_put(params, param_sh) \
+                    if multi else jax.device_put(params)
             else:
-                self._draft_params = draft_params
+                def init_fn(rng):
+                    import jax.numpy as jnp
+                    variables = module.init_params(
+                        rng, np.zeros((1, self.buckets[0]), np.int32))
+                    p = dict(variables)["params"]
+                    pd = getattr(module, "param_dtype", None)
+                    if pd is not None:
+                        p = jax.tree_util.tree_map(
+                            lambda a: a.astype(pd)
+                            if jnp.issubdtype(a.dtype, jnp.floating) else a,
+                            p)
+                    return p
 
-            # draft KV geometry from an abstract draft prefill capture
-            _, dcap = jax.eval_shape(
-                lambda p, t: draft_model.apply(
-                    {"params": p}, t, True, mutable=["kv_cache"]),
-                d_abstract, dummy)
-            dk_avals = [a for a, _ in kv_layer_pairs(dcap["kv_cache"])]
-            self.draft_kv_spec = KVCacheSpec.from_capture(
-                dk_avals, self.slots, self.max_seq_len)
-            d_shape = self.draft_kv_spec.shape
+                ikw = {"out_shardings": param_sh} if multi else {}
+                self.params = jax.jit(init_fn, **ikw)(
+                    jax.random.PRNGKey(self.seed))
+            self._weights = None
+            # (not waited for: the device makes the weights while this
+            # thread builds the programs and the AOT thread loads them;
+            # the span holds the host's seconds, trace and dispatch)
+        with span("build"):
 
-            def dkv_init():
-                z = jnp.zeros(d_shape, kv_dtype)
+            # -- programs ------------------------------------------------------
+            import jax.numpy as jnp
+            shape = self.kv_spec.shape
+
+            def kv_init():
+                z = jnp.zeros(shape, kv_dtype)
                 return z, z
 
-            self._dkv_init = jax.jit(
-                self._counted("draft_kv_init", dkv_init), **kkw)
+            kkw = {"out_shardings": (kv_sh, kv_sh)} if multi else {}
+            self._kv_init = jax.jit(self._counted("kv_init", kv_init), **kkw)
 
-            def jit_draft(name, fn):
-                # no in_shardings pin: the draft param tree is NOT the
-                # target tree (subtree, possibly quantized pairs) — jax
-                # reads the resident shardings of the shared views
+            def jit_step(name, fn, n_scalars):
                 kw: dict = {"donate_argnums": (1, 2)}
                 if multi:
+                    kw["in_shardings"] = (
+                        (param_sh, kv_sh, kv_sh) + (rep,) * n_scalars)
                     kw["out_shardings"] = (kv_sh, kv_sh, rep)
                 return jax.jit(self._counted(name, fn), **kw)
 
             for b in self.buckets:
-                self._draft_prefills[b] = jit_draft(
-                    f"draft_prefill_{b}",
-                    build_prefill_step(module, b, model=draft_model,
-                                       dequant=dequant))
-            self._draft = jit_draft(
-                "draft",
-                build_draft_step(module, self.spec.k,
-                                 page_table=page_table,
-                                 model=draft_model, dequant=dequant))
-            self._verify = jit_step(
-                "verify",
-                build_verify_step(module, self.spec.k,
-                                  page_table=page_table), 2)
+                self._prefills[b] = jit_step(
+                    f"prefill_{b}", build_prefill_step(module, b), 3)
 
-        if self.kvship:
-            # -- KV-page import programs (fleet disaggregation) ------------
-            # one per bucket: install shipped donor rows [0, b) at a
-            # slot with a single dynamic_update_slice per cache — the
-            # device half of cross-replica prefix donation
-            # (serve/fleet/router.py ships, PrefixIndex addresses)
-            def import_fn(k_caches, v_caches, ks, vs, slot):
-                zero = (0,) * (k_caches.ndim - 2)
-                k_caches = jax.lax.dynamic_update_slice(
-                    k_caches, ks, (0, slot) + zero)
-                v_caches = jax.lax.dynamic_update_slice(
-                    v_caches, vs, (0, slot) + zero)
-                return k_caches, v_caches
-
-            for b in self.buckets:
-                ikw2: dict = {"donate_argnums": (0, 1)}
+            # the paged kernel needs a page table whose pages tile the
+            # cache; with paging off or ragged no table is plumbed and
+            # "paged" lowers the slot-contiguous flash kernel instead
+            # (ops/flash_decode.py select_decode_kernel)
+            from ray_lightning_tpu.ops.flash_decode import resolve_decode_impl
+            page_table = suffix_table = None
+            if resolve_decode_impl(None) == "paged" \
+                    and self.paged is not None \
+                    and self.max_seq_len % self.paged.page_size == 0:
+                from ray_lightning_tpu.serve.fleet.pages import (
+                    identity_page_table)
+                page_table = identity_page_table(
+                    self.slots, self.max_seq_len, self.paged.page_size)
+                suffix_table = identity_page_table(
+                    1, self.max_seq_len, self.paged.page_size)
+            self._decode = jit_step(
+                "decode", build_decode_step(module, page_table=page_table), 2)
+            if self.paged is not None:
+                # paged-KV programs (serve/fleet/pages.py): a masked page
+                # copy for prefix-cache hits + the single-slot suffix step
+                # that computes only the unmatched tail of a prompt
+                self._suffix = jit_step(
+                    "suffix",
+                    build_suffix_step(module, page_table=suffix_table), 3)
+                ckw: dict = {"donate_argnums": (0, 1)}
                 if multi:
-                    ikw2["in_shardings"] = (kv_sh, kv_sh, rep, rep, rep)
-                    ikw2["out_shardings"] = (kv_sh, kv_sh)
-                self._kv_imports[b] = jax.jit(
-                    self._counted(f"kv_import_{b}", import_fn), **ikw2)
+                    ckw["in_shardings"] = (kv_sh, kv_sh, rep, rep, rep)
+                    ckw["out_shardings"] = (kv_sh, kv_sh)
+                self._kv_copy = jax.jit(
+                    self._counted("kv_copy", build_kv_copy()), **ckw)
 
-        # AOT avals must describe the params AS SERVED (post
-        # param_dtype cast / restore), not the fp32 init avals — a
-        # dtype drift here would background-compile a program the
-        # dispatch never runs (cache miss instead of the hit the
-        # compiled-once story is built on)
-        param_avals = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.params)
-        self._precompile_and_warm(jax, param_avals, shape, kv_dtype)
+            if self.spec is not None:
+                # -- draft plane (speculative decoding, serve/spec.py) ---------
+                draft_model = module.configure_draft(
+                    self.spec.draft_layers or None)
+                if draft_model is None:
+                    raise ValueError(
+                        f"spec= requires {type(module).__name__}."
+                        f"configure_draft() to return a draft module "
+                        f"(core/module.py hook); it returned None")
+                self._draft_model = draft_model
+                self.draft_layers = getattr(
+                    getattr(draft_model, "config", None), "n_layer", 0)
+                d_abstract = jax.eval_shape(
+                    draft_model.init, jax.random.PRNGKey(0), dummy)["params"]
+
+                def _subtree(target, aval, path=""):
+                    """Draft params BY PATH out of the target tree — the
+                    weight-sharing contract: every draft param is the
+                    target's same-named array (zero extra HBM)."""
+                    if isinstance(aval, dict):
+                        out = {}
+                        for name, sub in aval.items():
+                            if name not in target:
+                                raise ValueError(
+                                    f"draft param {path + name!r} missing "
+                                    f"from the target tree: "
+                                    f"configure_draft() must share the "
+                                    f"target's param naming")
+                            out[name] = _subtree(target[name], sub,
+                                                 path + name + "/")
+                        return out
+                    if tuple(target.shape) != tuple(aval.shape):
+                        raise ValueError(
+                            f"draft param {path!r}: shape {aval.shape} != "
+                            f"target {target.shape}")
+                    return target
+
+                draft_params = _subtree(self.params, d_abstract)
+                self.draft_fp_bytes = int(sum(
+                    int(np.prod(a.shape)) * 2
+                    for a in jax.tree_util.tree_leaves(d_abstract)))
+                dequant = None
+                if self.spec.draft_quant == "int8":
+                    # int8 residency (RLT_DRAFT_QUANT): hold the draft tree
+                    # as blockwise (payload, scale) pairs, dequantized
+                    # INSIDE the draft programs (comm/quant.py).  Trades
+                    # the zero-cost views for a ~2x-smaller standalone copy
+                    # whose bytes stay resident even if the target tree is
+                    # later offloaded; the measured delta rides stats().
+                    from ray_lightning_tpu.comm.quant import (
+                        dequantize_blob, quantize_blob)
+                    flat, treedef = jax.tree_util.tree_flatten(draft_params)
+                    shapes = [tuple(a.shape) for a in flat]
+                    dtypes = [a.dtype for a in flat]
+                    qflat = [tuple(quantize_blob(a, "int8")) for a in flat]
+                    self._draft_params = qflat
+                    self.draft_resident_bytes = int(sum(
+                        p.nbytes + s.nbytes for p, s in qflat))
+
+                    def dequant(qleaves):
+                        leaves = [
+                            dequantize_blob(p, s, "int8", shape, dtype=dt)
+                            for (p, s), shape, dt in zip(qleaves, shapes,
+                                                         dtypes)]
+                        return jax.tree_util.tree_unflatten(treedef, leaves)
+                else:
+                    self._draft_params = draft_params
+
+                # draft KV geometry from an abstract draft prefill capture
+                _, dcap = jax.eval_shape(
+                    lambda p, t: draft_model.apply(
+                        {"params": p}, t, True, mutable=["kv_cache"]),
+                    d_abstract, dummy)
+                dk_avals = [a for a, _ in kv_layer_pairs(dcap["kv_cache"])]
+                self.draft_kv_spec = KVCacheSpec.from_capture(
+                    dk_avals, self.slots, self.max_seq_len)
+                d_shape = self.draft_kv_spec.shape
+
+                def dkv_init():
+                    z = jnp.zeros(d_shape, kv_dtype)
+                    return z, z
+
+                self._dkv_init = jax.jit(
+                    self._counted("draft_kv_init", dkv_init), **kkw)
+
+                def jit_draft(name, fn):
+                    # no in_shardings pin: the draft param tree is NOT the
+                    # target tree (subtree, possibly quantized pairs) — jax
+                    # reads the resident shardings of the shared views
+                    kw: dict = {"donate_argnums": (1, 2)}
+                    if multi:
+                        kw["out_shardings"] = (kv_sh, kv_sh, rep)
+                    return jax.jit(self._counted(name, fn), **kw)
+
+                for b in self.buckets:
+                    self._draft_prefills[b] = jit_draft(
+                        f"draft_prefill_{b}",
+                        build_prefill_step(module, b, model=draft_model,
+                                           dequant=dequant))
+                self._draft = jit_draft(
+                    "draft",
+                    build_draft_step(module, self.spec.k,
+                                     page_table=page_table,
+                                     model=draft_model, dequant=dequant))
+                self._verify = jit_step(
+                    "verify",
+                    build_verify_step(module, self.spec.k,
+                                      page_table=page_table), 2)
+
+            if self.kvship:
+                # -- KV-page import programs (fleet disaggregation) ------------
+                # one per bucket: install shipped donor rows [0, b) at a
+                # slot with a single dynamic_update_slice per cache — the
+                # device half of cross-replica prefix donation
+                # (serve/fleet/router.py ships, PrefixIndex addresses)
+                def import_fn(k_caches, v_caches, ks, vs, slot):
+                    zero = (0,) * (k_caches.ndim - 2)
+                    k_caches = jax.lax.dynamic_update_slice(
+                        k_caches, ks, (0, slot) + zero)
+                    v_caches = jax.lax.dynamic_update_slice(
+                        v_caches, vs, (0, slot) + zero)
+                    return k_caches, v_caches
+
+                for b in self.buckets:
+                    ikw2: dict = {"donate_argnums": (0, 1)}
+                    if multi:
+                        ikw2["in_shardings"] = (kv_sh, kv_sh, rep, rep, rep)
+                        ikw2["out_shardings"] = (kv_sh, kv_sh)
+                    self._kv_imports[b] = jax.jit(
+                        self._counted(f"kv_import_{b}", import_fn), **ikw2)
+
+            # AOT avals must describe the params AS SERVED (post
+            # param_dtype cast / restore), not the fp32 init avals — a
+            # dtype drift here would background-compile a program the
+            # dispatch never runs (cache miss instead of the hit the
+            # compiled-once story is built on)
+            param_avals = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.params)
+            pre = self._submit_precompiles(jax, param_avals, shape,
+                                           kv_dtype)
+        self._warm(jax, pre)
         _log.info(
             "serve engine ready in %.2fs: mesh=%s buckets=%s slots=%d "
             "kv=%s (%.1f MB)", time.monotonic() - t0, dict(mesh.shape),
@@ -387,13 +400,12 @@ class ServeEngine:
             self.kv_spec.nbytes(np.dtype(kv_dtype).itemsize) / 2**20)
         return self
 
-    def _precompile_and_warm(self, jax, abstract_params, kv_shape,
-                             kv_dtype) -> None:
+    def _submit_precompiles(self, jax, abstract_params, kv_shape,
+                            kv_dtype) -> AotPrecompiler:
         """Background-compile every program through the persistent cache
-        (no-op when the cache is inactive, compile/aot.py), then warm
-        each with ONE dispatch on scratch state — after this, a serving
-        trace-count increment means a real retrace (the acceptance
-        counter)."""
+        (no-op when the cache is inactive, compile/aot.py): the AOT
+        thread lowers and compiles (or loads) them while this thread
+        goes on."""
         pre = AotPrecompiler.resolve()
         kv_aval = jax.ShapeDtypeStruct(kv_shape, kv_dtype)
         i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)  # noqa: E731
@@ -433,45 +445,68 @@ class ServeEngine:
                 rows = jax.ShapeDtypeStruct((nl, 1, b, nh, hd), kv_dtype)
                 pre.submit(f"kv_import_{b}", jitted,
                            (kv_aval, kv_aval, rows, rows, i32()))
-        pre.barrier()
+        return pre
 
-        # scratch warmup: the warmed cache state is garbage, so re-init
-        # the real cache afterwards (slots are overwritten by their
-        # admitting prefill anyway; this keeps even slot 0 pristine)
-        k, v = self._kv_init()
-        for b, jitted in self._prefills.items():
-            k, v, tok = jitted(self.params, k, v,
-                               np.zeros((1, b), np.int32),
-                               np.int32(0), np.int32(1))
-        zeros = np.zeros((self.slots,), np.int32)
-        k, v, toks = self._decode(self.params, k, v, zeros, zeros)
-        if self.paged is not None:
-            k, v = self._kv_copy(k, v, np.int32(0),
-                                 np.int32(self.slots - 1), np.int32(1))
-            k, v, toks = self._suffix(self.params, k, v, np.int32(0),
-                                      np.int32(0), np.int32(0))
-        if self.spec is not None:
-            dk, dv = self._dkv_init()
-            for b, jitted in self._draft_prefills.items():
-                dk, dv, _ = jitted(self._draft_params, dk, dv,
-                                   np.zeros((1, b), np.int32),
-                                   np.int32(0), np.int32(1))
-            dk, dv, _ = self._draft(self._draft_params, dk, dv, zeros,
-                                    zeros)
-            z2 = np.zeros((self.slots, self.spec.k + 1), np.int32)
-            k, v, toks = self._verify(self.params, k, v, z2, z2)
-            del dk, dv
-        if self.kvship:
-            nl, _, _, nh, hd = self.kv_spec.shape
-            for b, jitted in self._kv_imports.items():
-                rows = np.zeros((nl, 1, b, nh, hd), kv_dtype)
-                k, v = jitted(k, v, rows, rows, np.int32(0))
-        jax.block_until_ready(toks)
-        del k, v
-        self._k, self._v = self._kv_init()
-        if self.spec is not None:
-            # draft-cache warmup state is garbage too: re-init
-            self._dk, self._dv = self._dkv_init()
+    def _warm(self, jax, pre: AotPrecompiler) -> None:
+        """Warm each program with ONE dispatch on scratch state — after
+        this, a serving trace-count increment means a real retrace (the
+        acceptance counter).  One ``warm`` span per program around its
+        dispatch, which is where a cached executable loads (or, on a
+        cache miss, compiles); the programs run back to back on the
+        device meanwhile, and ``device_wait`` is the one wait for them
+        all."""
+        def warm(program, fn, *args):
+            with span("warm", program=program):
+                return fn(*args)
+
+        kv_dtype = self._k_dtype
+        with span("warmup"):
+            with span("aot_wait"):
+                pre.barrier()
+            # scratch warmup: the warmed cache state is garbage, so
+            # re-init the real cache afterwards (slots are overwritten
+            # by their admitting prefill anyway; this keeps even slot 0
+            # pristine)
+            k, v = warm("kv_init", self._kv_init)
+            for b, jitted in self._prefills.items():
+                k, v, tok = warm(f"prefill_{b}", jitted, self.params, k, v,
+                                 np.zeros((1, b), np.int32),
+                                 np.int32(0), np.int32(1))
+            zeros = np.zeros((self.slots,), np.int32)
+            k, v, toks = warm("decode", self._decode, self.params, k, v,
+                              zeros, zeros)
+            if self.paged is not None:
+                k, v = warm("kv_copy", self._kv_copy, k, v, np.int32(0),
+                            np.int32(self.slots - 1), np.int32(1))
+                k, v, toks = warm("suffix", self._suffix, self.params, k,
+                                  v, np.int32(0), np.int32(0), np.int32(0))
+            if self.spec is not None:
+                dk, dv = warm("draft_kv_init", self._dkv_init)
+                for b, jitted in self._draft_prefills.items():
+                    dk, dv, _ = warm(f"draft_prefill_{b}", jitted,
+                                     self._draft_params, dk, dv,
+                                     np.zeros((1, b), np.int32),
+                                     np.int32(0), np.int32(1))
+                dk, dv, _ = warm("draft", self._draft, self._draft_params,
+                                 dk, dv, zeros, zeros)
+                z2 = np.zeros((self.slots, self.spec.k + 1), np.int32)
+                k, v, toks = warm("verify", self._verify, self.params, k,
+                                  v, z2, z2)
+                del dk, dv
+            if self.kvship:
+                nl, _, _, nh, hd = self.kv_spec.shape
+                for b, jitted in self._kv_imports.items():
+                    rows = np.zeros((nl, 1, b, nh, hd), kv_dtype)
+                    k, v = warm(f"kv_import_{b}", jitted, k, v, rows,
+                                rows, np.int32(0))
+            with span("device_wait"):
+                jax.block_until_ready(toks)
+            del k, v
+        with span("kv_init"):
+            self._k, self._v = self._kv_init()
+            if self.spec is not None:
+                # draft-cache warmup state is garbage too: re-init
+                self._dk, self._dv = self._dkv_init()
         #: trace counts at the end of warmup — any later growth is a
         #: REAL decode-loop retrace (the acceptance counter)
         self.trace_counts_at_warmup = dict(self.trace_counts)
@@ -495,6 +530,9 @@ class ServeEngine:
             # one kernel per program: every layer has the same geometry
             self.decode_kernel = "+".join(sorted(lowered)) or None
             return out
+        # the program's name: the profiler trace and the compile cache
+        # read jit_serve_decode, jit_serve_prefill_512, ...
+        wrapped.__name__ = wrapped.__qualname__ = f"serve_{name}"
         return wrapped
 
     # -- serving -----------------------------------------------------------
@@ -504,12 +542,14 @@ class ServeEngine:
         """Insert a request at ``slot``: write its K/V block, return its
         first generated token."""
         t0 = time.monotonic()
-        self._k, self._v, tok = self._prefills[bucket](
-            self.params, self._k, self._v,
-            np.asarray(tokens, np.int32), np.int32(slot),
-            np.int32(length))
+        with span("dispatch"):
+            self._k, self._v, tok = self._prefills[bucket](
+                self.params, self._k, self._v,
+                np.asarray(tokens, np.int32), np.int32(slot),
+                np.int32(length))
         import jax
-        out = int(np.asarray(jax.device_get(tok)))
+        with span("fetch"):
+            out = int(np.asarray(jax.device_get(tok)))
         self._charge("rlt_serve_prefill_seconds_total",
                      time.monotonic() - t0)
         return out
@@ -548,11 +588,14 @@ class ServeEngine:
                positions: np.ndarray) -> np.ndarray:
         """One continuous-batching step: every slot advances a token."""
         t0 = time.monotonic()
-        self._k, self._v, out = self._decode(
-            self.params, self._k, self._v,
-            np.asarray(tokens, np.int32), np.asarray(positions, np.int32))
+        with span("dispatch"):
+            self._k, self._v, out = self._decode(
+                self.params, self._k, self._v,
+                np.asarray(tokens, np.int32),
+                np.asarray(positions, np.int32))
         import jax
-        toks = np.asarray(jax.device_get(out))
+        with span("fetch"):
+            toks = np.asarray(jax.device_get(out))
         self._charge("rlt_serve_decode_seconds_total",
                      time.monotonic() - t0)
         return toks

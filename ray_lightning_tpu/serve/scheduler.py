@@ -156,6 +156,59 @@ class ServeRequest:
         self._event.set()
 
 
+class PumpClock:
+    """Where the driver pump's wall time goes, always on: one clock read
+    at each boundary of a step (before ``plan``, after it, after the
+    ``serve_step`` calls went out, after they all came back, after
+    ``apply``), summed per phase.  ``Server``'s pump owns the reads; the
+    sums come back under ``Scheduler.stats()["pump"]`` so that whoever
+    reads the scheduler's stats reads them too.
+
+    ``loop_s`` is the pump's own bookkeeping between two steps (queue
+    drain, watchdog, goodput peek); ``worker_s`` the workers' own
+    ``serve_step`` seconds as the step results report them, so
+    ``call_s + wait_s - worker_s`` is what the RPC cost.  An iteration
+    that found nothing to plan is ``idle_s`` whole.  The phases of the
+    finished iterations add up to ``wall_s``; an iteration in flight is
+    the difference."""
+
+    PHASES = ("loop", "plan", "call", "wait", "apply", "idle")
+
+    def __init__(self, clock=time.monotonic):
+        self._clock = clock
+        self.steps = 0
+        self.worker_s = 0.0
+        self.seconds = dict.fromkeys(self.PHASES, 0.0)
+        self._t_start: Optional[float] = None
+        self._t_stop: Optional[float] = None
+
+    def start(self) -> float:
+        self._t_start = self._clock()
+        return self._t_start
+
+    def stop(self) -> None:
+        self._t_stop = self._clock()
+
+    def now(self) -> float:
+        return self._clock()
+
+    def add(self, phase: str, t0: float,
+            t1: Optional[float] = None) -> float:
+        """Charge ``phase`` with the time from ``t0`` to ``t1`` (now when
+        left out); returns ``t1``."""
+        if t1 is None:
+            t1 = self._clock()
+        self.seconds[phase] += t1 - t0
+        return t1
+
+    def snapshot(self) -> dict:
+        out = {f"{k}_s": v for k, v in self.seconds.items()}
+        out.update(steps=self.steps, worker_s=self.worker_s)
+        if self._t_start is not None:
+            out["wall_s"] = (self._t_stop or self._clock()) - self._t_start
+        return out
+
+
 @dataclass
 class _Tenant:
     name: str
@@ -221,6 +274,8 @@ class Scheduler:
         self.failed = 0
         self._occupancy_sum = 0.0
         self._decode_steps = 0
+        #: the driver pump's phase clock (serve/server.py owns the reads)
+        self.pump = PumpClock()
         # rolling latency tails (incident plane): the histograms above
         # are cumulative-forever, so a live p99 regression drowns in
         # history — these bounded deques carry only the recent window
@@ -744,6 +799,7 @@ class Scheduler:
                 self._occupancy_sum / self._decode_steps
                 if self._decode_steps else 0.0),
             "decode_steps": self._decode_steps,
+            "pump": self.pump.snapshot(),
             "per_tenant": {
                 name: {"active": t.active, "queued": len(t.queue),
                        "served_tokens": t.served_tokens,

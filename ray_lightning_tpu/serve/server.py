@@ -28,6 +28,7 @@ the caller, like every dataset concern in this framework.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -45,7 +46,7 @@ from ray_lightning_tpu.parallel.strategy import resolve_strategy
 from ray_lightning_tpu.serve.buckets import resolve_buckets
 from ray_lightning_tpu.serve.scheduler import Scheduler, ServeRequest
 from ray_lightning_tpu.serve.worker import ServeWorker
-from ray_lightning_tpu.telemetry import TelemetryConfig
+from ray_lightning_tpu.telemetry import TelemetryConfig, span, spans
 from ray_lightning_tpu.util import _handle_queue_item
 from ray_lightning_tpu.utils.platform import (host_device_count_flags,
                                               require_chip_free)
@@ -162,6 +163,9 @@ class Server:
         #: finalized doc — the serve half of the goodput surface
         self._goodput_ledger = None
         self.goodput_doc: Optional[dict] = None
+        self._next_peek = 0.0
+        self._kept_steps = 0   # steps left of a profile window's spans
+        self._kept_window = contextlib.ExitStack()   # holds its keep()
 
     @staticmethod
     def _resolve_weights(module, checkpoint: Optional[str]):
@@ -191,21 +195,42 @@ class Server:
                 "checkpoint from the fit, then build "
                 "Server(module, checkpoint=path) in a fresh process "
                 "that has not touched JAX.")
+        # set-up is kept whatever the telemetry flag says, under one
+        # root: a reader in this process reads it after start() returned
+        # (telemetry/spans.py ``kept("server_start")``)
+        with spans.keep("server_start") as kept:
+            with span("server_start"):
+                self._start_fleet(kept)
+        info = self._setup_info[0]
+        _log.info("serve fleet ready: %d worker(s), mesh=%s, buckets=%s, "
+                  "slots=%d", self.num_workers, info["mesh"],
+                  info["buckets"], info["slots"])
+        self._started = True
+        self._pump = threading.Thread(target=self._pump_loop, daemon=True,
+                                      name="rlt-serve-pump")
+        self._pump.start()
+        return self
+
+    def _start_fleet(self, kept) -> None:
         backend = get_backend()
         self._backend = backend
         base_env = self._worker_env_base()
         run_tag = uuid.uuid4().hex[:8]
-        self._workers = [
-            backend.create_actor(
-                ServeWorker,
-                env={**base_env, "RLT_PROCESS_ID": str(i)},
-                resources=self._worker_resources(),
-                name=f"rlt-serve-{os.getpid()}-{run_tag}-{i}",
-            )
-            for i in range(self.num_workers)
-        ]
+        with span("spawn"):
+            self._workers = [
+                backend.create_actor(
+                    ServeWorker,
+                    env={**base_env, "RLT_PROCESS_ID": str(i)},
+                    resources=self._worker_resources(),
+                    name=f"rlt-serve-{os.getpid()}-{run_tag}-{i}",
+                )
+                for i in range(self.num_workers)
+            ]
         try:
-            self._rendezvous()
+            with span("rendezvous"):
+                # the first call a worker answers: its process start
+                # and the package's imports are in here
+                self._rendezvous()
             self._start_telemetry()
             self._queue = (backend.worker_queue_proxy()
                            if hasattr(backend, "worker_queue_proxy")
@@ -220,28 +245,26 @@ class Server:
                 kvship=self.kvship)
             payload = (spec, self._weights)
             ref = None
-            if backend.supports_object_store:
-                payload = ref = backend.put(payload)
             try:
-                futures = [
-                    w.call("setup_serve", payload, i, self._queue)
-                    for i, w in enumerate(self._workers)]
-                self._setup_info = self._wait_all(futures, timeout=600)
+                with span("ship"):
+                    if backend.supports_object_store:
+                        payload = ref = backend.put(payload)
+                    futures = [
+                        w.call("setup_serve", payload, i, self._queue)
+                        for i, w in enumerate(self._workers)]
+                with span("worker_setup") as waiting:
+                    self._setup_info = self._wait_all(futures, timeout=600)
+                    for info in self._setup_info:
+                        # the worker's own set-up spans, on this host's
+                        # wall clock already: hang them here
+                        spans.adopt(kept, info.pop("spans", []),
+                                    parent=waiting.id, rank=info["rank"])
             finally:
                 if ref is not None:
                     backend.free(ref)
         except BaseException:
             self._kill_workers()
             raise
-        info = self._setup_info[0]
-        _log.info("serve fleet ready: %d worker(s), mesh=%s, buckets=%s, "
-                  "slots=%d", self.num_workers, info["mesh"],
-                  info["buckets"], info["slots"])
-        self._started = True
-        self._pump = threading.Thread(target=self._pump_loop, daemon=True,
-                                      name="rlt-serve-pump")
-        self._pump.start()
-        return self
 
     def _worker_env_base(self) -> dict:
         """Mirror of the fit path's worker env plumbing
@@ -469,86 +492,133 @@ class Server:
             self._finish_goodput()
 
     def _pump_iterations(self, sched, ledger) -> None:
-        next_peek = time.monotonic() + 2.0
-        while not self._stop.is_set():
-            self._drain_queue()
-            self._watchdog()
-            if time.monotonic() >= next_peek:
-                if ledger is not None:
-                    # live /status: ship a mid-run peek of the open
-                    # ledger (the finalized doc replaces it at pump exit)
-                    self._ship_goodput(ledger.peek())
-                if self._agg is not None:
-                    # incident-plane serve detectors (queue depth,
-                    # TTFT/TPOT p99) tick at the same cadence
-                    self._agg.note_serve_signals(
-                        queue_depth=sched.queued_count,
-                        ttft_p99_s=sched.recent_ttft_p99(),
-                        tpot_p99_s=sched.recent_tpot_p99())
-                next_peek = time.monotonic() + 2.0
-            plan = sched.plan()
-            if plan is None:
-                if self._draining and sched.idle():
+        pump = sched.pump
+        t = pump.start()
+        self._next_peek = t + 2.0
+        try:
+            while not self._stop.is_set():
+                with span("pump.step", step=pump.steps):
+                    t, what = self._pump_step(sched, ledger, pump, t)
+                if what == "stop":
                     return
-                t_idle = time.monotonic()
+                if what == "step" and self._kept_steps:
+                    # after the step's root span closed, so that the
+                    # window's last step keeps its root
+                    self._kept_steps -= 1
+                    if self._kept_steps == 0:
+                        self._kept_window.close()
+        finally:
+            pump.stop()
+            self._kept_steps = 0
+            self._kept_window.close()
+
+    def _pump_step(self, sched, ledger, pump, t: float):
+        """One iteration: ``(now, what)`` with ``what`` one of ``step``,
+        ``idle`` and ``stop``.  ``t`` is the clock at the last
+        iteration's end: the phases partition the pump's wall time
+        (scheduler.py ``PumpClock``)."""
+        self._drain_queue()
+        self._watchdog()
+        if t >= self._next_peek:
+            if ledger is not None:
+                # live /status: ship a mid-run peek of the open
+                # ledger (the finalized doc replaces it at pump exit)
+                self._ship_goodput(ledger.peek())
+            if self._agg is not None:
+                # incident-plane serve detectors (queue depth,
+                # TTFT/TPOT p99) tick at the same cadence
+                self._agg.note_serve_signals(
+                    queue_depth=sched.queued_count,
+                    ttft_p99_s=sched.recent_ttft_p99(),
+                    tpot_p99_s=sched.recent_tpot_p99())
+            self._next_peek = t + 2.0
+        t_loop = pump.now()
+        with span("pump.plan"):
+            plan = sched.plan()
+        if plan is None:
+            if self._draining and sched.idle():
+                return t, "stop"
+            with span("pump.idle"):
                 self._work.wait(0.02)
                 self._work.clear()
-                if ledger is not None:
-                    ledger.add("queue_idle", time.monotonic() - t_idle)
-                continue
-            if self._profile_ctl is not None:
-                # armed profile window rides the SAME broadcast as the
-                # trace ids — every worker starts its capture on this
-                # plan and the driver counts the window's steps
-                pending = self._profile_ctl.take_pending()
-                if pending is not None:
-                    plan["profile"] = pending
-            t_step = time.monotonic()
-            try:
+            now = pump.add("idle", t)
+            if ledger is not None:
+                ledger.add("queue_idle", now - t)
+            return now, "idle"
+        pump.add("loop", t, t_loop)
+        t_plan = pump.add("plan", t_loop)
+        if self._profile_ctl is not None:
+            # armed profile window rides the SAME broadcast as the
+            # trace ids — every worker starts its capture on this
+            # plan and the driver counts the window's steps
+            pending = self._profile_ctl.take_pending()
+            if pending is not None:
+                plan["profile"] = pending
+        # the step's number rides the plan too: the worker's spans of
+        # this step carry the same one
+        plan["step"] = pump.steps
+        if plan.get("profile") is not None and not self._kept_steps:
+            # the workers capture this many steps from this plan on; the
+            # pump is not in a captured process, so its spans of those
+            # steps are kept in this one, telemetry on or off
+            # (telemetry/spans.py ``kept("pump")``).  This step's own
+            # root and plan span are already behind it.
+            self._kept_steps = int(plan["profile"]["steps"])
+            self._kept_window.enter_context(
+                spans.keep("pump", own_thread=True))
+        try:
+            with span("pump.call", step=plan["step"]):
                 futures = [w.call("serve_step", plan)
                            for w in self._workers]
+            t_call = pump.add("call", t_plan)
+            with span("pump.wait", step=plan["step"]):
                 results = self._wait_all(futures, timeout=300)
-                # rank 0 alone carries the tokens (worker.py lockstep
-                # contract); all-None means the backend lost it — a
-                # fleet failure like any other, so it must raise INSIDE
-                # this try or the pump dies without failing the
-                # in-flight requests
-                result = next((r for r in results if r is not None), None)
-                if result is None:
-                    raise RuntimeError(
-                        "no serve worker returned a step result "
-                        "(rank 0's return value was lost)")
-            except BaseException as e:   # noqa: BLE001 - fleet failure
-                _log.error("serve step failed; failing %d live request(s)",
-                           sched.active_count + sched.queued_count,
-                           exc_info=True)
-                self._error = e
-                # black boxes FIRST: dump every rank's flight ring with
-                # the serve cause while the evidence is fresh (the
-                # elastic fit driver's death-classification discipline,
-                # now on the serve pump too), then fail the waiters
-                self.failure_report = self._dump_flights(e)
-                sched.fail_all(e)
-                return
-            if ledger is not None:
-                # attribution rule: a dispatch that decodes produced
-                # tokens (useful); a prefill-only dispatch is context
-                # build — measured, but not goodput.  A speculative
-                # round splits out its draft/verify wall (worker-
-                # measured) so the ledger shows what speculation costs;
-                # the verify IS the token-producing target forward, so
-                # it stays in the useful "decode" bucket.
-                step_s = time.monotonic() - t_step
-                timing = result.get("timing") or {}
-                draft_s = float(timing.get("draft", 0.0))
-                if plan.get("decode") is not None:
-                    ledger.add("draft", min(draft_s, step_s))
-                    ledger.note_step(max(0.0, step_s - draft_s))
-                else:
-                    ledger.add("prefill", step_s)
+            t_wait = pump.add("wait", t_call)
+            # rank 0 alone carries the tokens (worker.py lockstep
+            # contract); all-None means the backend lost it — a
+            # fleet failure like any other, so it must raise INSIDE
+            # this try or the pump dies without failing the
+            # in-flight requests
+            result = next((r for r in results if r is not None), None)
+            if result is None:
+                raise RuntimeError(
+                    "no serve worker returned a step result "
+                    "(rank 0's return value was lost)")
+        except BaseException as e:   # noqa: BLE001 - fleet failure
+            _log.error("serve step failed; failing %d live request(s)",
+                       sched.active_count + sched.queued_count,
+                       exc_info=True)
+            self._error = e
+            # black boxes FIRST: dump every rank's flight ring with
+            # the serve cause while the evidence is fresh (the
+            # elastic fit driver's death-classification discipline,
+            # now on the serve pump too), then fail the waiters
+            self.failure_report = self._dump_flights(e)
+            sched.fail_all(e)
+            return t_plan, "stop"
+        timing = result.get("timing") or {}
+        pump.worker_s += float(timing.get("serve_step", 0.0))
+        if ledger is not None:
+            # attribution rule: a dispatch that decodes produced
+            # tokens (useful); a prefill-only dispatch is context
+            # build — measured, but not goodput.  A speculative
+            # round splits out its draft/verify wall (worker-
+            # measured) so the ledger shows what speculation costs;
+            # the verify IS the token-producing target forward, so
+            # it stays in the useful "decode" bucket.
+            step_s = t_wait - t_plan
+            draft_s = float(timing.get("draft", 0.0))
+            if plan.get("decode") is not None:
+                ledger.add("draft", min(draft_s, step_s))
+                ledger.note_step(max(0.0, step_s - draft_s))
+            else:
+                ledger.add("prefill", step_s)
+        with span("pump.apply", step=plan["step"]):
             sched.apply(plan, result)
-            if self._profile_ctl is not None:
-                self._profile_ctl.note_step()
+        if self._profile_ctl is not None:
+            self._profile_ctl.note_step()
+        pump.steps += 1
+        return pump.add("apply", t_wait), "step"
 
     # -- goodput (telemetry/goodput.py) ------------------------------------
 

@@ -27,10 +27,11 @@ from __future__ import annotations
 
 import logging
 import os
+import time
 from typing import Any, Optional
 
 from ray_lightning_tpu.cluster.executor import RLTExecutor
-from ray_lightning_tpu.telemetry import span
+from ray_lightning_tpu.telemetry import span, spans
 from ray_lightning_tpu.telemetry.tracing import WorkerProfiler
 
 _log = logging.getLogger(__name__)
@@ -53,25 +54,38 @@ class ServeWorker(RLTExecutor):
     def setup_serve(self, payload: tuple, rank: int, queue) -> dict:
         """Join the distributed runtime, enable telemetry, build and
         warm the engine.  Returns setup facts the driver logs."""
-        from ray_lightning_tpu.plugins.xla import _configure_worker_jax
-        _configure_worker_jax()
-        import jax
+        # set-up is kept whatever the telemetry flag says: the spans
+        # ride back with this call's result and the driver hangs them
+        # under its own ``worker_setup`` (telemetry/spans.py adopt)
+        with spans.keep("setup_serve") as kept:
+            with span("setup_serve", rank=rank):
+                info = self._setup_serve(payload, rank, queue)
+        info["spans"] = list(kept)
+        return info
+
+    def _setup_serve(self, payload: tuple, rank: int, queue) -> dict:
+        with span("imports"):
+            from ray_lightning_tpu.plugins.xla import _configure_worker_jax
+            _configure_worker_jax()
+            import jax
+
+            from ray_lightning_tpu.compile import cache as compile_cache
+            from ray_lightning_tpu.serve.engine import ServeEngine
+            from ray_lightning_tpu.serve.spec import SpecConfig
 
         spec, weights = payload
         self._rank = rank
         self._nproc = int(os.environ.get("RLT_NUM_PROCESSES", "1"))
         if self._nproc > 1:
-            jax.distributed.initialize(
-                coordinator_address=os.environ["RLT_COORDINATOR"],
-                num_processes=self._nproc,
-                process_id=rank,
-            )
+            with span("devices"):
+                jax.distributed.initialize(
+                    coordinator_address=os.environ["RLT_COORDINATOR"],
+                    num_processes=self._nproc,
+                    process_id=rank,
+                )
         self._setup_telemetry(spec, rank, queue)
-        from ray_lightning_tpu.compile import cache as compile_cache
         compile_cache.activate(spec.compile_cache)
 
-        from ray_lightning_tpu.serve.engine import ServeEngine
-        from ray_lightning_tpu.serve.spec import SpecConfig
         # spec/kvship ride the pickled ServeSpec when the driver set
         # them; otherwise the RLT_SPEC_* / RLT_SERVE_KVSHIP worker env
         # (the fleet's replica-actor round-trip) decides here
@@ -139,6 +153,7 @@ class ServeWorker(RLTExecutor):
         engine = self._engine
         if engine is None:
             raise RuntimeError("serve_step before setup_serve")
+        t0 = time.monotonic()
         prof = plan.get("profile")
         if prof is not None:
             # on-demand jax.profiler window riding the plan broadcast
@@ -146,24 +161,37 @@ class ServeWorker(RLTExecutor):
             if self._profiler is None:
                 self._profiler = WorkerProfiler(rank=self._rank)
             self._profiler.maybe_start(prof)
+        # the step's number rides the plan: the driver pump's spans of
+        # this step carry the same one (children inherit it)
+        with span("serve_step", step=plan.get("step")):
+            result = self._run_plan(engine, plan)
+        if self._profiler is not None:
+            self._profiler.note_step()
+        # this call's wall seconds in the worker, a profile window's
+        # start and stop included: the pump's round trip minus this is
+        # what the RPC cost
+        result.setdefault("timing", {})["serve_step"] = \
+            time.monotonic() - t0
+        return result if self._rank == 0 else None
+
+    def _run_plan(self, engine, plan: dict) -> dict:
         result: dict[str, Any] = {"prefill": {}, "decode": {}}
         decode = plan.get("decode")
         if decode is not None and decode.get("spec"):
             # speculative round: k draft steps then ONE batched target
             # verify; the SCHEDULER decides acceptance from the raw
             # outputs (scheduler._apply_spec), workers stay stateless
-            import time as _time
-            t0 = _time.monotonic()
+            t0 = time.monotonic()
             with span("draft", traces=decode.get("traces"),
                       slots=len(decode["slots"])):
                 drafts = engine.draft(decode["tokens"],
                                       decode["positions"])
-            t1 = _time.monotonic()
+            t1 = time.monotonic()
             with span("verify", traces=decode.get("traces"),
                       slots=len(decode["slots"])):
                 ver = engine.verify(decode["tokens"],
                                     decode["positions"], drafts)
-            t2 = _time.monotonic()
+            t2 = time.monotonic()
             for s in decode["slots"]:
                 result["decode"][s] = {
                     "draft": [int(x) for x in drafts[s]],
@@ -210,9 +238,7 @@ class ServeWorker(RLTExecutor):
                           bucket=exp["bucket"]):
                     rows = engine.export_kv(p["slot"], exp["bucket"])
                 result.setdefault("kv_export", {})[p["slot"]] = rows
-        if self._profiler is not None:
-            self._profiler.note_step()
-        return result if self._rank == 0 else None
+        return result
 
     # -- KV-page shipping (fleet disaggregation) ---------------------------
 

@@ -63,7 +63,6 @@ from ray_lightning_tpu.telemetry.anatomy import (  # noqa: F401
     disable_anatomy,
     enable_anatomy,
     get_anatomy_controller,
-    parse_anatomy_or_none,
     parse_trace_anatomy,
 )
 from ray_lightning_tpu.telemetry.goodput import (  # noqa: F401
@@ -147,7 +146,6 @@ __all__ = [
     "disable_anatomy",
     "get_anatomy_controller",
     "parse_trace_anatomy",
-    "parse_anatomy_or_none",
     "Detector",
     "DetectorConfig",
     "Incident",

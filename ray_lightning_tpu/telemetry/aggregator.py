@@ -305,6 +305,15 @@ class TelemetryAggregator:
             peaks = [float(m.get("value", 0.0))
                      for m in item.get("metrics", ())
                      if m.get("name") == "rlt_hbm_peak_bytes"]
+            if self.incidents.run_kind == "fit" and not any(
+                    m.get("name") == "rlt_steps_total"
+                    and m.get("value", 0) > 0
+                    for m in item.get("metrics", ())):
+                # before the first step the high-water mark is set-up's
+                # (a few hundred MB): a baseline made of those samples
+                # calls the train state itself an anomaly (seen on the
+                # v5e, PR 21; PERF.md)
+                peaks = []
             if peaks:
                 self.incidents.note_sample(
                     "hbm_peak_bytes", rank, max(peaks),

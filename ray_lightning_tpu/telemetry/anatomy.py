@@ -491,40 +491,21 @@ def parse_trace_anatomy(trace_dir: str, *, steps: Optional[int] = None,
     return a
 
 
-def parse_anatomy_or_none(trace_dir: "str | None", **kw) -> Optional[dict]:
-    """Compact anatomy dict, or None when the capture is missing or
-    unparseable (profiler-less backends, empty windows) — the shared
-    never-raise recipe for bench/status surfaces."""
-    if not trace_dir:
-        return None
-    try:
-        return parse_trace_anatomy(trace_dir, **kw).as_dict()
-    except Exception as e:
-        _log.debug("anatomy parse skipped for %s: %s", trace_dir, e)
-        return None
-
-
 def profile_dir_anatomy(last_dir: "str | None") -> Optional[dict]:
     """Parsed anatomy for a completed ``POST /debug/profile`` window:
     ``{rank_label: anatomy_dict}`` over the window's ``rank<k>/``
-    subdirs (or a single ``"0"`` entry when the capture has no rank
-    subdirs).  None when nothing parses."""
+    subdirs).  None when there is no window directory; a capture that
+    is there and cannot be parsed raises (a failed parse used to become
+    a missing field)."""
     if not last_dir or not os.path.isdir(last_dir):
         return None
-    out: dict[str, dict] = {}
     subs = sorted(d for d in os.listdir(last_dir)
                   if d.startswith("rank")
                   and os.path.isdir(os.path.join(last_dir, d)))
     if subs:
-        for d in subs:
-            a = parse_anatomy_or_none(os.path.join(last_dir, d))
-            if a is not None:
-                out[d[len("rank"):]] = a
-    else:
-        a = parse_anatomy_or_none(last_dir)
-        if a is not None:
-            out["0"] = a
-    return out or None
+        return {d[len("rank"):]: parse_trace_anatomy(
+            os.path.join(last_dir, d)).as_dict() for d in subs}
+    return {"0": parse_trace_anatomy(last_dir).as_dict()}
 
 
 # -- synthetic-trace fixture (tests + selfcheck golden) --------------------
@@ -662,10 +643,8 @@ class AnatomyController:
         d, self._dir = self._dir, None
         tag, self._active_tag = self._active_tag, None
         try:
-            anatomy = parse_anatomy_or_none(
-                os.path.join(d, f"rank{self.rank}"))
-            if anatomy is None:
-                return
+            anatomy = parse_trace_anatomy(
+                os.path.join(d, f"rank{self.rank}")).as_dict()
             self.last = anatomy
             self.windows += 1
             self._publish_metrics(anatomy)
@@ -676,8 +655,8 @@ class AnatomyController:
                 self.sink(anatomy_item(
                     self.rank, anatomy,
                     capture_dir=d if tag else None))
-        except Exception:   # anatomy must never break the train loop
-            _log.debug("anatomy window dropped", exc_info=True)
+        except Exception as e:   # never break the train loop, but say so
+            _log.warning("anatomy window %s dropped: %s", d, e)
         finally:
             if d and not tag:
                 shutil.rmtree(d, ignore_errors=True)
@@ -759,7 +738,6 @@ __all__ = [
     "device_timelines",
     "bucket_of",
     "parse_trace_anatomy",
-    "parse_anatomy_or_none",
     "profile_dir_anatomy",
     "write_synthetic_trace",
     "anatomy_item",

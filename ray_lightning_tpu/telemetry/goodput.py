@@ -149,8 +149,17 @@ class GoodputLedger:
         if seconds > 0:
             self.buckets[bucket] += float(seconds)
 
-    def note_step(self, seconds: float, k: int = 1) -> None:
-        """One train/decode dispatch: ``k`` steps in ``seconds`` wall."""
+    def note_step(self, seconds: float, k: int = 1,
+                  first: bool = False) -> None:
+        """One train/decode dispatch: ``k`` steps in ``seconds`` wall.
+        A fit's ``first`` dispatch (with the wait for its result) is
+        where the step program loads from the cache or compiles: it is
+        ``compile`` time and no sample of the step wall.  Counted as a
+        step it made a 24-step fit's mean step 0.9 s and its MFU 0.035
+        where the steady step gives 0.65 (PERF.md, PR 21)."""
+        if first:
+            self.add("compile", seconds)
+            return
         self.add(USEFUL_BUCKET[self.kind], seconds)
         self.steps += max(1, int(k))
 

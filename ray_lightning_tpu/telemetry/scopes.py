@@ -1,0 +1,152 @@
+"""Which part of the model a device operation belongs to.
+
+A profiler trace names device operations ``fusion.2031``, ``copy.795``:
+a TPU trace's operation events carry no scope (read on the v5e, PR 24:
+their stats are ``device_offset_ps``, ``device_duration_ps`` and a time
+scale).  The compiled program's own text does: every instruction and
+fusion has ``metadata={op_name="jit(step_fn)/.../h3/attn/kv_cache/
+scatter"}``, the ``jax.named_scope`` / flax module path it was traced
+under.  ``tables()`` reads, when asked and not before, the text of
+every executable live in this process (the ones that loaded and ran,
+whatever tree compiled them into the cache) into a table from operation
+name to scope per program; each profiler window the program captures
+gets them beside it (``op_scopes.json``, telemetry/tracing.py
+``WorkerProfiler``, utils/profiling.py).  No window, no parse.
+
+The scopes are a short fixed list.  ``models/gpt.py``, ``core/steps.py``,
+``ops/attention.py`` and ``ops/losses.py`` enter them with
+``jax.named_scope`` (or rely on a flax module of that name: ``attn``,
+``mlp``).  An operation's scope is the INNERMOST listed name on its
+path, so the cache write inside a block's attention is ``kv_cache``,
+not ``attn``.  A fusion carries the path of its root instruction: that
+is the compiler's choice, and the table's.
+
+An instruction the compiler made itself has no path.  If it only moves
+a value (a layout copy, a bitcast, a tuple element) the table gives it
+the scope of the value it moves, through chains of such movers, MARKED
+as inherited: ``"kv_cache*"``.  That is a reading aid, not a placement:
+the per-layer relayout copies of the K/V cache in the decode program
+(72 of them, ~30 ms of its 110 on the v5e, PR 24) are the compiler's,
+their producer is one of two neighbours, and a reader that adds up a
+scope's time leaves them out or reports them apart.  ``None`` means no
+listed name was found either way: unscoped, and counted as such.
+
+No jax import: worker_main touches this package before jax exists.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+from typing import Optional
+
+#: every device operation of the train step and of the serve programs
+#: should fall under one of these
+SCOPES = ("embed", "attn", "mlp", "ln", "lm_head", "loss", "optimizer",
+          "kv_cache", "sample")
+
+#: the file of tables written beside a captured trace
+TABLE_FILE = "op_scopes.json"
+#: suffix of a scope that a pathless mover took from the value it moves
+INHERITED = "*"
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", re.M)
+#: instructions that only move a value: made by the compiler without a
+#: path (a layout copy, a tuple element), they are listed under the
+#: scope of the value they move, marked ``INHERITED``
+_MOVES = re.compile(r" (?:copy|copy-start|copy-done|bitcast|transpose|"
+                    r"reshape|get-tuple-element)\(")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_WORD = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The innermost listed scope on an ``op_name`` path such as
+    ``jit(step_fn)/transpose(jvp(GPT))/h0/attn/qkv/dot_general``."""
+    for word in reversed(_WORD.findall(op_name)):
+        if word in SCOPES:
+            return word
+    return None
+
+
+def table_from_text(hlo_text: str) -> "tuple[str, dict]":
+    """``(module name, {instruction name: scope, scope + "*" or None})``
+    from a compiled program's text.  Every instruction of every
+    computation is listed, with or without metadata, so that a reader
+    can tell "the compiler gave this one no path" (None, or an inherited
+    scope) from "this operation is not of this program" (absent)."""
+    m = _MODULE.search(hlo_text)
+    module = m.group(1) if m else ""
+    table: dict = {}
+    moved_from: dict = {}     # pathless mover -> the value it moves
+    for line in hlo_text.splitlines():
+        inst = _INSTRUCTION.match(line)
+        if inst is None:
+            continue
+        name = inst.group(1)
+        path = _OP_NAME.search(line)
+        table[name] = scope_of(path.group(1)) if path else None
+        if path is None:
+            mover = _MOVES.search(line, inst.end())
+            # operands come first, and no type holds a ``%``
+            source = mover and _OPERAND.search(line, mover.end())
+            if source:
+                moved_from[name] = source.group(1)
+    inherited = {}
+    for name in moved_from:
+        # a chain of movers ends at an instruction with a path (or at a
+        # parameter, which has none: unscoped)
+        source, hops = moved_from[name], 0
+        while source in moved_from and hops < 16:
+            source, hops = moved_from[source], hops + 1
+        if table.get(source) is not None:
+            inherited[name] = table[source] + INHERITED
+    table.update(inherited)
+    return module, table
+
+
+def tables() -> "dict[str, dict[str, Optional[str]]]":
+    """``{program: table}`` of every executable live in this process,
+    read now.  Two live programs of one name (a step retraced for
+    another shape) share a table; an operation name they place
+    differently is left out, so a reader that meets it fails.  Never
+    raises: the tables are evidence, not a dependency."""
+    jax = sys.modules.get("jax")
+    out: dict = {}
+    clash: set = set()
+    try:
+        live = jax.devices()[0].client.live_executables()
+    except Exception:   # noqa: BLE001 - no jax, no backend, no such call
+        return out
+    for exe in live:
+        try:
+            texts = [m.to_string() for m in exe.hlo_modules()]
+        except Exception:   # noqa: BLE001 - a backend without text
+            continue
+        for text in texts:
+            module, table = table_from_text(text)
+            seen = out.setdefault(module, table)
+            clash.update((module, op) for op, scope in table.items()
+                         if seen.setdefault(op, scope) != scope)
+    for module, op in clash:
+        del out[module][op]
+    return out
+
+
+def write_tables(trace_dir: str) -> Optional[str]:
+    """Write this process's tables beside a trace it captured."""
+    snap = tables()
+    if not snap:
+        return None
+    path = os.path.join(trace_dir, TABLE_FILE)
+    with open(path, "w") as f:
+        json.dump({"scopes": list(SCOPES), "programs": snap}, f)
+    return path
+
+
+__all__ = ["SCOPES", "TABLE_FILE", "INHERITED", "scope_of",
+           "table_from_text", "tables", "write_tables"]

@@ -42,6 +42,8 @@ import time
 import uuid
 from typing import Any, Optional
 
+from ray_lightning_tpu.telemetry import scopes, spans
+
 _log = logging.getLogger(__name__)
 
 #: span-record attribute carrying the request trace id (a single id on
@@ -107,10 +109,14 @@ def _attach_window_anatomy(controller, out: dict) -> None:
     cached = getattr(controller, "_anatomy_cache", None)
     if cached is None or cached[0] != last_dir:
         from ray_lightning_tpu.telemetry.anatomy import profile_dir_anatomy
-        cached = (last_dir, profile_dir_anatomy(last_dir))
+        try:
+            cached = (last_dir, "anatomy", profile_dir_anatomy(last_dir))
+        except Exception as e:   # noqa: BLE001 - /status must answer
+            # a capture that cannot be parsed says so, under its own key
+            cached = (last_dir, "anatomy_error", repr(e))
         controller._anatomy_cache = cached
-    if cached[1] is not None:
-        out["anatomy"] = cached[1]
+    if cached[2] is not None:
+        out[cached[1]] = cached[2]
 
 
 # -- on-demand profiling: serve plane (plan-broadcast control) -----------
@@ -201,6 +207,7 @@ class WorkerProfiler:
         self.rank = rank
         self._remaining = 0
         self._active = False
+        self._dir: Optional[str] = None
         self._seen: set[str] = set()
 
     def maybe_start(self, ctl: Optional[dict]) -> None:
@@ -215,7 +222,11 @@ class WorkerProfiler:
         except Exception as e:
             _log.warning("profile: start_trace failed: %s", e)
             return
+        # the trace counts from its own zero: anchor it to the wall
+        # clock that span records use (telemetry/spans.py)
+        spans.clock_anchor()
         self._active = True
+        self._dir = out_dir
         self._remaining = int(ctl["steps"])
         _log.info("profile: rank %d capturing %d steps -> %s",
                   self.rank, self._remaining, out_dir)
@@ -234,6 +245,9 @@ class WorkerProfiler:
         try:
             import jax
             jax.profiler.stop_trace()
+            # operation names in the trace are fusion.N: the table that
+            # says which part of the model each belongs to goes beside it
+            scopes.write_tables(self._dir)
         except Exception as e:
             _log.warning("profile: stop_trace failed: %s", e)
 

@@ -179,10 +179,11 @@ class JaxProfilerCallback(Callback):
                 or trainer.global_step < self.start_step:
             return
         import jax
-        path = self._dir(trainer)
+        path = self._path = self._dir(trainer)
         os.makedirs(path, exist_ok=True)
         try:
             jax.profiler.start_trace(path)
+            telemetry.spans.clock_anchor()
             self._active = True
             self._started_at = trainer.global_step
         except Exception as e:  # profiling must never kill training
@@ -206,6 +207,7 @@ class JaxProfilerCallback(Callback):
                 jax.block_until_ready(leaves[-1])
         try:
             jax.profiler.stop_trace()
+            telemetry.scopes.write_tables(self._path)
         except Exception as e:
             _log.warning("profiler trace failed to stop: %s", e)
         self._active = False

@@ -281,7 +281,12 @@ def test_default_flops_pricing_matches_hand_computed_gpt(tmp_path, seed):
     B, T, C, V, L = 4, 32, 32, 512, 2
     cfg = GPTConfig(vocab_size=V, block_size=T, n_layer=L, n_head=2,
                     n_embd=C, remat=False, attention_impl="dot")
-    module = GPTLightningModule(cfg, batch_size=B, dataset_size=8 * B)
+
+    class NoHook(GPTLightningModule):
+        def flops_per_step(self):      # GPT answers itself since PR 24
+            return None
+
+    module = NoHook(cfg, batch_size=B, dataset_size=8 * B)
     trainer = Trainer(max_epochs=1, limit_train_batches=2,
                       limit_val_batches=0, num_sanity_val_steps=0,
                       enable_checkpointing=False, seed=0,
@@ -298,6 +303,56 @@ def test_default_flops_pricing_matches_hand_computed_gpt(tmp_path, seed):
     fwd = L * (24 * B * T * C * C + 4 * B * T * T * C) + 2 * B * T * C * V
     expected = 3 * fwd
     assert abs(flops - expected) / expected < 0.05, (flops, expected)
+
+
+def test_gpt_answers_the_flops_hook_with_the_benchmarks_count(tmp_path,
+                                                               seed):
+    """``GPTLightningModule.flops_per_step`` is the count the benchmark
+    uses (chipbench/flops.py: 6 per matmul parameter and token plus
+    causal attention), over the global batch the trainer saw, so
+    goodput's MFU and ``train_mfu_pct`` share a numerator."""
+    from chipbench import flops as bench_flops
+
+    from ray_lightning_tpu.models.gpt import GPTConfig, GPTLightningModule
+    B, T, C, V, L = 4, 32, 32, 512, 2
+    module = GPTLightningModule(
+        GPTConfig(vocab_size=V, block_size=T, n_layer=L, n_head=2,
+                  n_embd=C, remat=False), batch_size=B,
+        dataset_size=8 * B)
+    assert module.flops_per_step() is None      # no trainer, no batch
+    trainer = Trainer(max_epochs=1, limit_train_batches=6,
+                      limit_val_batches=0, num_sanity_val_steps=0,
+                      enable_checkpointing=False, seed=0,
+                      default_root_dir=str(tmp_path), telemetry=True)
+    trainer.fit(module)
+    doc = trainer._goodput_local
+    per_token = bench_flops.train_flops_per_token(
+        {"n_embd": C, "n_layer": L, "vocab_size": V}, T)
+    assert doc["flops_per_step"] == pytest.approx(B * T * per_token)
+    # the first step (where the program loads or compiles) is compile
+    # time and no sample of the step wall
+    assert doc["steps"] == 5 and check_identity(doc)
+    assert doc["buckets"]["compile"] > doc["step_wall_mean_s"]
+
+
+def test_first_step_is_compile_time_not_a_step():
+    """A 24-step fit whose first dispatch compiles for 20 s: the mean
+    step wall and the MFU are the steady steps' (PERF.md, PR 21: 0.035
+    was printed where the steady step gives 0.65)."""
+    clock = [0.0]
+    ledger = GoodputLedger("fit", device_tflops=197.0, devices=1,
+                           clock=lambda: clock[0]).start()
+    ledger.set_flops_per_step(16 * 1024 * 798e6)
+    ledger.note_step(20.0, first=True)
+    for _ in range(23):
+        ledger.note_step(0.1005)
+    clock[0] = 20.0 + 23 * 0.1005
+    doc = ledger.finalize()
+    assert doc["steps"] == 23
+    assert doc["step_wall_mean_s"] == pytest.approx(0.1005)
+    assert doc["buckets"]["compile"] == pytest.approx(20.0)
+    assert doc["mfu"] == pytest.approx(0.66, abs=0.005)
+    assert check_identity(doc)
 
 
 # -- real 2-worker fit: the identity, fleetwide --------------------------

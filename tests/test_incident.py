@@ -330,6 +330,36 @@ def _span(name, rank, ts, dur, **attrs):
     return r
 
 
+def test_hbm_baseline_starts_with_the_first_step(tmp_path):
+    """The high-water mark before the first step is set-up's: a
+    baseline made of those samples called the train state itself an
+    anomaly (v5e, PR 21).  Windows before a step are not fed."""
+    def window(ts, peak, steps):
+        metrics = [{"name": "rlt_hbm_peak_bytes", "value": peak,
+                    "labels": {"device": "0"}}]
+        if steps is not None:
+            metrics.append({"name": "rlt_steps_total", "value": steps})
+        return {"kind": "metrics", "rank": 0, "ts": ts, "metrics": metrics}
+
+    agg = TelemetryAggregator(str(tmp_path), heartbeat_timeout=60)
+    for i in range(20):                      # set-up: 0.4 GB, no step yet
+        agg.ingest_metrics(window(1000.0 + i, 4e8, 0 if i % 2 else None))
+    assert agg.incidents.timeline.samples("hbm_peak_bytes", 0) == []
+    for i in range(40):                      # training: 11.7 GB, flat
+        agg.ingest_metrics(window(1020.0 + i, 11.7e9, 10 * (i + 1)))
+    assert len(agg.incidents.timeline.samples("hbm_peak_bytes", 0)) == 40
+    assert agg.incident_stats()["total"] == 0
+    # a real jump over the steady mark still opens one
+    for i in range(5):
+        agg.ingest_metrics(window(1060.0 + i, 15.5e9, 500 + i))
+    assert agg.incident_stats()["total"] == 1
+    # a serve fleet's mark is set at start-up and fed from the start
+    serve = TelemetryAggregator(str(tmp_path / "serve"),
+                                heartbeat_timeout=60, run_kind="serve")
+    serve.ingest_metrics(window(1000.0, 6.3e9, None))
+    assert len(serve.incidents.timeline.samples("hbm_peak_bytes", 0)) == 1
+
+
 def test_aggregator_feeds_timeline_from_spans(tmp_path):
     agg = TelemetryAggregator(str(tmp_path), heartbeat_timeout=60)
     for i in range(5):

@@ -100,7 +100,7 @@ def test_flash_under_jit_and_bf16():
                                atol=3e-2, rtol=3e-2)
 
 
-def test_gpt_attention_impl_flash_trains():
+def test_gpt_attention_impl_flash_trains(tmp_path):
     # end-to-end: tiny GPT with attention_impl="flash" takes a step
     from ray_lightning_tpu import Trainer
     from ray_lightning_tpu.models.gpt import GPTConfig, GPTLightningModule
@@ -110,7 +110,10 @@ def test_gpt_attention_impl_flash_trains():
     module = GPTLightningModule(cfg, dataset_size=16, batch_size=4)
     trainer = Trainer(max_steps=2, max_epochs=1, enable_checkpointing=False,
                       num_sanity_val_steps=0, limit_val_batches=0,
-                      log_every_n_steps=1)
+                      log_every_n_steps=1,
+                      # a directory of its own: under xdist another
+                      # worker's fit logs into the default one meanwhile
+                      default_root_dir=str(tmp_path))
     trainer.fit(module)
     assert np.isfinite(float(trainer.callback_metrics["loss"]))
 

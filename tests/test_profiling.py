@@ -96,3 +96,36 @@ def test_profiler_stops_cleanly_when_window_spans_train_end(tmp_path, seed):
                       callbacks=[JaxProfilerCallback(
                           start_step=2, num_steps=100)])
     trainer.fit(BoringModel(dataset_length=64, batch_size=4))
+
+
+def test_profiler_window_holds_the_loops_spans_and_the_clock_anchor(
+        tmp_path, seed):
+    """Telemetry off: the callback's window still holds the program's
+    ``rlt/`` spans in its host plane, with the anchor that maps the
+    trace's clock to the wall clock."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+    prof_dir = str(tmp_path / "prof")
+    trainer = Trainer(max_epochs=1, limit_train_batches=6,
+                      limit_val_batches=0, num_sanity_val_steps=0,
+                      enable_checkpointing=False, seed=0,
+                      default_root_dir=str(tmp_path), telemetry=False,
+                      callbacks=[JaxProfilerCallback(
+                          start_step=2, num_steps=2, log_dir=prof_dir)])
+    trainer.fit(BoringModel(dataset_length=64, batch_size=4))
+    path = glob.glob(os.path.join(prof_dir, "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("rlt/"):
+                        found.setdefault(e.name, []).append(dict(e.stats))
+    assert {"rlt/clock", "rlt/step", "rlt/data_wait",
+            "rlt/callbacks"} <= set(found)
+    assert sorted(s["step"] for s in found["rlt/step"]) == [2, 3]
+    anchor = found["rlt/clock"][-1]
+    assert abs(anchor["wall_ns"] * 1e-9 - time.time()) < 300

@@ -416,7 +416,7 @@ def test_e2e_two_workers_multi_tenant_live_metrics(tmp_path, seed,
         tenant_quotas={"alice": 2},
         default_root_dir=str(tmp_path),
         compile_cache=str(tmp_path / "compile_cache"),
-        telemetry={"metrics_port": 0, "metrics_interval": 0.2,
+        telemetry={"metrics_port": 0, "metrics_interval": 0.05,
                    "heartbeat_interval": 0.5})
     scrape = {}
 

@@ -71,6 +71,386 @@ def test_disabled_mode_is_noop_singleton():
     assert time.monotonic() - t0 < 0.2
 
 
+# -- a span is a span: ids, parents, steps, threads ---------------------------
+
+def test_span_ids_parents_and_inherited_step():
+    telemetry.enable(rank=0, sink=None, flush_every=None)
+    with telemetry.span("serve_step", step=41):
+        with telemetry.span("decode", slots=3):
+            with telemetry.span("fetch"):
+                pass
+        with telemetry.span("prefill", step=7):   # its own wins
+            pass
+    with telemetry.span("alone"):
+        pass
+    by = {r["name"]: r for r in telemetry.drain()}
+    ids = [r["id"] for r in by.values()]
+    assert len(set(ids)) == 5 and all(isinstance(i, int) for i in ids)
+    assert by["serve_step"]["parent"] is None
+    assert by["decode"]["parent"] == by["serve_step"]["id"]
+    assert by["fetch"]["parent"] == by["decode"]["id"]
+    assert by["prefill"]["parent"] == by["serve_step"]["id"]
+    assert by["alone"]["parent"] is None and "attrs" not in by["alone"]
+    # a child belongs to its parent's step unless it names its own
+    assert by["decode"]["attrs"] == {"slots": 3, "step": 41}
+    assert by["fetch"]["attrs"] == {"step": 41}
+    assert by["prefill"]["attrs"] == {"step": 7}
+    assert [by[n]["depth"] for n in ("serve_step", "decode", "fetch")] \
+        == [0, 1, 2]
+
+
+def test_span_stacks_are_per_thread():
+    """A background thread's spans (the AOT compile thread) are roots of
+    their own and leave the loop's nesting alone."""
+    import threading
+    telemetry.enable(rank=0, sink=None, flush_every=None)
+    inside, release = threading.Event(), threading.Event()
+
+    def other():
+        with telemetry.span("aot", program="p"):
+            inside.set()
+            release.wait(10)
+
+    with telemetry.span("loop", step=1):
+        t = threading.Thread(target=other)
+        t.start()
+        assert inside.wait(10)
+        with telemetry.span("child"):
+            pass
+        release.set()
+        t.join(10)
+    by = {r["name"]: r for r in telemetry.drain()}
+    assert by["aot"]["parent"] is None and by["aot"]["depth"] == 0
+    assert by["aot"]["attrs"] == {"program": "p"}      # no foreign step
+    assert by["child"]["parent"] == by["loop"]["id"]
+    assert by["child"]["depth"] == 1
+
+
+def test_recorder_off_span_site_allocates_no_record():
+    from ray_lightning_tpu.telemetry import spans
+    assert not telemetry.enabled() and spans._windows == ()
+    site = telemetry.span("step", step=3, traces={0: "abc"})
+    with site:
+        with telemetry.span("inner"):
+            pass
+    # no recorder, no keep window, no profiler session: the no-op
+    # singleton, and nothing anywhere afterwards
+    assert site is telemetry.span("other")
+    assert telemetry.drain() == []
+    assert spans.kept("step") == []
+
+
+def test_keep_window_records_without_a_recorder_and_adopt_renumbers():
+    from ray_lightning_tpu.telemetry import spans
+    assert not telemetry.enabled()
+    with spans.keep("server_start") as kept:
+        with telemetry.span("server_start"):
+            with telemetry.span("worker_setup") as waiting:
+                # what a worker returned with setup_serve's result: ids
+                # of another process, which may collide with ours
+                theirs = [
+                    {"t": "span", "name": "weights", "ts": 1.0, "dur": 2.0,
+                     "rank": 0, "depth": 1, "id": 2, "parent": 1},
+                    {"t": "span", "name": "setup_serve", "ts": 0.5,
+                     "dur": 3.0, "rank": 0, "depth": 0, "id": 1,
+                     "parent": None}]
+                spans.adopt(kept, theirs, parent=waiting.id, rank=5)
+    with telemetry.span("after"):     # window closed: not kept
+        pass
+    assert spans._windows == ()
+    got = {r["name"]: r for r in spans.kept("server_start")}
+    assert list(kept) == spans.kept("server_start")
+    assert set(got) == {"server_start", "worker_setup", "weights",
+                        "setup_serve"}
+    assert got["worker_setup"]["parent"] == got["server_start"]["id"]
+    assert got["setup_serve"]["parent"] == got["worker_setup"]["id"]
+    assert got["weights"]["parent"] == got["setup_serve"]["id"]
+    assert len({r["id"] for r in got.values()}) == 4
+    assert got["weights"]["rank"] == 5 and got["weights"]["ts"] == 1.0
+    assert theirs[0]["id"] == 2      # the caller's records are not touched
+    # the next window of the name replaces this one: nothing grows
+    with spans.keep("server_start"):
+        pass
+    assert spans.kept("server_start") == [] and len(kept) == 4
+
+
+def test_keep_windows_nest_and_can_be_held_to_their_own_thread():
+    """The pump's window keeps the pump thread's spans only; a set-up
+    window keeps the AOT thread's beside the main thread's."""
+    import threading
+
+    from ray_lightning_tpu.telemetry import spans
+
+    def other():
+        with telemetry.span("aot", thread="aot"):
+            pass
+
+    with spans.keep("fit_setup") as whole:
+        with spans.keep("pump", own_thread=True) as mine:
+            with telemetry.span("pump.wait", step=1):
+                t = threading.Thread(target=other)
+                t.start()
+                t.join(10)
+        with telemetry.span("later"):
+            pass
+    assert [r["name"] for r in mine] == ["pump.wait"]
+    assert [r["name"] for r in whole] == ["aot", "pump.wait", "later"]
+
+
+def _host_plane(trace_dir):
+    """``{name: [(start_s, dur_s, stats)]}`` of the ``rlt/`` annotations
+    in the newest trace under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("rlt/"):
+                        found.setdefault(e.name, []).append(
+                            (e.start_ns * 1e-9, e.duration_ns * 1e-9,
+                             dict(e.stats)))
+    return found
+
+
+def test_profiler_session_holds_annotations_and_the_clock_anchor(tmp_path):
+    """With a profiler session open every span site is an ``rlt/``
+    annotation in the trace's host plane, attrs as stats, recorder on or
+    off; ``rlt/clock`` maps the trace's clock to the recorder's."""
+    import jax
+
+    from ray_lightning_tpu.telemetry import spans
+    telemetry.enable(rank=0, sink=None, flush_every=None)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        spans.clock_anchor()
+        with telemetry.span("serve_step", step=12, traces={0: "x"}):
+            time.sleep(0.01)
+        telemetry.disable()
+        with telemetry.span("unrecorded", bucket=512):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (record,) = [r for r in telemetry.drain() or []] or [None]
+    host = _host_plane(str(tmp_path))
+    assert set(host) == {"rlt/clock", "rlt/serve_step", "rlt/unrecorded"}
+    (start, dur, stats), = host["rlt/serve_step"]
+    assert stats == {"step": 12}              # scalars only: no slot map
+    assert host["rlt/unrecorded"][0][2] == {"bucket": 512}
+    a_start, _, a_stats = max(host["rlt/clock"],
+                              key=lambda a: a[0])   # the last one made
+    assert abs(a_stats["wall_ns"] * 1e-9 - time.time()) < 60
+    offset = a_stats["wall_ns"] * 1e-9 - a_start
+    assert dur >= 0.009
+    if record is not None:      # the recorder's view of the same span
+        assert record["name"] == "serve_step"
+        assert abs((start + offset) - record["ts"]) < 1e-3
+
+
+def test_recorded_span_lines_up_with_its_annotation_through_the_anchor(
+        tmp_path):
+    import jax
+
+    from ray_lightning_tpu.telemetry import spans
+    telemetry.enable(rank=0, sink=None, flush_every=None)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        spans.clock_anchor()
+        for i in range(3):
+            with telemetry.span("step", step=i):
+                time.sleep(0.003)
+    finally:
+        jax.profiler.stop_trace()
+    records = [r for r in telemetry.drain() if r["name"] == "step"]
+    host = _host_plane(str(tmp_path))
+    a_start, _, a_stats = max(host["rlt/clock"],
+                              key=lambda a: a[0])   # the last one made
+    offset = a_stats["wall_ns"] * 1e-9 - a_start
+    assert len(records) == len(host["rlt/step"]) == 3
+    for rec, (start, _dur, stats) in zip(records, sorted(host["rlt/step"])):
+        assert stats == {"step": rec["attrs"]["step"]}
+        assert abs((start + offset) - rec["ts"]) < 1e-3
+
+
+# -- scopes and names -----------------------------------------------------------
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(step_fn)/transpose(jvp(GPT))/h3/attn/qkv/dot_general", "attn"),
+    ("jit(serve_decode)/GPT.decode/h0/attn/kv_cache/scatter", "kv_cache"),
+    ("jit(step_fn)/jvp(lm_head)/dot_general", "lm_head"),
+    ("jit(step_fn)/optimizer/mul", "optimizer"),
+    ("jit(step_fn)/jvp(GPT)/h0/ln/ln1/reduce_sum", "ln"),
+    ("jit(serve_decode)/lm_head/wte/dot_general", "lm_head"),
+    ("jit(step_fn)/convert_element_type", None),
+    ("state.params['h0']['ln1']['bias']", None),
+])
+def test_scope_of_takes_the_innermost_listed_name(op_name, want):
+    from ray_lightning_tpu.telemetry import scopes
+    assert scopes.scope_of(op_name) == want
+
+
+def test_a_compiler_made_mover_is_listed_under_what_it_moves_and_marked():
+    """The decode program's per-layer relayout copies of the cache have
+    no path of their own (read on the v5e, PR 24): the table names the
+    scope of what they move, marked, so that no reader takes it for the
+    program's own placement."""
+    from ray_lightning_tpu.telemetry import scopes
+    text = """HloModule jit_serve_decode, is_scheduled=true
+ENTRY %main (k: bf16[2]) -> bf16[2] {
+  %k = bf16[2]{0} parameter(0)
+  %fusion.2032 = (bf16[2]{0}, bf16[2]{0}) fusion(bf16[2]{0} %k), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(serve_decode)/GPT.decode/kv_cache/slice"}
+  %get-tuple-element.267 = bf16[2]{0} get-tuple-element((bf16[2]{0}, bf16[2]{0}) %fusion.2032), index=0
+  %copy.777 = bf16[2]{0:T(8,128)(2,1)} copy(bf16[2]{0:T(8,128)(2,1)} %get-tuple-element.267)
+  %copy.2 = bf16[2]{0} copy(%copy.777)
+  %copy.1 = bf16[2]{0} copy(bf16[2]{0} %k)
+  %add.5 = bf16[2]{0} add(%copy.2, %copy.1)
+  ROOT %flash_decode.3 = bf16[2]{0} custom-call(bf16[2]{0} %copy.777), custom_call_target="tpu_custom_call", metadata={op_name="jit(serve_decode)/GPT.decode/h0/attn/flash_decode/pallas_call"}
+}
+"""
+    module, table = scopes.table_from_text(text)
+    assert module == "jit_serve_decode"
+    assert table == {
+        "k": None, "fusion.2032": "kv_cache",
+        "get-tuple-element.267": "kv_cache*", "copy.777": "kv_cache*",
+        "copy.2": "kv_cache*",
+        "copy.1": None,          # moves a parameter: nothing to take
+        "add.5": None,           # computes: no path, no scope
+        "flash_decode.3": "attn"}
+
+
+def test_scope_tables_are_read_from_the_live_executables_when_asked(
+        tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.telemetry import scopes
+
+    def step_fn(x, w):
+        with jax.named_scope("mlp"):
+            h = jnp.tanh(x @ w)
+        with jax.named_scope("loss"):
+            return jnp.sum(h * h)
+
+    jitted = jax.jit(step_fn)
+    text = jitted.lower(jnp.ones((8, 8)), jnp.ones((8, 8))).compile() \
+        .as_text()
+    module, table = scopes.table_from_text(text)
+    assert module == "jit_step_fn"
+    assert {"mlp", "loss"} <= set(table.values())
+    assert None in table.values()       # parameters: no path, still listed
+    # nothing was registered anywhere: the program that ran is live, and
+    # its text is read when a window asks
+    jitted(jnp.ones((8, 8)), jnp.ones((8, 8)))
+    assert scopes.tables()["jit_step_fn"] == table
+    path = scopes.write_tables(str(tmp_path))
+    with open(path) as f:
+        doc = json.load(f)
+    assert doc["scopes"] == list(scopes.SCOPES)
+    assert doc["programs"]["jit_step_fn"] == table
+    # the same name compiled for another shape: operations both place
+    # alike stay, one they place differently is left out
+    jitted(jnp.ones((4, 8)), jnp.ones((8, 8)))
+    both = scopes.tables()["jit_step_fn"]
+    assert {"mlp", "loss"} <= set(both.values())
+    assert all(table[op] == scope for op, scope in both.items()
+               if op in table)
+
+
+def test_gpt_train_step_operations_fall_under_the_fixed_scopes():
+    """Every instruction of the tiny GPT train step that carries a path
+    at all carries a listed scope: models/gpt.py, ops/ and core/steps.py
+    enter them, and a new unscoped region shows here before a chip run."""
+    import re
+
+    import jax
+
+    from ray_lightning_tpu.core.steps import build_init_fn, build_train_step
+    from ray_lightning_tpu.models.gpt import GPTLightningModule
+    from ray_lightning_tpu.telemetry import scopes
+    module = GPTLightningModule("tiny", batch_size=2)
+    module.setup_model()
+    tx = module.configure_optimizers()
+    batch = next(iter(module.train_dataloader()))
+    state = jax.eval_shape(build_init_fn(module, tx),
+                           jax.random.PRNGKey(0), batch)
+    text = jax.jit(build_train_step(module, tx)).lower(
+        state, batch).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("(jit\(step_fn\)/[^"]+)"', text))
+    assert len(paths) > 50
+    unscoped = sorted(p for p in paths if scopes.scope_of(p) is None)
+    # what is left is the rng bookkeeping and the step counter
+    assert all(re.search(r"step_fn\)/(jit\(_?\w+\)/)*[\w\[\]]+$", p)
+               or "threefry" in p or "random" in p for p in unscoped), \
+        unscoped
+    assert {scopes.scope_of(p) for p in paths} >= {
+        "embed", "attn", "mlp", "ln", "lm_head", "loss", "optimizer"}
+
+
+def test_serve_programs_carry_their_names():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.serve.engine import ServeEngine
+    eng = ServeEngine(None, None, (16, 32), 4, 64)
+    for name in ("decode", "prefill_16", "kv_init"):
+        fn = eng._counted(name, lambda x: x + 1)
+        text = jax.jit(fn).lower(jnp.ones(())).as_text()
+        assert f"module @jit_serve_{name} " in text, name
+    assert eng.trace_counts == {"decode": 1, "prefill_16": 1, "kv_init": 1}
+
+
+@pytest.mark.parametrize("which,names", [
+    ("fwd", {"flash_fwd"}),
+    ("bwd", {"flash_fwd", "flash_bwd_fused", "flash_bwd_dkv",
+             "flash_bwd_dq"}),
+    ("decode", {"flash_decode"}),
+])
+def test_kernels_carry_their_names_in_a_program_lowered_for_the_chip(
+        which, names):
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    sh = SingleDeviceSharding(topo.devices[0])
+
+    def sds(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    if which == "decode":
+        from ray_lightning_tpu.ops.flash_decode import flash_decode_attention
+        low = jax.jit(lambda q, k, v, pos: flash_decode_attention(
+            q, k, v, pos, interpret=False)).lower(
+            sds(8, 1, 4, 64), sds(8, 256, 4, 64), sds(8, 256, 4, 64),
+            sds(8, dt=jnp.int32))
+    else:
+        from ray_lightning_tpu.ops.flash_attention import flash_attention
+
+        def fwd(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=False)
+
+        def bwd(q, k, v):
+            return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                            argnums=(0, 1, 2))(q, k, v)
+
+        x = sds(2, 256, 4, 64)
+        low = jax.jit(fwd if which == "fwd" else bwd).lower(x, x, x)
+    found = set(re.findall(r'kernel_name = "([^"]+)"', low.as_text()))
+    assert found and found <= names, found
+    assert "flash_fwd" in found or which == "decode"
+
+
+
 def test_counter_and_last_span():
     telemetry.enable(rank=0, sink=None, flush_every=None)
     assert telemetry.last_span() is None
@@ -406,6 +786,30 @@ def test_fit_profile_control_file_round_trip(tmp_path, monkeypatch):
 
 # -- anatomy plane (telemetry/anatomy.py) --------------------------------
 
+def test_status_names_a_capture_it_cannot_parse(tmp_path):
+    """A window's capture that cannot be parsed used to be a missing
+    ``anatomy`` field on ``/status``; now the reason is there."""
+    from ray_lightning_tpu.telemetry.anatomy import (
+        profile_dir_anatomy, write_synthetic_trace)
+    ctl = tracing.ServeProfileController(str(tmp_path))
+    ctl.last_dir = str(tmp_path / "profile" / "w1")
+    os.makedirs(os.path.join(ctl.last_dir, "rank0"))     # empty capture
+    status = ctl.status()
+    assert "anatomy" not in status
+    assert "rank0" in status["anatomy_error"] or "trace" in \
+        status["anatomy_error"].lower()
+    with pytest.raises(Exception):
+        profile_dir_anatomy(ctl.last_dir)
+    assert profile_dir_anatomy(str(tmp_path / "nowhere")) is None
+    # a capture that parses is linked as before
+    ctl.last_dir = str(tmp_path / "profile" / "w2")
+    write_synthetic_trace(
+        os.path.join(ctl.last_dir, "rank0"),
+        ops=[{"name": "fusion.1", "ts": 0.0, "dur": 900.0}],
+        modules=[{"name": "jit_step_fn", "ts": 0.0, "dur": 1000.0}])
+    assert "0" in ctl.status()["anatomy"]
+
+
 def test_anatomy_parses_real_capture(tmp_path, monkeypatch):
     """A REAL profiler capture (via the fit control-file machinery, the
     same path POST /debug/profile arms) parses into a StepAnatomy whose
@@ -637,6 +1041,206 @@ def test_local_fit_with_anatomy_armed(tmp_path, seed):
         <= a["wall_s"] + 1e-8
     # controller torn down with the rest of telemetry
     assert telemetry.get_anatomy_controller() is None
+
+
+# -- spans where the work happens, telemetry off ---------------------------
+
+SETUP_SPANS_FIT = {"setup_model", "loaders", "mesh", "compile", "init",
+                   "first_step"}
+SETUP_SPANS_SERVE = {"spawn", "rendezvous", "ship", "worker_setup",
+                     "setup_serve", "imports", "devices", "weights",
+                     "build", "warmup", "warm", "kv_init"}
+
+
+def _tree(records, root_name):
+    """``(root, {name: [records]})`` of the newest root's descendants."""
+    root = [r for r in records if r["name"] == root_name][-1]
+    kids = {}
+    for r in records:
+        kids.setdefault(r.get("parent"), []).append(r)
+    under, todo = {}, [root]
+    while todo:
+        for c in kids.get(todo.pop()["id"], []):
+            under.setdefault(c["name"], []).append(c)
+            todo.append(c)
+    return root, under
+
+
+def test_fit_leaves_every_setup_span_under_one_root(tmp_path, seed):
+    from ray_lightning_tpu.telemetry import spans
+    trainer = Trainer(max_epochs=1, limit_train_batches=3,
+                      limit_val_batches=0, num_sanity_val_steps=0,
+                      enable_checkpointing=False, seed=0,
+                      default_root_dir=str(tmp_path), telemetry=False)
+    trainer.fit(BoringModel())
+    assert trainer._telemetry_paths is None and not telemetry.enabled()
+    records = spans.kept("fit_setup")
+    assert spans._windows == ()
+    assert [r["name"] for r in records].count("fit_setup") == 1
+    root, under = _tree(records, "fit_setup")
+    assert root["parent"] is None
+    assert SETUP_SPANS_FIT <= set(under), SETUP_SPANS_FIT - set(under)
+    for name in SETUP_SPANS_FIT:
+        assert under[name][0]["parent"] == root["id"], name
+    # the first step's dispatch and the wait for its result are inside
+    first = under["first_step"][0]
+    assert {r["parent"] for r in under["step"]} == {first["id"]}
+    assert under["step"][0]["attrs"] == {"step": 0}
+    assert under["device_wait"][0]["parent"] == first["id"]
+    # the window closed with the first step: the loop's later steps are
+    # not kept (3 batches ran, one step span is here)
+    assert len(under["step"]) == 1
+    end = root["ts"] + root["dur"]
+    assert all(r["ts"] >= root["ts"] - 1e-6
+               and r["ts"] + r["dur"] <= end + 1e-6
+               for rs in under.values() for r in rs)
+
+
+@pytest.fixture(scope="module")
+def tiny_server(tmp_path_factory):
+    """One tiny CPU server with ``telemetry=False``, driven through a
+    handful of requests with an on-demand profile window on its third
+    plan: what the serve-side tests below read."""
+    import numpy as np
+
+    from ray_lightning_tpu.models.gpt import GPTLightningModule
+    from ray_lightning_tpu.serve import Server
+    from ray_lightning_tpu.telemetry import spans
+    tmp = tmp_path_factory.mktemp("tiny_server")
+    server = Server(GPTLightningModule("tiny"), checkpoint=None,
+                    platform="cpu", buckets=(16, 32), max_batch_slots=4,
+                    max_new_tokens=8, telemetry=False,
+                    # the AOT thread runs with the compile cache only
+                    compile_cache=str(tmp / "compile_cache"),
+                    default_root_dir=str(tmp / "root")).start()
+    try:
+        sched, window = server.scheduler, {"plans": 0}
+        plain_plan = sched.plan
+
+        def plan():
+            p = plain_plan()
+            if p is not None:
+                window["plans"] += 1
+                if window["plans"] == 3:
+                    p["profile"] = {"id": "t", "steps": 4,
+                                    "dir": str(tmp / "prof")}
+                    window["first_step"] = server.scheduler.pump.steps
+            return p
+
+        sched.plan = plan
+        reqs = [server.submit(np.arange(1, 9 + i, dtype=np.int32),
+                              max_new_tokens=8) for i in range(6)]
+        for r in reqs:
+            r.result(timeout=120)
+        time.sleep(1.0)       # idle iterations are pump time too
+        stats = server.stats()
+    finally:
+        server.shutdown()
+    return {"stats": stats,
+            "records": spans.kept("server_start") + spans.kept("pump"),
+            "prof": str(tmp / "prof"), "first_step": window["first_step"],
+            "after": server.scheduler.stats()}
+
+
+def test_pump_phases_add_up_to_the_pumps_wall_time(tiny_server):
+    pump = tiny_server["stats"]["scheduler"]["pump"]
+    phases = sum(pump[k + "_s"] for k in
+                 ("loop", "plan", "call", "wait", "apply", "idle"))
+    assert pump["steps"] >= 10 and pump["idle_s"] > 0.5
+    assert phases == pytest.approx(pump["wall_s"], rel=0.05)
+    # the worker's own seconds are inside call + wait: the rest is RPC
+    assert 0 < pump["worker_s"] < pump["call_s"] + pump["wait_s"]
+    # stopped with the pump: the clock does not run on after shutdown
+    done = tiny_server["after"]["pump"]
+    assert done["wall_s"] == pytest.approx(
+        sum(done[k + "_s"] for k in
+            ("loop", "plan", "call", "wait", "apply", "idle")), rel=0.05)
+
+
+def test_server_start_leaves_every_setup_span_under_one_root(tiny_server):
+    records = tiny_server["records"]
+    assert [r["name"] for r in records].count("server_start") == 1
+    root, under = _tree(records, "server_start")
+    assert root["parent"] is None
+    assert SETUP_SPANS_SERVE <= set(under), SETUP_SPANS_SERVE - set(under)
+    for name in ("spawn", "rendezvous", "ship", "worker_setup"):
+        assert under[name][0]["parent"] == root["id"], name
+    # the worker's own spans came back with setup_serve's result and
+    # hang under the driver's wait for it, renumbered
+    worker_root = under["setup_serve"][0]
+    assert worker_root["parent"] == under["worker_setup"][0]["id"]
+    for name in ("imports", "devices", "weights", "build", "warmup",
+                 "kv_init"):
+        assert all(r["parent"] == worker_root["id"]
+                   for r in under[name]), name
+    warmed = {r["attrs"]["program"] for r in under["warm"]}
+    assert warmed == {"kv_init", "prefill_16", "prefill_32", "decode"}
+    assert all(r["parent"] == under["warmup"][0]["id"]
+               for r in under["warm"])
+    assert len({r["id"] for r in records}) == len(records)
+    # one host, one wall clock: the worker's spans lie inside the wait
+    wait = under["worker_setup"][0]
+    assert wait["ts"] - 0.05 <= worker_root["ts"] and \
+        worker_root["ts"] + worker_root["dur"] <= \
+        wait["ts"] + wait["dur"] + 0.05
+
+
+def test_profile_window_keeps_the_pumps_spans_with_the_workers_steps(
+        tiny_server):
+    """The pump is not in the captured process: its spans of the
+    window's steps are kept in its own, and the step number on the plan
+    ties them to the worker's ``rlt/serve_step`` annotations."""
+    first = tiny_server["first_step"]
+    window = list(range(first, first + 4))
+    pump = [r for r in tiny_server["records"]
+            if r["name"].startswith("pump.")]
+    by_name = {}
+    for r in pump:
+        by_name.setdefault(r["name"], []).append(r["attrs"]["step"])
+    for name in ("pump.call", "pump.wait", "pump.apply"):
+        assert sorted(by_name[name]) == window, name
+    # the window opens on the plan that carries it: that step's root and
+    # plan span were already behind it
+    assert sorted(by_name["pump.step"]) == window[1:]
+    assert sorted(by_name["pump.plan"]) == window[1:]
+    roots = {r["attrs"]["step"]: r["id"] for r in pump
+             if r["name"] == "pump.step"}
+    assert all(r["parent"] == roots[r["attrs"]["step"]] for r in pump
+               if r["name"] != "pump.step"
+               and r["attrs"]["step"] in roots)
+    host = _host_plane(tiny_server["prof"])
+    steps = sorted(stats["step"] for _, _, stats in host["rlt/serve_step"])
+    assert steps == window
+    assert {"rlt/clock", "rlt/decode", "rlt/prefill", "rlt/dispatch",
+            "rlt/fetch"} <= set(host)
+    # anchor applied, the worker's step lies between the moment the pump
+    # sent the call and the moment its wait returned (on a loaded box the
+    # worker may start before the pump thread gets as far as the wait)
+    a_start, _, a_stats = max(host["rlt/clock"],
+                              key=lambda a: a[0])   # the last one made
+    offset = a_stats["wall_ns"] * 1e-9 - a_start
+    calls, waits = ({r["attrs"]["step"]: r for r in pump
+                     if r["name"] == name}
+                    for name in ("pump.call", "pump.wait"))
+    for start, dur, stats in host["rlt/serve_step"]:
+        c, w = calls[stats["step"]], waits[stats["step"]]
+        assert c["ts"] - 1e-3 <= start + offset
+        assert start + offset + dur <= w["ts"] + w["dur"] + 1e-3
+
+
+def test_profile_window_writes_the_scope_table_beside_the_trace(
+        tiny_server):
+    path = os.path.join(tiny_server["prof"], "rank0", "op_scopes.json")
+    with open(path) as f:
+        doc = json.load(f)
+    programs = doc["programs"]
+    assert {"jit_serve_decode", "jit_serve_prefill_16",
+            "jit_serve_prefill_32"} <= set(programs)
+    assert {"attn", "mlp", "ln", "embed", "lm_head", "kv_cache",
+            "sample"} <= set(programs["jit_serve_decode"].values())
+    assert set(programs["jit_serve_decode"].values()) - {None} \
+        <= set(doc["scopes"])
+
 
 
 # -- trainer integration -------------------------------------------------
