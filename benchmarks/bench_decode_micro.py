@@ -5,7 +5,7 @@ einsum vs the Pallas flash-decode kernel vs its paged variant
 Usage:  python -m benchmarks.bench_decode_micro [steps] [L ...]
 
 Times ``jit(cached_attention)`` — one new token per slot against a
-[S, L, H, D] KV cache with RAGGED per-slot positions (the serve
+[1, S, L, H*D] KV cache with RAGGED per-slot positions (the serve
 plane's steady state: every slot at a different depth) — and prints
 one JSON line per (impl, L) with wall ms/iter plus the device ms/iter
 of the dominant XLA module (device time, not host time — same
@@ -49,15 +49,15 @@ def _bench_impl(impl: str, L: int, steps: int, platform: str) -> dict:
     key = jax.random.PRNGKey(0)
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (S, 1, H, D), jnp.bfloat16)
-    kc = jax.random.normal(kk, (S, L, H, D), jnp.bfloat16)
-    vc = jax.random.normal(kv, (S, L, H, D), jnp.bfloat16)
+    kc = jax.random.normal(kk, (1, S, L, H * D), jnp.bfloat16)
+    vc = jax.random.normal(kv, (1, S, L, H * D), jnp.bfloat16)
     pos = jnp.asarray(_ragged_positions(L))
     table = (jnp.asarray(identity_page_table(S, L, PAGE_SIZE))
              if impl == "paged" else None)
 
     @jax.jit
     def step(q, kc, vc, pos):
-        return cached_attention(q, kc, vc, pos, impl=impl,
+        return cached_attention(q, kc, vc, pos, layer=0, impl=impl,
                                 page_table=table)
 
     out = step(q, kc, vc, pos)

@@ -242,8 +242,9 @@ def build_prefill_step(module, bucket_len: int, model=None,
                                              keepdims=False),
                 axis=-1).astype(tokens.dtype)
         # captured K/V ride the module tree ({'h0': {'attn': {'kv':
-        # ((k, v),)}}}); stack to [n_layer, 1, Tb, H, D] and write every
-        # layer's block with one dynamic_update_slice at the slot
+        # ((k, v),)}}}) as packed [1, Tb, C] rows, the cache's own row
+        # layout; stack to [n_layer, 1, Tb, C] and write every layer's
+        # block with one dynamic_update_slice at the slot
         with jax.named_scope("kv_cache"):
             ks, vs = _stacked_kv(captured["kv_cache"])
             k_caches = jax.lax.dynamic_update_slice(
@@ -276,7 +277,7 @@ def kv_layer_pairs(kv_tree) -> "list[tuple]":
 
 
 def _stacked_kv(kv_tree):
-    """[n_layer, B, Tb, H, D] k/v stacks from the sown collection."""
+    """[n_layer, B, Tb, C] k/v stacks from the sown collection."""
     pairs = kv_layer_pairs(kv_tree)
     ks = jnp.stack([k for k, _ in pairs])
     vs = jnp.stack([v for _, v in pairs])
@@ -290,7 +291,8 @@ def build_decode_step(module, page_table=None) -> Callable:
     ``(params, k_caches, v_caches, tokens, positions) ->
     (k', v', next_tokens)``: advances EVERY batch slot one token in one
     compiled SPMD program — ``tokens``/``positions`` are ``[S]``, the
-    caches ``[n_layer, S, L, H, D]``.  Static shapes by construction:
+    caches ``[n_layer, S, L, H*D]`` (serve/kvcache.py), donated by the
+    engine and updated in place.  Static shapes by construction:
     request insertion/eviction is a slot-index change in the host-side
     scheduler, so decode never re-traces (serve/scheduler.py).
 
@@ -407,7 +409,7 @@ def build_kv_copy() -> Callable:
 
     def copy_fn(k_caches, v_caches, src, dst, length):
         L = k_caches.shape[2]
-        mask = (jnp.arange(L) < length)[None, None, :, None, None]
+        mask = (jnp.arange(L) < length)[None, None, :, None]
 
         def one(c):
             src_rows = jax.lax.dynamic_slice_in_dim(c, src, 1, axis=1)
@@ -427,36 +429,33 @@ def build_suffix_step(module, page_table=None) -> Callable:
 
     ``(params, k_caches, v_caches, token, pos, slot) ->
     (k', v', next_token)``: advances ONE slot one token — the model's
-    decode forward on a 1-slot batch sliced out of the cache, written
-    back in place.  After a prefix-cache hit copies the matched pages
-    (:func:`build_kv_copy`), the unmatched suffix is teacher-forced
-    through this program one token at a time; only the suffix is ever
-    computed, which is the measured ``prefill tokens computed vs
-    requested`` savings.  Unlike the batched decode program this writes
-    NOTHING outside ``slot`` — no dummy writes to neighbors — so it can
-    run mid-step without the serve plan's dispatch-order contract.
+    decode forward on a 1-row batch whose row IS cache slot ``slot``
+    (``GPT.decode(slots=...)``): it writes that slot's row into the
+    resident buffers and reads that slot's rows where they lie, with no
+    slot sliced out or written back.  After a prefix-cache hit copies
+    the matched pages (:func:`build_kv_copy`), the unmatched suffix is
+    teacher-forced through this program one token at a time; only the
+    suffix is ever computed, which is the measured ``prefill tokens
+    computed vs requested`` savings.  Unlike the batched decode program
+    this writes NOTHING outside ``slot`` — no dummy writes to neighbors
+    — so it can run mid-step without the serve plan's dispatch-order
+    contract.
 
-    ``page_table`` here is the ONE-slot table (``identity_page_table(1,
-    L, page_size)``): the decode forward sees the cache sliced down to
-    its single slot, so physical pages are slice-relative — identical
-    for every slot, which is what lets one compiled program serve them
-    all.
+    ``page_table`` is the engine's whole ``[S, pages_per_slot]`` table;
+    the program takes row ``slot`` of it, so one compiled program
+    serves every slot.
     """
     module.setup_model()
     model = module.configure_decode_model()
-    kw = {} if page_table is None else {
-        "page_table": jnp.asarray(page_table, jnp.int32)}
+    table = None if page_table is None \
+        else jnp.asarray(page_table, jnp.int32)
 
     def step_fn(params, k_caches, v_caches, token, pos, slot):
-        k1 = jax.lax.dynamic_slice_in_dim(k_caches, slot, 1, axis=1)
-        v1 = jax.lax.dynamic_slice_in_dim(v_caches, slot, 1, axis=1)
-        logits, nk, nv = model.apply(
-            {"params": params}, token[None], pos[None], k1, v1,
-            method="decode", **kw)
-        k_caches = jax.lax.dynamic_update_slice_in_dim(k_caches, nk,
-                                                       slot, axis=1)
-        v_caches = jax.lax.dynamic_update_slice_in_dim(v_caches, nv,
-                                                       slot, axis=1)
+        kw = {} if table is None else {
+            "page_table": jax.lax.dynamic_slice_in_dim(table, slot, 1)}
+        logits, k_caches, v_caches = model.apply(
+            {"params": params}, token[None], pos[None], k_caches, v_caches,
+            method="decode", slots=slot[None], **kw)
         nxt = jnp.argmax(logits[0], axis=-1).astype(token.dtype)
         return k_caches, v_caches, nxt
 

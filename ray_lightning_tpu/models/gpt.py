@@ -143,7 +143,8 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, *,
-                 decode_cache=None, positions=None, page_table=None):
+                 decode_cache=None, positions=None, slots=None,
+                 page_table=None):
         cfg = self.config
         attn = MultiHeadAttention(
             n_head=cfg.n_head, causal=True, dropout=cfg.dropout,
@@ -155,11 +156,13 @@ class Block(nn.Module):
             h = nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x)
         new_cache = None
         if decode_cache is not None:
-            # serve-plane decode: the attention returns the updated slot
-            # cache alongside its output (ops/attention.py)
+            # serve-plane decode: ``decode_cache`` is the whole resident
+            # (k, v) plus this block's layer number; the attention
+            # returns the updated buffers alongside its output
+            # (ops/attention.py)
             a, new_cache = attn(h, deterministic,
                                 decode_cache=decode_cache,
-                                positions=positions,
+                                positions=positions, slots=slots,
                                 page_table=page_table)
         else:
             a = attn(h, deterministic)
@@ -247,46 +250,59 @@ class GPT(nn.Module):
             return self.wte.attend(x).astype(jnp.float32)
 
     def decode(self, tokens, positions, k_caches, v_caches,
-               page_table=None):
+               page_table=None, slots=None):
         """One continuous-batching decode step over ``S`` batch slots
         (the serve plane's hot program, ray_lightning_tpu/serve/).
 
         ``tokens`` [S] int32 — each slot's current token; ``positions``
         [S] int32 — that token's absolute position; ``k_caches`` /
-        ``v_caches`` [n_layer, S, L, H, D] — the slot-indexed KV cache.
-        Writes each token's K/V at its slot position and returns
-        ``(logits [S, V] fp32, new_k, new_v)``.  Traces with STATIC
-        shapes regardless of which slots are live — in-flight request
-        insertion/eviction happens by slot index, never by re-trace.
+        ``v_caches`` [n_layer, S, L, H*D] — the slot-indexed KV cache
+        in its resident layout (serve/kvcache.py).  Writes each token's
+        K/V row at ``[layer, slot, position]`` and returns ``(logits
+        [S, V] fp32, k_caches, v_caches)``: the buffers that come back
+        are the ones that went in, one row a slot and layer newer.
+        Traces with STATIC shapes regardless of which slots are live —
+        in-flight request insertion/eviction happens by slot index,
+        never by re-trace.
 
         Use through ``configure_decode_model()`` (remat/dropout off);
         MoE configs are rejected by the serve engine (token routing is
         batch-shaped, unsupported in the decode path).  ``page_table``
         ([S, pages_per_slot] int32, serve/fleet/pages.py) rides down to
         ``cached_attention`` for the paged flash-decode kernel; ``None``
-        keeps the slot-contiguous layout.
+        keeps the slot-contiguous layout.  ``slots`` ([B] int32) makes
+        ``tokens`` / ``positions`` a batch of B rows that live in cache
+        slots ``slots[b]`` and touch no other (the one-row suffix
+        program, core/steps.py ``build_suffix_step``); ``None`` is the
+        decode step proper: row s is slot s.
         """
         cfg = self.config
         with jax.named_scope("embed"):
             x = self.wte(tokens[:, None])
             x = x + jnp.take(self.wpe, positions,
                              axis=0)[:, None, :].astype(cfg.dtype)
-        new_k, new_v = [], []
-        for i, blk in enumerate(self.blocks):
-            with jax.named_scope("kv_cache"):
-                layer_cache = (k_caches[i], v_caches[i])
-            x, (k, v) = blk(x, True, decode_cache=layer_cache,
-                            positions=positions, page_table=page_table)
-            new_k.append(k)
-            new_v.append(v)
-        with jax.named_scope("ln"):
-            x = self.ln_f(x)
+        x, k_caches, v_caches = self._cached_blocks(
+            x, positions, k_caches, v_caches, page_table, slots)
         with jax.named_scope("lm_head"):
             logits = self.wte.attend(x).astype(jnp.float32)
-        with jax.named_scope("kv_cache"):
-            # every layer's slice out and stack back: the padded copy of
-            # K and V that the decode program pays (ROADMAP S4a)
-            return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
+        return logits[:, 0], k_caches, v_caches
+
+    def _cached_blocks(self, x, positions, k_caches, v_caches, page_table,
+                       slots=None):
+        """The blocks over the resident cache, for :meth:`decode` and
+        :meth:`verify`.  No layer is sliced out and nothing is stacked
+        back: every block gets the whole (donated) buffers and its own
+        layer number, writes its rows into them and reads them where
+        they lie (ops/attention.py), so the program's cache traffic is
+        the rows written and the rows attention reads.  (ROADMAP S4a:
+        slicing a layer out and stacking it back made the compiler copy
+        and relayout the whole cache every step; PERF.md, PR 25.)"""
+        for i, blk in enumerate(self.blocks):
+            x, (k_caches, v_caches) = blk(
+                x, True, decode_cache=(k_caches, v_caches, i),
+                positions=positions, slots=slots, page_table=page_table)
+        with jax.named_scope("ln"):
+            return self.ln_f(x), k_caches, v_caches
 
     def verify(self, tokens, positions, k_caches, v_caches,
                page_table=None):
@@ -304,8 +320,8 @@ class GPT(nn.Module):
         tolerance.  Rows written for later-rejected drafts are stale
         but masked (never at or below any live query's bound) and are
         overwritten by the next round, which restarts at the first
-        corrected position.  Returns ``(logits [S, T, V] fp32, new_k,
-        new_v)``.
+        corrected position.  Returns ``(logits [S, T, V] fp32,
+        k_caches, v_caches)``.
         """
         cfg = self.config
         with jax.named_scope("embed"):
@@ -315,20 +331,11 @@ class GPT(nn.Module):
             # truncated by the scheduler's max_new cap before anything
             # is emitted)
             x = x + jnp.take(self.wpe, positions, axis=0).astype(cfg.dtype)
-        new_k, new_v = [], []
-        for i, blk in enumerate(self.blocks):
-            with jax.named_scope("kv_cache"):
-                layer_cache = (k_caches[i], v_caches[i])
-            x, (k, v) = blk(x, True, decode_cache=layer_cache,
-                            positions=positions, page_table=page_table)
-            new_k.append(k)
-            new_v.append(v)
-        with jax.named_scope("ln"):
-            x = self.ln_f(x)
+        x, k_caches, v_caches = self._cached_blocks(
+            x, positions, k_caches, v_caches, page_table)
         with jax.named_scope("lm_head"):
             logits = self.wte.attend(x).astype(jnp.float32)
-        with jax.named_scope("kv_cache"):
-            return logits, jnp.stack(new_k), jnp.stack(new_v)
+        return logits, k_caches, v_caches
 
 
 def gpt_partition_rules(tensor_axis: str = "tensor") -> list[tuple[str, P]]:

@@ -112,17 +112,31 @@ def local_attention(q, k, v, **kw):
     return dot_product_attention(q, k, v, **kw)
 
 
-def cached_attention(q, k_cache, v_cache, positions, *,
-                     dtype=jnp.bfloat16, impl=None, page_table=None):
-    """Single-token attention against a slot-indexed KV cache (the serve
-    plane's decode core, ray_lightning_tpu/serve/).
+def cached_attention(q, k_cache, v_cache, positions, *, layer,
+                     slots=None, dtype=jnp.bfloat16, impl=None,
+                     page_table=None):
+    """Single-token attention against one layer of the slot-indexed KV
+    cache (the serve plane's decode core, ray_lightning_tpu/serve/).
 
     ``q``: [S, 1, H, D] — one new token per batch slot; ``k_cache`` /
-    ``v_cache``: [S, L, H, D] — each slot's full context; ``positions``:
-    [S] — the absolute position of slot s's current token.  Slot s
-    attends cache indices <= positions[s]: indices beyond its position
-    hold stale prefill padding or a previous tenant's leftovers, which
-    decode must never read (serve/kvcache.py invariant).
+    ``v_cache``: [n_layer, S, L, H*D] — the WHOLE resident buffers, in
+    the layout they live in (serve/kvcache.py: heads packed on the lane
+    axis); ``layer``: the static layer number this call reads;
+    ``positions``: [S] — the absolute position of slot s's current
+    token.  Slot s attends cache indices <= positions[s]: indices beyond
+    its position hold stale prefill padding or a previous tenant's
+    leftovers, which decode must never read (serve/kvcache.py
+    invariant).  ``slots`` ([B] int32, traced) makes ``q`` a batch of B
+    rows that read cache slots ``slots[b]`` instead of slots 0..S-1
+    (the one-row suffix program); ``page_table`` then has one row per
+    batch row.
+
+    The buffers are handed over whole so that nothing on the way slices
+    a layer out of them: the Pallas kernel offsets its block index by
+    the layer (ops/flash_decode.py), which costs nothing.  Only the
+    dense path below takes ``cache[layer]`` and unpacks the heads —
+    free on the CPU, one layer's relayout on a TPU geometry the kernel
+    cannot lower.
 
     ``impl`` picks the kernel (explicit > ``RLT_DECODE_IMPL`` env >
     ``auto``): ``dense`` is the masked einsum below; ``flash_decode`` is
@@ -154,31 +168,37 @@ def cached_attention(q, k_cache, v_cache, positions, *,
         else:
             return jnp.concatenate(
                 [cached_attention(q[:, j:j + 1], k_cache, v_cache,
-                                  positions[:, j], dtype=dtype, impl=impl,
+                                  positions[:, j], layer=layer,
+                                  slots=slots, dtype=dtype, impl=impl,
                                   page_table=page_table)
                  for j in range(q.shape[1])], axis=1)
 
-    _, _, H, D = q.shape
+    B, _, H, D = q.shape
+    L = k_cache.shape[2]
     kernel = select_decode_kernel(
-        k_cache.shape[1], H, D, dtype=q.dtype, impl=impl,
+        L, H, D, dtype=q.dtype, impl=impl,
         n_pages=None if page_table is None else page_table.shape[1])
     note_decode_kernel(kernel)
     if kernel != "dense":
         return flash_decode_attention(
-            q, k_cache, v_cache, positions, dtype=dtype,
+            q, k_cache, v_cache, positions, layer=layer, slots=slots,
+            dtype=dtype,
             page_table=page_table if kernel == "paged" else None)
-    d = q.shape[-1]
-    scores = jnp.einsum("sqhd,slhd->shql", q, k_cache,
+    k, v = k_cache[layer], v_cache[layer]
+    if slots is not None:
+        k, v = k[slots], v[slots]
+    k, v = k.reshape(B, L, H, D), v.reshape(B, L, H, D)
+    scores = jnp.einsum("sqhd,slhd->shql", q, k,
                         preferred_element_type=jnp.float32)
-    scores = scores / np.sqrt(d)
-    valid = jnp.arange(k_cache.shape[1])[None, :] <= positions[:, None]
+    scores = scores / np.sqrt(D)
+    valid = jnp.arange(L)[None, :] <= positions[:, None]
     # NEG_INF (-1e30), not finfo.min: the flash kernels' NaN-free
     # masking constant — finfo.min survives one subtract in fp32 but a
     # fully-masked row would softmax over exact -inf after scaling
     # drift; -1e30 keeps exp/log finite everywhere
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-    return jnp.einsum("shql,slhd->sqhd", probs, v_cache)
+    return jnp.einsum("shql,slhd->sqhd", probs, v)
 
 
 def resolve_attention(impl: str) -> Callable:
@@ -220,57 +240,66 @@ class MultiHeadAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, *,
-                 decode_cache=None, positions=None, page_table=None):
+                 decode_cache=None, positions=None, slots=None,
+                 page_table=None):
         B, T, C = x.shape
         head_dim = C // self.n_head
         qkv = nn.Dense(3 * C, dtype=self.dtype, name="qkv")(x)
+        # K and V stay packed ([B, T, C]: heads side by side on the lane
+        # axis) for the serve plane, whose cache rows are exactly that
         q, k, v = jnp.split(qkv, 3, axis=-1)
         shape = (B, T, self.n_head, head_dim)
-        q, k, v = (a.reshape(shape) for a in (q, k, v))
         if decode_cache is not None:
-            # serve-plane decode: B = batch slots, T = 1.  Write this
-            # token's k/v at each slot's own position, then attend the
-            # query over the (just-updated) cache — mask handled by
-            # cached_attention's per-slot position bound.  Shapes are
-            # static, so slots with no live request write too (the
-            # scheduler sends tokens=0/positions=0 for them): a dummy
-            # entry at position 0 the serve plane must overwrite via the
-            # slot's admitting prefill BEFORE the slot decodes — hence
-            # ServeWorker.serve_step dispatches decode before prefills.
-            k_cache, v_cache = decode_cache
-            slots = jnp.arange(B)
-            if T == 1:
-                # profiler scope (telemetry/scopes.py): the cache write
-                # is kv_cache, not the attention around it
-                with jax.named_scope("kv_cache"):
-                    k_cache = k_cache.at[slots, positions].set(k[:, 0])
-                    v_cache = v_cache.at[slots, positions].set(v[:, 0])
-            else:
-                # multi-query verify (T = speculation depth k+1,
-                # positions [B, T]): write every query's K/V first,
-                # then attend each query under its own position bound
-                # (cached_attention's multi-query form) — causal by the
-                # bound, so query j never sees rows j+1..T-1.  Rows at
-                # positions >= L (slots speculating past the cache end,
-                # and the paging dummy row's +j offsets) are DROPPED by
-                # jax's out-of-bounds scatter semantics — no per-slot
-                # gating, no shape change, no retrace.
-                with jax.named_scope("kv_cache"):
-                    k_cache = k_cache.at[slots[:, None], positions].set(k)
-                    v_cache = v_cache.at[slots[:, None], positions].set(v)
-            y = cached_attention(q, k_cache, v_cache, positions,
+            # serve-plane decode: B = batch slots, T = 1;
+            # ``decode_cache`` is (k_cache, v_cache, layer).  Write this
+            # token's k/v row straight into the resident, donated
+            # buffers ([n_layer, S, L, C]) at [layer, slot, position] —
+            # the only bytes of the cache this program writes — then
+            # attend the query over layer ``layer`` of the
+            # (just-updated) buffers; mask handled by cached_attention's
+            # per-slot position bound.  Batch row b is cache slot b,
+            # unless ``slots`` ([B] int32) names the rows' slots (the
+            # one-row suffix program, core/steps.py build_suffix_step).
+            # Shapes are static, so slots with no live request write
+            # too (the scheduler sends tokens=0/positions=0 for them):
+            # a dummy entry at position 0 the serve plane must
+            # overwrite via the slot's admitting prefill BEFORE the
+            # slot decodes — hence ServeWorker.serve_step dispatches
+            # decode before prefills.
+            k_cache, v_cache, layer = decode_cache
+            # profiler scope (telemetry/scopes.py): the cache write is
+            # kv_cache, not the attention around it.  Decode writes one
+            # row a slot; multi-query verify (T = speculation depth
+            # k+1, positions [B, T]) writes every query's K/V first and
+            # then attends each query under its own position bound
+            # (cached_attention's multi-query form) — causal by the
+            # bound, so query j never sees rows j+1..T-1.  Rows at
+            # positions >= L (slots speculating past the cache end, and
+            # the paging dummy row's +j offsets) are DROPPED by jax's
+            # out-of-bounds scatter semantics — no per-slot gating, no
+            # shape change, no retrace.
+            with jax.named_scope("kv_cache"):
+                rows = jnp.arange(B) if slots is None else slots
+                at = (layer, rows[:, None], positions.reshape(B, T))
+                k_cache = k_cache.at[at].set(k)
+                v_cache = v_cache.at[at].set(v)
+            y = cached_attention(q.reshape(shape), k_cache, v_cache,
+                                 positions, layer=layer, slots=slots,
                                  dtype=self.dtype, page_table=page_table)
             y = nn.Dense(C, dtype=self.dtype,
                          name="proj")(y.reshape(B, T, C))
             return y, (k_cache, v_cache)
         # prefill capture: when the caller applies with
         # mutable=("kv_cache",) the per-layer K/V land in that collection
-        # (serve/engine.py reads them into the slot cache); in every
+        # as the packed [B, T, C] halves of qkv — the rows the slot cache
+        # holds, so the prefill program writes them with no relayout
+        # (serve/engine.py, core/steps.py build_prefill_step); in every
         # other apply — training included — sow is a no-op.  Never sown
         # at init (init makes every collection mutable, which would leak
         # a kv_cache collection into the train state).
         if not self.is_initializing():
             self.sow("kv_cache", "kv", (k, v))
+        q, k, v = (a.reshape(shape) for a in (q, k, v))
         attend = resolve_attention(self.attention_impl)
         y = attend(q, k, v, causal=self.causal, dtype=self.dtype)
         y = nn.Dense(C, dtype=self.dtype, name="proj")(y.reshape(B, T, C))

@@ -1,8 +1,9 @@
 """Flash-decode: the per-token serve hot path as a TPU Pallas kernel.
 
 ``cached_attention`` (ops/attention.py) is a masked dense einsum: every
-decoded token reads ALL ``[S, L, H, D]`` cache rows and materializes
-``[S, H, 1, L]`` fp32 scores, however short each slot's live context is.
+decoded token reads ALL ``S x L`` cache rows of its layer and
+materializes ``[S, H, 1, L]`` fp32 scores, however short each slot's
+live context is.
 Decode is bandwidth-bound — one query token against L cache rows — so
 the win is not FLOPs, it is *bytes not read*.  This kernel:
 
@@ -18,19 +19,27 @@ the win is not FLOPs, it is *bytes not read*.  This kernel:
   whose mapped index is unchanged from the previous grid step, so a slot
   at position p reads ``ceil((p+1)/block_k)`` KV blocks, not ``L/block_k``;
 - has a **paged** variant whose KV index_map walks a page table
-  (``serve/fleet/pages.py identity_page_table``): the cache is viewed as
-  ``[S*pages_per_slot, page_size, C]`` physical pages and block ``p`` of
-  slot ``s`` fetches physical page ``table[s, p]``.  Today's table is the
-  identity (the device cache is slot-contiguous); the kernel contract is
-  already the indirect one, so physical page sharing only changes the
-  table.
+  (``serve/fleet/pages.py identity_page_table``): a layer of the cache
+  is viewed as ``[S*pages_per_slot, page_size, C]`` physical pages and
+  block ``p`` of slot ``s`` fetches physical page ``table[s, p]``.
+  Today's table is the identity (the device cache is slot-contiguous);
+  the kernel contract is already the indirect one, so physical page
+  sharing only changes the table;
+- reads the **resident cache as it lies**: the serve plane keeps K and V
+  as ``[n_layer, S, L, C]`` (serve/kvcache.py) and every layer's call
+  gets the whole buffer plus its static layer number.  The kernel sees
+  ``[n_layer*S, L, C]`` — a merge of leading dimensions, which is a
+  bitcast on the TPU's tiled layouts — and its K/V index_map adds
+  ``layer*S`` to the slot (paged: ``layer*S*pages_per_slot`` to the
+  physical page).  No slice of a layer and no relayout is ever made.
 
-Heads are packed on the lane axis (``C = H*D``) and looped in-kernel
-with static column slices, mirroring the packed flash kernels; per head
-the scores sit on the lane axis too (``[1, block_k]``), so both
-products are MXU matmuls.  On non-TPU backends everything runs under
-the Pallas interpreter so the tier-1 suite executes the real kernel
-body on CPU — which says nothing about what Mosaic accepts:
+Heads are packed on the lane axis (``C = H*D``), which is the cache's
+own minor dimension, and looped in-kernel with static column slices,
+mirroring the packed flash kernels; per head the scores sit on the lane
+axis too (``[1, block_k]``), so both products are MXU matmuls.  On
+non-TPU backends everything runs under the Pallas interpreter so the
+tier-1 suite executes the real kernel body on CPU — which says nothing
+about what Mosaic accepts:
 tests/test_chip_compile.py compiles flat and paged, bf16 and f32, for a
 described v5e.
 
@@ -250,21 +259,36 @@ def flash_decode_paged_kernel(positions_ref, table_ref, q_ref, k_ref,
                  m_ref, l_ref, acc_ref, **kw)
 
 
-def flash_decode_attention(q, k_cache, v_cache, positions, *,
-                           dtype=jnp.bfloat16, block_k=None,
+def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
+                           slots=None, dtype=jnp.bfloat16, block_k=None,
                            page_table=None, interpret=None):
-    """Length-aware flash decode over the slot cache.
+    """Length-aware flash decode over one layer of the slot cache.
 
-    ``q`` [S, 1, H, D]; ``k_cache``/``v_cache`` [S, L, H, D];
+    ``q`` [S, 1, H, D]; ``k_cache``/``v_cache`` [n_layer, S, L, H*D] —
+    the whole resident buffers; ``layer`` — which layer of them this
+    call reads (a static int: it only offsets the K/V index_map);
     ``positions`` [S] int32; returns [S, 1, H, D] in ``dtype``.  With
     ``page_table`` ([S, pages_per_slot] int32, physical page ids into
-    the ``[S*pages_per_slot, page_size, C]`` page view) the KV
+    the layer's ``[S*pages_per_slot, page_size, C]`` page view) the KV
     index_map walks the table instead of the slot-contiguous layout;
     ``page_size`` is implied by ``L // page_table.shape[1]``.
+
+    ``slots`` ([B] int32, traced): ``q`` is then [B, 1, H, D] and row b
+    reads cache slot ``slots[b]`` — an index_map can follow a traced
+    slot only through a scalar-prefetch table, so without a
+    ``page_table`` (whose rows are the batch rows' then) the rows walk
+    their slots' own ``block_k``-row pages through the paged kernel.
     """
-    S, _, H, D = q.shape
-    L = k_cache.shape[1]
-    C = H * D
+    B, _, H, D = q.shape
+    n_layer, S, L, C = k_cache.shape
+    if C != H * D or not 0 <= layer < n_layer \
+            or (slots is None and B != S):
+        raise ValueError(
+            f"cache {k_cache.shape} does not hold layer {layer} of "
+            f"{B} rows x {H} heads x {D}")
+    if slots is not None and page_table is None:
+        nk = L // (block_k or _pick_block_k(L))
+        page_table = slots[:, None] * nk + jnp.arange(nk)[None, :]
     paged = page_table is not None
     if paged:
         n_pages = page_table.shape[1]
@@ -278,18 +302,22 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *,
     if interpret is None:
         interpret = _use_interpret()
 
-    q2 = q.reshape(S, 1, C)
-    k2 = k_cache.reshape(S, L, C)
-    v2 = v_cache.reshape(S, L, C)
+    q2 = q.reshape(B, 1, C)
+    # merges of leading dimensions only: the minor (L, C) tiles stay
+    # where they are, so these are bitcasts, not copies
+    k2 = k_cache.reshape(n_layer * S, L, C)
+    v2 = v_cache.reshape(n_layer * S, L, C)
+    base = layer * S
 
     if paged:
         # physical page view; the table maps (slot, logical page) ->
-        # physical page row
-        k2 = k2.reshape(S * nk, bk, C)
-        v2 = v2.reshape(S * nk, bk, C)
+        # physical page row of the layer, whose pages start at base*nk
+        k2 = k2.reshape(n_layer * S * nk, bk, C)
+        v2 = v2.reshape(n_layer * S * nk, bk, C)
 
         def kv_map(s, p, pos_ref, tab_ref):
-            return (tab_ref[s, kv_block_bound(p, pos_ref[s], bk)], 0, 0)
+            return (base * nk
+                    + tab_ref[s, kv_block_bound(p, pos_ref[s], bk)], 0, 0)
 
         def sq_map(s, p, pos_ref, tab_ref):
             return (s, 0, 0)
@@ -300,7 +328,7 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *,
         kv_block = (1, bk, C)
     else:
         def kv_map(s, kb, pos_ref):
-            return (s, kv_block_bound(kb, pos_ref[s], bk), 0)
+            return (base + s, kv_block_bound(kb, pos_ref[s], bk), 0)
 
         def sq_map(s, kb, pos_ref):
             return (s, 0, 0)
@@ -311,7 +339,7 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(S, nk),
+        grid=(B, nk),
         in_specs=[
             pl.BlockSpec((1, 1, C), sq_map),
             pl.BlockSpec(kv_block, kv_map),
@@ -337,12 +365,12 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *,
         # the custom call's name in the compiled program and the trace
         name="flash_decode" if not paged else "flash_decode_paged",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, 1, C), dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, C), dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*scalars, q2, k2, v2)
-    return out.reshape(S, 1, H, D)
+    return out.reshape(B, 1, H, D)
 
 
 __all__ = [

@@ -94,17 +94,18 @@ def _main(argv) -> int:   # noqa: ARG001 - argv kept for parity
             problems.append(f"decode_kernel_supported{args} raised "
                             f"{e!r}")
 
-    # 4. lowering sanity: kernel (interpret) vs dense masked einsum
+    # 4. lowering sanity: kernel (interpret) vs dense masked einsum,
+    # both on layer 1 of a two-layer resident cache [n_layer, S, L, H*D]
     S, L, H, D = 2, 64, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (S, 1, H, D), jnp.float32)
-    kc = jax.random.normal(ks[1], (S, L, H, D), jnp.float32)
-    vc = jax.random.normal(ks[2], (S, L, H, D), jnp.float32)
+    kc = jax.random.normal(ks[1], (2, S, L, H * D), jnp.float32)
+    vc = jax.random.normal(ks[2], (2, S, L, H * D), jnp.float32)
     pos = jnp.asarray([3, L - 1], jnp.int32)
-    dense = cached_attention(q, kc, vc, pos, dtype=jnp.float32,
+    dense = cached_attention(q, kc, vc, pos, layer=1, dtype=jnp.float32,
                              impl="dense")
-    flash = flash_decode_attention(q, kc, vc, pos, dtype=jnp.float32,
-                                   block_k=16)
+    flash = flash_decode_attention(q, kc, vc, pos, layer=1,
+                                   dtype=jnp.float32, block_k=16)
     err = float(jnp.max(jnp.abs(dense - flash)))
     if not err < 2e-5:
         problems.append(f"flash-decode kernel diverged from the dense "
@@ -120,7 +121,8 @@ def _main(argv) -> int:   # noqa: ARG001 - argv kept for parity
         pass
     else:
         problems.append("non-tiling page size did not raise")
-    paged = flash_decode_attention(q, kc, vc, pos, dtype=jnp.float32,
+    paged = flash_decode_attention(q, kc, vc, pos, layer=1,
+                                   dtype=jnp.float32,
                                    page_table=jnp.asarray(table))
     if not np.array_equal(np.asarray(paged), np.asarray(flash)):
         problems.append("paged kernel over the identity table is not "

@@ -173,9 +173,9 @@ class ShardingStrategy:
     def data_parallel_size(self, mesh: Mesh) -> int:
         return mesh_axis_size(mesh, *self.data_axis_names)
 
-    def kv_cache_spec(self, mesh: Mesh, ndim: int = 5) -> P:
+    def kv_cache_spec(self, mesh: Mesh, ndim: int = 4) -> P:
         """Sharding of the serve plane's slot-indexed KV cache
-        ``[n_layer, slot, pos, head, dim]`` (serve/kvcache.py): slots
+        ``[n_layer, slot, pos, head*dim]`` (serve/kvcache.py): slots
         shard exactly like the batch's leading dim — each data shard
         decodes its own slots with no cross-device attention traffic.
         Requires ``max_batch_slots`` divisible by the data-axis size
@@ -493,10 +493,12 @@ class SpmdStrategy(ShardingStrategy):
             return P(data, "sequence")
         return P(data)
 
-    def kv_cache_spec(self, mesh: Mesh, ndim: int = 5) -> P:
-        """Slots on the data axes plus heads on ``tensor`` when the mesh
-        has one — the decode attention is head-parallel the same way the
-        training attention is (gpt_partition_rules)."""
+    def kv_cache_spec(self, mesh: Mesh, ndim: int = 4) -> P:
+        """Slots on the data axes plus the packed head axis ``C`` on
+        ``tensor`` when the mesh has one: a shard of ``C`` is whole
+        heads as long as ``n_head`` divides by the axis, so the decode
+        attention is head-parallel the same way the training attention
+        is (gpt_partition_rules)."""
         spec = list(super().kv_cache_spec(mesh, ndim))
         if ndim >= 4 and mesh.shape.get("tensor", 1) > 1:
             spec[3] = "tensor"

@@ -141,8 +141,8 @@ class ServeEngine:
 
         with span("build"):
             # abstract params + cache geometry, no device work: params from
-            # the model's own init avals, K/V head shapes from an abstract
-            # prefill capture on the smallest bucket
+            # the model's own init avals, the K/V row width from an
+            # abstract prefill capture on the smallest bucket
             dummy = jax.ShapeDtypeStruct((1, self.buckets[0]), np.int32)
             abstract_vars = jax.eval_shape(
                 model.init, jax.random.PRNGKey(0), dummy)
@@ -226,7 +226,7 @@ class ServeEngine:
             # "paged" lowers the slot-contiguous flash kernel instead
             # (ops/flash_decode.py select_decode_kernel)
             from ray_lightning_tpu.ops.flash_decode import resolve_decode_impl
-            page_table = suffix_table = None
+            page_table = None
             if resolve_decode_impl(None) == "paged" \
                     and self.paged is not None \
                     and self.max_seq_len % self.paged.page_size == 0:
@@ -234,8 +234,6 @@ class ServeEngine:
                     identity_page_table)
                 page_table = identity_page_table(
                     self.slots, self.max_seq_len, self.paged.page_size)
-                suffix_table = identity_page_table(
-                    1, self.max_seq_len, self.paged.page_size)
             self._decode = jit_step(
                 "decode", build_decode_step(module, page_table=page_table), 2)
             if self.paged is not None:
@@ -244,7 +242,7 @@ class ServeEngine:
                 # that computes only the unmatched tail of a prompt
                 self._suffix = jit_step(
                     "suffix",
-                    build_suffix_step(module, page_table=suffix_table), 3)
+                    build_suffix_step(module, page_table=page_table), 3)
                 ckw: dict = {"donate_argnums": (0, 1)}
                 if multi:
                     ckw["in_shardings"] = (kv_sh, kv_sh, rep, rep, rep)
@@ -440,9 +438,9 @@ class ServeEngine:
                         i32(self.slots, self.spec.k + 1),
                         i32(self.slots, self.spec.k + 1)))
         if self.kvship:
-            nl, _, _, nh, hd = self.kv_spec.shape
+            nl, _, _, width = self.kv_spec.shape
             for b, jitted in self._kv_imports.items():
-                rows = jax.ShapeDtypeStruct((nl, 1, b, nh, hd), kv_dtype)
+                rows = jax.ShapeDtypeStruct((nl, 1, b, width), kv_dtype)
                 pre.submit(f"kv_import_{b}", jitted,
                            (kv_aval, kv_aval, rows, rows, i32()))
         return pre
@@ -494,9 +492,9 @@ class ServeEngine:
                                   v, z2, z2)
                 del dk, dv
             if self.kvship:
-                nl, _, _, nh, hd = self.kv_spec.shape
+                nl, _, _, width = self.kv_spec.shape
                 for b, jitted in self._kv_imports.items():
-                    rows = np.zeros((nl, 1, b, nh, hd), kv_dtype)
+                    rows = np.zeros((nl, 1, b, width), kv_dtype)
                     k, v = warm(f"kv_import_{b}", jitted, k, v, rows,
                                 rows, np.int32(0))
             with span("device_wait"):
@@ -659,7 +657,7 @@ class ServeEngine:
     def export_kv(self, slot: int, bucket: int
                   ) -> "tuple[np.ndarray, np.ndarray]":
         """Device→host copy of ``slot``'s cache rows ``[0, bucket)``
-        across every layer: ``([n_layer, 1, bucket, H, D], same)`` —
+        across every layer: ``([n_layer, 1, bucket, H*D], same)`` —
         the payload a prefill replica ships to a decode replica.  Rows
         past the prompt are pad garbage; the importer only registers
         (and the reuse path only copies) the prompt's whole pages, so

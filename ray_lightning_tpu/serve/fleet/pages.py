@@ -1,7 +1,7 @@
 """Paged KV accounting + prefix-hash reuse (the host half of the
 fleet's "prefill once per replica" story).
 
-The device cache stays the slot-contiguous ``[n_layer, S, L, H, D]``
+The device cache stays the slot-contiguous ``[n_layer, S, L, H*D]``
 pair (serve/kvcache.py) — preallocated like every static-shape array in
 this framework — so "paging" here is NOT physical indirection but the
 two host-side structures that make page-granular reuse sound:

@@ -1,16 +1,27 @@
 """Slot-indexed, device-resident KV cache for continuous batching.
 
-The cache is two arrays ``[n_layer, S, L, H, D]`` (keys / values): ``S``
-batch slots x ``L`` max context, living on device for the whole life of
-the serve fleet and sharded through the training strategies
-(``ShardingStrategy.kv_cache_spec`` — slots ride the data axes like a
-batch dim, heads ride ``tensor`` under SPMD).  In-flight request
-insertion and eviction are SLOT INDEX operations:
+The cache is two arrays ``[n_layer, S, L, C]`` (keys / values): ``S``
+batch slots x ``L`` max context x ``C = n_head * head_dim``, living on
+device for the whole life of the serve fleet and sharded through the
+training strategies (``ShardingStrategy.kv_cache_spec`` — slots ride
+the data axes like a batch dim, ``C`` rides ``tensor`` under SPMD: whole
+heads, as long as ``n_head`` divides).
+
+There is ONE layout, the one the decode kernel reads: a row is a
+token's heads side by side on the lane axis, which is how the qkv
+projection makes it (ops/attention.py) and how ops/flash_decode.py
+computes on it.  Every program takes the two arrays whole, donated, and
+touches of them only the rows it writes and the rows attention reads;
+none slices a layer out, unpacks the heads or stacks layers back (on
+the TPU's tiled layouts ``[.., H, D] -> [.., H*D]`` is a copy of the
+whole cache, not a view).  In-flight request insertion and eviction are
+SLOT INDEX operations:
 
 - insert  = the bucket prefill program ``dynamic_update_slice``-writes a
   prompt's K/V block at its slot (core/steps.py build_prefill_step);
-- advance = the decode program scatter-writes one position per slot
-  (ops/attention.py cached_attention);
+- advance = the decode program scatter-writes one row per slot and
+  layer at ``[layer, slot, position]`` (ops/attention.py
+  MultiHeadAttention);
 - evict   = the driver frees the slot index — NO device work.  Stale
   K/V beyond a slot's position bound are unreachable by construction
   (the per-slot position mask), so a freed slot is reusable the moment
@@ -31,18 +42,19 @@ import numpy as np
 @dataclass(frozen=True)
 class KVCacheSpec:
     """Host-side description of the device cache (picklable; shipped to
-    workers inside the serve payload)."""
+    workers inside the serve payload).  ``width`` is a row's length
+    ``C = n_head * head_dim``: the cache never sees heads apart."""
 
     n_layer: int
     slots: int
     max_seq_len: int
-    n_head: int
-    head_dim: int
+    width: int
 
     @property
-    def shape(self) -> tuple[int, int, int, int, int]:
-        return (self.n_layer, self.slots, self.max_seq_len, self.n_head,
-                self.head_dim)
+    def shape(self) -> tuple[int, int, int, int]:
+        """``[n_layer, S, L, C]`` — THE shape every serve program and
+        the decode kernel share."""
+        return (self.n_layer, self.slots, self.max_seq_len, self.width)
 
     def nbytes(self, itemsize: int = 2) -> int:
         """Device residency of BOTH cache arrays (k and v) at the given
@@ -54,15 +66,15 @@ class KVCacheSpec:
                      max_seq_len: int) -> "KVCacheSpec":
         """Derive the cache geometry from a prefill ``eval_shape``
         capture: ``kv_shapes`` is any per-layer K aval list with entries
-        shaped ``[B, T, H, D]`` (core/steps.py _stacked_kv order)."""
+        shaped ``[B, T, C]`` (core/steps.py _stacked_kv order)."""
         n_layer = len(kv_shapes)
         if n_layer == 0:
             raise ValueError("model captured no kv_cache entries; does "
                              "its attention sow the 'kv_cache' "
                              "collection? (ops/attention.py)")
-        _, _, n_head, head_dim = kv_shapes[0].shape
+        _, _, width = kv_shapes[0].shape
         return cls(n_layer=n_layer, slots=slots, max_seq_len=max_seq_len,
-                   n_head=int(n_head), head_dim=int(head_dim))
+                   width=int(width))
 
 
 class SlotAllocator:

@@ -150,9 +150,9 @@ def _check_spec_lowers() -> None:
     adraft = jax.eval_shape(draft.init, jax.random.PRNGKey(0),
                             jax.ShapeDtypeStruct((1, 8), np.int32)
                             )["params"]
-    S, L, H, D, k = 2, 16, 2, 16, 3
-    kv = jax.ShapeDtypeStruct((2, S, L, H, D), draft.config.dtype)
-    dkv = jax.ShapeDtypeStruct((1, S, L, H, D), draft.config.dtype)
+    S, L, C, k = 2, 16, 32, 3
+    kv = jax.ShapeDtypeStruct((2, S, L, C), draft.config.dtype)
+    dkv = jax.ShapeDtypeStruct((1, S, L, C), draft.config.dtype)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)  # noqa: E731
     jax.jit(build_draft_step(module, k, model=draft)).lower(
         adraft, dkv, dkv, i32(S), i32(S))
@@ -187,8 +187,8 @@ def _check_decode_lowers() -> None:
     aparams = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                              jax.ShapeDtypeStruct((1, 8), np.int32)
                              )["params"]
-    S, L, H, D = 2, 16, 2, 16
-    kv = jax.ShapeDtypeStruct((2, S, L, H, D), model.config.dtype)
+    S, L, C = 2, 16, 32
+    kv = jax.ShapeDtypeStruct((2, S, L, C), model.config.dtype)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)  # noqa: E731
     jax.jit(build_decode_step(module)).lower(
         aparams, kv, kv, i32(S), i32(S))

@@ -21,6 +21,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -28,6 +29,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs stay out of /t
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -86,16 +88,118 @@ def test_flash_decode_compiles_for_v5e(v5e, dtype, paged):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
 
-    args = [sds((S, 1, H, D), dtype), sds((S, L, H, D), dtype),
-            sds((S, L, H, D), dtype), sds((S,), jnp.int32)]
+    # layer 1 of a two-layer resident cache [n_layer, S, L, H*D]
+    args = [sds((S, 1, H, D), dtype), sds((2, S, L, H * D), dtype),
+            sds((2, S, L, H * D), dtype), sds((S,), jnp.int32)]
     if paged:
         args.append(sds((S, L // 128), jnp.int32))
 
     def decode(q, k, v, pos, table=None):
-        return flash_decode_attention(q, k, v, pos, dtype=dtype,
+        return flash_decode_attention(q, k, v, pos, layer=1, dtype=dtype,
                                       page_table=table, interpret=False)
 
     _compiles_with_kernel(decode, *args)
+
+
+# -- the cache's trip through the serve programs ----------------------------
+#
+# The K/V cache is resident as [n_layer, S, L, H*D] and every program
+# that advances it gets it donated.  What the chip's compiler makes of
+# that is the whole point (PERF.md, PR 25): the old trip — a layer
+# sliced out, its heads unpacked, the layers stacked back — compiled to
+# 7.74 GB of temporaries beside a 4.53 GB cache at gpt2-large x 24 slots
+# and to 88.7 of the decode step's 110.1 ms.  These compile the programs
+# for the described v5e from the engine's own cache shape and hold them
+# to "no scratch worth the name, no copy and no slice of a layer".
+
+_GEOMETRY = {
+    # name: (n_layer, n_head, n_embd), block_size 1024
+    "gpt2-small": (12, 12, 768),
+    "gpt2-large": (36, 20, 1280),
+}
+
+_HLO_RESULT = re.compile(
+    r"= \(?(?:bf16|f32)\[([0-9,]+)\][^ ]* (copy|copy-start|slice|"
+    r"dynamic-slice|concatenate|transpose)\(")
+
+
+def _serve_program(monkeypatch, v5e, config, slots, program, paged):
+    """``(compiled, cache_bytes, layer_elems)`` of one serve program at
+    ``slots``, lowered for the described chip with the Pallas decode
+    kernel (this process's backend is the CPU, so the test steers the
+    code that asks: the program has no option for it)."""
+    from ray_lightning_tpu.core import steps
+    from ray_lightning_tpu.models.gpt import GPTConfig, GPTLightningModule
+    from ray_lightning_tpu.ops import flash_decode
+    from ray_lightning_tpu.serve.fleet.pages import identity_page_table
+    from ray_lightning_tpu.serve.kvcache import KVCacheSpec
+
+    monkeypatch.setenv("RLT_DECODE_IMPL", "paged" if paged else
+                       "flash_decode")
+    monkeypatch.setattr(flash_decode, "_use_interpret", lambda: False)
+    n_layer, n_head, n_embd = _GEOMETRY[config]
+    module = GPTLightningModule(GPTConfig(
+        block_size=L, n_layer=n_layer, n_head=n_head, n_embd=n_embd,
+        remat=False))
+    module.setup_model()
+    net = module.configure_decode_model()
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    # the engine's own derivation (serve/engine.py setup): params from
+    # the model's init avals, the cache's shape from a prefill capture
+    dummy = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0),
+                            dummy)["params"]
+    _, cap = jax.eval_shape(
+        lambda p, t: net.apply({"params": p}, t, True,
+                               mutable=["kv_cache"]), params, dummy)
+    spec = KVCacheSpec.from_capture(
+        [k for k, _ in steps.kv_layer_pairs(cap["kv_cache"])], slots, L)
+    assert spec.shape == (n_layer, slots, L, n_embd)
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, jnp.bfloat16), params)
+    cache = on_chip(spec.shape, jnp.bfloat16)
+    table = identity_page_table(slots, L, 128) if paged else None
+    i32 = lambda *shape: on_chip(shape, jnp.int32)   # noqa: E731
+    if program == "decode":
+        fn = steps.build_decode_step(module, page_table=table)
+        args = (i32(slots), i32(slots))
+    elif program == "verify":
+        fn = steps.build_verify_step(module, 3, page_table=table)
+        args = (i32(slots, 4), i32(slots, 4))
+    else:
+        fn = steps.build_suffix_step(module, page_table=table)
+        args = (i32(), i32(), i32())
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, cache, cache, *args).compile()
+    return compiled, spec.nbytes() // 2, slots * L * n_embd
+
+
+@pytest.mark.parametrize("config,slots,program,paged", [
+    ("gpt2-large", 24, "decode", False),
+    ("gpt2-small", 24, "decode", False),
+    ("gpt2-small", 24, "decode", True),
+    ("gpt2-small", 24, "verify", False),
+    ("gpt2-small", 24, "verify", True),
+    ("gpt2-small", 24, "suffix", False),
+    ("gpt2-small", 24, "suffix", True),
+], ids=lambda v: str(v))
+def test_serve_program_leaves_the_cache_where_it_lies(
+        monkeypatch, v5e, config, slots, program, paged):
+    compiled, cache_bytes, layer_elems = _serve_program(
+        monkeypatch, v5e, config, slots, program, paged)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.05 * cache_bytes, (
+        f"{temp / 1e9:.3f} GB of temporaries beside a "
+        f"{cache_bytes / 1e9:.3f} GB cache array")
+    movers = [m.group(0) for m in _HLO_RESULT.finditer(text)
+              if np.prod([int(d) for d in m.group(1).split(",")])
+              >= layer_elems]
+    assert not movers, movers[:5]
 
 
 # -- which decode kernel lowers ---------------------------------------------
@@ -123,13 +227,14 @@ def test_explicit_decode_kernel_never_falls_back(monkeypatch, impl):
     from ray_lightning_tpu.ops.attention import cached_attention
     monkeypatch.setattr(fd, "_use_interpret", lambda: False)  # as on TPU
     q = jnp.zeros((2, 1, 3, 24), jnp.bfloat16)
-    kv = jnp.zeros((2, 128, 3, 24), jnp.bfloat16)
+    kv = jnp.zeros((1, 2, 128, 3 * 24), jnp.bfloat16)
     pos = jnp.zeros((2,), jnp.int32)
     table = jnp.zeros((2, 8), jnp.int32)
     with pytest.raises(ValueError, match="requested explicitly"):
-        cached_attention(q, kv, kv, pos, impl=impl, page_table=table)
+        cached_attention(q, kv, kv, pos, layer=0, impl=impl,
+                         page_table=table)
     with fd.record_decode_kernels() as lowered:
-        cached_attention(q, kv, kv, pos, impl="dense")
+        cached_attention(q, kv, kv, pos, layer=0, impl="dense")
     assert lowered == {"dense"}
 
 

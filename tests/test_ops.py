@@ -374,19 +374,74 @@ def test_fwd_rowres_with_grid_tri_backward(monkeypatch):
 # -- decode kernel tier (ops/flash_decode.py) ------------------------------
 
 
+#: the resident cache is [n_layer, S, L, H*D] and every call reads ONE
+#: layer of it: the tier runs on layer 1 of 3, so an index_map that
+#: forgets the layer's offset (or adds the wrong one) reads random rows
+N_LAYER, LAYER = 3, 1
+
+
 def _rand_decode(s=4, L=256, h=2, d=32, seed=3, dtype=jnp.float32):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (s, 1, h, d), dtype)
-    kc = jax.random.normal(ks[1], (s, L, h, d), dtype)
-    vc = jax.random.normal(ks[2], (s, L, h, d), dtype)
+    kc = jax.random.normal(ks[1], (N_LAYER, s, L, h * d), dtype)
+    vc = jax.random.normal(ks[2], (N_LAYER, s, L, h * d), dtype)
     return q, kc, vc
 
 
-def _decode(impl, q, kc, vc, pos, dtype=jnp.float32, page_table=None):
+def _decode(impl, q, kc, vc, pos, dtype=jnp.float32, page_table=None,
+            slots=None):
     from ray_lightning_tpu.ops.attention import cached_attention
     return cached_attention(q, kc, vc, jnp.asarray(pos, jnp.int32),
-                            dtype=dtype, impl=impl,
-                            page_table=page_table)
+                            layer=LAYER, slots=slots, dtype=dtype,
+                            impl=impl, page_table=page_table)
+
+
+def _einsum_ref(q, kc, vc, pos, dtype=jnp.float32):
+    """The plain mathematics, written here and not in the package: the
+    masked einsum over layer LAYER of the cache, heads unpacked to
+    ``[S, L, H, D]``."""
+    s, _, h, d = q.shape
+    k = kc[LAYER].reshape(s, -1, h, d)
+    v = vc[LAYER].reshape(s, -1, h, d)
+    scores = jnp.einsum("sqhd,slhd->shql", q, k,
+                        preferred_element_type=jnp.float32) / np.sqrt(d)
+    valid = jnp.arange(k.shape[1])[None, :] <= jnp.asarray(pos)[:, None]
+    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum("shql,slhd->sqhd", probs, v)
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash_decode", "paged"])
+def test_decode_impls_match_unpacked_einsum(impl):
+    """Every decode path reads layer LAYER of the packed, stacked cache
+    and agrees with the einsum over its unpacked ``[S, L, H, D]`` view,
+    across ragged positions including 0 and the cache's last row."""
+    from ray_lightning_tpu.serve.fleet.pages import identity_page_table
+    q, kc, vc = _rand_decode()
+    pos = [0, 17, 128, 255]
+    table = jnp.asarray(identity_page_table(4, 256, 64)) \
+        if impl == "paged" else None
+    out = _decode(impl, q, kc, vc, pos, page_table=table)
+    np.testing.assert_allclose(out, _einsum_ref(q, kc, vc, pos),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash_decode", "paged"])
+def test_decode_rows_in_named_slots(impl):
+    """``slots``: a batch of rows that live in cache slots of their own
+    choosing (the one-row suffix program) reads exactly what the full
+    batch reads at those slots."""
+    from ray_lightning_tpu.serve.fleet.pages import identity_page_table
+    q, kc, vc = _rand_decode()
+    pos = np.array([0, 17, 128, 255])
+    table = jnp.asarray(identity_page_table(4, 256, 64)) \
+        if impl == "paged" else None
+    full = _einsum_ref(q, kc, vc, pos)
+    pick = jnp.asarray([3, 1], jnp.int32)
+    out = _decode(impl, q[pick], kc, vc, pos[np.asarray(pick)],
+                  page_table=None if table is None else table[pick],
+                  slots=pick)
+    np.testing.assert_allclose(out, full[pick], atol=2e-5, rtol=2e-5)
 
 
 def test_flash_decode_matches_dense_ragged():
@@ -436,8 +491,8 @@ def test_paged_decode_page_boundary_straddle():
     out = _decode("paged", q, kc, vc, pos, page_table=table)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
     flat = flash_decode_attention(
-        q, kc, vc, jnp.asarray(pos, jnp.int32), dtype=jnp.float32,
-        block_k=page)
+        q, kc, vc, jnp.asarray(pos, jnp.int32), layer=LAYER,
+        dtype=jnp.float32, block_k=page)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(flat))
 
 
@@ -449,4 +504,5 @@ def test_dense_decode_fully_masked_no_nan():
     q, kc, vc = _rand_decode(s=2, L=64)
     out = _decode("dense", q, kc, vc, [-1, 0])
     assert np.isfinite(np.asarray(out)).all()
-    np.testing.assert_allclose(out[1, 0], vc[1, 0], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(out[1, 0].reshape(-1), vc[LAYER, 1, 0],
+                               atol=2e-5, rtol=2e-5)

@@ -74,12 +74,30 @@ def test_slot_allocator_insert_evict():
 
 
 def test_kv_cache_spec_geometry():
+    """One layout: ``[n_layer, S, L, C]``, a row being a token's heads
+    packed side by side as the prefill capture sows them."""
     class _Aval:
-        shape = (1, 16, 4, 32)
+        shape = (1, 16, 4 * 32)
     spec = KVCacheSpec.from_capture([_Aval(), _Aval()], slots=8,
                                     max_seq_len=64)
-    assert spec.shape == (2, 8, 64, 4, 32)
+    assert spec.shape == (2, 8, 64, 4 * 32)
     assert spec.nbytes(2) == 2 * 2 * 8 * 64 * 4 * 32 * 2
+
+
+@pytest.mark.parametrize("tensor", [1, 2], ids=["data", "data_tensor"])
+def test_kv_cache_spec_sharding(tensor):
+    """Four dimensions: slots ride the data axes; under SPMD with a
+    ``tensor`` axis the packed head axis ``C`` rides it (whole heads)."""
+    from jax.sharding import PartitionSpec as P
+    from ray_lightning_tpu.parallel.strategy import SpmdStrategy
+    if tensor == 1:
+        strategy, want = DataParallelStrategy(), P(None, "data", None, None)
+    else:
+        strategy = SpmdStrategy(axis_names=("data", "tensor"),
+                                axis_sizes={"data": 4, "tensor": 2})
+        want = P(None, "data", None, "tensor")
+    mesh = strategy.build_mesh(batch_hint=4)
+    assert strategy.kv_cache_spec(mesh) == want
 
 
 # -- scheduler: fairness, quota, slot uniqueness, drain-ability ------------
@@ -377,8 +395,10 @@ def test_engine_kernel_decode_parity_and_zero_retrace(impl, monkeypatch):
     serves the hot path."""
     from ray_lightning_tpu.serve.fleet.pages import PageConfig
     monkeypatch.setenv("RLT_DECODE_IMPL", impl)
-    paged = PageConfig(enabled=True, page_size=8) if impl == "paged" \
-        else None
+    # paging on under both: the flat kernel's engine then has the
+    # one-row suffix program too, whose row follows its (traced) slot
+    # through a table the kernel call makes for itself
+    paged = PageConfig(enabled=True, page_size=8)
     module = GPTLightningModule(TINY)
     eng = ServeEngine(module, DataParallelStrategy(), buckets=(8,),
                       slots=4, max_seq_len=TINY.block_size,
@@ -393,10 +413,203 @@ def test_engine_kernel_decode_parity_and_zero_retrace(impl, monkeypatch):
                         paged=paged).setup()
     assert dense.stats()["decode_kernel"] == "dense"
     assert got == _generate(dense, 1, prompt, 6), impl
+    # a prefix hit from slot 1's rows into slot 3: copy + suffix
+    longer = np.concatenate([prompt[:4], [17, 4, 8]]).astype(np.int32)
+    assert eng.prefill_reused(3, 1, longer, len(longer), matched=4) \
+        == dense.prefill_reused(3, 1, longer, len(longer), matched=4) \
+        == _reference(dense, longer, 1)[0], impl
     # zero retraces: more decode traffic on other slots reuses programs
     before = dict(eng.trace_counts)
     _generate(eng, 3, np.array([9, 1], np.int32), 3)
     assert eng.trace_counts == before, impl
+
+
+# -- every program that owns the cache's shape ------------------------------
+#
+# One layout, [n_layer, S, L, H*D], and every program takes the buffers
+# whole and donated (serve/kvcache.py).  Each case below drives one
+# program of ONE engine that has them all (paged + spec + kvship) and
+# holds it to something computed without the cache: the whole-sequence
+# forward's argmax for tokens, and for the rows themselves the plain
+# projection of layer 0, heads unpacked to [T, H, D].
+
+@pytest.fixture(scope="module")
+def full_engine():
+    from ray_lightning_tpu.serve.fleet.pages import PageConfig
+    from ray_lightning_tpu.serve.spec import SpecConfig
+    return ServeEngine(
+        GPTLightningModule(TINY), DataParallelStrategy(), buckets=(8,),
+        slots=4, max_seq_len=TINY.block_size, seed=0,
+        paged=PageConfig(enabled=True, page_size=8),
+        spec=SpecConfig(enabled=True, k=3, draft_layers=1),
+        kvship=True).setup()
+
+
+def _layer0_kv(eng, tokens):
+    """K and V of layer 0 for ``tokens`` at positions 0.., straight from
+    the parameters in float32: ``[T, H, D]`` each."""
+    p = jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32),
+                               jax.device_get(eng.params))
+    x = p["wte"]["embedding"][np.asarray(tokens)] + p["wpe"][:len(tokens)]
+    mu = x.mean(-1, keepdims=True)
+    h = (x - mu) / np.sqrt(x.var(-1, keepdims=True) + 1e-6)
+    h = h * p["h0"]["ln1"]["scale"] + p["h0"]["ln1"]["bias"]
+    qkv = h @ p["h0"]["attn"]["qkv"]["kernel"] + p["h0"]["attn"]["qkv"]["bias"]
+    _, k, v = np.split(qkv, 3, axis=-1)
+    shape = (len(tokens), TINY.n_head, TINY.head_dim)
+    return k.reshape(shape), v.reshape(shape)
+
+
+def _rows(eng, slot, n, layer=0):
+    """Rows ``[0, n)`` of ``slot``, unpacked to ``[n, H, D]`` float32."""
+    shape = (n, TINY.n_head, TINY.head_dim)
+    return tuple(np.asarray(c[layer, slot, :n], np.float32).reshape(shape)
+                 for c in (eng._k, eng._v))
+
+
+def _idle(eng):
+    """Tokens/positions of a step in which no slot is live: the dummy
+    writes aim at the last row, as the paged scheduler aims them."""
+    return (np.zeros(eng.slots, np.int32),
+            np.full(eng.slots, eng.max_seq_len - 1, np.int32))
+
+
+PROMPT = np.array([5, 9, 2, 7, 11, 3, 1], np.int32)
+
+
+def _case_prefill_write(eng):
+    """The prompt's rows land packed, heads side by side, at the slot."""
+    first = eng.prefill(2, pad_to_bucket(PROMPT, 8), len(PROMPT), 8)
+    assert first == _reference(eng, PROMPT, 1)[0]
+    assert eng._k.shape == eng._v.shape == eng.kv_spec.shape \
+        == (TINY.n_layer, 4, TINY.block_size, TINY.n_embd)
+    for got, want in zip(_rows(eng, 2, len(PROMPT)),
+                         _layer0_kv(eng, PROMPT)):
+        np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+
+
+def _case_decode(eng):
+    """Greedy tokens equal the plain forward's argmax, and each step
+    writes exactly the new token's row."""
+    got = _generate(eng, 1, PROMPT, 5)
+    assert got == _reference(eng, PROMPT, 5)
+    seq = list(PROMPT) + got[:-1]
+    for got_rows, want in zip(_rows(eng, 1, len(seq)),
+                              _layer0_kv(eng, seq)):
+        np.testing.assert_allclose(got_rows, want, atol=2e-2, rtol=2e-2)
+
+
+def _case_decode_donated(eng):
+    """A second step on the buffers the first returned gives what two
+    steps on fresh copies of the same contents give."""
+    eng.prefill(0, pad_to_bucket(PROMPT, 8), len(PROMPT), 8)
+    k0, v0 = np.asarray(eng._k), np.asarray(eng._v)
+    sh = eng._k.sharding
+    t, p = _idle(eng)
+
+    def step(k, v, tok, pos):
+        t[0], p[0] = tok, pos
+        k, v, out = eng._decode(eng.params, k, v, t.copy(), p.copy())
+        return k, v, int(np.asarray(out)[0])
+
+    first = _reference(eng, PROMPT, 1)[0]
+    k, v, a1 = step(jax.device_put(k0, sh), jax.device_put(v0, sh),
+                    first, len(PROMPT))
+    k, v, a2 = step(k, v, a1, len(PROMPT) + 1)        # donated chain
+    fk, fv, b1 = step(jax.device_put(k0, sh), jax.device_put(v0, sh),
+                      first, len(PROMPT))
+    fk, fv, b2 = step(jax.device_put(np.asarray(fk), sh),
+                      jax.device_put(np.asarray(fv), sh),
+                      b1, len(PROMPT) + 1)            # fresh copies
+    assert [first, a1, a2] == [first, b1, b2] == _reference(eng, PROMPT, 3)
+    np.testing.assert_array_equal(np.asarray(k, np.float32),
+                                  np.asarray(fk, np.float32))
+    np.testing.assert_array_equal(np.asarray(v, np.float32),
+                                  np.asarray(fv, np.float32))
+
+
+def _case_verify(eng):
+    """One batched forward over k drafted positions scores each under
+    its own bound: right drafts are all confirmed, a wrong one is
+    corrected at its column and cannot change the columns before it."""
+    k = eng.spec.k
+    want = _reference(eng, PROMPT, k + 2)
+    for wrong_at in (None, 1):
+        eng.prefill(3, pad_to_bucket(PROMPT, 8), len(PROMPT), 8)
+        t, p = _idle(eng)
+        t[3], p[3] = want[0], len(PROMPT)
+        drafts = np.zeros((eng.slots, k), np.int32)
+        drafts[3] = want[1:k + 1]
+        if wrong_at is not None:
+            drafts[3, wrong_at] = (want[1 + wrong_at] + 1) % TINY.vocab_size
+        ver = eng.verify(t, p, drafts)[3].tolist()
+        upto = k + 1 if wrong_at is None else wrong_at + 1
+        assert ver[:upto] == want[1:1 + upto], (wrong_at, ver, want)
+
+
+def _case_draft(eng):
+    """k unrolled decodes of the draft model over the draft cache equal
+    k greedy steps of the draft model's plain forward."""
+    eng.draft_prefill(1, pad_to_bucket(PROMPT, 8), len(PROMPT), 8)
+    assert eng._dk.shape == eng.draft_kv_spec.shape \
+        == (1, 4, TINY.block_size, TINY.n_embd)
+    first = _reference(eng, PROMPT, 1)[0]
+    t, p = _idle(eng)
+    t[1], p[1] = first, len(PROMPT)
+    got = eng.draft(t, p)[1].tolist()
+    params = jax.device_get(eng.params)
+    seq, want = list(PROMPT) + [first], []
+    for _ in range(eng.spec.k):
+        logits = eng._draft_model.apply(
+            {"params": params}, np.asarray([seq], np.int32), True)
+        want.append(int(np.argmax(np.asarray(logits)[0, -1])))
+        seq.append(want[-1])
+    assert got == want
+
+
+def _case_copy_and_suffix(eng):
+    """A prefix-cache hit: ``kv_copy`` moves the matched rows bit for
+    bit, the suffix program teacher-forces the rest into the SAME slot
+    and touches no other; the first token is the cold prefill's."""
+    longer = np.concatenate([PROMPT[:4], [17, 4, 8]]).astype(np.int32)
+    eng.prefill(0, pad_to_bucket(PROMPT, 8), len(PROMPT), 8)
+    before = np.asarray(eng._k, np.float32), np.asarray(eng._v, np.float32)
+    first = eng.prefill_reused(3, 0, longer, len(longer), matched=4)
+    assert first == _reference(eng, longer, 1)[0]
+    after = np.asarray(eng._k, np.float32), np.asarray(eng._v, np.float32)
+    for b, a in zip(before, after):
+        np.testing.assert_array_equal(a[:, :3], b[:, :3])   # neighbours
+        np.testing.assert_array_equal(a[:, 3, :4], b[:, 0, :4])  # copied
+        np.testing.assert_array_equal(a[:, 3, len(longer):],
+                                      b[:, 3, len(longer):])
+    for got, want in zip(_rows(eng, 3, len(longer)),
+                         _layer0_kv(eng, longer)):
+        np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+
+
+def _case_ship_round_trip(eng):
+    """``export_kv`` -> ``import_kv``: the rows arrive bit for bit, in
+    the cache's own row shape, and decode goes on from them."""
+    first = eng.prefill(0, pad_to_bucket(PROMPT, 8), len(PROMPT), 8)
+    k_rows, v_rows = eng.export_kv(0, 8)
+    assert k_rows.shape == v_rows.shape == (TINY.n_layer, 1, 8, TINY.n_embd)
+    eng.import_kv(2, k_rows.astype(np.float32), v_rows.astype(np.float32))
+    for c in (eng._k, eng._v):
+        c = np.asarray(c, np.float32)
+        np.testing.assert_array_equal(c[:, 2, :8], c[:, 0, :8])
+    t, p = _idle(eng)
+    t[2], p[2] = first, len(PROMPT)
+    assert int(eng.decode(t, p)[2]) == _reference(eng, PROMPT, 2)[1]
+
+
+@pytest.mark.parametrize("case", [
+    _case_prefill_write, _case_decode, _case_decode_donated, _case_verify,
+    _case_draft, _case_copy_and_suffix, _case_ship_round_trip,
+], ids=lambda f: f.__name__[len("_case_"):])
+def test_program_parity_on_the_packed_cache(full_engine, case):
+    before = dict(full_engine.trace_counts)
+    case(full_engine)
+    assert full_engine.trace_counts == before      # one layout, no retrace
 
 
 # -- 2-worker e2e: the acceptance run --------------------------------------

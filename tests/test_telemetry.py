@@ -430,9 +430,9 @@ def test_kernels_carry_their_names_in_a_program_lowered_for_the_chip(
     if which == "decode":
         from ray_lightning_tpu.ops.flash_decode import flash_decode_attention
         low = jax.jit(lambda q, k, v, pos: flash_decode_attention(
-            q, k, v, pos, interpret=False)).lower(
-            sds(8, 1, 4, 64), sds(8, 256, 4, 64), sds(8, 256, 4, 64),
-            sds(8, dt=jnp.int32))
+            q, k, v, pos, layer=0, interpret=False)).lower(
+            sds(8, 1, 4, 64), sds(1, 8, 256, 4 * 64),
+            sds(1, 8, 256, 4 * 64), sds(8, dt=jnp.int32))
     else:
         from ray_lightning_tpu.ops.flash_attention import flash_attention
 
