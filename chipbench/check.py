@@ -67,12 +67,15 @@ def verdict(numbers: dict, limits: dict) -> tuple[bool, list[dict]]:
 ROWS_PER_BLOCK = 8
 
 
-def served_positions(ref, weights: dict, model: dict, finished: list,
-                     also: tuple = ()) -> dict:
+def served_positions(ref, weights, model: dict, finished: list,
+                     also: tuple = (), *, context: int,
+                     rows_per_block: int = ROWS_PER_BLOCK) -> dict:
     """``finished`` is [(prompt ids, served ids)]: every request the
     window finished.  The reference runs once over each prompt with its
-    served tokens, ``ROWS_PER_BLOCK`` requests to a call, and yields one
-    entry per served token: ``gap``, how far the served token's reference
+    served tokens, ``rows_per_block`` requests of ``context`` positions
+    (the family's, ``adapter.context``) to a call, and is handed
+    ``weights`` as the family's ``make_weights`` returned them.  It yields
+    one entry per served token: ``gap``, how far the served token's reference
     logit lies below the reference's best there, and ``request``, whose
     token it was.  For every precision in ``also`` (the control's, by
     hand) the same mathematics runs again on lower-precision operands
@@ -81,7 +84,7 @@ def served_positions(ref, weights: dict, model: dict, finished: list,
     import jax
     import jax.numpy as jnp
 
-    T = int(model["n_positions"])
+    T = int(context)
 
     @jax.jit
     def block(w, tokens, targets):
@@ -98,11 +101,11 @@ def served_positions(ref, weights: dict, model: dict, finished: list,
 
     names = ["gap"] + ["gap_" + p for p in also]
     cols = {k: [] for k in names + ["request"]}
-    for at in range(0, len(finished), ROWS_PER_BLOCK):
-        rows = finished[at:at + ROWS_PER_BLOCK]
-        tokens = np.zeros((ROWS_PER_BLOCK, T), np.int32)
-        targets = np.zeros((ROWS_PER_BLOCK, T), np.int32)
-        mask = np.zeros((ROWS_PER_BLOCK, T), bool)
+    for at in range(0, len(finished), rows_per_block):
+        rows = finished[at:at + rows_per_block]
+        tokens = np.zeros((rows_per_block, T), np.int32)
+        targets = np.zeros((rows_per_block, T), np.int32)
+        mask = np.zeros((rows_per_block, T), bool)
         for r, (prompt, served) in enumerate(rows):
             P, A = len(prompt), len(served)
             tokens[r, :P] = prompt
@@ -142,24 +145,24 @@ def served_numbers(positions: dict) -> dict:
 
 # -- training ----------------------------------------------------------------
 
-def leaf_norms(tree: dict) -> dict:
-    """Per-leaf L2 norms of a reference-layout dict, one per layer for the
-    stacked tensors (arrays, still on the device)."""
+def leaf_norms(tree: dict, axes) -> dict:
+    """L2 norms of a reference-layout dict's tensors (arrays, still on
+    the device), each over the axes that the family's ``axes(name,
+    array)`` names (``adapter.leaf_norm_axes``): what is left is one norm
+    per leaf of the program's tree."""
     import jax.numpy as jnp
-    out = {}
-    for k, a in tree.items():
-        a = a.astype(jnp.float32)
-        stacked = a.ndim >= 2 and k not in ("wte", "wpe")
-        axes = tuple(range(1, a.ndim)) if stacked else None
-        out[k] = jnp.sqrt(jnp.sum(jnp.square(a), axis=axes))
-    return out
+    return {k: jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32)),
+                                axis=axes(k, a)))
+            for k, a in tree.items()}
 
 
 def train_reference(ref, weights: dict, model: dict, job: dict, rows,
-                    precision: str = "float32", place=None) -> dict:
+                    precision: str = "float32", place=None, *,
+                    axes) -> dict:
     """Three plain AdamW steps from ``weights`` on the job's first three
     batches.  Returns the losses, the first gradient's leaf norms and
-    the leaf norms of the parameters' change (reference layout).
+    the leaf norms of the parameters' change (reference layout, over
+    ``axes``: ``leaf_norms``).
 
     ``place`` (a multi-chip cell's) constrains every parameter-shaped
     tree to the sharding the weights came in, so that the float32
@@ -212,10 +215,10 @@ def train_reference(ref, weights: dict, model: dict, job: dict, rows,
             total = g if total is None else accumulate(total, g)
         g = jax.tree_util.tree_map(lambda a: a * (block / B), total)
         if t == 0:
-            first_grad = leaf_norms(g)
+            first_grad = leaf_norms(g, axes)
         losses.append(loss_sum)
         w, m, v = adamw(w, g, m, v, jnp.float32(t))
-    change = leaf_norms(jax.tree_util.tree_map(jnp.subtract, w, w0))
+    change = leaf_norms(jax.tree_util.tree_map(jnp.subtract, w, w0), axes)
     return {"losses": losses, "grad_norms": first_grad,
             "change_norms": change}
 

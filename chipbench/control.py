@@ -53,11 +53,10 @@ def main(argv=None) -> int:
         return 0
 
     from chipbench import check, train_cell
-    from chipbench.module import BenchModule
     train_cell.claim_devices(cell["chips"], "tpu")
     for seed in seeds:
-        rows = BenchModule(cell["config"]["model"], seed,
-                           cell["traffic"]).train_rows()
+        rows = cell["adapter"].module(cell["config"]["model"], seed,
+                                      cell["traffic"]).train_rows()
         exact = train_cell.reference_numbers(cell, seed, rows, None)
         low = train_cell.reference_numbers(cell, seed, rows, None,
                                            args.precision)

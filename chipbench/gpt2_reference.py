@@ -15,7 +15,7 @@ LayerNorm's epsilon is the configuration's (1e-6, the program's; the
 published 1e-5 is listed under `reduced`, with what it moves).
 
 Parameters are one dict of arrays with the layers stacked on a leading
-axis (``chipbench/weights.py`` makes them from the seed), so the blocks
+axis (``chipbench/adapters/gpt2.py`` makes them from the seed), so the blocks
 run under one ``lax.scan``.
 
 ``precision`` re-computes the same mathematics with every matrix product

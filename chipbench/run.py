@@ -17,6 +17,7 @@ by calling ``run_cell`` with overrides that no argument reaches.)
 from __future__ import annotations
 
 import argparse
+import importlib
 import importlib.util
 import json
 import os
@@ -32,6 +33,22 @@ EXIT_NO_ACCELERATOR = 2
 def _read(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_adapter(config_doc: dict, root: str):
+    """The configuration's model family: the module its file names under
+    ``"adapter"`` (``chipbench/README.md`` has the contract).  Imported by
+    name, as the serve worker will import it to unpickle the module it is
+    sent.  There is no default family."""
+    path = config_doc.get("adapter")
+    if not path:
+        raise SystemExit(
+            f"configuration {config_doc.get('name')!r} names no model "
+            f"family: its file needs \"adapter\": \"chipbench/adapters/"
+            f"<family>.py\" beside \"reference\" (there is no default)")
+    if not os.path.isfile(os.path.join(root, path)):
+        raise SystemExit(f"no adapter {path!r} under {root}")
+    return importlib.import_module(os.path.splitext(path)[0].replace("/", "."))
 
 
 def load_cell(root: str, workload: str, rehearsal: "dict | None") -> dict:
@@ -57,6 +74,7 @@ def load_cell(root: str, workload: str, rehearsal: "dict | None") -> dict:
     return {
         "name": workload, "root": root, "work": work, "chips": chips,
         "config": config, "traffic": traffic, "bench": bench,
+        "adapter": load_adapter(config, root),
         "peaks": {k: v for k, v in _read(os.path.join(
             root, "chipbench", "peaks.json")).items()
             if not k.startswith("_")},

@@ -16,7 +16,7 @@ import os
 import statistics
 import time
 
-from chipbench import check, weights
+from chipbench import check
 from chipbench.generator import LoadThread, RequestStream, build_table
 from chipbench.train_cell import NoAccelerator
 
@@ -96,21 +96,25 @@ class ServeSession:
     def __init__(self, cell: dict, seed: int, platform: str, t_process):
         from ray_lightning_tpu.serve import Server
 
-        from chipbench.module import BenchModule, program_seed
+        from chipbench.module import program_seed
 
         self.cell, self.seed = cell, seed
         model, mix = cell["config"]["model"], cell["traffic"]
+        adapter = cell["adapter"]
         self.phases = {"imports_s": time.monotonic() - t_process}
         kw = {"use_tpu": True} if platform == "tpu" \
             else {"platform": platform}
+        # the mix's own ``server`` arguments come first: what the harness
+        # sets below is not the mix's to change
         self.server = Server(
-            BenchModule(model, seed), checkpoint=None,
-            max_batch_slots=int(mix["slots"]),
-            buckets=used_buckets(mix, int(model["n_positions"])),
-            seed=program_seed(seed), default_root_dir=cell["work"],
-            telemetry=False,
-            worker_env={"PYTHONPATH": cell["root"] + os.pathsep
-                        + os.environ.get("PYTHONPATH", "")}, **kw)
+            adapter.module(model, seed), checkpoint=None,
+            **{**mix.get("server", {}),
+               "max_batch_slots": int(mix["slots"]),
+               "buckets": used_buckets(mix, adapter.context(model)),
+               "seed": program_seed(seed),
+               "default_root_dir": cell["work"], "telemetry": False,
+               "worker_env": {"PYTHONPATH": cell["root"] + os.pathsep
+                              + os.environ.get("PYTHONPATH", "")}, **kw})
         t = time.monotonic()
         self.server.start()
         self.phases["server_start_s"] = time.monotonic() - t
@@ -135,11 +139,9 @@ class ServeSession:
         window closes.  Returns what was sent and the window's edges."""
         server, counter = self.server, self.counter
         sched = server.scheduler
-        model = self.cell["config"]["model"]
         closed = mix["kind"] == "serve-closed"
         load = LoadThread(
-            RequestStream(mix, self.seed, int(mix.get(
-                "token_ids_below", model["vocab_size"]))),
+            RequestStream(mix, self.seed, int(mix["token_ids_below"])),
             lambda tokens, n: server.submit(tokens, max_new_tokens=n),
             backlog=(int(mix["backlog_per_slot"]) * int(mix["slots"])
                      if closed else None),
@@ -253,7 +255,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float,
                           "ttft_due_s": s.req.t_first - s.due,
                           "tpot_s": s.req.tpot_s} for s in in_window],
             "retraces": sum(worker["retraces"].values()),
-            "model": model, "traffic": mix, "chips": cell["chips"],
+            "model": model, "adapter": cell["adapter"], "traffic": mix,
+            "chips": cell["chips"],
             "peaks": cell["peaks"].get(device["kind"]),
         },
     }
@@ -267,9 +270,15 @@ def served_positions(cell: dict, seed: int, finished: list,
 
     from chipbench.module import init_key
 
-    model = cell["config"]["model"]
+    model, adapter = cell["config"]["model"], cell["adapter"]
     ref = check.load_reference(cell["config"], cell["root"])
-    w = jax.jit(lambda key: weights.make_weights(model, key))(
+    # whatever the family's make_weights returns (arrays, or the key for a
+    # reference that makes each layer's weights where it applies them)
+    # goes to the reference's forward untouched
+    w = jax.jit(lambda key: adapter.make_weights(model, key))(
         init_key("serve", seed))
-    return check.served_positions(ref, w, model, finished,
-                                  (control,) if control else ())
+    return check.served_positions(
+        ref, w, model, finished, (control,) if control else (),
+        context=adapter.context(model),
+        rows_per_block=int(cell["traffic"].get(
+            "reference_rows_per_block", check.ROWS_PER_BLOCK)))

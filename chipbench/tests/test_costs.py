@@ -1,12 +1,12 @@
-"""FLOP and byte functions against hand arithmetic; the table of peaks."""
+"""The GPT-2 adapter's FLOP and byte functions against hand arithmetic; the
+table of peaks."""
 
 import json
 import os
 
 import pytest
 
-from chipbench import bytes as traffic_bytes
-from chipbench import flops
+from chipbench.adapters import gpt2
 
 from conftest import ROOT
 
@@ -23,8 +23,8 @@ def _model(name):
 ])
 def test_train_flops_per_token(name, params, mflop):
     m = _model(name)
-    assert flops.matmul_params(m) == params
-    assert flops.train_flops_per_token(m, 1024) / 1e6 == pytest.approx(
+    assert gpt2.matmul_params(m) == params
+    assert gpt2.train_flops_per_token(m, 1024) / 1e6 == pytest.approx(
         mflop, abs=0.01)
 
 
@@ -34,16 +34,16 @@ def test_train_flops_per_token(name, params, mflop):
 ])
 def test_decode_bytes(name, weights_gb, kv_row):
     m = _model(name)
-    assert traffic_bytes.weight_bytes(m) / 1e9 == pytest.approx(
+    assert gpt2.weight_bytes(m) / 1e9 == pytest.approx(
         weights_gb, abs=0.001)
-    assert traffic_bytes.kv_bytes_per_token(m) == kv_row
-    assert traffic_bytes.decode_step_bytes(m, 1000) == \
-        traffic_bytes.weight_bytes(m) + 1000 * kv_row
+    assert gpt2.kv_bytes_per_token(m) == kv_row
+    assert gpt2.decode_step_bytes(m, 1000) == \
+        gpt2.weight_bytes(m) + 1000 * kv_row
 
 
 def test_a_slot_of_gpt2_large_holds_189_megabytes_of_cache():
     m = _model("gpt2-large")
-    assert traffic_bytes.kv_bytes_per_token(m) * 1024 / 1e6 == \
+    assert gpt2.kv_bytes_per_token(m) * 1024 / 1e6 == \
         pytest.approx(188.7, abs=0.1)
 
 

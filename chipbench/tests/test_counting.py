@@ -88,7 +88,7 @@ def test_the_check_reads_every_served_token_of_every_finished_request():
 
     from chipbench import check
 
-    model = {"n_positions": 32, "vocab_size": 50}
+    model = {"vocab_size": 50}
 
     def forward(w, tokens, model, precision="float32"):
         want = (tokens + (1 if precision == "float32" else 2)) % 50
@@ -100,7 +100,7 @@ def test_the_check_reads_every_served_token_of_every_finished_request():
                  [(i + 3 + i % 5 + j) % 50 for j in range(2 + i % 4)])
                 for i in range(19)]           # three blocks, the last short
     got = check.served_positions(ref, {}, model, finished,
-                                 also=("bfloat16",))
+                                 also=("bfloat16",), context=32)
     served = sum(len(a) for _, a in finished)
     assert got["gap"].shape == got["gap_bfloat16"].shape == (served,)
     assert np.array_equal(np.bincount(got["request"].astype(int)),
@@ -112,10 +112,10 @@ def test_the_check_reads_every_served_token_of_every_finished_request():
     assert numbers["logit_gap"] == 0.0 and numbers["not_best_share"] == 0.0
     assert numbers["bfloat16_mean_logit_gap"] == 1.0
     finished[11][1][1] = (finished[11][1][1] + 7) % 50
-    bad = check.served_positions(ref, {}, model, finished)
+    bad = check.served_positions(ref, {}, model, finished, context=32)
     hit = np.nonzero(bad["gap"])[0]
     # the altered token, and the one that follows from it
     assert set(bad["request"][hit].astype(int)) == {11} and len(hit) == 2
     assert check.served_numbers(bad)["logit_gap"] == 7.0
     assert check.served_numbers(check.served_positions(
-        ref, {}, model, [])) == {}
+        ref, {}, model, [], context=32)) == {}

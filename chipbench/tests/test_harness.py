@@ -183,10 +183,11 @@ def test_layer_metric_readers_on_a_scripted_context():
                            "gpt2-large.json")) as f:
         model = json.load(f)["model"]
     peaks = {"tflops_bf16": 197.0, "hbm_gbps": 819.0}
+    from chipbench.adapters import gpt2
     train = {"kind": "train", "trace": red, "setup_s": 50.0, "chips": 4,
              "compile": {"hits": 6, "misses": 0, "backend_compile_s": 12.5},
-             "window": {"tokens_per_s": 80000.0}, "model": model,
-             "peaks": peaks}
+             "window": {"tokens_per_s": 80000.0, "seq_len": 1024},
+             "model": model, "adapter": gpt2, "peaks": peaks}
     read = lambda name, ctx: run.read_layer_metric(ROOT, name, ctx)  # noqa
     assert read("start_noncompile_s", train) == 37.5
     assert read("compile_misses", train) == 0
@@ -197,7 +198,7 @@ def test_layer_metric_readers_on_a_scripted_context():
     assert read("train_mfu_pct", train) == pytest.approx(49.91, abs=0.01)
     assert read("tput_decode_device_ms", dict(train, trace=None)) is None
     doc = {"kind": "serve-closed", "trace": red, "model": model,
-           "peaks": peaks, "traffic": {"slots": 32},
+           "adapter": gpt2, "peaks": peaks, "traffic": {"slots": 32},
            "scheduler": {"batch_occupancy": 0.75},
            "window": {"live_tokens_mean": 20000.0}}
     assert read("tput_batch_occupancy", doc) == 24.0

@@ -9,9 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import check, weights
-from chipbench.module import (BenchModule, init_key, leaves,
-                              to_program_tree)
+from chipbench import check
+from chipbench.adapters import gpt2
+from chipbench.adapters.gpt2 import BenchModule, to_program_tree
+from chipbench.module import init_key, leaves
 
 from conftest import ROOT, TINY
 
@@ -26,7 +27,7 @@ def _reference(name):
 
 
 def _weights(seed):
-    return weights.make_weights(MODEL, init_key("serve", seed))
+    return gpt2.make_weights(MODEL, init_key("serve", seed))
 
 
 def _tokens(shape, seed=0):
@@ -56,7 +57,7 @@ def test_configuration_file_names_its_reference_and_its_cuts(name):
     assert sorted(doc["reduced"]) == sorted(doc["reduced_why"])
     for key, published in doc["published"].items():
         assert doc["model"][key] != published and key in doc["reduced"]
-    shapes = weights.shapes(doc["model"])
+    shapes = gpt2.shapes(doc["model"])
     assert shapes["qkv_w"] == (doc["model"]["n_layer"],
                                doc["model"]["n_embd"],
                                3 * doc["model"]["n_embd"])
@@ -64,7 +65,7 @@ def test_configuration_file_names_its_reference_and_its_cuts(name):
 
 def test_weights_follow_the_seed_and_take_the_drivers_large_seeds():
     a, b, c = _weights(2 ** 31 + 11), _weights(2 ** 31 + 11), _weights(11)
-    assert any((a[k] != weights.make_weights(
+    assert any((a[k] != gpt2.make_weights(
         MODEL, init_key("train", 2 ** 31 + 11))[k]).any() for k in a)
     assert all((a[k] == b[k]).all() for k in a)
     assert any((a[k] != c[k]).any() for k in a)
@@ -85,7 +86,8 @@ def test_served_control_fp8_fails_where_bfloat16_passes(name):
     rows = _tokens((8, 64), seed=4)
     samples = [(r[:1], list(r[1:])) for r in rows]
     got = check.served_numbers(check.served_positions(
-        ref, w, MODEL, samples, also=("bfloat16", "fp8")))
+        ref, w, MODEL, samples, also=("bfloat16", "fp8"),
+        context=gpt2.context(MODEL)))
     assert got["tokens_compared"] == 8 * 63
     sound = {k: got["bfloat16_" + k] for k in ("logit_gap", "mean_logit_gap")}
     control = {k: got["fp8_" + k] for k in ("logit_gap", "mean_logit_gap")}
@@ -108,7 +110,8 @@ def test_training_control_fp8_fails_where_bfloat16_passes():
     y = _tokens((12, 64), seed=2)
 
     def numbers(precision):
-        got = check.train_reference(ref, w, MODEL, job, (x, y), precision)
+        got = check.train_reference(ref, w, MODEL, job, (x, y), precision,
+                                    axes=gpt2.leaf_norm_axes)
         return {"losses": got["losses"],
                 "grad_norms": {k: float(v) for k, v in leaves(
                     to_program_tree(got["grad_norms"])).items()},

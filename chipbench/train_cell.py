@@ -13,7 +13,7 @@ import gc
 import os
 import time
 
-from chipbench import check, weights
+from chipbench import check
 from chipbench.module import leaves
 
 CHECK_STEPS = 3
@@ -90,14 +90,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float,
     from ray_lightning_tpu.compile import cache as compile_cache
     from ray_lightning_tpu.core.callbacks import Callback
 
-    from chipbench.module import BenchModule, program_seed
+    from chipbench.module import program_seed
 
     phases = {"imports_s": time.monotonic() - t_process}
     device = claim_devices(cell["chips"], platform)
     phases["devices_s"] = time.monotonic() - t_process
     model, job = cell["config"]["model"], cell["traffic"]
     b1 = float(job["optimizer"]["b1"])
-    module = BenchModule(model, seed, job)
+    module = cell["adapter"].module(model, seed, job)
     trace_dir = os.path.join(cell["work"], "trace")
 
     grad_norms = jax.jit(lambda mu: {
@@ -205,7 +205,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float,
                               win.program["grad_norms"].items()},
                "change_norms": {k: float(v) for k, v in
                                 win.program["change_norms"].items()}}
-    tokens = win.steps * int(job["global_batch"]) * int(model["n_positions"])
+    seq_len = int(rows[0].shape[1])
+    tokens = win.steps * int(job["global_batch"]) * seq_len
     window_s = win.t1 - win.t0
     setup_s = win.t0 - t_process
 
@@ -237,9 +238,10 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float,
                         "backend_compile_s":
                             cache_stats.backend_compile_secs},
             "window": {"seconds": window_s, "steps": win.steps,
-                       "tokens": tokens,
+                       "tokens": tokens, "seq_len": seq_len,
                        "tokens_per_s": tokens / window_s},
-            "model": model, "traffic": job, "chips": cell["chips"],
+            "model": model, "adapter": cell["adapter"], "traffic": job,
+            "chips": cell["chips"],
             "peaks": cell["peaks"].get(device["kind"]),
         },
     }
@@ -251,11 +253,13 @@ def reference_numbers(cell: dict, seed: int, rows, program: dict,
     ``precision``, beside the float32 reference's: the control)."""
     import jax
 
-    from chipbench.module import init_key, to_program_tree
+    from chipbench.module import init_key
 
     model, job = cell["config"]["model"], cell["traffic"]
+    adapter = cell["adapter"]
+    to_program_tree = adapter.to_program_tree
     ref = check.load_reference(cell["config"], cell["root"])
-    make = lambda key: weights.make_weights(model, key)   # noqa: E731
+    make = lambda key: adapter.make_weights(model, key)   # noqa: E731
     place = None
     if len(jax.devices()) > 1:
         # no one chip holds this model's float32 parameters, gradients
@@ -272,7 +276,8 @@ def reference_numbers(cell: dict, seed: int, rows, program: dict,
     else:
         make = jax.jit(make)
     w = make(init_key("train", seed))
-    got = check.train_reference(ref, w, model, job, rows, precision, place)
+    got = check.train_reference(ref, w, model, job, rows, precision, place,
+                                axes=adapter.leaf_norm_axes)
     reference = {
         "losses": got["losses"],
         "grad_norms": {k: float(v) for k, v in leaves(
