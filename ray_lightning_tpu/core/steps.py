@@ -226,6 +226,13 @@ def build_prefill_step(module, bucket_len: int, model=None,
     over the draft KV cache, speculative decoding); ``dequant`` maps
     the params argument inside the traced body (int8-resident draft
     weights decode inline, comm/quant.py ``dequantize_blob``).
+
+    A model whose state is not a row per position (models/evabyte.py: a
+    prompt's last window and its chunk summaries) has a ``prefill``
+    method of its own, ``(tokens, length, slot, k_caches, v_caches) ->
+    (logits [vocab] at length - 1, k', v')``: it needs ``length`` to
+    build that state, and writes it at the slot itself.  The program's
+    signature is the same.
     """
     module.setup_model()
     if model is None:
@@ -234,6 +241,13 @@ def build_prefill_step(module, bucket_len: int, model=None,
     def step_fn(params, k_caches, v_caches, tokens, slot, length):
         if dequant is not None:
             params = dequant(params)
+        if hasattr(model, "prefill"):
+            logits, k_caches, v_caches = model.apply(
+                {"params": params}, tokens, length, slot, k_caches,
+                v_caches, method="prefill")
+            with jax.named_scope("sample"):
+                return k_caches, v_caches, jnp.argmax(
+                    logits, axis=-1).astype(tokens.dtype)
         logits, captured = model.apply({"params": params}, tokens, True,
                                        mutable=["kv_cache"])
         with jax.named_scope("sample"):

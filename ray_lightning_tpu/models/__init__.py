@@ -3,6 +3,11 @@ from ray_lightning_tpu.models.boring import (
     LightningMNISTClassifier,
     RandomDataset,
 )
+from ray_lightning_tpu.models.evabyte import (
+    EvaByte,
+    EvaByteConfig,
+    EvaByteLightningModule,
+)
 from ray_lightning_tpu.models.gpt import GPT, GPTConfig, GPTLightningModule
 from ray_lightning_tpu.models.pipeline_gpt import PipelinedGPT
 from ray_lightning_tpu.models.resnet import (
@@ -23,6 +28,9 @@ __all__ = [
     "BoringModel",
     "LightningMNISTClassifier",
     "RandomDataset",
+    "EvaByte",
+    "EvaByteConfig",
+    "EvaByteLightningModule",
     "GPT",
     "GPTConfig",
     "GPTLightningModule",

@@ -1542,3 +1542,29 @@ def flash_attention(q, k, v, *, causal: bool = True, dtype=jnp.bfloat16,
                v.reshape(b, t, h * d), h, causal, sm_scale, block_q,
                block_k, interpret)
     return o.reshape(b, t, h, d).astype(dtype)
+
+
+def flash_attention_lse(q, k, v, *, causal: bool = True,
+                        sm_scale: float | None = None,
+                        interpret: bool | None = None):
+    """The forward kernels of :func:`flash_attention` with the softmax's
+    log-sum-exp beside the output: ``(o [B, T, H, D] in q's type, lse
+    [B, T, H] float32)``.  Forward only (no vjp).  For a caller that
+    merges this attention with another over further keys under ONE
+    softmax (ops/eva_attention.py: a window's exact rows here, the
+    summaries of earlier windows there): ``logaddexp`` of the two lse
+    weighs the two outputs."""
+    b, t, h, d = q.shape
+    block = t if t <= 1024 else 512
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    if interpret is None:
+        interpret = _use_interpret()
+    o, lse = _fwd(q.reshape(b, t, h * d), k.reshape(b, t, h * d),
+                  v.reshape(b, t, h * d), h, causal, sm_scale, block, block,
+                  interpret)
+    # _fwd's two layouts: packed [B, H/pack, T, pack], folded [B*H, T, 1]
+    lse = lse.transpose(0, 2, 1, 3) if lse.ndim == 4 \
+        else lse.reshape(b, h, t).transpose(0, 2, 1)
+    return o.reshape(b, t, h, d), lse.reshape(b, t, h)
+

@@ -186,11 +186,18 @@ def _pick_block_k(L: int) -> int:
 
 def _decode_body(pos, kb, nk, logical_base,
                  q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                 *, sm_scale, block_k, n_head, head_dim):
+                 *, sm_scale, block_k, n_head, head_dim, rows=None):
     """Online-softmax update for one ``block_k``-row KV block of one
     slot, looped over the packed heads.  ``logical_base`` is the block's
     first LOGICAL cache row (page-table indirection moves only the
-    physical fetch; masking is always in logical positions)."""
+    physical fetch; masking is always in logical positions).
+
+    A slot sees the rows ``<= pos``.  A cache whose rows are not
+    positions (ops/eva_attention.py: two ranges of rows a slot) gives
+    its own bound as ``rows = (live, seen)``: whether this block holds a
+    row the slot sees, and which of a block's logical rows it sees
+    (``seen(cols)``).  Row 0 is seen under either bound."""
+    live = kb * block_k <= pos if rows is None else rows[0]
 
     @pl.when(kb == 0)
     def _init():
@@ -198,7 +205,7 @@ def _decode_body(pos, kb, nk, logical_base,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(kb * block_k <= pos)
+    @pl.when(live)
     def _compute():
         # scores live on the LANE axis ([1, block_k]): both products are
         # plain MXU matmuls (q·kᵀ, p·v) with fp32 accumulation.  Scores
@@ -209,7 +216,7 @@ def _decode_body(pos, kb, nk, logical_base,
         # compiles this body for a described v5e.
         cols = (jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
                 + logical_base)
-        valid = cols <= pos
+        valid = cols <= pos if rows is None else rows[1](cols)
         for h in range(n_head):
             sl = slice(h * head_dim, (h + 1) * head_dim)
             q = q_ref[0, :, sl]                       # [1, D]

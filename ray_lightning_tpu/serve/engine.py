@@ -155,6 +155,8 @@ class ServeEngine:
             self.kv_spec = KVCacheSpec.from_capture(
                 k_avals, self.slots, self.max_seq_len)
             kv_dtype = self._k_dtype = k_avals[0].dtype
+            if self.kv_spec.rows is not None:
+                self._check_own_state(module)
 
             param_sh = self.strategy._shardings_with(
                 mesh, abstract_params, self.strategy.param_spec)
@@ -397,6 +399,23 @@ class ServeEngine:
             self.buckets, self.slots, shape,
             self.kv_spec.nbytes(np.dtype(kv_dtype).itemsize) / 2**20)
         return self
+
+    def _check_own_state(self, module) -> None:
+        """A model that keeps its own kind of rows in the cache
+        (serve/kvcache.py) sized them from its configuration's
+        positions, and a prefix of a prompt is not a prefix of them."""
+        positions = getattr(getattr(module, "config", None),
+                            "block_size", self.max_seq_len)
+        if self.max_seq_len > positions:
+            raise ValueError(
+                f"max_seq_len {self.max_seq_len} exceeds the {positions} "
+                f"positions the model sized its cache rows for")
+        if self.paged is not None or self.kvship or self.spec is not None:
+            raise ValueError(
+                f"{type(module).__name__} keeps its own kind of cache "
+                f"rows ({self.kv_spec.rows} a slot, not a row per "
+                f"position): paged=, kvship= and spec= copy or replay "
+                f"rows by position and are refused")
 
     def _submit_precompiles(self, jax, abstract_params, kv_shape,
                             kv_dtype) -> AotPrecompiler:

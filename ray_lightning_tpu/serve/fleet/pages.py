@@ -26,10 +26,16 @@ two host-side structures that make page-granular reuse sound:
   requested is the measured ``prefix_reuse`` savings number the bench
   reports.
 
-Soundness of reuse: a K/V cache row is a pure per-token value —
-``k/v = Dense(embed(token) + wpe[pos])`` — so identical (token,
-position) prefixes have identical rows whatever bucket or slot computed
-them.  Donor rows stay valid because (a) live slots only ever write at
+Soundness of reuse, for a cache that holds a row per position
+(serve/kvcache.py): row ``t`` of layer 0 is a pure value of (token,
+position) — GPT-2's ``k/v = Dense(LN(embed(token) + wpe[pos]))``; under
+rotary positions ``k = R(pos) W_k n(embed(token))``, the same kind of
+value — and a deeper layer's row ``t`` depends on the tokens at
+positions ``<= t`` only (causal attention), so identical prefixes have
+identical rows whatever bucket or slot computed them.  It does NOT hold
+for a window-and-summary cache (models/evabyte.py): a slot keeps its
+LAST window and pooled summaries, so a prefix's rows are not a prefix of
+the state, and that model refuses ``paged=``.  Donor rows stay valid because (a) live slots only ever write at
 their own advancing position, and (b) with paging enabled the scheduler
 points idle slots' dummy decode writes at ``max_seq_len - 1`` (outside
 every registered page; registration is capped below that row) instead

@@ -1,13 +1,30 @@
 """Slot-indexed, device-resident KV cache for continuous batching.
 
-The cache is two arrays ``[n_layer, S, L, C]`` (keys / values): ``S``
-batch slots x ``L`` max context x ``C = n_head * head_dim``, living on
+The cache is two arrays ``[n_layer, S, R, C]`` (keys / values): ``S``
+batch slots x ``R`` rows a slot x ``C = n_head * head_dim``, living on
 device for the whole life of the serve fleet and sharded through the
 training strategies (``ShardingStrategy.kv_cache_spec`` — slots ride
 the data axes like a batch dim, ``C`` rides ``tensor`` under SPMD: whole
 heads, as long as ``n_head`` divides).
 
-There is ONE layout, the one the decode kernel reads: a row is a
+What a row is depends on the model, and the model says (``KVCacheSpec.
+from_capture`` reads it off the model's prefill capture):
+
+- **a row per position** (models/gpt.py): ``R = max_seq_len``, row ``t``
+  is position ``t``'s key (value), and a slot at position ``p`` sees the
+  rows ``<= p``.  Everything below about prefixes, pages and masks by
+  position is this kind's.
+- **one window and one summary row per chunk** (models/evabyte.py,
+  ops/eva_attention.py): ``R = window + max_seq_len // chunk``; position
+  ``m`` of the slot's current window lives in row ``m % window``, the
+  pooled key (value) of chunk ``j`` in row ``window + j``, and a slot sees
+  two ranges of rows (``visible_rows``).  Both kinds of row have the
+  width ``C``, so the arrays, their donation and the programs' plumbing
+  are the same; the model's own ``prefill`` and ``decode`` methods write
+  and read them.  A prefix of a prompt is NOT a prefix of this state:
+  prefix reuse, paging and KV shipping refuse such a model.
+
+There is ONE layout, the one the decode kernels read: a row is a
 token's heads side by side on the lane axis, which is how the qkv
 projection makes it (ops/attention.py) and how ops/flash_decode.py
 computes on it.  Every program takes the two arrays whole, donated, and
@@ -18,14 +35,16 @@ whole cache, not a view).  In-flight request insertion and eviction are
 SLOT INDEX operations:
 
 - insert  = the bucket prefill program ``dynamic_update_slice``-writes a
-  prompt's K/V block at its slot (core/steps.py build_prefill_step);
+  prompt's K/V block at its slot (core/steps.py build_prefill_step; the
+  window-and-summary kind: its last window and its summaries);
 - advance = the decode program scatter-writes one row per slot and
   layer at ``[layer, slot, position]`` (ops/attention.py
-  MultiHeadAttention);
+  MultiHeadAttention; the window-and-summary kind: at ``position %
+  window``, and the current chunk's summary row again);
 - evict   = the driver frees the slot index — NO device work.  Stale
-  K/V beyond a slot's position bound are unreachable by construction
-  (the per-slot position mask), so a freed slot is reusable the moment
-  the next prefill overwrites its prefix.
+  rows beyond what a slot's position lets it see are unreachable by
+  construction (the per-slot mask), so a freed slot is reusable the
+  moment the next prefill overwrites its rows.
 
 Shapes are static whatever the live-request mix, so the decode loop
 never re-traces — the property the serve acceptance pins with trace
@@ -43,18 +62,25 @@ import numpy as np
 class KVCacheSpec:
     """Host-side description of the device cache (picklable; shipped to
     workers inside the serve payload).  ``width`` is a row's length
-    ``C = n_head * head_dim``: the cache never sees heads apart."""
+    ``C = n_head * head_dim``: the cache never sees heads apart.
+    ``max_seq_len`` is the positions a slot's sequence may reach;
+    ``rows`` the rows a slot holds per layer: None for a model that
+    keeps a row per position (``max_seq_len`` rows), the model's own
+    count otherwise (module docstring)."""
 
     n_layer: int
     slots: int
     max_seq_len: int
     width: int
+    rows: "int | None" = None
 
     @property
     def shape(self) -> tuple[int, int, int, int]:
-        """``[n_layer, S, L, C]`` — THE shape every serve program and
-        the decode kernel share."""
-        return (self.n_layer, self.slots, self.max_seq_len, self.width)
+        """``[n_layer, S, R, C]`` — THE shape every serve program and
+        the decode kernels share."""
+        return (self.n_layer, self.slots,
+                self.max_seq_len if self.rows is None else self.rows,
+                self.width)
 
     def nbytes(self, itemsize: int = 2) -> int:
         """Device residency of BOTH cache arrays (k and v) at the given
@@ -65,16 +91,22 @@ class KVCacheSpec:
     def from_capture(cls, kv_shapes, slots: int,
                      max_seq_len: int) -> "KVCacheSpec":
         """Derive the cache geometry from a prefill ``eval_shape``
-        capture: ``kv_shapes`` is any per-layer K aval list with entries
-        shaped ``[B, T, C]`` (core/steps.py _stacked_kv order)."""
+        capture: ``kv_shapes`` is any per-layer K aval list (core/steps.py
+        _stacked_kv order).  An entry shaped ``[B, T, C]`` is a row per
+        captured position: the cache holds ``max_seq_len`` rows a slot.
+        An entry shaped ``[B, 1, R, C]`` is a model's own state block,
+        as its ``prefill`` method writes it at a slot: ``R`` rows a
+        slot, whatever ``max_seq_len`` (the model sized it from its own
+        configuration's positions, which the engine checks)."""
         n_layer = len(kv_shapes)
         if n_layer == 0:
             raise ValueError("model captured no kv_cache entries; does "
                              "its attention sow the 'kv_cache' "
                              "collection? (ops/attention.py)")
-        _, _, width = kv_shapes[0].shape
+        shape = tuple(kv_shapes[0].shape)
+        rows = int(shape[2]) if len(shape) == 4 else None
         return cls(n_layer=n_layer, slots=slots, max_seq_len=max_seq_len,
-                   width=int(width))
+                   width=int(shape[-1]), rows=rows)
 
 
 class SlotAllocator:

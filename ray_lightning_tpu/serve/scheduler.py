@@ -46,7 +46,7 @@ import itertools
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Optional, Sequence
+from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
 
@@ -232,7 +232,8 @@ class Scheduler:
                  default_max_new_tokens: int = 32,
                  eos_token: Optional[int] = None,
                  paged: Any = None,
-                 spec: Any = None):
+                 spec: Any = None,
+                 live_rows: "Callable[[int], int] | None" = None):
         self.buckets = tuple(buckets)
         self.max_seq_len = int(max_seq_len)
         self.allocator = SlotAllocator(slots)
@@ -274,6 +275,12 @@ class Scheduler:
         self.failed = 0
         self._occupancy_sum = 0.0
         self._decode_steps = 0
+        #: cache rows a slot at a position reads in a decode step: a row
+        #: per position unless the model says otherwise (models/
+        #: evabyte.py: one window and one summary row per chunk)
+        self._live_rows = live_rows or (lambda position: position + 1)
+        self._live_rows_sum = 0
+        self._live_positions_sum = 0
         #: the driver pump's phase clock (serve/server.py owns the reads)
         self.pump = PumpClock()
         # rolling latency tails (incident plane): the histograms above
@@ -483,6 +490,9 @@ class Scheduler:
             self._occupancy_sum += (
                 len(decode_slots) + len(prefills)) / self.allocator.slots
             self._decode_steps += 1
+            at = [self._by_slot[s].pos for s in decode_slots]
+            self._live_positions_sum += sum(at) + len(at)
+            self._live_rows_sum += sum(self._live_rows(p) for p in at)
         self._gauge("rlt_serve_queue_depth_total", self.queued_count)
         self._gauge("rlt_serve_active_slots_total",
                     len(self._by_slot))
@@ -788,6 +798,7 @@ class Scheduler:
                 s["emitted"] / s["slot_steps"], 4) \
                 if s["slot_steps"] else 0.0
             spec = {"spec": s}
+        steps = max(1, self._decode_steps)
         return {
             **pages,
             **spec,
@@ -799,6 +810,10 @@ class Scheduler:
                 self._occupancy_sum / self._decode_steps
                 if self._decode_steps else 0.0),
             "decode_steps": self._decode_steps,
+            # means over decode steps, summed over the occupied slots:
+            # context positions live, and the cache rows their slots read
+            "live_positions": self._live_positions_sum / steps,
+            "live_rows": self._live_rows_sum / steps,
             "pump": self.pump.snapshot(),
             "per_tenant": {
                 name: {"active": t.active, "queued": len(t.queue),

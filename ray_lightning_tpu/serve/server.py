@@ -132,13 +132,20 @@ class Server:
         self.paged = PageConfig.resolve(paged)
         self.spec = SpecConfig.resolve(spec)
         self.kvship = bool(kvship)
+        # a model family says here, before anything starts, what it
+        # cannot be served with and why (models/evabyte.py)
+        refuse = getattr(module, "refuse_serve_options", None)
+        if refuse is not None:
+            refuse(paged=self.paged.enabled, spec=self.spec.enabled,
+                   kvship=self.kvship)
         self.worker_env = dict(worker_env or {})
         self.scheduler = Scheduler(
             self.buckets, self.max_batch_slots, self.max_seq_len,
             quotas=tenant_quotas,
             max_prefills_per_step=max_prefills_per_step,
             default_max_new_tokens=max_new_tokens, eos_token=eos_token,
-            paged=self.paged, spec=self.spec)
+            paged=self.paged, spec=self.spec,
+            live_rows=getattr(module, "live_cache_rows", None))
         self._weights = self._resolve_weights(module, checkpoint)
         self._backend = None
         self._workers: list = []

@@ -47,6 +47,13 @@ from typing import Optional
 SCOPES = ("embed", "attn", "mlp", "ln", "lm_head", "loss", "optimizer",
           "kv_cache", "sample")
 
+#: finer names inside a scope, for the parts of a layer that a reader
+#: wants apart (ops/eva_attention.py: a chunk's pooling, the attention).
+#: They are NOT scopes of the table above: readers of that table know
+#: its short list and refuse another name, so a second table, ``"fine"``
+#: in the file, places the operations that lie under one of these
+FINE_SCOPES = ("eva_summary", "eva_attn")
+
 #: the file of tables written beside a captured trace
 TABLE_FILE = "op_scopes.json"
 #: suffix of a scope that a pathless mover took from the value it moves
@@ -64,16 +71,16 @@ _WORD = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 _MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
 
 
-def scope_of(op_name: str) -> Optional[str]:
+def scope_of(op_name: str, names=SCOPES) -> Optional[str]:
     """The innermost listed scope on an ``op_name`` path such as
     ``jit(step_fn)/transpose(jvp(GPT))/h0/attn/qkv/dot_general``."""
     for word in reversed(_WORD.findall(op_name)):
-        if word in SCOPES:
+        if word in names:
             return word
     return None
 
 
-def table_from_text(hlo_text: str) -> "tuple[str, dict]":
+def table_from_text(hlo_text: str, names=SCOPES) -> "tuple[str, dict]":
     """``(module name, {instruction name: scope, scope + "*" or None})``
     from a compiled program's text.  Every instruction of every
     computation is listed, with or without metadata, so that a reader
@@ -89,7 +96,7 @@ def table_from_text(hlo_text: str) -> "tuple[str, dict]":
             continue
         name = inst.group(1)
         path = _OP_NAME.search(line)
-        table[name] = scope_of(path.group(1)) if path else None
+        table[name] = scope_of(path.group(1), names) if path else None
         if path is None:
             mover = _MOVES.search(line, inst.end())
             # operands come first, and no type holds a ``%``
@@ -109,9 +116,10 @@ def table_from_text(hlo_text: str) -> "tuple[str, dict]":
     return module, table
 
 
-def tables() -> "dict[str, dict[str, Optional[str]]]":
+def tables(names=SCOPES) -> "dict[str, dict[str, Optional[str]]]":
     """``{program: table}`` of every executable live in this process,
-    read now.  Two live programs of one name (a step retraced for
+    read now (``names``: the list a path is searched for, ``SCOPES`` or
+    ``FINE_SCOPES``).  Two live programs of one name (a step retraced for
     another shape) share a table; an operation name they place
     differently is left out, so a reader that meets it fails.  Never
     raises: the tables are evidence, not a dependency."""
@@ -128,7 +136,7 @@ def tables() -> "dict[str, dict[str, Optional[str]]]":
         except Exception:   # noqa: BLE001 - a backend without text
             continue
         for text in texts:
-            module, table = table_from_text(text)
+            module, table = table_from_text(text, names)
             seen = out.setdefault(module, table)
             clash.update((module, op) for op, scope in table.items()
                          if seen.setdefault(op, scope) != scope)
@@ -143,10 +151,13 @@ def write_tables(trace_dir: str) -> Optional[str]:
     if not snap:
         return None
     path = os.path.join(trace_dir, TABLE_FILE)
+    fine = {program: placed for program, table in tables(FINE_SCOPES).items()
+            if (placed := {op: s for op, s in table.items() if s})}
     with open(path, "w") as f:
-        json.dump({"scopes": list(SCOPES), "programs": snap}, f)
+        json.dump({"scopes": list(SCOPES), "programs": snap,
+                   "fine_scopes": list(FINE_SCOPES), "fine": fine}, f)
     return path
 
 
-__all__ = ["SCOPES", "TABLE_FILE", "INHERITED", "scope_of",
+__all__ = ["SCOPES", "FINE_SCOPES", "TABLE_FILE", "INHERITED", "scope_of",
            "table_from_text", "tables", "write_tables"]

@@ -1,0 +1,468 @@
+"""EvaByte (models/evabyte.py, ops/eva_attention.py) against its plain
+reference (chipbench/evabyte_reference.py), at a tiny size on the CPU:
+width 64, 2 heads of 32, window 32, chunk 4, 2 layers, 2 output heads,
+256 positions, everything in float32.
+
+Tolerance: the two sides are the same mathematics written twice in
+float32 (the reference window by window with its own masks and pooling,
+the program over padded chunks, one ``lax.map`` and, served, through the
+cache), so they differ by summation order only.  Logits here have a
+spread of about 0.8; float32 rounding through 2 layers reaches a few
+1e-6 of that, and ``ATOL = 1e-5`` leaves it room while a wrong row, mask,
+position or pooled member moves a logit by 1e-2 or more.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+import signal
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import evabyte_reference as ref
+from chipbench.adapters import evabyte as adapter
+from ray_lightning_tpu.models.evabyte import (
+    EvaByte, EvaByteConfig, EvaByteLightningModule)
+from ray_lightning_tpu.ops import eva_attention as eva
+from ray_lightning_tpu.parallel.strategy import DataParallelStrategy
+from ray_lightning_tpu.serve.buckets import pad_to_bucket
+from ray_lightning_tpu.serve.engine import ServeEngine
+from ray_lightning_tpu.serve.kvcache import KVCacheSpec
+
+ATOL = 1e-5
+MODEL = dict(vocab_size=320, hidden_size=64, num_hidden_layers=2,
+             num_attention_heads=2, intermediate_size=176, num_pred_heads=2,
+             window_size=32, chunk_size=4, max_position_embeddings=256,
+             rope_theta=100000.0, rms_norm_eps=1e-5, init_std=0.05)
+CFG = dataclasses.replace(adapter.config_of(MODEL), dtype=jnp.float32)
+SLOTS = 3
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    """``@pytest.mark.limit(seconds)``: each test's own time limit (an
+    alarm in the worker's main thread; no plugin to install)."""
+    mark = request.node.get_closest_marker("limit")
+    if mark is None:
+        yield
+        return
+
+    def late(signum, frame):
+        raise TimeoutError(f"over its limit of {mark.args[0]} s")
+
+    was = signal.signal(signal.SIGALRM, late)
+    signal.alarm(int(mark.args[0]))
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, was)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    w = dict(adapter.make_weights(MODEL, jax.random.PRNGKey(3)))
+    # the published initialisation makes the pooling nearly uniform; a
+    # larger phi and mu make a wrong pooling weight or member show
+    w["phi"], w["mu"] = 8.0 * w["phi"], 4.0 * w["mu"]
+    return w
+
+
+class _Module(EvaByteLightningModule):
+    """The module a user would hand to ``Server``, in float32 and with
+    the test's weights."""
+
+    param_dtype = None
+
+    def __init__(self, w):
+        super().__init__(CFG)
+        self._w = w
+
+    def init_params(self, rng, batch):
+        return {"params": adapter.to_program_tree(self._w)}
+
+
+@pytest.fixture(scope="module")
+def engine(weights):
+    return ServeEngine(_Module(weights), DataParallelStrategy(),
+                       buckets=(16, 48, 112), slots=SLOTS, max_seq_len=256,
+                       seed=0).setup()
+
+
+@pytest.fixture
+def programs():
+    """The model's own serve methods, jitted to return LOGITS (the
+    engine's programs return the sampled token).  Made anew for each
+    test: which decode kernel lowers is read when a program traces."""
+    net = EvaByte(CFG)
+    return (jax.jit(lambda p, k, v, t, n, s: net.apply(
+                {"params": p}, t, n, s, k, v, method="prefill")),
+            jax.jit(lambda p, k, v, t, at: net.apply(
+                {"params": p}, t, at, k, v, method="decode")))
+
+
+def _tokens(seed, n):
+    return np.random.default_rng(seed).integers(0, 320, (n,)).astype(np.int32)
+
+
+def _full(weights, tokens):
+    return np.asarray(ref.forward(weights, jnp.asarray(tokens)[None],
+                                  MODEL))[0]
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("T", [20, 32, 77, 131])
+def test_forward_matches_reference_on_every_head(weights, T):
+    toks = jnp.asarray(np.stack([_tokens(T, T), _tokens(T + 1, T)]))
+    got = EvaByte(CFG).apply({"params": adapter.to_program_tree(weights)},
+                             toks)
+    want = ref.forward_heads(weights, toks, MODEL)
+    assert got.shape == (2, T, 2, 320)
+    np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
+
+
+@pytest.mark.limit(120)
+def test_one_window_is_plain_causal_attention(weights):
+    """A context within one window: EVA is softmax(q k^T / sqrt(d)) v
+    under the causal mask, and phi, mu and the summaries play no part."""
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 32, 2, 32)), jnp.float32)
+               for _ in range(3))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(32)
+    scores = jnp.where(jnp.tril(jnp.ones((32, 32), bool)), scores, -jnp.inf)
+    plain = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+    phi, mu = (jnp.asarray(rng.normal(size=(2, 32)), jnp.float32)
+               for _ in range(2))
+    np.testing.assert_allclose(
+        ref.eva_attention(q, k, v, phi, mu, 32, 4, "float32"), plain,
+        atol=ATOL, rtol=0)
+    k_sum, v_sum = eva.chunk_summaries(
+        k.reshape(2, 8, 4, 64), v.reshape(2, 8, 4, 64), phi, mu,
+        jnp.ones((2, 8, 4), bool))
+    got = eva.eva_attention(q, k, v, k_sum.reshape(2, 8, 2, 32),
+                            v_sum.reshape(2, 8, 2, 32), window=32, chunk=4,
+                            dtype=jnp.float32)
+    np.testing.assert_allclose(got, plain, atol=ATOL, rtol=0)
+
+
+def _serve(engine, programs, weights, lengths, buckets, steps, k=None,
+           v=None, seeds=(0, 1, 2)):
+    """Prefill ``len(lengths)`` slots, then decode ``steps`` steps with
+    the slots at their own positions, teacher-forced; the widest gap of a
+    logit to the reference's full forward at every position served."""
+    prefill, decode = programs
+    k = engine._k if k is None else k
+    v = engine._v if v is None else v
+    seqs = [_tokens(s, 256) for s in seeds]
+    full = [_full(weights, s) for s in seqs]
+    worst = 0.0
+    for s, (n, b) in enumerate(zip(lengths, buckets)):
+        logits, k, v = prefill(engine.params, k, v,
+                               pad_to_bucket(seqs[s][:n], b), np.int32(n),
+                               np.int32(s))
+        worst = max(worst, np.abs(np.asarray(logits) - full[s][n - 1]).max())
+    at = np.array(lengths, np.int32)
+    for _ in range(steps):
+        toks = np.array([seqs[s][at[s]] for s in range(len(at))], np.int32)
+        logits, k, v = decode(engine.params, k, v, toks, at)
+        worst = max(worst, max(
+            np.abs(np.asarray(logits)[s] - full[s][at[s]]).max()
+            for s in range(len(at))))
+        at = at + 1
+    return worst, k, v
+
+
+@pytest.mark.limit(240)
+@pytest.mark.parametrize("impl", ["dense", "flash_decode"])
+def test_prefill_then_decode_through_the_cache(engine, programs, weights,
+                                               monkeypatch, impl):
+    """Three slots at different positions: lengths 13, 45 and 97 are no
+    multiples of the chunk (4) and sit right-padded in buckets 16, 48 and
+    112; 100 decode steps take the slots across window edges (32) and
+    the third past its sixth window.  Every logit, prefill's and each
+    decoded position's, against the reference's full forward; under the
+    dense path and under the Pallas kernel (interpreted)."""
+    monkeypatch.setenv("RLT_DECODE_IMPL", impl)
+    worst, _, _ = _serve(engine, programs, weights, (13, 45, 97),
+                         (16, 48, 112), 100)
+    assert worst < ATOL
+
+
+@pytest.mark.limit(120)
+def test_pad_rows_never_enter_a_summary(engine, programs, weights):
+    """Length 13 in bucket 48: chunk 3 holds position 12 and three pad
+    rows.  The summary row the prefill wrote pools position 12 alone
+    (its key plus mu, its value), whatever the pad tokens are, and the
+    decode step at position 13 pools 12 and 13 and nothing else."""
+    prefill, decode = programs
+    seq = _tokens(5, 64)
+    rows = []
+    for pad_id in (0, 7):
+        padded = pad_to_bucket(seq[:13], 48, pad_id=pad_id)
+        _, k, v = prefill(engine.params, engine._k, engine._v, padded,
+                          np.int32(13), np.int32(1))
+        rows.append((np.asarray(k[:, 1, 32 + 3]), np.asarray(v[:, 1, 32 + 3]),
+                     k, v))
+    np.testing.assert_array_equal(rows[0][0], rows[1][0])
+    np.testing.assert_array_equal(rows[0][1], rows[1][1])
+    k, v = rows[1][2], rows[1][3]
+    mu = np.stack([np.asarray(weights["mu"][i]).reshape(-1)
+                   for i in range(2)])
+    np.testing.assert_allclose(rows[1][0], np.asarray(k[:, 1, 12]) + mu,
+                               atol=1e-6)
+    np.testing.assert_allclose(rows[1][1], np.asarray(v[:, 1, 12]),
+                               atol=1e-6)
+    at = np.zeros(SLOTS, np.int32)
+    toks = np.zeros(SLOTS, np.int32)
+    at[1], toks[1] = 13, seq[13]
+    logits, k, v = decode(engine.params, k, v, toks, at)
+    pooled = np.asarray(v[0, 1, 32 + 3])
+    pair = np.asarray(v[0, 1, 12:14])
+    # a convex combination of exactly rows 12 and 13, per head
+    for h in range(2):
+        sl = slice(32 * h, 32 * h + 32)
+        a = np.linalg.lstsq(pair[:, sl].T, pooled[sl], rcond=None)[0]
+        assert abs(a.sum() - 1) < 1e-5 and (a > 0).all()
+    np.testing.assert_allclose(np.asarray(logits)[1], _full(weights, seq)[13],
+                               atol=ATOL)
+
+
+@pytest.mark.limit(240)
+def test_a_reused_slot_reads_nothing_of_its_old_rows(engine, programs,
+                                                     weights):
+    """Slot 2 serves a long request (to position 197: every row of its
+    window part and 49 summary rows written), is freed, and serves a
+    short one (length 13): the new tenant's logits are the reference's,
+    so none of the former rows is seen."""
+    _, k, v = _serve(engine, programs, weights, (13, 45, 97),
+                     (16, 48, 112), 100)
+    assert float(jnp.abs(k[:, 2, 32 + 40]).max()) > 0   # old summaries lie there
+    worst, _, _ = _serve(engine, programs, weights, (97, 45, 13),
+                         (112, 48, 16), 60, k=k, v=v, seeds=(7, 8, 9))
+    assert worst < ATOL
+
+
+@pytest.mark.limit(120)
+def test_engine_serves_the_reference_tokens(engine, weights):
+    """The engine's own programs (``jit_serve_prefill_48``,
+    ``jit_serve_decode``) sample head 0: greedy tokens equal the
+    reference's argmax, the cache holds ``window + positions / chunk``
+    rows a slot, and nothing retraces."""
+    assert engine.kv_spec == KVCacheSpec(n_layer=2, slots=SLOTS,
+                                         max_seq_len=256, width=64, rows=96)
+    assert engine.stats()["decode_kernel"] == "dense"
+    seq = _tokens(11, 256)
+    want = _full(weights, seq).argmax(-1)
+    got = [engine.prefill(1, pad_to_bucket(seq[:45], 48), 45, 48)]
+    toks, at = np.zeros(SLOTS, np.int32), np.zeros(SLOTS, np.int32)
+    for t in range(45, 75):
+        toks[1], at[1] = seq[t], t
+        got.append(int(engine.decode(toks, at)[1]))
+    assert got == [int(x) for x in want[44:75]]
+    assert sum(engine.stats()["retraces"].values()) == 0
+
+
+@pytest.mark.limit(60)
+def test_gpt2_cache_shape_is_untouched():
+    """A row per position: ``[B, T, C]`` captures give ``max_seq_len``
+    rows a slot, whatever T; only a model's own ``[B, 1, R, C]`` block
+    sets the rows."""
+    k = jax.ShapeDtypeStruct((1, 8, 64), jnp.bfloat16)
+    spec = KVCacheSpec.from_capture([k, k], slots=4, max_seq_len=64)
+    assert spec.shape == (2, 4, 64, 64) and spec.rows is None
+    own = jax.ShapeDtypeStruct((1, 1, 96, 64), jnp.bfloat16)
+    assert KVCacheSpec.from_capture([own], 4, 256).shape == (1, 4, 96, 64)
+
+
+@pytest.mark.limit(60)
+@pytest.mark.parametrize("what", ["paged", "kvship", "spec", "paged_kernel",
+                                  "suffix", "engine"])
+def test_refusals_name_the_reason(weights, monkeypatch, what):
+    from ray_lightning_tpu.core import steps
+    from ray_lightning_tpu.serve import Server
+    from ray_lightning_tpu.serve.fleet.pages import PageConfig
+    from ray_lightning_tpu.serve.spec import SpecConfig
+    module = _Module(weights)
+    if what == "paged":
+        with pytest.raises(ValueError, match="not a prefix of this state"):
+            Server(module, paged=PageConfig(enabled=True, page_size=8))
+    elif what == "kvship":
+        with pytest.raises(ValueError, match="not a prefix of this state"):
+            Server(module, kvship=True)
+    elif what == "spec":
+        with pytest.raises(ValueError, match="no draft model"):
+            Server(module, spec=SpecConfig(enabled=True, k=2))
+    elif what == "engine":
+        with pytest.raises(ValueError, match="own kind of cache rows"):
+            ServeEngine(module, DataParallelStrategy(), buckets=(16,),
+                        slots=2, max_seq_len=256,
+                        paged=PageConfig(enabled=True, page_size=8)).setup()
+    else:
+        net = EvaByte(CFG)
+        params = adapter.to_program_tree(weights)
+        cache = jnp.zeros((2, 2, 96, 64), jnp.float32)
+        ints = jnp.zeros((2,), jnp.int32)
+        if what == "paged_kernel":
+            monkeypatch.setenv("RLT_DECODE_IMPL", "paged")
+            with pytest.raises(ValueError, match="page table maps positions"):
+                net.apply({"params": params}, ints, ints, cache, cache,
+                          method="decode")
+        else:
+            step = steps.build_suffix_step(module)
+            with pytest.raises(ValueError, match="no one-slot suffix"):
+                step(params, cache, cache, jnp.int32(0), jnp.int32(0),
+                     jnp.int32(0))
+
+
+@pytest.mark.limit(60)
+def test_scheduler_counts_live_rows():
+    """``Scheduler.stats()``: positions live and the rows their slots
+    read, means over decode steps.  A row per position by default; the
+    window-and-summary count when the module gives one."""
+    from ray_lightning_tpu.serve.scheduler import Scheduler
+    module = EvaByteLightningModule(CFG)
+    assert module.live_cache_rows(0) == 1
+    assert module.live_cache_rows(31) == 32
+    assert module.live_cache_rows(32) == 1 + 8
+    assert module.live_cache_rows(100) == 5 + 24
+    for live_rows, want in ((None, 41.0), (module.live_cache_rows, 17.0)):
+        sched = Scheduler((48,), 2, 256, live_rows=live_rows)
+        sched.submit(_tokens(0, 40), max_new_tokens=4)
+        plan = sched.plan()
+        sched.apply(plan, {"prefill": {plan["prefills"][0]["slot"]: 5},
+                           "decode": {}})
+        assert sched.plan()["decode"]["positions"].max() == 40
+        stats = sched.stats()
+        assert stats["live_positions"] == 41.0
+        assert stats["live_rows"] == want       # 40 % 32 + 1 + 8
+
+
+# -- what the chip's compiler makes of it (no chip: a described v5e) -----------
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.limit(240)
+@pytest.mark.parametrize("program", ["decode", "prefill_1024"])
+def test_serve_programs_compile_for_v5e_and_leave_the_cache_where_it_lies(
+        monkeypatch, v5e, program):
+    """Heads of the published size (128) at a width of 512, windows of
+    256, 32 slots, bf16: Mosaic accepts the ``eva_decode`` kernel (and,
+    where this process has one device as a serve worker has, the flash
+    kernel under the prefill's windows), the decode program needs
+    no scratch worth the name beside the donated cache, and no program
+    copies, slices or transposes anything of a layer's size."""
+    import re
+    from ray_lightning_tpu.core import steps
+    from ray_lightning_tpu.ops import flash_decode
+    cfg = EvaByteConfig(hidden_size=512, num_hidden_layers=2,
+                        num_attention_heads=4, intermediate_size=1408,
+                        window_size=256, chunk_size=16,
+                        max_position_embeddings=4096)
+    monkeypatch.setenv("RLT_DECODE_IMPL", "flash_decode")
+    monkeypatch.setattr(flash_decode, "_use_interpret", lambda: False)
+    module = EvaByteLightningModule(cfg)
+    module.setup_model()
+    net = module.configure_decode_model()
+    slots = 32
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    dummy = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0), dummy)["params"]
+    _, cap = jax.eval_shape(
+        lambda p, t: net.apply({"params": p}, t, True, mutable=["kv_cache"]),
+        params, dummy)
+    spec = KVCacheSpec.from_capture(
+        [k for k, _ in steps.kv_layer_pairs(cap["kv_cache"])], slots, 4096)
+    assert spec.shape == (2, slots, 256 + 4096 // 16, 512)
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, jnp.bfloat16), params)
+    cache = on_chip(spec.shape, jnp.bfloat16)
+    if program == "decode":
+        fn = steps.build_decode_step(module)
+        args = (on_chip((slots,), jnp.int32),) * 2
+    else:
+        fn = steps.build_prefill_step(module, 1024)
+        args = (on_chip((1, 1024), jnp.int32), on_chip((), jnp.int32),
+                on_chip((), jnp.int32))
+    # (the module's float32 tests run at "highest"; a bf16 kernel is
+    # compiled as the chip runs it)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+            params, cache, cache, *args).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (
+        program == "decode" or jax.device_count() == 1)
+    layer = slots * spec.shape[2] * 512
+    if program == "decode":
+        # less scratch than half of ONE layer of one cache array
+        assert compiled.memory_analysis().temp_size_in_bytes < layer
+    movers = [m.group(0) for m in re.finditer(
+        r"= \(?(?:bf16|f32)\[([0-9,]+)\][^ ]* (copy|copy-start|slice|"
+        r"dynamic-slice|concatenate|transpose)\(", text)
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= layer]
+    assert not movers, movers[:5]
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("T,H,D", [(64, 2, 32), (64, 2, 128), (1024, 2, 128)],
+                         ids=["folded", "packed", "tiled"])
+def test_flash_attention_lse_in_both_of_the_kernels_layouts(T, H, D):
+    """The prefill merges the flash kernel's causal attention over a
+    window with a dense one over the summaries by their log-sum-exps:
+    ``flash_attention_lse`` returns ``[B, T, H]`` whichever layout the
+    forward kernel keeps it in (the kernel under the interpreter)."""
+    from ray_lightning_tpu.ops.flash_attention import flash_attention_lse
+    rng = np.random.default_rng(T + D)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, T, H, D)), jnp.float32)
+               for _ in range(3))
+    o, lse = flash_attention_lse(q, k, v, interpret=True)
+    s = jnp.einsum("bqhd,bkhd->bqhk", q, k) / np.sqrt(D)
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, :, None, :], s,
+                  -jnp.inf)
+    np.testing.assert_allclose(lse, jax.nn.logsumexp(s, -1), atol=ATOL)
+    np.testing.assert_allclose(
+        o, jnp.einsum("bqhk,bkhd->bqhd", jax.nn.softmax(s, -1), v),
+        atol=ATOL)
+
+
+@pytest.mark.limit(120)
+def test_the_reference_computes_in_every_precision_it_lists(weights):
+    """``PRECISIONS`` has the control's fp8: the same mathematics on
+    lower-precision operands moves the logits, more the lower it is, and
+    nowhere by the size of a mistake."""
+    toks = jnp.asarray(_tokens(3, 100))[None]
+    exact = ref.forward(weights, toks, MODEL)
+    gaps = {p: float(jnp.abs(ref.forward(weights, toks, MODEL, p)
+                             - exact).max()) for p in ref.PRECISIONS}
+    assert gaps["float32"] == 0.0
+    assert 0 < gaps["bfloat16"] < gaps["fp8"] < 0.5
+    assert np.isfinite(float(ref.loss(weights, toks, toks, MODEL, "fp8")))
