@@ -27,10 +27,11 @@ Here: the rotary embedding, the pooling (:func:`chunk_summaries`, scope
 (:func:`eva_attention`, scope ``eva_attn``) and decode attention of one
 query a slot against the resident cache (:func:`eva_cached_attention`):
 the dense ``jax.numpy`` path, and on the TPU the Pallas kernel
-``eva_decode``, which is ops/flash_decode.py's online-softmax body under
-the two-range bound: its index_map clamps dead blocks of either part to
-the last live one, so a slot reads only the blocks that hold rows it may
-see.
+``eva_decode``, which is ops/flash_decode.py's online-softmax body
+(every head of a block in one product against the block-diagonal query,
+heads on sublanes; nothing of it is this file's) under the two-range
+bound: its index_map clamps dead blocks of either part to the last live
+one, so a slot reads only the blocks that hold rows it may see.
 """
 
 from __future__ import annotations
@@ -256,14 +257,12 @@ def eva_cached_attention(q, k_cache, v_cache, positions, *, layer: int,
         return jnp.einsum("shql,slhd->sqhd", probs, v)
 
 
-def _eva_decode_kernel(positions_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-                       l_ref, acc_ref, *, window, per_window, **kw):
+def _eva_decode_kernel(positions_ref, *refs, window, per_window, **kw):
     s, kb = pl.program_id(0), pl.program_id(1)
     pos, bk = positions_ref[s], kw["block_k"]
     exact, far = pos % window + 1, (pos // window) * per_window
     _fd._decode_body(
-        pos, kb, pl.num_programs(1), kb * bk, q_ref, k_ref, v_ref, o_ref,
-        m_ref, l_ref, acc_ref, **kw,
+        pos, kb, pl.num_programs(1), kb * bk, *refs, **kw,
         rows=(jnp.where(kb * bk < window, kb * bk < exact,
                         kb * bk - window < far),
               lambda cols: (cols < exact) | (
@@ -299,7 +298,7 @@ def _eva_decode_kernel_call(q, k_cache, v_cache, positions, *, layer,
 
     body = functools.partial(
         _eva_decode_kernel, window=window, per_window=per,
-        sm_scale=1.0 / math.sqrt(D), block_k=bk, n_head=H, head_dim=D)
+        sm_scale=1.0 / math.sqrt(D), block_k=bk, head_dim=D)
     body.__name__ = KERNEL_NAME + "_kernel"
     out = pl.pallas_call(
         body,
@@ -311,9 +310,7 @@ def _eva_decode_kernel_call(q, k_cache, v_cache, positions, *, layer,
                       pl.BlockSpec((1, bk, C), kv_map),
                       pl.BlockSpec((1, bk, C), kv_map)],
             out_specs=pl.BlockSpec((1, 1, C), sq_map),
-            scratch_shapes=[pltpu.VMEM((H, 128), jnp.float32),
-                            pltpu.VMEM((H, 128), jnp.float32),
-                            pltpu.VMEM((H, D), jnp.float32)]),
+            scratch_shapes=_fd.decode_scratch(H, C, k_cache.dtype)),
         out_shape=jax.ShapeDtypeStruct((S, 1, C), dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),

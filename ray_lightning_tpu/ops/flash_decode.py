@@ -34,19 +34,34 @@ the win is not FLOPs, it is *bytes not read*.  This kernel:
   physical page).  No slice of a layer and no relayout is ever made.
 
 Heads are packed on the lane axis (``C = H*D``), which is the cache's
-own minor dimension, and looped in-kernel with static column slices,
-mirroring the packed flash kernels; per head the scores sit on the lane
-axis too (``[1, block_k]``), so both products are MXU matmuls.  On
-non-TPU backends everything runs under the Pallas interpreter so the
-tier-1 suite executes the real kernel body on CPU — which says nothing
-about what Mosaic accepts:
-tests/test_chip_compile.py compiles flat and paged, bf16 and f32, for a
-described v5e.
+own minor dimension, and a block's heads are computed TOGETHER: the
+query is kept in VMEM as a block-diagonal ``[Hp, C]`` matrix (row ``h``
+holds head ``h``'s ``D`` columns, zeros elsewhere; ``Hp`` is ``H``
+rounded up to the sublane tile), so one product with the packed K block
+gives ``[Hp, block_k]`` scores, one masked online-softmax update runs
+on them with ``m`` and ``l`` as ``[Hp, 1]`` columns (heads on sublanes,
+keys on lanes: the flash forward kernel's own layout), and one product
+``p @ V`` adds ``[Hp, C]`` to the accumulator, whose diagonal blocks
+the slot's last grid step picks out as the ``[1, C]`` output row.  No
+head is sliced out of the lanes and no product has one row; the
+off-diagonal products are MXU work nobody waits for (a K or V tile is
+pushed into the MXU once either way, and ``Hp`` rows pass it instead of
+one).  On the v5e, at ``gpt2-large``'s 20 heads of 64 this runs a call
+in 0.145 ms where the loop over heads (two M=1 products a head a block)
+took 0.384, at EvaByte's 32 of 128 1.18 ms against 1.84 (PERF.md,
+PR 29).  On non-TPU backends everything runs under the Pallas
+interpreter so the tier-1 suite executes the real kernel body on CPU —
+which says nothing about what Mosaic accepts:
+tests/test_chip_compile.py compiles flat, paged and ``eva_decode``,
+bf16 and f32, for a described v5e at the served geometries.
 
-Numerics: fp32 softmax statistics, ``NEG_INF = -1e30`` masking (NaN-free
-under exp, ops/flash_attention.py idiom), output in the caller's compute
-dtype — parity with the dense einsum within the documented bf16 2e-2 bar
-(tests/test_ops.py decode-parity tier).
+Numerics: operands in the cache's dtype, fp32 accumulation, fp32
+softmax statistics, ``NEG_INF = -1e30`` masking (NaN-free under exp,
+ops/flash_attention.py idiom), ``p`` cast to the cache's dtype before
+the second product, output in the caller's compute dtype.  The zeros of
+the block-diagonal query add exact zeros to the fp32 sums — parity with
+the dense einsum within the documented bars, f32 2e-5 and bf16 2e-2
+(tests/test_ops.py ``test_decode_parity``).
 """
 
 from __future__ import annotations
@@ -185,10 +200,10 @@ def _pick_block_k(L: int) -> int:
 
 
 def _decode_body(pos, kb, nk, logical_base,
-                 q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                 *, sm_scale, block_k, n_head, head_dim, rows=None):
+                 q_ref, k_ref, v_ref, o_ref, qd_ref, m_ref, l_ref, acc_ref,
+                 *, sm_scale, block_k, head_dim, rows=None):
     """Online-softmax update for one ``block_k``-row KV block of one
-    slot, looped over the packed heads.  ``logical_base`` is the block's
+    slot, every packed head at once.  ``logical_base`` is the block's
     first LOGICAL cache row (page-table indirection moves only the
     physical fetch; masking is always in logical positions).
 
@@ -196,74 +211,95 @@ def _decode_body(pos, kb, nk, logical_base,
     positions (ops/eva_attention.py: two ranges of rows a slot) gives
     its own bound as ``rows = (live, seen)``: whether this block holds a
     row the slot sees, and which of a block's logical rows it sees
-    (``seen(cols)``).  Row 0 is seen under either bound."""
+    (``seen(cols)``).  Row 0 is seen under either bound.
+
+    Heads lie on the SUBLANE axis of everything the body keeps (the
+    module's docstring says why): ``qd_ref`` [Hp, C] the block-diagonal
+    query, ``m_ref`` / ``l_ref`` [Hp, 128] the running max and sum,
+    ``acc_ref`` [Hp, C] the rescaled ``p @ V``, of whose row ``h`` only
+    head ``h``'s own columns are kept (:func:`decode_scratch`)."""
     live = kb * block_k <= pos if rows is None else rows[0]
+    hp, width = qd_ref.shape
+
+    def own_columns():
+        # [Hp, C] bool: the columns of row h's own head (none for a row
+        # of padding, h >= H)
+        first = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 0) \
+            * head_dim
+        col = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
+        return (col >= first) & (col < first + head_dim)
 
     @pl.when(kb == 0)
     def _init():
+        # the query broadcast over sublanes under the mask: no
+        # transpose, no lane slice (through float32, which holds every
+        # bfloat16 exactly)
+        q = q_ref[0].astype(jnp.float32)                    # [1, C]
+        qd_ref[:] = jnp.where(own_columns(), q, 0.0).astype(qd_ref.dtype)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     @pl.when(live)
     def _compute():
-        # scores live on the LANE axis ([1, block_k]): both products are
-        # plain MXU matmuls (q·kᵀ, p·v) with fp32 accumulation.  Scores
-        # on the sublane axis (k·qᵀ -> [block_k, 1]) would be a matvec,
-        # which Mosaic lowers as a broadcast-multiply and refuses for
-        # bf16 operands with an fp32 result ('vector.broadcast'
-        # element-type verification) — tests/test_chip_compile.py
-        # compiles this body for a described v5e.
+        k = k_ref[0]                                        # [block_k, C]
+        v = v_ref[0]
         cols = (jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
                 + logical_base)
         valid = cols <= pos if rows is None else rows[1](cols)
-        for h in range(n_head):
-            sl = slice(h * head_dim, (h + 1) * head_dim)
-            q = q_ref[0, :, sl]                       # [1, D]
-            k = k_ref[0, :, sl]                       # [block_k, D]
-            v = v_ref[0, :, sl]                       # [block_k, D]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # [1, bk]
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_ref[h:h + 1, :1]                         # [1, 1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)                     # [1, 1]
-            p = jnp.exp(s - m_new)                              # [1, bk]
-            l_ref[h:h + 1, :] = (alpha * l_ref[h:h + 1, :]
-                                 + jnp.sum(p, axis=1, keepdims=True))
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)             # [1, D]
-            acc_ref[h:h + 1, :] = alpha * acc_ref[h:h + 1, :] + pv
-            m_ref[h:h + 1, :] = jnp.broadcast_to(
-                m_new, (1, m_ref.shape[1]))
+        # q . k^T, the flash forward kernel's own form
+        s = jax.lax.dot_general(
+            qd_ref[:], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [Hp, bk]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[:, :1]                               # [Hp, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)                     # [Hp, 1]
+        p = jnp.exp(s - m_new)                              # [Hp, bk]
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [Hp, C]
+        acc_ref[:] = alpha * acc_ref[:] + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(kb == nk - 1)
     def _final():
-        for h in range(n_head):
-            sl = slice(h * head_dim, (h + 1) * head_dim)
-            # l > 0 always: logical row 0 satisfies ``0 <= pos`` for any
-            # non-negative position, so at least one key is live
-            o_ref[0, :, sl] = (acc_ref[h:h + 1, :]
-                               / l_ref[h:h + 1, :1]).astype(o_ref.dtype)
+        # each column's own head: its accumulator and its sum, one
+        # nonzero a column, so the sums over sublanes are exact.
+        # l > 0 always: logical row 0 satisfies ``0 <= pos`` for any
+        # non-negative position, so at least one key is live
+        own = own_columns()
+        acc = jnp.sum(jnp.where(own, acc_ref[:], 0.0), axis=0,
+                      keepdims=True)                        # [1, C]
+        l = jnp.sum(jnp.where(own, l_ref[:, :1], 0.0), axis=0,
+                    keepdims=True)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-def flash_decode_kernel(positions_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_ref, l_ref, acc_ref, **kw):
+def decode_scratch(n_head: int, width: int, dtype) -> list:
+    """The VMEM a call around :func:`_decode_body` keeps across a slot's
+    blocks: the block-diagonal query in the cache's dtype and the
+    float32 running max, running sum and rescaled accumulator, heads on
+    sublanes (``Hp``: ``n_head`` rounded up to ``dtype``'s tile)."""
+    sub = 8 * 4 // jnp.dtype(dtype).itemsize
+    hp = -(-n_head // sub) * sub
+    return [pltpu.VMEM((hp, width), dtype),
+            pltpu.VMEM((hp, 128), jnp.float32),
+            pltpu.VMEM((hp, 128), jnp.float32),
+            pltpu.VMEM((hp, width), jnp.float32)]
+
+
+def flash_decode_kernel(positions_ref, *refs, **kw):
     s, kb = pl.program_id(0), pl.program_id(1)
     _decode_body(positions_ref[s], kb, pl.num_programs(1),
-                 kb * kw["block_k"], q_ref, k_ref, v_ref, o_ref,
-                 m_ref, l_ref, acc_ref, **kw)
+                 kb * kw["block_k"], *refs, **kw)
 
 
-def flash_decode_paged_kernel(positions_ref, table_ref, q_ref, k_ref,
-                              v_ref, o_ref, m_ref, l_ref, acc_ref, **kw):
+def flash_decode_paged_kernel(positions_ref, table_ref, *refs, **kw):
     s, p = pl.program_id(0), pl.program_id(1)
     _decode_body(positions_ref[s], p, pl.num_programs(1),
-                 p * kw["block_k"], q_ref, k_ref, v_ref, o_ref,
-                 m_ref, l_ref, acc_ref, **kw)
+                 p * kw["block_k"], *refs, **kw)
 
 
 def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
@@ -353,15 +389,10 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
             pl.BlockSpec(kv_block, kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, C), sq_map),
-        scratch_shapes=[
-            pltpu.VMEM((H, 128), jnp.float32),   # running max m
-            pltpu.VMEM((H, 128), jnp.float32),   # running sum l
-            pltpu.VMEM((H, D), jnp.float32),     # rescaled accumulator
-        ],
+        scratch_shapes=decode_scratch(H, C, k_cache.dtype),
     )
     body = functools.partial(
-        kernel, sm_scale=1.0 / float(np.sqrt(D)), block_k=bk,
-        n_head=H, head_dim=D)
+        kernel, sm_scale=1.0 / float(np.sqrt(D)), block_k=bk, head_dim=D)
     # both names keep the "flash" stem: the anatomy category table and
     # the collective classifier key on it (telemetry/anatomy.py
     # bucket_of, comm/audit.py collective_kind)

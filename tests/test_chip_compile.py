@@ -79,26 +79,76 @@ def test_flash_attention_compiles_for_v5e(v5e, grad):
     _compiles_with_kernel(bwd if grad else fwd, x, x, x)
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["flat", "paged"])
+_HLO_RESULT = re.compile(
+    r"= \(?(?:bf16|f32)\[([0-9,]+)\][^ ]* (copy|copy-start|slice|"
+    r"dynamic-slice|concatenate|transpose)\(")
+
+
+def _layer_movers(text: str, layer_elems: int) -> list:
+    """The operations of a compiled program that copy, slice or
+    transpose something as large as one layer of a cache array."""
+    return [m.group(0) for m in _HLO_RESULT.finditer(text)
+            if np.prod([int(d) for d in m.group(1).split(",")])
+            >= layer_elems]
+
+
+#: the decode kernels at the geometries that are served: (H, D, rows a
+#: slot, slots); gpt2-small's, and the two benchmark cells' own
+#: (gpt2-large's 20 heads of 64 are no sublane multiple and half a vreg
+#: each; EvaByte's 32 of 128 with 2048 exact + 2048 summary rows a slot)
+_DECODE_GEOMETRY = {
+    "gpt2-small": (H, D, L, S),
+    "gpt2-large": (20, 64, 1024, 24),
+    "evabyte": (32, 128, 2048 + 2048, 32),
+}
+
+
+@pytest.mark.parametrize("geometry,call", [
+    ("gpt2-small", "flash_decode"), ("gpt2-small", "flash_decode_paged"),
+    ("gpt2-large", "flash_decode"), ("gpt2-large", "flash_decode_paged"),
+    ("evabyte", "eva_decode"),
+])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-def test_flash_decode_compiles_for_v5e(v5e, dtype, paged):
-    from ray_lightning_tpu.ops.flash_decode import flash_decode_attention
+def test_decode_kernels_compile_for_v5e(monkeypatch, v5e, dtype, geometry,
+                                        call):
+    """Mosaic takes the decode kernels' shared body (every head of a
+    block at once: ops/flash_decode.py ``_decode_body``) under each of
+    its calls: the result is a ``tpu_custom_call`` under the call's old
+    name (the benchmark's readers find the kernels by it), the cache
+    enters whole, and nothing beside the kernel copies or slices a
+    layer of it."""
+    from ray_lightning_tpu.ops import eva_attention, flash_decode
+    h, d, rows, slots = _DECODE_GEOMETRY[geometry]
+    monkeypatch.setattr(flash_decode, "_use_interpret", lambda: False)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
 
-    # layer 1 of a two-layer resident cache [n_layer, S, L, H*D]
-    args = [sds((S, 1, H, D), dtype), sds((2, S, L, H * D), dtype),
-            sds((2, S, L, H * D), dtype), sds((S,), jnp.int32)]
-    if paged:
-        args.append(sds((S, L // 128), jnp.int32))
+    # layer 1 of a two-layer resident cache [n_layer, S, rows, H*D]
+    cache = sds((2, slots, rows, h * d), dtype)
+    args = [sds((slots, 1, h, d), dtype), cache, cache,
+            sds((slots,), jnp.int32)]
+    if call == "flash_decode_paged":
+        args.append(sds((slots, rows // 128), jnp.int32))
 
     def decode(q, k, v, pos, table=None):
-        return flash_decode_attention(q, k, v, pos, layer=1, dtype=dtype,
-                                      page_table=table, interpret=False)
+        if call == "eva_decode":
+            return eva_attention.eva_cached_attention(
+                q, k, v, pos, layer=1, window=2048, chunk=16, dtype=dtype,
+                impl="flash_decode")
+        return flash_decode.flash_decode_attention(
+            q, k, v, pos, layer=1, dtype=dtype, page_table=table,
+            interpret=False)
 
-    _compiles_with_kernel(decode, *args)
+    compiled = jax.jit(decode).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(rf"%{call}(\.\d+)? = [^\n]* custom-call\([^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text), text
+    layer = slots * rows * h * d
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.05 * layer * jnp.dtype(dtype).itemsize
+    assert not _layer_movers(text, layer)
 
 
 # -- the cache's trip through the serve programs ----------------------------
@@ -117,10 +167,6 @@ _GEOMETRY = {
     "gpt2-small": (12, 12, 768),
     "gpt2-large": (36, 20, 1280),
 }
-
-_HLO_RESULT = re.compile(
-    r"= \(?(?:bf16|f32)\[([0-9,]+)\][^ ]* (copy|copy-start|slice|"
-    r"dynamic-slice|concatenate|transpose)\(")
 
 
 def _serve_program(monkeypatch, v5e, config, slots, program, paged):
@@ -196,9 +242,7 @@ def test_serve_program_leaves_the_cache_where_it_lies(
     assert temp < 0.05 * cache_bytes, (
         f"{temp / 1e9:.3f} GB of temporaries beside a "
         f"{cache_bytes / 1e9:.3f} GB cache array")
-    movers = [m.group(0) for m in _HLO_RESULT.finditer(text)
-              if np.prod([int(d) for d in m.group(1).split(",")])
-              >= layer_elems]
+    movers = _layer_movers(text, layer_elems)
     assert not movers, movers[:5]
 
 

@@ -6,6 +6,8 @@ level: flash output and gradients must match the naive attention to
 tight fp32 tolerances.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -411,26 +413,129 @@ def _einsum_ref(q, kc, vc, pos, dtype=jnp.float32):
     return jnp.einsum("shql,slhd->sqhd", probs, v)
 
 
-@pytest.mark.parametrize("impl", ["dense", "flash_decode", "paged"])
-def test_decode_impls_match_unpacked_einsum(impl):
-    """Every decode path reads layer LAYER of the packed, stacked cache
-    and agrees with the einsum over its unpacked ``[S, L, H, D]`` view,
-    across ragged positions including 0 and the cache's last row."""
-    from ray_lightning_tpu.serve.fleet.pages import identity_page_table
-    q, kc, vc = _rand_decode()
-    pos = [0, 17, 128, 255]
-    table = jnp.asarray(identity_page_table(4, 256, 64)) \
-        if impl == "paged" else None
-    out = _decode(impl, q, kc, vc, pos, page_table=table)
-    np.testing.assert_allclose(out, _einsum_ref(q, kc, vc, pos),
-                               atol=2e-5, rtol=2e-5)
+#: the decode-parity tier: every caller of ops/flash_decode.py's shared
+#: body x the geometries that stress its layout (heads on sublanes, the
+#: packed columns on lanes) x positions on the edges of a block and of
+#: the cache x both dtypes, against the dense einsum written above.
+#: Blocks are 64 rows of a 256-row cache: four a slot.
+_BK, _L, _SLOTS = 64, 256, 4
+_GEOMETRIES = {
+    "h20d64": (20, 64),      # gpt2-large: H no sublane multiple, D half a vreg
+    "h32d128": (32, 128),    # EvaByte: whole-vreg heads
+    "tiny": (2, 32),         # the tier's old shape
+}
+_POSITIONS = {
+    "first": [0] * _SLOTS,                     # one live row a slot
+    "block_end": [_BK - 1] * _SLOTS,           # a block exactly full
+    "block_start": [_BK] * _SLOTS,             # one row into the next
+    "last": [_L - 1] * _SLOTS,                 # the cache's last row
+    "mixed": [0, _BK - 1, _BK, _L - 1],
+    "ragged": [0, 17, 128, 255],               # the tier's old cases
+    "straddle": [_BK - 1, _BK, 2 * _BK + 1, 255],
+    "late": [5, 100, 200, 255],
+}
+#: EvaByte's cache at a tiny size: a window of 128 exact rows and one
+#: summary row per 4 of 512 positions; a position is no row there
+_WINDOW, _CHUNK, _EVA_POSITIONS = 128, 4, 512
+_BARS = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+@functools.lru_cache(maxsize=2)
+def _decode_case(h, d, dtype):
+    return _rand_decode(s=_SLOTS, L=_L, h=h, d=d, dtype=dtype)
+
+
+#: the ``paged`` caller: logical page p of slot s lies at physical page
+#: _PERM[s * n + p] of the layer; ``slots``: rows in cache slots 3 and 1
+_PERM = np.random.default_rng(7).permutation(_SLOTS * (_L // _BK))
+_PICK = np.array([3, 1])
+
+
+def _scatter_pages(c):
+    pages = c.reshape(N_LAYER, len(_PERM), _BK, c.shape[-1])
+    return jnp.zeros_like(pages).at[:, _PERM].set(pages).reshape(c.shape)
+
+
+@functools.lru_cache(maxsize=None)
+def _parity_call(caller, dtype):
+    """``f(q, kc, vc, pos)`` of one caller, jitted once a shape: the
+    positions are an argument, so their cases share a compilation."""
+    from ray_lightning_tpu.ops.eva_attention import eva_cached_attention
+    if caller == "eva":
+        return jax.jit(lambda q, kc, vc, pos: eva_cached_attention(
+            q, kc, vc, pos, layer=LAYER, window=_WINDOW, chunk=_CHUNK,
+            dtype=dtype, impl="flash_decode"))
+    if caller == "slots":
+        return jax.jit(lambda q, kc, vc, pos: _decode(
+            "flash_decode", q[_PICK], kc, vc, pos[_PICK], dtype=dtype,
+            slots=jnp.asarray(_PICK, jnp.int32)))
+    if caller == "paged":
+        table = jnp.asarray(_PERM.reshape(_SLOTS, -1), jnp.int32)
+        return jax.jit(lambda q, kc, vc, pos: _decode(
+            "paged", q, _scatter_pages(kc), _scatter_pages(vc), pos,
+            dtype=dtype, page_table=table))
+    impl = "dense" if caller == "dense" else "flash_decode"
+    return jax.jit(lambda q, kc, vc, pos: _decode(impl, q, kc, vc, pos,
+                                                  dtype=dtype))
+
+
+def _eva_ref(q, kc, vc, pos, dtype):
+    """The two-range bound over layer LAYER, written here: rows up to
+    ``pos % window`` of the exact part, the summaries of every earlier
+    window."""
+    s, _, h, d = q.shape
+    k = kc[LAYER].reshape(s, -1, h, d)
+    v = vc[LAYER].reshape(s, -1, h, d)
+    scores = jnp.einsum("sqhd,slhd->shql", q, k,
+                        preferred_element_type=jnp.float32) / np.sqrt(d)
+    pos = np.asarray(pos)[:, None]
+    row = np.arange(k.shape[1])[None, :]
+    far = (pos // _WINDOW) * (_WINDOW // _CHUNK)
+    seen = (row <= pos % _WINDOW) | (
+        (row >= _WINDOW) & (row < _WINDOW + far))
+    scores = jnp.where(seen[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum("shql,slhd->sqhd", probs, v)
+
+
+@pytest.mark.parametrize("dtype", list(_BARS), ids=["f32", "bf16"])
+@pytest.mark.parametrize("positions", list(_POSITIONS))
+@pytest.mark.parametrize("geometry", list(_GEOMETRIES))
+@pytest.mark.parametrize("caller", ["dense", "flat", "paged", "slots", "eva"])
+def test_decode_parity(monkeypatch, caller, geometry, positions, dtype):
+    """One body under every call: ``flat`` (``flash_decode``), ``paged``
+    (a PERMUTED page table over a cache whose pages are permuted to
+    match, so a walk that ignores the table reads other rows), ``slots``
+    (two rows in cache slots of their own choosing, through the paged
+    kernel), ``eva`` (``eva_decode``: the two-range bound) and the
+    ``dense`` einsum of the package, each on layer LAYER of the stacked
+    cache against the plain mathematics above."""
+    monkeypatch.setenv("RLT_DECODE_BLOCK_K", str(_BK))
+    q, kc, vc = _decode_case(*_GEOMETRIES[geometry], dtype)
+    pos = np.asarray(_POSITIONS[positions], np.int32)
+    if caller == "eva":
+        # the cache's window + positions / chunk rows; the positions
+        # spread over the four windows, on the same edges and beside them
+        rows = _WINDOW + _EVA_POSITIONS // _CHUNK
+        kc, vc = kc[:, :, :rows], vc[:, :, :rows]
+        pos = pos * (_EVA_POSITIONS // _L) + pos % 2
+        ref = _eva_ref(q, kc, vc, pos, dtype)
+    else:
+        ref = _einsum_ref(q, kc, vc, pos, dtype)
+        if caller == "slots":
+            ref = ref[_PICK]
+    out = _parity_call(caller, dtype)(q, kc, vc, pos)
+    assert out.shape == ref.shape and out.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        atol=_BARS[dtype], rtol=_BARS[dtype])
 
 
 @pytest.mark.parametrize("impl", ["dense", "flash_decode", "paged"])
 def test_decode_rows_in_named_slots(impl):
     """``slots``: a batch of rows that live in cache slots of their own
     choosing (the one-row suffix program) reads exactly what the full
-    batch reads at those slots."""
+    batch reads at those slots, with and without a page table."""
     from ray_lightning_tpu.serve.fleet.pages import identity_page_table
     q, kc, vc = _rand_decode()
     pos = np.array([0, 17, 128, 255])
@@ -444,52 +549,24 @@ def test_decode_rows_in_named_slots(impl):
     np.testing.assert_allclose(out, full[pick], atol=2e-5, rtol=2e-5)
 
 
-def test_flash_decode_matches_dense_ragged():
-    """Length-aware kernel vs the masked dense einsum across ragged
-    per-slot positions — including position 0 (single valid index) and
-    the last index of the cache."""
-    q, kc, vc = _rand_decode()
-    pos = [0, 17, 128, 255]
-    ref = _decode("dense", q, kc, vc, pos)
-    out = _decode("flash_decode", q, kc, vc, pos)
-    assert out.shape == ref.shape == q.shape
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-
 def test_flash_decode_single_slot():
     q, kc, vc = _rand_decode(s=1, L=128)
-    ref = _decode("dense", q, kc, vc, [63])
     out = _decode("flash_decode", q, kc, vc, [63])
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(out, _einsum_ref(q, kc, vc, [63]),
+                               atol=2e-5, rtol=2e-5)
 
 
-def test_flash_decode_bf16_tolerance():
-    """bf16 caches (the serve plane's storage dtype) stay within bf16
-    rounding of the dense reference."""
-    q, kc, vc = _rand_decode(dtype=jnp.bfloat16)
-    pos = [5, 100, 200, 255]
-    ref = _decode("dense", q, kc, vc, pos, dtype=jnp.bfloat16)
-    out = _decode("flash_decode", q, kc, vc, pos, dtype=jnp.bfloat16)
-    np.testing.assert_allclose(out.astype(jnp.float32),
-                               ref.astype(jnp.float32),
-                               atol=2e-2, rtol=2e-2)
-
-
-def test_paged_decode_page_boundary_straddle():
-    """The paged variant (identity page table — slot-contiguous cache)
-    must agree with dense at positions ON and AROUND page boundaries,
-    where an off-by-one in the table walk or the logical-position
-    masking would surface, and agree bitwise with the slot-contiguous
-    kernel at matching block size."""
+def test_paged_decode_bitwise_equal_to_flat():
+    """The paged variant under the identity table (the slot-contiguous
+    cache) is the slot-contiguous kernel at the same block size, bit for
+    bit, at positions on and around page boundaries."""
     from ray_lightning_tpu.ops.flash_decode import flash_decode_attention
     from ray_lightning_tpu.serve.fleet.pages import identity_page_table
     page = 64
     q, kc, vc = _rand_decode(s=4, L=256)
     table = jnp.asarray(identity_page_table(4, 256, page))
     pos = [page - 1, page, 2 * page + 1, 255]
-    ref = _decode("dense", q, kc, vc, pos)
     out = _decode("paged", q, kc, vc, pos, page_table=table)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
     flat = flash_decode_attention(
         q, kc, vc, jnp.asarray(pos, jnp.int32), layer=LAYER,
         dtype=jnp.float32, block_k=page)
