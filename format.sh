@@ -105,7 +105,7 @@ if [[ "$CHECK" == 1 ]]; then
 fi
 
 if [[ "$ALL" == 1 ]]; then
-    exec flake8 "${FLAKE8_ARGS[@]}" ray_lightning_tpu tests benchmarks bench.py __graft_entry__.py
+    exec flake8 "${FLAKE8_ARGS[@]}" ray_lightning_tpu tests __graft_entry__.py
 fi
 
 MERGEBASE="$(git merge-base origin/main HEAD 2>/dev/null \

@@ -333,7 +333,8 @@ def publish_metrics(registry) -> None:
 
 def note_first_step(seconds: float) -> None:
     """Record time-to-first-step into the metrics plane (the trainer
-    calls this once per fit; bench.py reads the trainer attribute)."""
+    calls this once per fit; chip_smoke.py reads the trainer
+    attribute)."""
     from ray_lightning_tpu.telemetry import metrics as _metrics
     reg = _metrics.get_registry()
     if reg is not None:

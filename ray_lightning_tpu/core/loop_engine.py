@@ -20,8 +20,8 @@ metrics, val cadence) are the engine's and exist once.
   frozen-membership divergence is gone by construction).  Per-step
   dispatches then gather batch i on-device; only integer indices cross
   the host→device link (for small models the link, not the compute,
-  bounds the step — a claim to re-measure, benchmarks/README.md
-  config #1).  A trailing partial batch (drop_last=False) cannot ride
+  bounds the step — a pre-round claim that no cell re-measures).
+  A trailing partial batch (drop_last=False) cannot ride
   the fixed-shape cache and is assembled host-side and routed through
   the single-step program instead (the np.stack shape crash of the
   round-2 cache is structurally impossible here: samples stack at the

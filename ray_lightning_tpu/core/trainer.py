@@ -225,7 +225,8 @@ class Trainer:
         self._cache_bytes_hint = None
         self._mesh = None
         #: seconds from stage entry to the first completed train step
-        #: (compile + init + upload startup cost; bench.py reports it)
+        #: (compile + init + upload startup cost; chip_smoke.py and
+        #: ``rlt_time_to_first_step_seconds`` report it)
         self.time_to_first_step: Optional[float] = None
         self._stage_t0: Optional[float] = None
         self._precompiler: Optional[AotPrecompiler] = None
@@ -1964,7 +1965,7 @@ class Trainer:
         for cb in self.callbacks:
             cb.on_load_checkpoint(self, module, meta)
 
-    # elapsed-time helper used by examples/benchmarks
+    # elapsed-time helper for example scripts
     @staticmethod
     def _now() -> float:
         return time.monotonic()

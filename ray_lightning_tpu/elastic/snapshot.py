@@ -59,7 +59,7 @@ class Snapshotter:
         self.cfg = cfg
         self.directory = cfg.resolve_dir(trainer.default_root_dir)
         #: cumulative counters mirrored into the metrics registry; read
-        #: directly by bench_checkpoint and the chaos tests
+        #: directly by the chaos tests
         self.stats = {
             "snapshots": 0,
             "skipped": 0,

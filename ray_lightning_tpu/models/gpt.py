@@ -62,9 +62,8 @@ class GPTConfig:
     # intermediates (ops/moe.py checkpoint_names — measured SLOWER than
     # plain dots on gpt2-moe-8e, kept as documented options);
     # "off" = save everything.  The policy is THE lever of the
-    # memory-bound regime — measured walk in benchmarks/README.md
-    # (gpt2-medium).  ``RLT_REMAT_POLICY`` overrides at model build for
-    # A/B sweeps.
+    # memory-bound regime (the gpt2-medium walk beside CONFIGS below).
+    # ``RLT_REMAT_POLICY`` overrides at model build for A/B sweeps.
     remat_policy: str = "full"
     dtype: Any = jnp.bfloat16        # compute dtype; params stay fp32
     # "auto" | "dot" | "flash" | "ring" | "local" (ops/attention.py;
@@ -101,7 +100,7 @@ CONFIGS = {
     # dots_saveable: keep matmul outputs, recompute only elementwise
     # chains — measured +17% steps/s over full remat on v5e (150.3 vs
     # 177.4 ms/step device) and still fits with 6+ GB to spare; policy
-    # "off" needs 18.95 GB and OOMs (benchmarks/README.md round-4 walk)
+    # "off" needs 18.95 GB and OOMs (pre-round walk, one v5e)
     "gpt2-medium": GPTConfig(block_size=1024, n_layer=24, n_head=16,
                              n_embd=1024, remat_policy="dots"),
     # 1.3B class: remat + chunked CE — at T=2048 the full fp32 logits
@@ -115,8 +114,8 @@ CONFIGS = {
     # dots remat beats BOTH full remat (92.7 ms) and no remat (95.3 ms)
     # here: the dispatch/combine and expert-FFN intermediates are huge,
     # and recomputing their elementwise chains is cheaper than
-    # round-tripping them through HBM (benchmarks/README.md round-4 MoE
-    # table; 80.1 ms/step, MFU 0.44 → 0.535)
+    # round-tripping them through HBM (pre-round walk, one v5e:
+    # 80.1 ms/step, MFU 0.44 → 0.535)
     "gpt2-moe-8e": GPTConfig(block_size=1024, n_layer=12, n_head=12,
                              n_embd=768, n_experts=8,
                              remat_policy="dots"),

@@ -190,8 +190,8 @@ def eva_attention(q, k, v, k_sum, v_sum, *, window: int, chunk: int,
 
 def decode_block_k(window: int, far_rows: int) -> int:
     """Rows a grid step of the decode kernel reads: the largest block of
-    at most ``RLT_DECODE_BLOCK_K`` (128) rows that tiles both parts of a
-    slot, so that no block holds rows of the two."""
+    at most ``flash_decode._BLOCK_K`` (128) rows that tiles both parts
+    of a slot, so that no block holds rows of the two."""
     return _fd._pick_block_k(math.gcd(window, far_rows))
 
 

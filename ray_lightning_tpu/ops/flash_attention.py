@@ -31,8 +31,7 @@ from __future__ import annotations
 
 import functools
 import math
-import os
-import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -187,9 +186,12 @@ def _fwd_tri_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         lse_ref[0] = m_ref[:, :1] + jnp.log(l)
 
 
-def _use_tri(causal: bool, bq: int, bk: int, nq: int) -> bool:
-    return (causal and bq == bk and nq > 1
-            and os.environ.get("RLT_FLASH_TRI", "1") != "0")
+#: Rows of a causal staircase sub-block, for every T that holds at
+#: least two of them (so from T = 512).  The pre-round sweep of a whole
+#: gpt2-small step at T=1024 on v5e (IMPLEMENTATION_MAP.md): off 54.42 /
+#: 128 52.39 / 256 51.08 / 512 51.59 ms; the train cell's
+#: ``train_attn_kernel_ms`` 17.21 (ledger, PR 29) is made of 256.
+_STAIRCASE_SUB = 256
 
 
 def _sub_block(t: int, causal: bool) -> int:
@@ -204,21 +206,9 @@ def _sub_block(t: int, causal: bool) -> int:
     with ZERO grid overhead because the loop unrolls statically inside
     the kernel (unlike the round-2 512×512 *grid* tiles, which lost to
     the single block on per-block prefetch + pl.when dead iterations).
-    ``RLT_FLASH_SUB`` overrides (0 disables).
     """
-    if not causal:
-        return 0
-    env = os.environ.get("RLT_FLASH_SUB")
-    if env:   # empty string falls through to the default (cf. RLT_FLASH_BLOCK_Q)
-        try:
-            s = int(env)
-        except ValueError:
-            warnings.warn(
-                f"RLT_FLASH_SUB={env!r} is not an integer; using the "
-                "default staircase sub-block (set 0 to disable)")
-        else:
-            return s if s > 0 and t % s == 0 and s < t else 0
-    return 256 if t % 256 == 0 and t >= 512 else 0
+    sub = _STAIRCASE_SUB
+    return sub if causal and t >= 2 * sub and t % sub == 0 else 0
 
 
 def _staircase_fold(sm_scale: float) -> bool:
@@ -644,34 +634,22 @@ def _bwd_tri_packed(q, k, v, h, lse, do, delta, sm_scale, bq, nq,
 # causal columns (no dead iterations, no per-block prefetch), dq
 # finalizes per row step, and dk/dv accumulate in fp32 VMEM scratch
 # via dynamic-slice read-modify-write, emitted once at the last row.
-# Engagement differs by direction (``RLT_FLASH_ROWRES=0`` opts out of
-# both): the FORWARD (online softmax in registers, no big scratch)
-# wins up to T=8192 (−15%/−16% at 4096/8192); the BACKWARD, whose
+# Engagement differs by direction (:func:`_select_family`): the
+# FORWARD (online softmax in registers, no big scratch) wins up to
+# T=8192 (−15%/−16% at 4096/8192 vs the grid-tri forward; k/v residency
+# is the win, loaded once per batch·head-group); the BACKWARD, whose
 # fp32 [T,128] dk/dv accumulators weigh on the scoped-VMEM budget,
 # caps at T=2048 (−28% whole fwd+bwd there with both kernels) — at
 # 4096 its 512-tiles overflow scoped VMEM by ~0.5 MB and 256-tiles
 # underfeed the MXU (24.3 vs 19.5 ms/iter), so longer sequences pair
 # the rowres forward with the grid-tri backward.
 
-
-def _use_row_resident(t: int, w: int = 128) -> bool:
-    """Backward engagement: the fp32 [T, w] dk/dv accumulators plus the
-    resident k/v scale with t·w, so the budget is the measured t=2048
-    point AT w=128 — wide heads (d ≥ 256 pack to w=d) hit the same
-    VMEM ceiling at proportionally shorter t."""
-    return t * w <= 2048 * 128 \
-        and os.environ.get("RLT_FLASH_ROWRES", "1") != "0"
-
-
-def _use_row_resident_fwd(t: int, w: int = 128) -> bool:
-    """The forward kernel carries no fp32 [T,128] accumulators (online
-    softmax lives in registers), so its VMEM budget stretches to
-    T=8192 (measured −15%/−16% at 4096/8192 vs the grid-tri forward;
-    k/v residency is the win — loaded once per batch·head-group).
-    The resident k/v are [T, w] each, so the budget caps t·w at the
-    measured w=128 point rather than t alone."""
-    return t * w <= 8192 * 128 \
-        and os.environ.get("RLT_FLASH_ROWRES", "1") != "0"
+#: The budgets are t·w, not t: the resident k/v (and the backward's
+#: fp32 dk/dv accumulators) are [T, w] each and both points were
+#: measured at w=128, so wide heads (d ≥ 256 pack to w=d) meet the same
+#: VMEM ceiling at proportionally shorter t.
+_ROWRES_FWD_BUDGET = 8192 * 128
+_ROWRES_BWD_BUDGET = 2048 * 128
 
 
 def _fwd_rowres_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -900,34 +878,94 @@ def _unfold(x, b, t, h, d):
     return x.reshape(b, h, t, d).transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
+# -- which family runs ------------------------------------------------------
+
+#: Default tiling: one full-T block per grid row up to T=1024 (no
+#: inner-loop grid overhead — measured +7% whole-model step rate at
+#: T=1024 on v5e vs fixed 512), 512×512 tiles beyond, whose VMEM
+#: footprint stays safe as T grows (3.3× over 128×128 at T=4096).
+_SINGLE_BLOCK_MAX_T = 1024
+_TILE = 512
+
+
+class _Family(NamedTuple):
+    """What :func:`_select_family` chose for one call."""
+    fwd: str   # packed | rowres | tri_packed | tri | rect
+    bwd: str   # packed | rowres | tri_packed | fused | tri | rect
+    lse: str   # "packed": [B, H/pack, T, pack]; "folded": [B·H, T, 1]
+    bq: int
+    bk: int
+
+
+def _select_family(t: int, h: int, d: int, causal: bool,
+                   block_q: int | None = None,
+                   block_k: int | None = None) -> _Family:
+    """The ONE place a kernel family is chosen, from the call's shape
+    alone: the forward and the backward both ask here, so the ``lse``
+    residual one writes is in the layout the other reads.
+
+    ============  ==========  ==========  ======  =============================
+    shape         forward     backward    lse     when
+    ============  ==========  ==========  ======  =============================
+    one block     packed      packed      packed  heads pack into lanes
+    causal tiles  rowres      rowres      packed  … t·w ≤ the backward's budget
+    causal tiles  rowres      tri_packed  packed  … t·w ≤ the forward's budget
+    causal tiles  tri_packed  tri_packed  packed  … beyond both
+    one block     rect        fused       folded  heads do not pack
+    causal tiles  tri         tri         folded  heads do not pack
+    other         rect        rect        folded  non-causal or bq ≠ bk
+    ============  ==========  ==========  ======  =============================
+
+    "causal tiles" is more than one block of a causal call with square
+    blocks; ``w`` is the lane width of a packed head group and the
+    budgets are ``_ROWRES_BWD_BUDGET`` < ``_ROWRES_FWD_BUDGET``.  Explicit
+    ``block_q`` / ``block_k`` replace the default tiling; either is
+    clamped to T and halved until it divides T.
+    """
+    default = t if t <= _SINGLE_BLOCK_MAX_T else _TILE
+    bq = _pick_block(t, default if block_q is None else block_q)
+    bk = _pick_block(t, default if block_k is None else block_k)
+    single = bq == t and bk == t
+    tri = causal and bq == bk and not single
+    pack = _head_pack(d, h)
+    if pack and single:
+        return _Family("packed", "packed", "packed", bq, bk)
+    if pack and tri:
+        tw = t * pack * d
+        return _Family(
+            "rowres" if tw <= _ROWRES_FWD_BUDGET else "tri_packed",
+            "rowres" if tw <= _ROWRES_BWD_BUDGET else "tri_packed",
+            "packed", bq, bk)
+    if tri:
+        return _Family("tri", "tri", "folded", bq, bk)
+    return _Family("rect", "fused" if single else "rect", "folded", bq, bk)
+
+
 def _fwd(q, k, v, h, causal, sm_scale, block_q, block_k, interpret):
     """Core forward on head-packed [B, T, C] arrays.
 
     Single-block shapes take the transpose-free packed path; longer
     sequences fold to [B·H, T, D] for the tiled/triangular kernels.
-    Returns ``(o[B,T,C], lse)`` where lse's layout depends on the path
-    taken (packed: [B, H/pack, T, pack]; folded: [B·H, T, 1]) — the
-    matching ``_bwd`` branch consumes it.
+    Returns ``(o[B,T,C], lse)``, lse in the layout
+    :func:`_select_family` names.
     """
     b, t, c = q.shape
     d = c // h
     bh = b * h
-    bq = _pick_block(t, block_q)
-    bk = _pick_block(t, block_k)
+    fam = _select_family(t, h, d, causal, block_q, block_k)
+    bq, bk = fam.bq, fam.bk
     nq, nk = t // bq, t // bk
 
-    pack = _head_pack(d, h)
-    if nq == 1 and nk == 1 and pack:
+    if fam.fwd == "packed":
         return _fwd_packed(q, k, v, h, causal, sm_scale, interpret)
-
-    if _use_tri(causal, bq, bk, nq) and pack:
-        if _use_row_resident_fwd(t, pack * d):
-            return _fwd_rowres(q, k, v, h, sm_scale, bq, nq, interpret)
+    if fam.fwd == "rowres":
+        return _fwd_rowres(q, k, v, h, sm_scale, bq, nq, interpret)
+    if fam.fwd == "tri_packed":
         return _fwd_tri_packed(q, k, v, h, sm_scale, bq, nq, interpret)
 
     q, k, v = (_fold(x, b, t, h, d) for x in (q, k, v))
 
-    if _use_tri(causal, bq, bk, nq):
+    if fam.fwd == "tri":
         n_tri = nq * (nq + 1) // 2
         kernel = functools.partial(_fwd_tri_kernel, sm_scale=sm_scale,
                                    block=bq)
@@ -1378,27 +1416,27 @@ def _bwd_tri(q, k, v, o, lse, do, sm_scale, bq, nq, delta, interpret):
 
 def _bwd(q, k, v, h, o, lse, do, causal, sm_scale, block_q, block_k,
          interpret):
-    """Backward on head-packed [B, T, C]; must mirror ``_fwd``'s branch
-    (the packed path's residuals carry a [B, H/pack, T, pack] lse)."""
+    """Backward on head-packed [B, T, C]; ``lse`` arrives in the layout
+    :func:`_select_family` names for this shape."""
     b, t, c = q.shape
     d = c // h
     bh = b * h
-    bq = _pick_block(t, block_q)
-    bk = _pick_block(t, block_k)
+    fam = _select_family(t, h, d, causal, block_q, block_k)
+    bq, bk = fam.bq, fam.bk
     nq, nk = t // bq, t // bk
 
-    if nq == 1 and nk == 1 and _head_pack(d, h):
+    if fam.bwd == "packed":
         return _bwd_packed(q, k, v, h, o, lse, do, causal, sm_scale,
                            interpret)
 
-    if _use_tri(causal, bq, bk, nq) and _head_pack(d, h):
+    if fam.bwd in ("rowres", "tri_packed"):
         # per-head delta in the packed lse layout [B, H/pack, T, pack]
         pack = _head_pack(d, h)
         delta = jnp.sum((do.astype(jnp.float32)
                          * o.astype(jnp.float32)).reshape(b, t, h, d),
                         axis=-1)
         delta = delta.reshape(b, t, h // pack, pack).transpose(0, 2, 1, 3)
-        if _use_row_resident(t, pack * d):
+        if fam.bwd == "rowres":
             return _bwd_rowres(q, k, v, h, lse, do, delta, sm_scale,
                                bq, nq, interpret)
         return _bwd_tri_packed(q, k, v, h, lse, do, delta, sm_scale, bq,
@@ -1410,10 +1448,10 @@ def _bwd(q, k, v, h, o, lse, do, causal, sm_scale, block_q, block_k,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)                      # [bh, t, 1]
 
-    if nq == 1 and nk == 1:
+    if fam.bwd == "fused":
         dq, dk, dv = _bwd_fused(q, k, v, lse, do, delta, causal, sm_scale,
                                 interpret)
-    elif _use_tri(causal, bq, bk, nq):
+    elif fam.bwd == "tri":
         dq, dk, dv = _bwd_tri(q, k, v, o, lse, do, sm_scale, bq, nq, delta,
                               interpret)
     else:
@@ -1509,11 +1547,9 @@ def flash_attention(q, k, v, *, causal: bool = True, dtype=jnp.bfloat16,
     (same scaling 1/√D, same causal semantics); differentiable via the
     Pallas backward kernels above.
 
-    Default block sizes adapt to T: sequences up to 1024 use one full-T
-    block per grid row (no inner-loop grid overhead — measured +7%
-    whole-model step rate at T=1024 on v5e vs fixed 512); longer
-    sequences keep 512×512 tiles, whose VMEM footprint stays safe as T
-    grows.
+    ``block_q`` / ``block_k`` ask for a tiling other than the default
+    (:func:`_select_family`: one block up to T=1024, 512×512 beyond);
+    they are part of the traced program, not of the process.
 
     Note: under a multi-device ``pjit`` program, call this inside
     ``shard_map`` (the batch/head grid is per-device); single-device jit
@@ -1521,16 +1557,6 @@ def flash_attention(q, k, v, *, causal: bool = True, dtype=jnp.bfloat16,
     parallelism.
     """
     b, t, h, d = q.shape
-    # RLT_FLASH_BLOCK_Q/K override the heuristic (the sweep knob used to
-    # tune per-shape defaults; also a user escape hatch)
-    if block_q is None:
-        env_q = os.environ.get("RLT_FLASH_BLOCK_Q")
-        block_q = int(env_q) if env_q else (t if t <= 1024 else 512)
-    if block_k is None:
-        env_k = os.environ.get("RLT_FLASH_BLOCK_K")
-        block_k = int(env_k) if env_k else (t if t <= 1024 else 512)
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if interpret is None:
@@ -1555,16 +1581,15 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
     summaries of earlier windows there): ``logaddexp`` of the two lse
     weighs the two outputs."""
     b, t, h, d = q.shape
-    block = t if t <= 1024 else 512
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = _use_interpret()
     o, lse = _fwd(q.reshape(b, t, h * d), k.reshape(b, t, h * d),
-                  v.reshape(b, t, h * d), h, causal, sm_scale, block, block,
+                  v.reshape(b, t, h * d), h, causal, sm_scale, None, None,
                   interpret)
-    # _fwd's two layouts: packed [B, H/pack, T, pack], folded [B*H, T, 1]
-    lse = lse.transpose(0, 2, 1, 3) if lse.ndim == 4 \
-        else lse.reshape(b, h, t).transpose(0, 2, 1)
+    if _select_family(t, h, d, causal).lse == "packed":
+        lse = lse.transpose(0, 2, 1, 3)          # [B, H/pack, T, pack]
+    else:
+        lse = lse.reshape(b, h, t).transpose(0, 2, 1)       # [B·H, T, 1]
     return o.reshape(b, t, h, d), lse.reshape(b, t, h)
-

@@ -192,8 +192,16 @@ def decode_kernel_supported(L: int, H: int, D: int, *,
     return C % 128 == 0 and block_k % sub == 0
 
 
+#: Cache rows a grid step reads, halved until it tiles the cache.  The
+#: kernel alone, ms a call at 128 / 256 / 512 rows (builder's chip run,
+#: PR 29; PERF.md section 6): 0.1453 / 0.1437 / 0.1536 at gpt2-large's
+#: geometry; at EvaByte's 256 is +5 % and 512 does not fit Mosaic's
+#: 16 MB of scoped VMEM.
+_BLOCK_K = 128
+
+
 def _pick_block_k(L: int) -> int:
-    b = min(int(os.environ.get("RLT_DECODE_BLOCK_K", "128") or 128), L)
+    b = min(_BLOCK_K, L)
     while L % b:
         b //= 2
     return max(b, 1)

@@ -36,7 +36,8 @@ def fused_lm_cross_entropy(hidden, table, targets):
     materializes BOTH an fp32 [B,T,V] logits tensor (~1.6 GB at
     gpt2-small scale) and a bf16 copy saved for the softmax recompute —
     measured 3.76 ms at 2.56 GB accessed for the forward head fusion
-    alone (benchmarks/profile_headline.py roofline).  Here the head
+    alone (a pre-round roofline trace; the head and loss today:
+    ``train_head_loss_ms``, chipbench).  Here the head
     matmul emits logits in the compute dtype once, and the
     max/logsumexp/label-gather reductions upcast per-element *inside*
     their fusions (fp32 accumulators, nothing fp32 ever hits HBM).

@@ -4,8 +4,8 @@ fp32 master copy carried in the optimizer state.
 TPU-first rationale: with fp32-resident params and bf16 compute (flax
 ``dtype=bfloat16``), every forward re-casts every kernel fp32->bf16 and
 every backward produces an fp32 cotangent — on the gpt2-small headline
-that is ~8.7 ms/step of pure dtype-convert fusions (benchmarks/README.md
-device trace).  Keeping the *resident* params bf16 deletes those casts
+that is ~8.7 ms/step of pure dtype-convert fusions (a pre-round device
+trace, records since removed).  Keeping the *resident* params bf16 deletes those casts
 from the hot program and halves param HBM residency.  (It does NOT
 shrink the gradient all-reduce: the partitioner must resolve each
 cross-replica partial sum at the f32-accumulating grad dot, BEFORE the

@@ -63,8 +63,8 @@ HBM_GBPS = 819.0
 #: modeled ACHIEVED matmul rate for recompute chains — deliberately
 #: below a v5e's ~197 bf16 peak TFLOPs because remat'd forward
 #:re-execution runs inside backward fusions at well under peak MFU
-#: (calibrated against the measured gpt2-medium full-vs-dots walk,
-#: benchmarks/README.md round 4)
+#: (calibrated against a pre-round gpt2-medium full-vs-dots walk on
+#: one v5e: 177.4 vs 150.3 ms/step device; no cell re-measures it)
 DEVICE_TFLOPS = 65.0
 
 

@@ -87,9 +87,9 @@ def _sharded_elements(abstract_tree, shardings_tree) -> int:
 #: (and round-tripped): ``saved_residuals`` lists every residual at its
 #: own dtype while XLA's buffer assignment shares/dedups aggressively —
 #: calibrated against compiled ``memory_analysis`` temp deltas of the
-#: tiny-GPT programs (tests/test_plan.py remat drift leg) and the
-#: measured gpt2-medium walk (off 18.95 GB vs dots ~10 GB,
-#: benchmarks/README.md round 4)
+#: tiny-GPT programs (tests/test_plan.py remat drift leg) and a
+#: pre-round gpt2-medium walk on one v5e (off 18.95 GB vs dots ~10 GB;
+#: no cell re-measures it)
 REMAT_RESIDENCY_FACTOR = 0.3
 
 #: modeled fixed cost of one remat region's backward re-entry (extra
@@ -136,7 +136,8 @@ def link_gbps(op: str, config: PlanConfig, process_count: int) -> float:
 #: EXPOSED after XLA's latency-hiding scheduler overlaps it with
 #: adjacent compute.  Deliberately conservative (half hidden): the
 #: planner must not promise overlap the fabric can't deliver; the
-#: measured judge is bench_comm's anatomy exposed-comm A/B, and the
+#: measured judge is ``train_exposed_comm_ms`` once a cell runs on
+#: four chips (ROADMAP R11), and the
 #: declared bytes stay the full payload (only seconds are discounted —
 #: bucketing moves WHEN bytes travel, never how many).
 BUCKETED_EXPOSED_FRACTION = 0.5

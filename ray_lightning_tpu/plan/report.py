@@ -5,9 +5,8 @@ enumerated combination with its status (``pruned`` / ``rejected`` /
 ``scored`` / ``compiled`` / ``winner``) and — for pruned/rejected
 entries — the NAMED reason, plus the winner and the planning-cost
 accounting (seconds, compile-cache misses).  It surfaces in three
-places: ``trainer._plan_report`` (the dict form), the bench JSON
-``plan`` line (benchmarks/bench_plan.py), and the ``rlt_plan_*``
-metrics gauges.  The dict schema is pinned by plan/selfcheck.py.
+places: ``trainer._plan_report`` (the dict form), ``/status``, and
+the ``rlt_plan_*`` metrics gauges.  The dict schema is pinned by plan/selfcheck.py.
 """
 
 from __future__ import annotations
@@ -16,7 +15,7 @@ import dataclasses
 from typing import Optional
 
 #: top-level keys every ``PlanReport.to_dict()`` carries (schema pinned
-#: by plan/selfcheck.py; bench_plan.py and the tests consume these).
+#: by plan/selfcheck.py; the tests consume these).
 #: ``remat`` is the per-policy ladder summary at the winner's other
 #: axes (None when the module has no configure_remat() ladder).
 REPORT_KEYS = ("winner", "topk", "plan_seconds", "cache_misses",

@@ -124,8 +124,8 @@ def _worker_run(payload: tuple, rank: int, queue,
         "callback_metrics": dict(trainer.callback_metrics),
         "epoch": int(trainer.current_epoch),
         "global_step": int(trainer.global_step),
-        # startup cost as rank 0 saw it (bench.py reports it; the
-        # compile plane's cold/warm A/B is measured on this number)
+        # startup cost as rank 0 saw it (chip_smoke.py reports it;
+        # tests/test_compile_cache.py's cold/warm A/B reads this number)
         "time_to_first_step": trainer.time_to_first_step,
         # the planner's verdict when strategy="auto" ran in the workers
         # (every rank plans identically; rank 0's copy is THE report)

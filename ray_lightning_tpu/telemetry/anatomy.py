@@ -13,9 +13,8 @@ process).  This module is the measurement side: parse a captured
   collective-name / replica-group classification);
 - ``exposed_s``   — the MEASURED exposed comm: collective interval
   time not covered by any compute interval on the same device
-  timeline.  This is the number the wall-minus-floor proxy in
-  bench_comm approximates; the divergence between the two is itself a
-  finding (the proxy includes quantize/dequantize compute, the
+  timeline.  This is the number a wall-minus-floor proxy
+  approximates (the proxy includes quantize/dequantize compute, the
   measured number is pure serialization);
 - ``host_s``      — host-gap/dispatch time: window wall not covered by
   ANY device op (the host→device link, the python loop, a pipeline
@@ -28,8 +27,8 @@ The decomposition is an interval-algebra identity, not an estimate:
 because ``exposed = |collective ∖ compute|`` and ``host = wall −
 |collective ∪ compute|``.  Tests and the selfcheck pin it.
 
-ONE parser for every trace layout (`benchmarks/trace_tools.py` is a
-thin wrapper over this module):
+ONE parser for every trace layout (`chipbench/reduce.py` keeps its own
+copy of the interval algebra: the benchmark imports nothing it judges):
 
 - TPU/device traces: processes named ``/device:TPU:k`` with nested
   "XLA Ops" (per-instruction) and "XLA Modules" (per-execution)
@@ -96,7 +95,7 @@ DEFAULT_WINDOW = 4
 
 def locate_trace_json(trace_dir: str) -> str:
     """Newest ``*.trace.json.gz`` under a profiler capture dir (the ONE
-    locator — trace_tools delegates here)."""
+    locator)."""
     paths = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.trace.json.gz")))
     if not paths:
@@ -151,7 +150,7 @@ def device_track_events(trace_path: str, track: str = "XLA Ops") -> list:
 
 def bucket_of(name: str) -> str:
     """Coarse op-category for a device event name (HLO-ish).  The ONE
-    category-bucketing table (trace_tools delegates here)."""
+    category-bucketing table."""
     n = name.lower()
     if "pallas" in n or "custom-call" in n or "flash" in n:
         return "pallas/custom"
@@ -674,8 +673,7 @@ class AnatomyController:
         reg.gauge("rlt_anatomy_dcn_seconds").set(
             anatomy["collective_by_link"].get("dcn", 0.0))
         reg.counter("rlt_anatomy_windows_total").inc(1)
-        # the exposed-comm gauge's MEASURED source (satellite: the
-        # wall-minus-floor proxy only feeds it in bench legs)
+        # the exposed-comm gauge's MEASURED source
         _metrics.note_exposed_comm(anatomy["exposed_s"], source="anatomy")
 
     def stop(self) -> None:

@@ -35,8 +35,7 @@ the residual lands in ``other``, and if instrumented time ever
 overshoots the measured wall (clock skew between overlapping
 accumulators) every bucket is scaled down proportionally so the
 partition still closes.  Tests and ``telemetry/selfcheck.py`` pin the
-identity; ``benchmarks/ledger.py`` gates goodput-fraction and MFU
-regressions between rounds.
+identity; ``/status`` and ``/goodput`` serve the fractions.
 
 The useful bucket additionally carries a *sub-split* (``useful_split``,
 deliberately outside the top-level identity): the anatomy plane's

@@ -2,7 +2,7 @@
 
 Every prior observability plane is either instantaneous (``/metrics``,
 ``/status`` serve the *current* value) or post-hoc (flight rings dump at
-death, ``bench.py --compare`` gates at merge time).  This module makes
+death, the driver's ledger compares at merge time).  This module makes
 the run watch itself:
 
 - :class:`TimelineStore` — bounded per-(series, rank) ring buffers of

@@ -89,7 +89,7 @@ CORE_METRICS = (
     # gauge carries a ``source`` label naming its provenance:
     # ``anatomy`` = measured from trace-event overlap on the device
     # timelines during instrumented runs (telemetry/anatomy.py — the
-    # number of record); ``wall_minus_floor`` = bench_comm.py's
+    # number of record); ``wall_minus_floor`` = a caller's
     # differential proxy (leg wall minus the same-process fp32 floor,
     # which also pays codec quantize/dequantize compute)
     "rlt_comm_dcn_bytes_total",
@@ -611,11 +611,11 @@ def note_exposed_comm(seconds: float,
       overlap on the device timelines of a real profiler capture
       (telemetry/anatomy.py publishes it during instrumented runs;
       this is the number of record);
-    - ``"wall_minus_floor"`` — benchmarks/bench_comm.py's differential
-      proxy: the leg's wall seconds/step minus the comm-off fp32 floor
-      measured in the same process (includes codec quantize/dequantize
-      compute, so it upper-bounds the measured figure; the divergence
-      between the two series is itself a finding).
+    - ``"wall_minus_floor"`` — a caller's differential proxy: a leg's
+      wall seconds/step minus the comm-off fp32 floor measured in the
+      same process (includes codec quantize/dequantize compute, so it
+      upper-bounds the measured figure).  Nothing in the package feeds
+      it; tests/test_metrics.py pins the label.
     """
     reg = _registry
     if reg is None:

@@ -145,44 +145,6 @@ def test_fleet_and_page_config_env_roundtrip(monkeypatch):
     assert not PageConfig.resolve(False).enabled
 
 
-def test_ledger_covers_serve_and_fleet_figures():
-    """Satellite: the perf ledger gates serve-side fields (tokens/s,
-    TTFT p99) from `serve`/`fleet` records, not just fit-side steps."""
-    from benchmarks import ledger
-    prev = [{"metric": "m", "unit": "tokens/s", "value": 1,
-             "fleet": {"tokens_per_sec": 1000.0, "ttft_p99_ms": 50.0},
-             "serve": {"tokens_per_sec": 500.0, "ttft_p99_ms": 20.0}}]
-    ok = ledger.compare(prev, [{
-        "metric": "m", "unit": "tokens/s", "value": 1,
-        "fleet": {"tokens_per_sec": 950.0, "ttft_p99_ms": 55.0},
-        "serve": {"tokens_per_sec": 480.0, "ttft_p99_ms": 21.0}}])
-    assert ok["ok"] and ok["compared"] == 4, ok
-    bad = ledger.compare(prev, [{
-        "metric": "m", "unit": "tokens/s", "value": 1,
-        "fleet": {"tokens_per_sec": 700.0, "ttft_p99_ms": 90.0},
-        "serve": {"tokens_per_sec": 480.0, "ttft_p99_ms": 21.0}}])
-    assert not bad["ok"]
-    assert {x["figure"] for x in bad["regressions"]} \
-        == {"fleet.tokens_per_sec", "fleet.ttft_p99_ms"}
-    # sub-floor TTFT jitter is noise, not a regression
-    floor = ledger.compare(
-        [{"metric": "m", "serve": {"ttft_p99_ms": 1.0}}],
-        [{"metric": "m", "serve": {"ttft_p99_ms": 2.4}}])
-    assert floor["ok"], floor
-    # federated prefix reuse: a collapse regresses; a sub-floor dip
-    # (under 2 points of fraction) is replay noise
-    fed = ledger.compare(
-        [{"metric": "m", "fleet": {"federated_reuse_ratio": 0.5}}],
-        [{"metric": "m", "fleet": {"federated_reuse_ratio": 0.1}}])
-    assert not fed["ok"], fed
-    assert fed["regressions"][0]["figure"] \
-        == "fleet.federated_reuse_ratio", fed
-    fed_ok = ledger.compare(
-        [{"metric": "m", "fleet": {"federated_reuse_ratio": 0.05}}],
-        [{"metric": "m", "fleet": {"federated_reuse_ratio": 0.04}}])
-    assert fed_ok["ok"], fed_ok
-
-
 # -- paged scheduler against a fabricated fleet ----------------------------
 
 def _fake_step(sched):

@@ -5,9 +5,8 @@ Three tiers:
 
 - host-only units: the :class:`GoodputLedger` partition identity
   (``sum(buckets) == run_wall`` exact), overshoot scaling, the anatomy
-  sub-split, replay re-attribution, fleet aggregation, the env-knob
-  round-trip, and the benchmarks/ledger.py goodput bands (including the
-  bootstrap path against a real pre-goodput ``BENCH_r*.json``);
+  sub-split, replay re-attribution, fleet aggregation and the env-knob
+  round-trip;
 - local-fit integration: the default ``flops_per_step`` jaxpr pricing
   against a hand-computed GPT matmul count (within 5%);
 - distributed: the identity on a REAL 2-worker fit's per-rank and
@@ -16,7 +15,6 @@ Three tiers:
   same fault with redundancy off shows a measured replay cost.
 """
 
-import os
 import sys
 import time
 
@@ -177,96 +175,6 @@ def test_goodput_env_knobs_roundtrip_worker_env(monkeypatch):
     assert cfg.resolved_goodput_tflops() == 275.0
 
 
-# -- benchmarks/ledger.py goodput bands ----------------------------------
-
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _round(value=10.0, goodput=None, extra=None):
-    rec = {"metric": "gpt_tiny_steps_per_sec", "unit": "steps/sec",
-           "value": value}
-    if goodput is not None:
-        rec["goodput"] = goodput
-    rec.update(extra or {})
-    return [rec]
-
-
-def test_ledger_bootstraps_against_pre_goodput_blob(tmp_path):
-    """Comparing a goodput-bearing round against a pre-goodput record
-    (the last one the driver took before the goodput plane existed; an
-    older claim, its file since removed) must skip-with-note, never
-    KeyError and never gate (satellite 1)."""
-    import json
-    from benchmarks import ledger
-    prev_path = str(tmp_path / "pre_goodput.json")
-    with open(prev_path, "w") as f:
-        json.dump({"metric": "gpt2s_train_steps_per_sec_tpu",
-                   "value": 18.998, "unit": "steps/sec",
-                   "vs_baseline": 1.9, "device_ms": 49.35}, f)
-    prev_by = ledger.load_records(prev_path)
-    assert prev_by and not any(
-        isinstance(r.get("goodput"), dict) for r in prev_by.values()), \
-        "fixture blob unexpectedly already carries goodput"
-    # current round: same figures, plus the new goodput field
-    curr = [dict(rec, goodput={"fraction": 0.8, "mfu": 0.35})
-            for rec in prev_by.values()]
-    report = ledger.compare(prev_path, curr)
-    assert report["ok"], report["regressions"]
-    notes = {(s["metric"], s["figure"]): s["note"]
-             for s in report["skipped"]}
-    assert notes, "one-sided goodput figures produced no skip notes"
-    assert all("bootstrapping" in n for n in notes.values())
-    assert any(fig == "goodput.fraction" for _, fig in notes)
-    # and the reverse direction (figure dropped) notes too
-    rev = ledger.compare(curr, prev_path)
-    assert rev["ok"]
-    assert any("missing from current round" in s["note"]
-               for s in rev["skipped"])
-
-
-def test_ledger_gates_injected_goodput_regression():
-    from benchmarks import ledger
-    prev = _round(goodput={"fraction": 0.80, "mfu": 0.40})
-    # fraction 0.80 -> 0.60: -25% past the 10% band and past the 2-point
-    # absolute floor
-    bad = ledger.compare(prev, _round(goodput={"fraction": 0.60,
-                                               "mfu": 0.40}))
-    assert not bad["ok"]
-    assert [r["figure"] for r in bad["regressions"]] == ["goodput.fraction"]
-    # MFU gates independently
-    bad_mfu = ledger.compare(prev, _round(goodput={"fraction": 0.80,
-                                                   "mfu": 0.20}))
-    assert not bad_mfu["ok"]
-    assert [r["figure"] for r in bad_mfu["regressions"]] == ["goodput.mfu"]
-    # same figures -> clean pass
-    assert ledger.compare(prev, _round(goodput={"fraction": 0.80,
-                                                "mfu": 0.40}))["ok"]
-
-
-def test_ledger_goodput_floor_absorbs_small_drift():
-    """A relatively large but absolutely tiny fraction drop stays under
-    the MIN_GOODPUT_DELTA floor — wall-clock noise, not a regression."""
-    from benchmarks import ledger
-    prev = _round(goodput={"fraction": 0.010})
-    curr = _round(goodput={"fraction": 0.008})      # -20% rel, 0.002 abs
-    assert ledger.compare(prev, curr)["ok"]
-
-
-def test_ledger_gates_measured_bubble_fraction():
-    from benchmarks import ledger
-    prev = _round(extra={"measured_bubble_fraction_1f1b": 0.10})
-    worse = _round(extra={"measured_bubble_fraction_1f1b": 0.20})
-    report = ledger.compare(prev, worse)
-    assert not report["ok"]
-    assert report["regressions"][0]["figure"] == \
-        "measured_bubble_fraction_1f1b"
-    # bootstrap: bubble figure new this round -> skipped, not gated
-    boot = ledger.compare(_round(), worse)
-    assert boot["ok"]
-    assert any(s["figure"] == "measured_bubble_fraction_1f1b"
-               for s in boot["skipped"])
-
-
 # -- default flops_per_step pricing vs hand count ------------------------
 
 @pytest.mark.slow
@@ -305,8 +213,7 @@ def test_default_flops_pricing_matches_hand_computed_gpt(tmp_path, seed):
     assert abs(flops - expected) / expected < 0.05, (flops, expected)
 
 
-def test_gpt_answers_the_flops_hook_with_the_benchmarks_count(tmp_path,
-                                                               seed):
+def test_gpt_answers_the_flops_hook_with_chipbench_count(tmp_path, seed):
     """``GPTLightningModule.flops_per_step`` is the count the benchmark
     uses (chipbench/flops.py: 6 per matmul parameter and token plus
     causal attention), over the global batch the trainer saw, so

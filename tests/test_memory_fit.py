@@ -17,9 +17,9 @@ Two layers of evidence, both computed on the 8-virtual-device CPU mesh:
   deliberately NOT used: the CPU lowering materializes full attention
   scores that the TPU flash kernels never allocate.
 
-Budgets: v5e = 16 GB HBM/chip (the 8-chip mesh shapes in
-benchmarks/README.md), v4 = 32 GB/chip (BASELINE.md config #5's v4-128,
-64 chips).  A 10% headroom is reserved for XLA workspace/fragmentation.
+Budgets: v5e = 16 GB HBM/chip (one v5e-8 host's eight chips), v4 =
+32 GB/chip (BASELINE.md config #5's v4-128, 64 chips).  A 10% headroom
+is reserved for XLA workspace/fragmentation.
 """
 
 from __future__ import annotations
@@ -170,9 +170,10 @@ def test_compiled_args_match_sharded_account(audited):
 
 
 def test_fits_v5e_8(audited):
-    """Config #5's model class must fit the 8-chip v5e mesh shapes the
-    benchmarks document (benchmarks/README.md) under every sharded
-    strategy."""
+    """Config #5's model class must fit a v5e-8 (16 GB a chip) under
+    every sharded strategy: zero1 and fsdp at data=8, spmd at fsdp=4 ×
+    tensor=2 (fsdp=2 × tensor=2 does not: state alone 7.35 GB a device,
+    ~15 GB in all against the 14.4 GB budget)."""
     g_by, u_by = _shard_factors(audited["name"], audited["n_dev"])
     total = audited["compiled_args"] + _transient_bytes(
         audited["n_params"], audited["batch_local"],
@@ -249,9 +250,9 @@ def _full_state_bytes(n_params: int) -> int:
 def test_single_chip_cannot_train_this(audited):
     """The README's negative claim, kept honest: at data-parallel 1 the
     state plus a gradient tree (the irreducible training residents)
-    exceed one v5e chip's 16 GB — this workload NEEDS the sharded
-    strategies (benchmarks/README.md: 'Adam state + grads alone exceed
-    16 GB HBM at 1.3B')."""
+    exceed one v5e chip's 16 GB (Adam state + grads alone, at 1.3B,
+    even at B=1 with remat and chunked CE) — this workload NEEDS the
+    sharded strategies."""
     n = audited["n_params"]
     assert _full_state_bytes(n) + 2 * n > V5E_HBM
 

@@ -48,8 +48,7 @@ def test_plain_1f1b_bubble_ties_gpipe():
 
 def test_interleaved_1f1b_beats_gpipe_bubble():
     """The bubble win comes from interleaving: >= 4 microbatches with
-    v=2 chunks per rank must sit strictly below GPipe (the acceptance
-    comparison bench_pipeline.py emits)."""
+    v=2 chunks per rank must sit strictly below GPipe."""
     for stages, micro in ((2, 4), (2, 8), (4, 8)):
         g = sched.build_schedule("gpipe", stages, micro, 1)
         f = sched.build_schedule("1f1b", stages, micro, 2)

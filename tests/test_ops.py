@@ -7,6 +7,7 @@ tight fp32 tolerances.
 """
 
 import functools
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,11 @@ import numpy as np
 import pytest
 
 from ray_lightning_tpu.models.gpt import dot_product_attention
+from ray_lightning_tpu.ops import flash_decode
 from ray_lightning_tpu.ops.flash_attention import flash_attention
+
+#: the module: the package's ``flash_attention`` function shadows its name
+fa = sys.modules["ray_lightning_tpu.ops.flash_attention"]
 
 
 def _rand_qkv(b=2, t=128, h=2, d=32, seed=0, dtype=jnp.float32):
@@ -181,9 +186,8 @@ def test_packed_triangular_multiblock(h, d):
     triangular-grid kernels (transpose-free [B,T,C] layout at T>=2048
     in production; forced here with small blocks) — forward and grads
     must match the XLA reference."""
-    from ray_lightning_tpu.ops.flash_attention import _head_pack, _use_tri
-    assert _head_pack(d, h) > 0
-    assert _use_tri(True, 64, 64, 4)
+    assert fa._head_pack(d, h) > 0
+    assert fa._select_family(256, h, d, True, 64, 64).lse == "packed"
     q, k, v = _rand_qkv(t=256, h=h, d=d)
 
     out = flash_attention(q, k, v, causal=True, dtype=jnp.float32,
@@ -210,9 +214,9 @@ def test_packed_triangular_multiblock(h, d):
 # -- causal staircase subtiling (the round-4 single-block fast path) --------
 #
 # _sub_block auto-engages at T>=512 (the production headline runs
-# T=1024, sub=256); these tests force small sub sizes via RLT_FLASH_SUB
-# so the staircase math is pinned at CI-friendly shapes, and one case
-# pins the auto default at its threshold.
+# T=1024, sub=256); these tests lower the module's _STAIRCASE_SUB so
+# the staircase math is pinned at CI-friendly shapes, and one case
+# pins the constant's own threshold.
 
 
 # (2,64)/(3,64): packed/folded with the sm_scale fold (1/8 is a power
@@ -223,7 +227,6 @@ def test_staircase_single_block_matches_full(h, d, monkeypatch):
     """Staircase on (sub=32 at T=128) must match staircase off bit-for-
     bit on dq/dv and to fp tolerance elsewhere, and match the XLA
     reference — for BOTH the head-packed and the folded fused kernels."""
-    from ray_lightning_tpu.ops.flash_attention import _sub_block
     q, k, v = _rand_qkv(t=128, h=h, d=d)
 
     def loss(attn):
@@ -237,13 +240,12 @@ def test_staircase_single_block_matches_full(h, d, monkeypatch):
     ref = loss(lambda q, k, v: dot_product_attention(
         q, k, v, causal=True, dtype=jnp.float32))
 
-    monkeypatch.setenv("RLT_FLASH_SUB", "0")
-    assert _sub_block(128, True) == 0
+    assert fa._sub_block(128, True) == 0          # 128 < 2 x 256
     v_off = flash(q, k, v)
     g_off = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
 
-    monkeypatch.setenv("RLT_FLASH_SUB", "32")
-    assert _sub_block(128, True) == 32
+    monkeypatch.setattr(fa, "_STAIRCASE_SUB", 32)
+    assert fa._sub_block(128, True) == 32
     v_on = flash(q, k, v)
     g_on = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
 
@@ -257,12 +259,11 @@ def test_staircase_single_block_matches_full(h, d, monkeypatch):
                                    err_msg=f"d{name} staircase vs ref")
 
 
-def test_staircase_auto_threshold(monkeypatch):
-    """The auto default: off below T=512, sub=256 at T in [512, 1024]
-    (single-block territory), irrelevant past 1024 where the tiled tri
-    grid takes over — and off for non-causal always."""
+def test_staircase_auto_threshold():
+    """The constant as shipped: off below T=512, sub=256 at T in [512,
+    1024] (single-block territory), irrelevant past 1024 where the tiled
+    tri grid takes over — and off for non-causal always."""
     from ray_lightning_tpu.ops.flash_attention import _sub_block
-    monkeypatch.delenv("RLT_FLASH_SUB", raising=False)
     assert _sub_block(128, True) == 0
     assert _sub_block(256, True) == 0
     assert _sub_block(512, True) == 256
@@ -270,37 +271,23 @@ def test_staircase_auto_threshold(monkeypatch):
     assert _sub_block(1024, False) == 0
 
 
-def test_staircase_env_malformed_warns_and_defaults(monkeypatch):
-    """A typo'd opt-out like RLT_FLASH_SUB=off must warn and fall back
-    to the auto default instead of crashing at trace time
-    (ADVICE r4 #4)."""
-    from ray_lightning_tpu.ops.flash_attention import _sub_block
-    monkeypatch.setenv("RLT_FLASH_SUB", "off")
-    with pytest.warns(UserWarning, match="RLT_FLASH_SUB"):
-        assert _sub_block(512, True) == 256   # the auto default
-    monkeypatch.setenv("RLT_FLASH_SUB", "")
-    assert _sub_block(512, True) == 256       # empty: silent default
-
-
-def test_rowres_gates_factor_head_width(monkeypatch):
+def test_rowres_gates_factor_head_width():
     """The row-resident VMEM budgets were measured at w=128; wide heads
     (d >= 256 pack to w=d) must cap t·w, not t alone (ADVICE r4 #3)."""
-    from ray_lightning_tpu.ops.flash_attention import (
-        _use_row_resident, _use_row_resident_fwd)
-    monkeypatch.delenv("RLT_FLASH_ROWRES", raising=False)
-    assert _use_row_resident_fwd(8192, 128)        # the measured point
-    assert not _use_row_resident_fwd(8192, 256)    # 2x resident k/v
-    assert _use_row_resident_fwd(4096, 256)        # same t*w budget
-    assert _use_row_resident(2048, 128)
-    assert not _use_row_resident(2048, 256)
-    assert _use_row_resident(1024, 256)
-    monkeypatch.setenv("RLT_FLASH_ROWRES", "0")
-    assert not _use_row_resident_fwd(1024, 128)
+    def fam(t, d):
+        return fa._select_family(t, 1, d, True, 512, 512)
+
+    assert fam(8192, 128).fwd == "rowres"          # the measured point
+    assert fam(8192, 256).fwd == "tri_packed"      # 2x resident k/v
+    assert fam(4096, 256).fwd == "rowres"          # same t*w budget
+    assert fam(2048, 128).bwd == "rowres"
+    assert fam(2048, 256).bwd == "tri_packed"
+    assert fam(1024, 256).bwd == "rowres"
 
 
 def test_staircase_non_causal_unaffected(monkeypatch):
-    """Non-causal single block must ignore RLT_FLASH_SUB entirely."""
-    monkeypatch.setenv("RLT_FLASH_SUB", "32")
+    """Non-causal single block must ignore the staircase entirely."""
+    monkeypatch.setattr(fa, "_STAIRCASE_SUB", 32)
     q, k, v = _rand_qkv(t=128, h=2, d=64)
     out = flash_attention(q, k, v, causal=False, dtype=jnp.float32)
     ref = dot_product_attention(q, k, v, causal=False, dtype=jnp.float32)
@@ -312,16 +299,18 @@ def test_staircase_non_causal_unaffected(monkeypatch):
 def test_rowres_backward_matches_reference(rowres, sm_scale, monkeypatch):
     """The row-resident fused triangular backward (default at
     multi-block causal T<=2048) and the grid-tri pair it replaces must
-    BOTH match the reference — the env A/B pins the dispatch seam and
-    keeps the fallback path covered.  sm_scale=0.1 (not a power of
+    BOTH match the reference — ``rowres="0"`` reaches the pair the way
+    a long sequence does, by a t·w over the budgets (lowered here), and
+    keeps that path covered.  sm_scale=0.1 (not a power of
     two) exercises the no-fold scaling branches, checked against the
     full-precision einsum recipe directly (the XLA helper hardwires
     1/sqrt(d))."""
-    from ray_lightning_tpu.ops.flash_attention import (_head_pack,
-                                                       _use_row_resident)
-    monkeypatch.setenv("RLT_FLASH_ROWRES", rowres)
-    assert _use_row_resident(256) == (rowres == "1")
-    assert _head_pack(64, 2) > 0
+    if rowres == "0":
+        monkeypatch.setattr(fa, "_ROWRES_FWD_BUDGET", 0)
+        monkeypatch.setattr(fa, "_ROWRES_BWD_BUDGET", 0)
+    fam = fa._select_family(256, 2, 64, True, 64, 64)
+    assert (fam.fwd, fam.bwd) == (("rowres", "rowres") if rowres == "1"
+                                  else ("tri_packed", "tri_packed"))
     q, k, v = _rand_qkv(t=256, h=2, d=64)
     scale = sm_scale if sm_scale is not None else 64 ** -0.5
 
@@ -348,13 +337,12 @@ def test_rowres_backward_matches_reference(rowres, sm_scale, monkeypatch):
 def test_fwd_rowres_with_grid_tri_backward(monkeypatch):
     """The 2048 < T <= 8192 production combination: row-resident FORWARD
     (whose lse ships in the packed [B, H/pack, T, pack] layout) feeding
-    the grid-tri backward.  Forced at small T by disabling only the
-    backward gate — a layout drift between the two would break grads
+    the grid-tri backward.  Forced at small T by lowering only the
+    backward's budget — a layout drift between the two would break grads
     here."""
-    import sys
-    fa = sys.modules["ray_lightning_tpu.ops.flash_attention"]
-    monkeypatch.setattr(fa, "_use_row_resident", lambda t, w=128: False)
-    assert fa._use_row_resident_fwd(256)
+    monkeypatch.setattr(fa, "_ROWRES_BWD_BUDGET", 0)
+    fam = fa._select_family(256, 2, 64, True, 64, 64)
+    assert (fam.fwd, fam.bwd, fam.lse) == ("rowres", "tri_packed", "packed")
     q, k, v = _rand_qkv(t=256, h=2, d=64)
 
     def loss_flash(q, k, v):
@@ -371,6 +359,91 @@ def test_fwd_rowres_with_grid_tri_backward(monkeypatch):
     for a, b, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5,
                                    err_msg=f"d{name} fwd-rowres+tri-bwd")
+
+
+# -- which family a geometry takes (ops/flash_attention.py _select_family) --
+
+#: (t, h, d, causal, block_q, block_k) -> (fwd, bwd, lse, bq, bk, sub):
+#: the geometries the benchmark's cells lower, and one on each side of
+#: every gate.  ``sub`` is the staircase sub-block a one-block kernel
+#: runs with.
+_FAMILIES = {
+    "train_cell": ((1024, 12, 64, True, None, None),
+                   ("packed", "packed", "packed", 1024, 1024, 256)),
+    "gpt2l_prefill_256": ((256, 20, 64, True, None, None),
+                          ("packed", "packed", "packed", 256, 256, 0)),
+    "gpt2l_prefill_512": ((512, 20, 64, True, None, None),
+                          ("packed", "packed", "packed", 512, 512, 256)),
+    "gpt2l_prefill_1024": ((1024, 20, 64, True, None, None),
+                           ("packed", "packed", "packed", 1024, 1024, 256)),
+    "evabyte_window": ((2048, 32, 128, True, None, None),
+                       ("rowres", "rowres", "packed", 512, 512, 0)),
+    "causal_4096": ((4096, 12, 64, True, None, None),
+                    ("rowres", "tri_packed", "packed", 512, 512, 0)),
+    "causal_16384": ((16384, 12, 64, True, None, None),
+                     ("tri_packed", "tri_packed", "packed", 512, 512, 0)),
+    "wide_heads_4096": ((4096, 4, 256, True, None, None),
+                        ("rowres", "tri_packed", "packed", 512, 512, 0)),
+    "unpackable_1024": ((1024, 8, 96, True, None, None),
+                        ("rect", "fused", "folded", 1024, 1024, 256)),
+    "unpackable_2048": ((2048, 8, 96, True, None, None),
+                        ("tri", "tri", "folded", 512, 512, 0)),
+    "odd_heads_1024": ((1024, 3, 64, True, None, None),
+                       ("rect", "fused", "folded", 1024, 1024, 256)),
+    "bidirectional_512": ((512, 12, 64, False, None, None),
+                          ("packed", "packed", "packed", 512, 512, 0)),
+    "bidirectional_2048": ((2048, 12, 64, False, None, None),
+                           ("rect", "rect", "folded", 512, 512, 0)),
+    "unequal_blocks": ((1024, 12, 64, True, 256, 512),
+                       ("rect", "rect", "folded", 256, 512, 0)),
+    "explicit_tiles_1024": ((1024, 12, 64, True, 512, 512),
+                            ("rowres", "rowres", "packed", 512, 512, 0)),
+}
+
+
+@pytest.mark.parametrize("case", list(_FAMILIES))
+def test_select_family(case):
+    """Which kernels a geometry lowers, forward and backward, and the
+    layout of the ``lse`` that passes between them — and that the
+    forward really writes that layout (shapes only: nothing runs)."""
+    (t, h, d, causal, block_q, block_k), expect = _FAMILIES[case]
+    fam = fa._select_family(t, h, d, causal, block_q, block_k)
+    single = fam.bq == t and fam.bk == t
+    sub = fa._sub_block(t, causal) if single else 0
+    assert tuple(fam) + (sub,) == expect
+
+    x = jax.ShapeDtypeStruct((1, t, h * d), jnp.bfloat16)
+    _, lse = jax.eval_shape(
+        lambda q, k, v: fa._fwd(q, k, v, h, causal, d ** -0.5, block_q,
+                                block_k, True), x, x, x)
+    if fam.lse == "packed":
+        pack = fa._head_pack(d, h)
+        assert lse.shape == (1, h // pack, t, pack)
+    else:
+        assert lse.shape == (h, t, 1)
+
+
+def test_kernels_read_no_environment_but_decode_impl():
+    """The kernel a program lowers follows from its shapes and from
+    ``RLT_DECODE_IMPL`` (the explicit request for a decode kernel),
+    never from another variable of the process: a name read from the
+    environment at trace time is in no program's name, no cache key's
+    visible inputs and no ledger line."""
+    import ast
+    import inspect
+
+    from ray_lightning_tpu.ops import eva_attention
+    reads = {}
+    for mod in (fa, flash_decode, eva_attention):
+        src = inspect.getsource(mod)
+        found = [ast.literal_eval(n.args[0]) for n in ast.walk(ast.parse(src))
+                 if isinstance(n, ast.Call)
+                 and ast.unparse(n.func) in ("os.environ.get", "os.getenv")]
+        # every mention is one of those calls: no subscript, no alias
+        assert src.count("environ") + src.count("getenv") == len(found)
+        reads[mod.__name__.rsplit(".", 1)[-1]] = found
+    assert reads == {"flash_attention": [], "eva_attention": [],
+                     "flash_decode": ["RLT_DECODE_IMPL"]}
 
 
 # -- decode kernel tier (ops/flash_decode.py) ------------------------------
@@ -510,7 +583,7 @@ def test_decode_parity(monkeypatch, caller, geometry, positions, dtype):
     kernel), ``eva`` (``eva_decode``: the two-range bound) and the
     ``dense`` einsum of the package, each on layer LAYER of the stacked
     cache against the plain mathematics above."""
-    monkeypatch.setenv("RLT_DECODE_BLOCK_K", str(_BK))
+    monkeypatch.setattr(flash_decode, "_BLOCK_K", _BK)
     q, kc, vc = _decode_case(*_GEOMETRIES[geometry], dtype)
     pos = np.asarray(_POSITIONS[positions], np.int32)
     if caller == "eva":

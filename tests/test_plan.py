@@ -508,7 +508,15 @@ def test_auto_end_to_end_gpt_applies_remat_winner(tmp_path, seed):
 def remat_compiled_peaks():
     """Compile the tiny-GPT train step (single device, donated) under
     full / dots / off and yield each program's memory_analysis peak —
-    the measured side of the activation-model drift guard."""
+    the measured side of the activation-model drift guard.
+
+    Compiled with the CPU backend's memory-minimising scheduler: what
+    the model prices is the least a schedule must keep live, and the
+    scheduler this toolchain's CPU backend runs by default (jaxlib
+    0.9.0: ``xla_cpu_enable_concurrency_optimized_scheduler``) orders
+    for concurrency instead, under which the three programs' temp sizes
+    are one number (24,051,776 bytes give or take 128) whatever the
+    policy saves."""
     from ray_lightning_tpu.models.gpt import GPTLightningModule
 
     peaks = {}
@@ -523,7 +531,9 @@ def remat_compiled_peaks():
         abstract = jax.eval_shape(build_init_fn(module, tx),
                                   jax.random.PRNGKey(0), batch)
         jitted = jax.jit(build_train_step(module, tx), donate_argnums=0)
-        mem = jitted.lower(abstract, batch).compile().memory_analysis()
+        mem = jitted.lower(abstract, batch).compile(compiler_options={
+            "xla_cpu_enable_concurrency_optimized_scheduler": False,
+        }).memory_analysis()
         peaks[policy] = (int(mem.argument_size_in_bytes)
                          + int(mem.output_size_in_bytes)
                          + int(mem.temp_size_in_bytes)
@@ -536,9 +546,10 @@ def test_remat_drift_modeled_vs_compiled(remat_compiled_peaks):
     modeled saved-activation bytes (core/remat.py probe through
     plan/cost.py remat_terms) must track the COMPILED programs'
     memory_analysis peak deltas vs the save-nothing baseline within a
-    calibrated band (measured on this toolchain: off 1.05x, dots
-    0.52x — the model lists residuals at their own dtype while XLA's
-    buffer assignment shares buffers), and the modeled policy ordering
+    calibrated band (measured on this toolchain: off 0.83x, dots 5.2x —
+    the model lists residuals at their own dtype while XLA's buffer
+    assignment shares buffers, and of the matmul outputs ``dots`` names
+    the tiny program keeps a fifth), and the modeled policy ordering
     must match the compiled one."""
     from ray_lightning_tpu.plan.cost import remat_terms
 
@@ -557,11 +568,10 @@ def test_remat_drift_modeled_vs_compiled(remat_compiled_peaks):
     assert modeled["off"] > modeled["dots"] > modeled["full"] == 0
     assert compiled["off"] > compiled["dots"] > compiled["full"]
     # calibrated bands on the deltas vs the save-nothing program
-    for policy in ("dots", "off"):
+    for policy, (lo, hi) in {"off": (0.4, 2.0), "dots": (0.4, 8.0)}.items():
         measured_delta = compiled[policy] - compiled["full"]
         ratio = modeled[policy] / measured_delta
-        assert 0.2 <= ratio <= 4.0, (policy, modeled[policy],
-                                     measured_delta)
+        assert lo <= ratio <= hi, (policy, modeled[policy], measured_delta)
 
 
 # -- resolve_strategy surface (satellite: docstring/README drift) ----------
