@@ -25,6 +25,19 @@ At setup it:
 After setup the engine is a pure executor: ``prefill``/``decode`` calls
 carry no Python branching on request state, so the decode loop shape
 never changes (scheduler.py keeps insertion/eviction host-side).
+
+Each slot's newest token also stays on the device, as one ``int32[S]``
+vector: the decode program's output IS that vector, and every prefill
+program takes it and returns it with ``[slot]`` set to the prompt's
+first token (``_with_newest`` below wraps whatever step the family
+built).  So a decode can be queued from the vector before any token of
+the programs ahead of it has come back: ``dispatch_decode`` /
+``dispatch_prefill`` queue a program and return a handle, ``fetch``
+waits for one.  ``decode`` and ``prefill`` are the blocking pair of the
+two.  ``ServeWorker._run_ahead`` (worker.py) uses the non-blocking forms
+to keep the device one decode ahead of the scheduler, on an engine
+whose ``runs_ahead`` says that nothing but these programs touches its
+state between two plans.
 """
 
 from __future__ import annotations
@@ -50,6 +63,24 @@ from ray_lightning_tpu.telemetry import metrics as _metrics
 from ray_lightning_tpu.telemetry import span
 
 _log = logging.getLogger(__name__)
+
+
+def _with_newest(step):
+    """A prefill step that also keeps the newest-token vector:
+    ``(params, k, v, tokens, slot, length, newest) -> (k', v', first,
+    newest')`` with ``newest'[slot] = first``, around whatever
+    ``(…, length) -> (k', v', first)`` the family built."""
+    import jax
+
+    def step_fn(params, k_caches, v_caches, tokens, slot, length, newest):
+        k_caches, v_caches, first = step(params, k_caches, v_caches,
+                                         tokens, slot, length)
+        with jax.named_scope("sample"):
+            newest = jax.lax.dynamic_update_index_in_dim(
+                newest, first.astype(newest.dtype), slot, 0)
+        return k_caches, v_caches, first, newest
+
+    return step_fn
 
 
 class ServeEngine:
@@ -99,6 +130,14 @@ class ServeEngine:
         self._kv_init = None
         self._k = None
         self._v = None
+        #: int32[S] on the device: each slot's newest token (module
+        #: docstring).  Never donated: a step's tokens are read from
+        #: its handle after later programs were queued.
+        self._newest = None
+        #: where a token vector from the host is put before the decode
+        #: program sees it (None: the one device, uncommitted), so that
+        #: the program is fed one type of argument whoever feeds it
+        self._rep = None
         self._k_dtype = None
         # draft plane (spec decode)
         self.draft_kv_spec: Optional[KVCacheSpec] = None
@@ -163,6 +202,7 @@ class ServeEngine:
             kv_sh = NamedSharding(mesh, self.strategy.kv_cache_spec(mesh))
             rep = NamedSharding(mesh, P())
             multi = mesh.devices.size > 1
+            self._rep = rep if multi else None
 
         with span("weights"):
             # -- params: restored weights or a seeded fresh init --------------
@@ -211,17 +251,18 @@ class ServeEngine:
             kkw = {"out_shardings": (kv_sh, kv_sh)} if multi else {}
             self._kv_init = jax.jit(self._counted("kv_init", kv_init), **kkw)
 
-            def jit_step(name, fn, n_scalars):
+            def jit_step(name, fn, n_scalars, n_out=1):
                 kw: dict = {"donate_argnums": (1, 2)}
                 if multi:
                     kw["in_shardings"] = (
                         (param_sh, kv_sh, kv_sh) + (rep,) * n_scalars)
-                    kw["out_shardings"] = (kv_sh, kv_sh, rep)
+                    kw["out_shardings"] = (kv_sh, kv_sh) + (rep,) * n_out
                 return jax.jit(self._counted(name, fn), **kw)
 
             for b in self.buckets:
                 self._prefills[b] = jit_step(
-                    f"prefill_{b}", build_prefill_step(module, b), 3)
+                    f"prefill_{b}",
+                    _with_newest(build_prefill_step(module, b)), 4, 2)
 
             # the paged kernel needs a page table whose pages tile the
             # cache; with paging off or ragged no table is plumbed and
@@ -426,13 +467,17 @@ class ServeEngine:
         pre = AotPrecompiler.resolve()
         kv_aval = jax.ShapeDtypeStruct(kv_shape, kv_dtype)
         i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)  # noqa: E731
+        # the newest-token vector is a device array wherever a program
+        # is fed it (_put_tokens): the aval says where it lies
+        newest = jax.ShapeDtypeStruct((self.slots,), np.int32,
+                                      sharding=self._rep)
         for b, jitted in self._prefills.items():
             pre.submit(f"prefill_{b}", jitted,
                        (abstract_params, kv_aval, kv_aval,
-                        i32(1, b), i32(), i32()))
+                        i32(1, b), i32(), i32(), newest))
         pre.submit("decode", self._decode,
                    (abstract_params, kv_aval, kv_aval,
-                    i32(self.slots), i32(self.slots)))
+                    newest, i32(self.slots)))
         if self.paged is not None:
             pre.submit("suffix", self._suffix,
                        (abstract_params, kv_aval, kv_aval,
@@ -485,13 +530,18 @@ class ServeEngine:
             # by their admitting prefill anyway; this keeps even slot 0
             # pristine)
             k, v = warm("kv_init", self._kv_init)
-            for b, jitted in self._prefills.items():
-                k, v, tok = warm(f"prefill_{b}", jitted, self.params, k, v,
-                                 np.zeros((1, b), np.int32),
-                                 np.int32(0), np.int32(1))
+            # (the newest-token vector is a device array from the
+            # start, as it is in serving: _put_tokens)
             zeros = np.zeros((self.slots,), np.int32)
-            k, v, toks = warm("decode", self._decode, self.params, k, v,
-                              zeros, zeros)
+            newest = self._put_tokens(zeros)
+            for b, jitted in self._prefills.items():
+                k, v, tok, newest = warm(
+                    f"prefill_{b}", jitted, self.params, k, v,
+                    np.zeros((1, b), np.int32), np.int32(0), np.int32(1),
+                    newest)
+            k, v, newest = warm("decode", self._decode, self.params, k, v,
+                                newest, zeros)
+            toks = newest
             if self.paged is not None:
                 k, v = warm("kv_copy", self._kv_copy, k, v, np.int32(0),
                             np.int32(self.slots - 1), np.int32(1))
@@ -521,6 +571,7 @@ class ServeEngine:
             del k, v
         with span("kv_init"):
             self._k, self._v = self._kv_init()
+            self._newest = newest
             if self.spec is not None:
                 # draft-cache warmup state is garbage too: re-init
                 self._dk, self._dv = self._dkv_init()
@@ -554,22 +605,76 @@ class ServeEngine:
 
     # -- serving -----------------------------------------------------------
 
+    @property
+    def runs_ahead(self) -> bool:
+        """Whether a decode may be queued before its plan arrives
+        (worker.py ``_run_ahead``): only where the programs of a plain
+        step are all that touches the engine's state between two plans.
+        ``paged`` copies donor pages, ``spec`` runs draft rounds and
+        ``kvship`` installs imported rows in between."""
+        return self.paged is None and self.spec is None \
+            and not self.kvship
+
+    def dispatch_prefill(self, slot: int, tokens: np.ndarray, length: int,
+                         bucket: int):
+        """Queue the insertion of a request at ``slot`` (its K/V block,
+        and its first token into the newest-token vector) and return the
+        first token's handle for :meth:`fetch`."""
+        t0 = time.monotonic()
+        with span("dispatch"):
+            self._k, self._v, first, self._newest = self._prefills[bucket](
+                self.params, self._k, self._v,
+                np.asarray(tokens, np.int32), np.int32(slot),
+                np.int32(length), self._newest)
+            first.copy_to_host_async()
+        self._charge("rlt_serve_prefill_seconds_total",
+                     time.monotonic() - t0)
+        return first
+
+    def dispatch_decode(self, positions: np.ndarray,
+                        tokens: Optional[np.ndarray] = None):
+        """Queue one continuous-batching step at ``positions`` and
+        return the handle of its ``[S]`` tokens for :meth:`fetch`.
+        ``tokens`` left out, each slot is fed its newest token from the
+        vector on the device: what the programs queued so far will have
+        produced, whether or not any of it has come back."""
+        t0 = time.monotonic()
+        with span("dispatch"):
+            self._k, self._v, self._newest = self._decode(
+                self.params, self._k, self._v,
+                self._newest if tokens is None
+                else self._put_tokens(tokens),
+                np.asarray(positions, np.int32))
+            self._newest.copy_to_host_async()
+        self._charge("rlt_serve_decode_seconds_total",
+                     time.monotonic() - t0)
+        return self._newest
+
+    def _put_tokens(self, tokens: np.ndarray):
+        """A host token vector as the decode program is fed it: on the
+        device, placed like the program's own output, so that the one
+        trace and the one jit cache entry of warm-up serve a decode fed
+        from the host and one fed the vector alike."""
+        import jax
+        return jax.device_put(np.asarray(tokens, np.int32), self._rep)
+
+    def fetch(self, handle, charge: str) -> np.ndarray:
+        """Wait for a dispatched program's tokens; the wait is charged
+        to the counter ``charge`` names."""
+        import jax
+        t0 = time.monotonic()
+        with span("fetch"):
+            out = np.asarray(jax.device_get(handle))
+        self._charge(charge, time.monotonic() - t0)
+        return out
+
     def prefill(self, slot: int, tokens: np.ndarray, length: int,
                 bucket: int) -> int:
         """Insert a request at ``slot``: write its K/V block, return its
         first generated token."""
-        t0 = time.monotonic()
-        with span("dispatch"):
-            self._k, self._v, tok = self._prefills[bucket](
-                self.params, self._k, self._v,
-                np.asarray(tokens, np.int32), np.int32(slot),
-                np.int32(length))
-        import jax
-        with span("fetch"):
-            out = int(np.asarray(jax.device_get(tok)))
-        self._charge("rlt_serve_prefill_seconds_total",
-                     time.monotonic() - t0)
-        return out
+        return int(self.fetch(
+            self.dispatch_prefill(slot, tokens, length, bucket),
+            "rlt_serve_prefill_seconds_total"))
 
     def prefill_reused(self, slot: int, src_slot: int,
                        tokens: np.ndarray, length: int,
@@ -604,18 +709,8 @@ class ServeEngine:
     def decode(self, tokens: np.ndarray,
                positions: np.ndarray) -> np.ndarray:
         """One continuous-batching step: every slot advances a token."""
-        t0 = time.monotonic()
-        with span("dispatch"):
-            self._k, self._v, out = self._decode(
-                self.params, self._k, self._v,
-                np.asarray(tokens, np.int32),
-                np.asarray(positions, np.int32))
-        import jax
-        with span("fetch"):
-            toks = np.asarray(jax.device_get(out))
-        self._charge("rlt_serve_decode_seconds_total",
-                     time.monotonic() - t0)
-        return toks
+        return self.fetch(self.dispatch_decode(positions, tokens),
+                          "rlt_serve_decode_seconds_total")
 
     # -- speculative decoding ----------------------------------------------
 

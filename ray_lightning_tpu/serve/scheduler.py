@@ -170,7 +170,12 @@ class PumpClock:
     ``call_s + wait_s - worker_s`` is what the RPC cost.  An iteration
     that found nothing to plan is ``idle_s`` whole.  The phases of the
     finished iterations add up to ``wall_s``; an iteration in flight is
-    the difference."""
+    the difference.
+
+    ``ahead_hits`` / ``ahead_misses`` count the steps whose decode the
+    worker had queued before the plan arrived, and those where it had
+    to drop one or queue the plan's own (worker.py ``_run_ahead``), as
+    the step results report them."""
 
     PHASES = ("loop", "plan", "call", "wait", "apply", "idle")
 
@@ -178,6 +183,8 @@ class PumpClock:
         self._clock = clock
         self.steps = 0
         self.worker_s = 0.0
+        self.ahead_hits = 0
+        self.ahead_misses = 0
         self.seconds = dict.fromkeys(self.PHASES, 0.0)
         self._t_start: Optional[float] = None
         self._t_stop: Optional[float] = None
@@ -203,7 +210,9 @@ class PumpClock:
 
     def snapshot(self) -> dict:
         out = {f"{k}_s": v for k, v in self.seconds.items()}
-        out.update(steps=self.steps, worker_s=self.worker_s)
+        out.update(steps=self.steps, worker_s=self.worker_s,
+                   ahead_hits=self.ahead_hits,
+                   ahead_misses=self.ahead_misses)
         if self._t_start is not None:
             out["wall_s"] = (self._t_stop or self._clock()) - self._t_start
         return out

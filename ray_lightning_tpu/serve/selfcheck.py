@@ -207,6 +207,7 @@ def _check_metric_names() -> None:
                  "rlt_serve_traces_total",
                  "rlt_serve_prefill_seconds_total",
                  "rlt_serve_decode_seconds_total",
+                 "rlt_serve_decode_ahead_total",
                  "rlt_spec_acceptance_rate", "rlt_spec_drafted_total",
                  "rlt_spec_accepted_total", "rlt_spec_fallbacks_total"):
         validate_metric_name(name)

@@ -605,6 +605,8 @@ class Server:
             return t_plan, "stop"
         timing = result.get("timing") or {}
         pump.worker_s += float(timing.get("serve_step", 0.0))
+        pump.ahead_hits += timing.get("ahead") == "hit"
+        pump.ahead_misses += timing.get("ahead") == "miss"
         if ledger is not None:
             # attribution rule: a dispatch that decodes produced
             # tokens (useful); a prefill-only dispatch is context

@@ -14,13 +14,28 @@ and dispatches the same SPMD programs in the same order; rank 0 alone
 returns the produced tokens (outputs are replicated, the others return
 ``None`` to keep the RPC thin).
 
+One decode ahead: on an engine whose state only its plain programs
+touch (``ServeEngine.runs_ahead``: built without ``paged``, ``spec`` and
+``kvship``) the worker queues a plan's prefills and then the NEXT
+step's decode, fed by the token vector that stays on the device, before
+it waits for any of the plan's tokens; the next plan finds its decode
+already running (a hit) or has it dropped (a miss).  The prediction is
+a function of the plans alone, so every rank makes the same one.  Plan
+and result formats, and the programs a plan runs on the device and
+their order, are the blocking order's (``_run_plan``), which every
+other engine and every speculative round still takes.
+
 Trace plane (telemetry/tracing.py): the plan carries each request's
 trace id (prefill entries) and a slot→trace map (decode), so this
 worker's prefill/decode spans carry the ids back over the queue channel
 and the driver aggregator reassembles one span tree per request.  The
 plan may also carry a ``profile`` control dict — the on-demand
 ``jax.profiler`` window armed by ``POST /debug/profile``; every rank
-captures its own subdir for the window's step count.
+captures its own subdir for the window's step count.  A window holds
+exactly its plans' programs in plan order: the decode in flight is
+waited for before the trace starts (that plan's own runs inside it),
+and the decode after the window's last plan is held back until the
+trace has stopped.
 """
 
 from __future__ import annotations
@@ -30,7 +45,10 @@ import os
 import time
 from typing import Any, Optional
 
+import numpy as np
+
 from ray_lightning_tpu.cluster.executor import RLTExecutor
+from ray_lightning_tpu.telemetry import metrics as _metrics
 from ray_lightning_tpu.telemetry import span, spans
 from ray_lightning_tpu.telemetry.tracing import WorkerProfiler
 
@@ -48,6 +66,11 @@ class ServeWorker(RLTExecutor):
         self._hb = None
         self._telemetry_cfg = None
         self._profiler: Optional[WorkerProfiler] = None
+        #: the decode in flight ahead of its plan: (the positions it
+        #: runs at, its tokens' handle), and the positions of the next
+        #: one between its prediction and its dispatch (_run_ahead)
+        self._ahead: Optional[tuple] = None
+        self._next = None
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -138,18 +161,25 @@ class ServeWorker(RLTExecutor):
         """Execute one scheduler plan: one decode over every live slot,
         then the admitting prefills (scheduler.py plan format).
 
-        The order is load-bearing.  The decode program has static shapes,
-        so it writes K/V for EVERY slot — slots outside ``decode_slots``
-        carry ``tokens=0/positions=0`` and get a dummy write at position
-        0.  Decode never reads a same-step prefill's state (a slot
-        admitted at step k joins the decode at step k+1), so decode-first
-        lets each admitting prefill overwrite its slot's dummy entry;
-        prefill-first would let the dummy write clobber the prompt's
-        position-0 K/V just after the prefill produced it, corrupting
-        every subsequent token (position 0 is always inside the mask).
-        Free slots that are NOT admitted this step keep the dummy entry
+        The order ON THE DEVICE is load-bearing.  The decode program has
+        static shapes, so it writes K/V for EVERY slot — slots outside
+        ``decode_slots`` get a dummy write (position 0 from a plan; one
+        row past its last position from a decode queued ahead, for a
+        slot whose request has ended since).  Decode never reads a
+        same-step prefill's state (a slot admitted at step k joins the
+        decode at step k+1), so decode-first lets each admitting prefill
+        overwrite its slot's dummy entry; prefill-first would let the
+        dummy write clobber the prompt's K/V just after the prefill
+        produced it, corrupting every subsequent token.  Free slots
+        that are NOT admitted this step keep the dummy entry
         harmlessly: their next prefill rewrites the whole prefix
-        (kvcache.py invariant)."""
+        (kvcache.py invariant), and a row ahead of a slot's position is
+        rewritten by the slot's own decode before it is ever inside the
+        mask.
+
+        The order ON THE HOST (:meth:`_run_ahead`) keeps the device one
+        decode ahead of the scheduler: nothing is read back before the
+        next step's decode is queued."""
         engine = self._engine
         if engine is None:
             raise RuntimeError("serve_step before setup_serve")
@@ -160,6 +190,10 @@ class ServeWorker(RLTExecutor):
             # (POST /debug/profile, telemetry/tracing.py)
             if self._profiler is None:
                 self._profiler = WorkerProfiler(rank=self._rank)
+            # a window holds exactly its plans' programs, in plan
+            # order: the decode in flight ends before the trace starts,
+            # and this plan's runs again inside it (a miss)
+            self._drop_ahead(engine, wait=True)
             self._profiler.maybe_start(prof)
         # the step's number rides the plan: the driver pump's spans of
         # this step carry the same one (children inherit it)
@@ -167,6 +201,9 @@ class ServeWorker(RLTExecutor):
             result = self._run_plan(engine, plan)
         if self._profiler is not None:
             self._profiler.note_step()
+        # (held back over a window's last step, so that no program of
+        # the next plan starts inside the trace)
+        self._dispatch_ahead(engine)
         # this call's wall seconds in the worker, a profile window's
         # start and stop included: the pump's round trip minus this is
         # what the RPC cost
@@ -175,8 +212,10 @@ class ServeWorker(RLTExecutor):
         return result if self._rank == 0 else None
 
     def _run_plan(self, engine, plan: dict) -> dict:
-        result: dict[str, Any] = {"prefill": {}, "decode": {}}
         decode = plan.get("decode")
+        if engine.runs_ahead and not (decode or {}).get("spec"):
+            return self._run_ahead(engine, plan)
+        result: dict[str, Any] = {"prefill": {}, "decode": {}}
         if decode is not None and decode.get("spec"):
             # speculative round: k draft steps then ONE batched target
             # verify; the SCHEDULER decides acceptance from the raw
@@ -239,6 +278,97 @@ class ServeWorker(RLTExecutor):
                     rows = engine.export_kv(p["slot"], exp["bucket"])
                 result.setdefault("kv_export", {})[p["slot"]] = rows
         return result
+
+    # -- one decode ahead --------------------------------------------------
+
+    def _run_ahead(self, engine, plan: dict) -> dict:
+        """A plain plan on an engine whose ``runs_ahead`` holds: the
+        same programs in the same device order as above, and no result
+        read back before the NEXT step's decode is queued.
+
+        That decode is known now, for every slot that will still be
+        live: its token is this step's decode's output, or the first
+        token of this step's prefill — both in the engine's vector on
+        the device — and its position is this step's + 1, or the
+        prompt's length.  The scheduler can only take slots away (a
+        request ended) or add slots this very step prefilled, and the
+        program runs every slot whatever happens; so plan n+1 only says
+        which of its outputs count.
+
+        (a) The plan's decode: the one in flight, if it ran at the
+        plan's positions on every slot the plan decodes (a hit); else
+        that one is dropped and the plan's own is queued from the
+        plan's host tokens (a miss: a decode writes the same row from
+        the same token however often it runs, and a row ahead of a
+        slot's position is rewritten before it is read).  (b) The
+        plan's prefills.  (c) The next decode.  (d) Only now this
+        plan's tokens are waited for.  Every rank does the same: the
+        prediction is a function of the plans alone."""
+        result: dict[str, Any] = {"prefill": {}, "decode": {}}
+        decode = plan.get("decode")
+        ahead = None
+        handle = None
+        if decode is not None:
+            slots = decode["slots"]
+            positions = np.asarray(decode["positions"], np.int32)
+            with span("decode", traces=decode.get("traces"),
+                      slots=len(slots)):
+                flying, self._ahead = self._ahead, None
+                if flying is not None and np.array_equal(
+                        flying[0][slots], positions[slots]):
+                    ahead, handle = "hit", flying[1]
+                else:
+                    ahead = "miss"
+                    handle = engine.dispatch_decode(positions,
+                                                    decode["tokens"])
+            nxt = positions.copy()
+            nxt[slots] += 1
+        else:
+            if self._drop_ahead(engine):
+                ahead = "miss"
+            nxt = np.zeros((engine.slots,), np.int32)
+        firsts = []
+        for p in plan["prefills"]:
+            with span("prefill", trace=p.get("trace"),
+                      bucket=p["bucket"], slot=p["slot"], reused=0):
+                firsts.append(engine.dispatch_prefill(
+                    p["slot"], p["tokens"], p["length"], p["bucket"]))
+            nxt[p["slot"]] = p["length"]
+        # (a request at its last row ends there: its slot's write ahead
+        # is a dead slot's, and stays inside the cache)
+        self._next = np.minimum(nxt, engine.max_seq_len - 1)
+        if self._profiler is None or not self._profiler.on_last_step:
+            self._dispatch_ahead(engine)
+        if handle is not None:
+            toks = engine.fetch(handle, "rlt_serve_decode_seconds_total")
+            for s in decode["slots"]:
+                result["decode"][s] = int(toks[s])
+        for p, first in zip(plan["prefills"], firsts):
+            result["prefill"][p["slot"]] = int(engine.fetch(
+                first, "rlt_serve_prefill_seconds_total"))
+        if ahead is not None:
+            result["timing"] = {"ahead": ahead}
+            reg = _metrics.get_registry()
+            if reg is not None:
+                reg.counter("rlt_serve_decode_ahead_total").inc(
+                    1, result=ahead)
+        return result
+
+    def _dispatch_ahead(self, engine) -> None:
+        """Queue the decode that the last plan predicted, once."""
+        nxt, self._next = self._next, None
+        if nxt is not None:
+            with span("decode_ahead"):
+                self._ahead = (nxt, engine.dispatch_decode(nxt))
+
+    def _drop_ahead(self, engine, wait: bool = False) -> bool:
+        """Forget the decode in flight (its outputs are never read);
+        ``wait`` for it to have left the device first.  True where
+        there was one."""
+        flying, self._ahead = self._ahead, None
+        if flying is not None and wait:
+            engine.fetch(flying[1], "rlt_serve_decode_seconds_total")
+        return flying is not None
 
     # -- KV-page shipping (fleet disaggregation) ---------------------------
 

@@ -231,6 +231,12 @@ class WorkerProfiler:
         _log.info("profile: rank %d capturing %d steps -> %s",
                   self.rank, self._remaining, out_dir)
 
+    @property
+    def on_last_step(self) -> bool:
+        """The step now running is the last one the window captures:
+        the next ``note_step`` stops the trace."""
+        return self._active and self._remaining <= 1
+
     def note_step(self) -> None:
         if not self._active:
             return

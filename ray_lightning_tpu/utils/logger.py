@@ -135,7 +135,13 @@ class CSVLogger:
         if not self._started or not os.path.exists(self.path):
             return
         with open(self.path, newline="") as f:
-            old_rows = list(csv.DictReader(f))
+            reader = csv.DictReader(f)
+            old_rows = list(reader)
+        # another writer may have put columns there since this one
+        # looked (two runs sharing a root dir): keep them, or their rows
+        # could not be written back
+        self._fields.extend(k for k in reader.fieldnames or ()
+                            if k not in self._fields)
         fd, tmp = tempfile.mkstemp(dir=self.log_dir, suffix=".csv")
         try:
             with os.fdopen(fd, "w", newline="") as f:

@@ -32,6 +32,7 @@ from ray_lightning_tpu.parallel.strategy import DataParallelStrategy
 from ray_lightning_tpu.serve.buckets import pad_to_bucket
 from ray_lightning_tpu.serve.engine import ServeEngine
 from ray_lightning_tpu.serve.kvcache import KVCacheSpec
+from tests import serve_ahead
 
 ATOL = 1e-5
 MODEL = dict(vocab_size=320, hidden_size=64, num_hidden_layers=2,
@@ -269,6 +270,32 @@ def test_engine_serves_the_reference_tokens(engine, weights):
         toks[1], at[1] = seq[t], t
         got.append(int(engine.decode(toks, at)[1]))
     assert got == [int(x) for x in want[44:75]]
+    assert sum(engine.stats()["retraces"].values()) == 0
+
+
+AHEAD_LENGTHS = (13, 45, 30, 97, 27, 64)
+
+
+@pytest.mark.limit(240)
+@pytest.mark.parametrize("name", sorted(serve_ahead.SCENARIOS))
+def test_decode_ahead_serves_what_the_blocking_order_serves(engine, name):
+    """serve/worker.py ``_run_ahead`` on a state that is no row per
+    position: the same traffic through the order that waits for every
+    program and through the one that queues the next decode first.
+    Equal tokens, and at every step equal rows where a live slot can
+    read: the exact rows of its window so far and the summaries of its
+    finished chunks (the row and the summary that the decode ahead has
+    already written lie one past them).  Prompts of 27 and 30 cross
+    into their second window while they decode, 64 starts on a window's
+    edge, and a slot freed by a long request is taken by a short one."""
+    window, chunk = CFG.window_size, CFG.chunk_size
+
+    def live_rows(pos):
+        return np.concatenate([np.arange(pos % window),
+                               window + np.arange(pos // chunk)])
+
+    prompts = [_tokens(20 + i, n) for i, n in enumerate(AHEAD_LENGTHS)]
+    serve_ahead.check_equal_and_counted(engine, prompts, name, live_rows)
     assert sum(engine.stats()["retraces"].values()) == 0
 
 

@@ -125,3 +125,17 @@ def test_new_run_truncates_stale_file(tmp_path):
     assert len(rows) == 1                     # truncated, not appended
     assert rows[0]["acc"] == "0.9"
     assert "loss" not in rows[0]
+
+
+def test_a_grown_header_keeps_columns_another_writer_put_there(tmp_path):
+    """Two runs that share a root dir (tests under the default
+    ``rlt_logs``, side by side): the file one writer re-headers may hold
+    columns the other wrote since.  They are kept, not a ValueError."""
+    mine = CSVLogger(str(tmp_path))
+    mine.log_metrics({"loss": 1.0}, step=0)
+    other = CSVLogger(str(tmp_path))          # starts the file afresh
+    other.log_metrics({"val_acc": 0.9}, step=0)
+    mine.log_metrics({"loss": 0.5, "val_loss": 0.7}, step=1)
+    rows = _read(mine.path)
+    assert [r["val_acc"] for r in rows] == ["0.9", ""]
+    assert rows[1]["loss"] == "0.5" and rows[1]["val_loss"] == "0.7"

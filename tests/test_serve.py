@@ -10,6 +10,7 @@ tokens-per-second live while requests are in flight.
 """
 
 import json
+import os
 import threading
 import time
 import urllib.request
@@ -30,6 +31,7 @@ from ray_lightning_tpu.serve.engine import ServeEngine
 from ray_lightning_tpu.serve.kvcache import KVCacheSpec, SlotAllocator
 from ray_lightning_tpu.serve.scheduler import Scheduler
 from ray_lightning_tpu.serve.worker import ServeWorker
+from tests import serve_ahead
 
 
 @pytest.fixture(autouse=True)
@@ -386,6 +388,184 @@ def test_engine_zero_retraces_across_slots_lengths_buckets(engine):
         eng.trace_counts
 
 
+# -- one decode ahead of the scheduler (worker.py _run_ahead) ---------------
+
+AHEAD_PROMPTS = [np.arange(1 + i, 4 + i + (i * 3) % 7, dtype=np.int32)
+                 for i in range(5)] + [np.arange(2, 13, dtype=np.int32)]
+
+
+@pytest.mark.parametrize("name", sorted(serve_ahead.SCENARIOS))
+def test_decode_ahead_serves_what_the_blocking_order_serves(engine, name):
+    """The same traffic through the order that waits for every program
+    and through the one that queues the next decode first: equal tokens
+    (the whole-sequence forward's), equal rows under every live slot's
+    mask at every step, each step counted a hit or a miss as the order
+    predicts, and no retrace with the vector fed from the device."""
+    got = serve_ahead.check_equal_and_counted(
+        engine, AHEAD_PROMPTS, name, lambda pos: np.arange(pos))
+    if name != "eos":
+        _, waves = serve_ahead.scenario(name, engine.slots)
+        for (i, _), toks in zip([r for w in waves for r in w],
+                                got["tokens"]):
+            _assert_greedy_parity(engine, AHEAD_PROMPTS[i], toks)
+    assert sum(engine.stats()["retraces"].values()) == 0
+
+
+def _plan(eng, decode=None, prefills=()):
+    """A scheduler's plan by hand: ``decode`` is ``{slot: (token,
+    position)}``, ``prefills`` ``[(slot, prompt)]``."""
+    out = {"decode": None, "prefills": [
+        {"slot": s, "tokens": pad_to_bucket(p, 8), "length": len(p),
+         "bucket": 8} for s, p in prefills]}
+    if decode:
+        toks = np.zeros(eng.slots, np.int32)
+        at = np.zeros(eng.slots, np.int32)
+        for s, (tok, pos) in decode.items():
+            toks[s], at[s] = tok, pos
+        out["decode"] = {"tokens": toks, "positions": at,
+                         "slots": sorted(decode)}
+    return out
+
+
+def test_decode_ahead_miss_is_counted_and_still_right(engine):
+    """A plan that does not continue the one before it (here: the same
+    plan sent twice, so the decode in flight ran one position further)
+    is a miss: that decode is dropped, the plan's own runs from the
+    plan's tokens, and the tokens stay the whole-sequence forward's."""
+    counted = telemetry.enable_metrics(rank=0, pump=False).counter(
+        "rlt_serve_decode_ahead_total")
+    engine._k, engine._v = engine._kv_init()
+    worker = serve_ahead.worker_on(engine)
+    prompt = AHEAD_PROMPTS[3]
+    want = _reference(engine, prompt, 5)
+    n = len(prompt)
+    r = worker.serve_step(_plan(engine, prefills=[(1, prompt)]))
+    assert r["prefill"] == {1: want[0]} and "ahead" not in r["timing"]
+    got, aheads = [want[0]], []
+    for step, pos in enumerate([n, n, n + 1, n + 2, n + 2, n + 3]):
+        plan = _plan(engine, {1: (want[pos - n], pos)})
+        r = worker.serve_step(plan)
+        aheads.append(r["timing"]["ahead"])
+        assert r["decode"] == {1: want[pos - n + 1]}, (step, pos)
+    assert aheads == ["hit", "miss", "hit", "hit", "miss", "hit"]
+    assert (counted.value(result="hit"), counted.value(result="miss")) \
+        == (4.0, 2.0)
+
+
+@pytest.mark.parametrize("what", ["paged", "spec", "kvship"])
+def test_engines_with_state_between_plans_never_go_ahead(what, full_engine,
+                                                         monkeypatch):
+    """``paged``, ``spec`` and ``kvship`` each put work between two
+    plans that a decode queued ahead would run before: an engine built
+    with any of them says so, and the worker then takes the blocking
+    order and counts nothing."""
+    from ray_lightning_tpu.serve.fleet.pages import PageConfig
+    from ray_lightning_tpu.serve.spec import SpecConfig
+    kw = {"paged": {"paged": PageConfig(enabled=True, page_size=8)},
+          "spec": {"spec": SpecConfig(enabled=True, k=3, draft_layers=1)},
+          "kvship": {"kvship": True}}[what]
+    assert not ServeEngine(GPTLightningModule(TINY), DataParallelStrategy(),
+                           buckets=(8,), slots=4, max_seq_len=32,
+                           **kw).runs_ahead
+    assert ServeEngine(GPTLightningModule(TINY), DataParallelStrategy(),
+                       buckets=(8,), slots=4, max_seq_len=32).runs_ahead
+    # and an engine that has them, driven: no decode without its tokens
+    eng = full_engine
+    assert not eng.runs_ahead
+    fed = []
+    real = eng.dispatch_decode
+    monkeypatch.setattr(eng, "dispatch_decode", lambda positions, tokens=None:
+                        (fed.append(tokens is not None),
+                         real(positions, tokens))[1])
+    worker = serve_ahead.worker_on(eng)
+    first = worker.serve_step(_plan(eng, prefills=[(0, PROMPT)]))
+    tok = first["prefill"][0]
+    t, p = _idle(eng)
+    t[0], p[0] = tok, len(PROMPT)
+    r = worker.serve_step({"prefills": [], "decode": {
+        "tokens": t, "positions": p, "slots": [0]}})
+    assert [tok, r["decode"][0]] == _reference(eng, PROMPT, 2)
+    assert fed == [True] and worker._ahead is None
+    assert "ahead" not in first["timing"] and "ahead" not in r["timing"]
+
+
+class _Programs:
+    """A stand-in for the engine that runs nothing and records what is
+    queued and what is waited for, in order."""
+
+    runs_ahead = True
+    slots, max_seq_len = 4, 64
+
+    def __init__(self, log):
+        self.log, self.unfetched, self._n = log, set(), 0
+
+    def _queue(self, kind):
+        self._n += 1
+        self.log.append(kind)
+        self.unfetched.add((kind, self._n))
+        return kind, self._n
+
+    def dispatch_decode(self, positions, tokens=None):
+        return self._queue("decode")
+
+    def dispatch_prefill(self, slot, tokens, length, bucket):
+        return self._queue("prefill")
+
+    def fetch(self, handle, charge):
+        self.unfetched.discard(handle)
+        return np.zeros(self.slots, np.int32) if handle[0] == "decode" \
+            else np.int32(7)
+
+
+def test_a_profile_window_holds_its_plans_programs_and_nothing_else(
+        monkeypatch):
+    """What the benchmark's reduction pairs a trace's runs by
+    (chipbench/reduce.py ms_per_run_by_kind): inside a window the
+    programs queued are ``decode, prefill*`` of each of its plans in
+    plan order, nothing is in flight when the trace starts (the decode
+    queued ahead is waited for, and the plan's own runs inside), and the
+    decode after the window's last plan is queued only once the trace
+    has stopped."""
+    import jax.profiler
+
+    from ray_lightning_tpu.telemetry import scopes
+    log = []
+    eng = _Programs(log)
+    in_flight_at_start = []
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: (
+        in_flight_at_start.append(set(eng.unfetched)), log.append("start")))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: log.append("stop"))
+    monkeypatch.setattr(scopes, "write_tables", lambda d: None)
+    worker = serve_ahead.worker_on(eng)
+    p3, p4 = np.arange(1, 4), np.arange(1, 5)
+    plans = [
+        _plan(eng, prefills=[(0, p4)]),
+        _plan(eng, {0: (7, 4)}, prefills=[(1, p3)]),
+        _plan(eng, {0: (0, 5), 1: (7, 3)}),                  # window: 3
+        _plan(eng, {0: (0, 6), 1: (0, 4)}, prefills=[(2, p4), (3, p3)]),
+        _plan(eng, {0: (0, 7), 1: (0, 5), 2: (7, 4), 3: (7, 3)}),
+        _plan(eng, {0: (0, 8), 1: (0, 6), 2: (0, 5), 3: (0, 4)}),
+        _plan(eng, {0: (0, 9), 1: (0, 7), 2: (0, 6), 3: (0, 5)}),
+    ]
+    plans[2]["profile"] = {"id": "w", "steps": 3, "dir": "/nonexistent/w"}
+    monkeypatch.setattr(os, "makedirs", lambda *a, **k: None)
+    aheads = [worker.serve_step(dict(p, step=i))["timing"].get("ahead")
+              for i, p in enumerate(plans)]
+    assert in_flight_at_start == [set()]
+    inside = log[log.index("start") + 1:log.index("stop")]
+    # plan 2's decode again (a miss), then each later plan's decode is
+    # the one queued ahead during the plan before it
+    assert inside == ["decode",                          # plan 2
+                      "decode", "prefill", "prefill",    # plan 3
+                      "decode"]                          # plan 4
+    assert log[log.index("stop") + 1] == "decode"        # plan 5's, ahead
+    assert log[:log.index("start")] == [
+        "prefill", "decode",                             # plan 0, 1 ahead
+        "prefill", "decode"]                             # plan 1, 2 ahead
+    assert aheads == [None, "hit", "miss", "hit", "hit", "hit", "hit"]
+
+
 @pytest.mark.parametrize("impl", ["flash_decode", "paged"])
 def test_engine_kernel_decode_parity_and_zero_retrace(impl, monkeypatch):
     """RLT_DECODE_IMPL forces the Pallas decode kernel (interpret mode
@@ -509,7 +689,8 @@ def _case_decode_donated(eng):
 
     def step(k, v, tok, pos):
         t[0], p[0] = tok, pos
-        k, v, out = eng._decode(eng.params, k, v, t.copy(), p.copy())
+        k, v, out = eng._decode(eng.params, k, v, eng._put_tokens(t),
+                                p.copy())
         return k, v, int(np.asarray(out)[0])
 
     first = _reference(eng, PROMPT, 1)[0]
@@ -661,6 +842,14 @@ def test_e2e_two_workers_multi_tenant_live_metrics(tmp_path, seed,
         reqs = [server.submit(np.arange(1, 4 + (i % 5)), tenant=tenant)
                 for i, tenant in enumerate(
                     ["alice", "bob", "alice", "bob", "alice", "bob"])]
+        # (the six are served in ~50 ms on this box since the device runs
+        # a decode ahead: a third tenant keeps requests in flight until
+        # the scraper has seen the metrics live)
+        extra = []
+        busy_until = time.monotonic() + 60
+        while "body" not in scrape and time.monotonic() < busy_until:
+            extra.append(server.submit(np.arange(1, 6), tenant="carol"))
+            extra[-1].result(timeout=180)
         outs = [r.result(timeout=180) for r in reqs]
         t.join(timeout=60)
 
@@ -674,7 +863,7 @@ def test_e2e_two_workers_multi_tenant_live_metrics(tmp_path, seed,
             assert len(out) == 8 and r.ttft_s is not None
             _assert_greedy_parity(engine, r.tokens, out.tolist())
         sched = server.scheduler.stats()
-        assert sched["completed"] == 6
+        assert sched["completed"] == 6 + len(extra)
         assert sched["per_tenant"]["alice"]["served_tokens"] == 24
         assert sched["per_tenant"]["bob"]["served_tokens"] == 24
         assert 0 < sched["batch_occupancy"] <= 1.0
