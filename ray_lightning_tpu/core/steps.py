@@ -232,7 +232,11 @@ def build_prefill_step(module, bucket_len: int, model=None,
     method of its own, ``(tokens, length, slot, k_caches, v_caches) ->
     (logits [vocab] at length - 1, k', v')``: it needs ``length`` to
     build that state, and writes it at the slot itself.  The program's
-    signature is the same.
+    signature is the same.  Where such a model's layers are of more than
+    one kind (models/command.py: rings beside a row per position),
+    ``k_caches`` / ``v_caches`` are the small pytrees ``KVCacheSpec.
+    state`` makes, a tuple an array a kind: the step hands them through
+    as it hands the two arrays.
     """
     module.setup_model()
     if model is None:

@@ -3,6 +3,11 @@ from ray_lightning_tpu.models.boring import (
     LightningMNISTClassifier,
     RandomDataset,
 )
+from ray_lightning_tpu.models.command import (
+    Command,
+    CommandConfig,
+    CommandLightningModule,
+)
 from ray_lightning_tpu.models.evabyte import (
     EvaByte,
     EvaByteConfig,
@@ -28,6 +33,9 @@ __all__ = [
     "BoringModel",
     "LightningMNISTClassifier",
     "RandomDataset",
+    "Command",
+    "CommandConfig",
+    "CommandLightningModule",
     "EvaByte",
     "EvaByteConfig",
     "EvaByteLightningModule",

@@ -200,8 +200,8 @@ def decode_kernel_supported(L: int, H: int, D: int, *,
 _BLOCK_K = 128
 
 
-def _pick_block_k(L: int) -> int:
-    b = min(_BLOCK_K, L)
+def _pick_block_k(L: int, most: int = _BLOCK_K) -> int:
+    b = min(most, L)
     while L % b:
         b //= 2
     return max(b, 1)
@@ -209,7 +209,7 @@ def _pick_block_k(L: int) -> int:
 
 def _decode_body(pos, kb, nk, logical_base,
                  q_ref, k_ref, v_ref, o_ref, qd_ref, m_ref, l_ref, acc_ref,
-                 *, sm_scale, block_k, head_dim, rows=None):
+                 *, sm_scale, block_k, head_dim, rows=None, group=None):
     """Online-softmax update for one ``block_k``-row KV block of one
     slot, every packed head at once.  ``logical_base`` is the block's
     first LOGICAL cache row (page-table indirection moves only the
@@ -225,7 +225,14 @@ def _decode_body(pos, kb, nk, logical_base,
     module's docstring says why): ``qd_ref`` [Hp, C] the block-diagonal
     query, ``m_ref`` / ``l_ref`` [Hp, 128] the running max and sum,
     ``acc_ref`` [Hp, C] the rescaled ``p @ V``, of whose row ``h`` only
-    head ``h``'s own columns are kept (:func:`decode_scratch`)."""
+    head ``h``'s own columns are kept (:func:`decode_scratch`).
+
+    ``group`` (:func:`grouped_decode_attention`): ``group`` query heads
+    read one K/V head, so ``Hp`` query heads stand against ``Hp / group``
+    heads of ``head_dim`` lanes in a row.  Row ``h`` of the query then
+    owns the columns of K/V head ``h // group``; ``q_ref`` and ``o_ref``
+    are ``[1, Hp, head_dim]``, and the block-diagonal query is filled,
+    and the output taken back, by ``Hp / group`` static tile copies."""
     live = kb * block_k <= pos if rows is None else rows[0]
     hp, width = qd_ref.shape
 
@@ -237,13 +244,26 @@ def _decode_body(pos, kb, nk, logical_base,
         col = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
         return (col >= first) & (col < first + head_dim)
 
+    def tiles():
+        # (rows of a group's query heads, columns of its K/V head)
+        return [(slice(g * group, (g + 1) * group),
+                 slice(g * head_dim, (g + 1) * head_dim))
+                for g in range(width // head_dim)]
+
     @pl.when(kb == 0)
     def _init():
-        # the query broadcast over sublanes under the mask: no
-        # transpose, no lane slice (through float32, which holds every
-        # bfloat16 exactly)
-        q = q_ref[0].astype(jnp.float32)                    # [1, C]
-        qd_ref[:] = jnp.where(own_columns(), q, 0.0).astype(qd_ref.dtype)
+        if group is None:
+            # the query broadcast over sublanes under the mask: no
+            # transpose, no lane slice (through float32, which holds
+            # every bfloat16 exactly)
+            q = q_ref[0].astype(jnp.float32)                # [1, C]
+            qd_ref[:] = jnp.where(own_columns(), q, 0.0).astype(
+                qd_ref.dtype)
+        else:
+            qd_ref[:] = jnp.zeros_like(qd_ref)
+            for heads, cols in tiles():
+                qd_ref[heads, cols] = q_ref[0, heads, :].astype(
+                    qd_ref.dtype)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
@@ -270,6 +290,14 @@ def _decode_body(pos, kb, nk, logical_base,
             preferred_element_type=jnp.float32)             # [Hp, C]
         acc_ref[:] = alpha * acc_ref[:] + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    if group is not None:
+        @pl.when(kb == nk - 1)
+        def _final_grouped():
+            for heads, cols in tiles():
+                o_ref[0, heads, :] = (acc_ref[heads, cols]
+                                      / l_ref[heads, :1]).astype(o_ref.dtype)
+        return
 
     @pl.when(kb == nk - 1)
     def _final():
@@ -419,11 +447,87 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
     return out.reshape(B, 1, H, D)
 
 
+#: Cache rows a grid step of the GROUPED call reads, halved until it
+#: tiles the cache.  Its row is 8 K/V heads of 128 lanes (2 KB in bf16)
+#: and its scores are [128, block_k]: larger blocks pay.  The kernel
+#: alone at 32 slots, ms a call at 128 / 256 / 512 rows (builder's chip
+#: run, PR 33; PERF.md section 6): a ring of 4096 rows read whole 1.147 /
+#: 0.883 / 0.780 (its bytes' time 0.656); 6,901 of 8,960 rows (which 512
+#: does not tile) 1.997 / 1.498 (1.104); 101 rows 0.511 / 0.337.
+_GROUPED_BLOCK_K = 512
+#: the grouped call's name in the compiled program and the trace
+GROUPED_KERNEL_NAME = "gqa_decode"
+
+
+def grouped_block_k(L: int) -> int:
+    return _pick_block_k(L, _GROUPED_BLOCK_K)
+
+
+def grouped_decode_attention(q, k_cache, v_cache, bound, *, layer,
+                             dtype=jnp.bfloat16):
+    """Flash decode where ``H`` query heads read ``G`` K/V heads (query
+    head ``i`` the K/V head ``i // (H / G)``), over one layer of a
+    resident cache whose row is the ``G`` K/V heads side by side.
+
+    ``q`` [S, 1, H, D]; ``k_cache`` / ``v_cache`` [n_layer, S, L, G*D],
+    whole; ``bound`` [S] int32: slot ``s`` sees the rows ``<= bound[s]``
+    (a row-per-position cache: its position; a ring of ``L`` positions:
+    ``min(position, L - 1)``, ops/window_attention.py).  Returns [S, 1,
+    H, D] in ``dtype``.  The same body as :func:`flash_decode_attention`
+    (``_decode_body`` with ``group``), the same length-aware index_map."""
+    S, _, H, D = q.shape
+    n_layer, slots, L, C = k_cache.shape
+    if C % D or H % (C // D) or slots != S or not 0 <= layer < n_layer:
+        raise ValueError(
+            f"cache {k_cache.shape} does not hold layer {layer} of {S} "
+            f"slots x K/V heads of {D} that divide {H} query heads")
+    bk = grouped_block_k(L)
+    nk = L // bk
+    base = layer * S
+
+    def kv_map(s, kb, pos_ref):
+        return (base + s, kv_block_bound(kb, pos_ref[s], bk), 0)
+
+    def sq_map(s, kb, pos_ref):
+        return (s, 0, 0)
+
+    def kernel(bound_ref, *refs, **kw):
+        s, kb = pl.program_id(0), pl.program_id(1)
+        _decode_body(bound_ref[s], kb, pl.num_programs(1), kb * bk,
+                     *refs, **kw)
+
+    body = functools.partial(
+        kernel, sm_scale=1.0 / float(np.sqrt(D)), block_k=bk, head_dim=D,
+        group=H // (C // D))
+    body.__name__ = GROUPED_KERNEL_NAME + "_kernel"
+    out = pl.pallas_call(
+        body,
+        name=GROUPED_KERNEL_NAME,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S, nk),
+            in_specs=[pl.BlockSpec((1, H, D), sq_map),
+                      pl.BlockSpec((1, bk, C), kv_map),
+                      pl.BlockSpec((1, bk, C), kv_map)],
+            out_specs=pl.BlockSpec((1, H, D), sq_map),
+            scratch_shapes=decode_scratch(H, C, k_cache.dtype)),
+        out_shape=jax.ShapeDtypeStruct((S, H, D), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )(jnp.asarray(bound, jnp.int32), q.reshape(S, H, D),
+      # merges of leading dimensions: bitcasts, not copies
+      k_cache.reshape(n_layer * S, L, C),
+      v_cache.reshape(n_layer * S, L, C))
+    return out.reshape(S, 1, H, D)
+
+
 __all__ = [
     "NEG_INF",
     "VALID_DECODE_IMPLS",
     "decode_kernel_supported",
     "flash_decode_attention",
+    "grouped_decode_attention",
     "kv_block_bound",
     "note_decode_kernel",
     "record_decode_kernels",

@@ -165,3 +165,115 @@ def total_aux_loss(model_state) -> "jax.Array | None":
     for leaf in leaves[1:]:
         total = total + leaf
     return total
+
+
+# -- dropless serving layer (models/command.py) -------------------------------
+#
+# The capacity router above drops tokens and computes every expert for
+# ``capacity`` slots.  Serving wants neither: every pair a token chose is
+# computed, and only the pairs.  What is here is also told which experts
+# it HOLDS (``offset`` and the leading dimension of the weights): it
+# routes over all the published experts and computes the part of the
+# result its own experts give; a pair routed to an absent expert adds
+# nothing.  On one chip there is no exchange, and nothing stands in for
+# the absent chips.
+
+#: tiles (rows, contraction, columns) of the megablox grouped product on
+#: the TPU, by whether a call is a decode batch's few rows or a prompt's.
+#: One product [rows, 4096] x 16 x [4096, 4096] by hand on the v5e
+#: (builder's chip run, PR 33; PERF.md section 6), ms a call.  256 rows
+#: (32 tokens), 34 pairs on 12 experts: (128, 512, 1024) 0.751, (128,
+#: 1024, 1024) 0.739, (128, 1024, 2048) 0.710, (128, 2048, 1024) 0.742,
+#: (256, 1024, 1024) 0.811; ``jax.lax.ragged_dot`` 1.073; the hit
+#: experts' bytes' time 0.49.  65,536 rows (8192 tokens), 8,297 pairs on
+#: 16: (256, 1024, 1024) 3.005, (512, 1024, 1024) 3.154, (512, 512, 1024)
+#: 3.346, larger tiles do not fit VMEM; ``ragged_dot`` 6.439; one dense
+#: product of as many rows 1.580.
+_GMM_TILING_FEW = (128, 1024, 2048)
+_GMM_TILING_MANY = (256, 1024, 1024)
+
+
+def sigmoid_topk(h, router_w, top_k: int):
+    """``(idx [T, k] int32, w [T, k] float32)``: the ``k`` largest of
+    ``sigmoid(h @ router_w)`` over ALL the router's outputs, and their
+    weights normalised to sum to 1 (``norm_topk_prob``).  float32
+    throughout, the product at ``highest``: a rounding that swaps the
+    k-th and the (k+1)-th score swaps an expert."""
+    with jax.named_scope("moe_route"):
+        r = jnp.einsum("td,de->te", h.astype(jnp.float32),
+                       router_w.astype(jnp.float32), precision="highest")
+        top, idx = jax.lax.top_k(jax.nn.sigmoid(r), top_k)
+        return idx, top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def _grouped_dot(rows, weights, sizes, impl: str):
+    """``rows[group g's rows] @ weights[g]``, accumulated in float32 and
+    returned in ``rows``' type (the T * k rows of a prompt are eight
+    times the pairs that land here, and three float32 copies of them
+    were 3.2 GB of a prefill's scratch); rows past the groups' total are
+    undefined."""
+    if impl == "gmm":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        m = rows.shape[0]
+        tiling = _GMM_TILING_FEW if m <= 1024 else _GMM_TILING_MANY
+        tiling = (min(tiling[0], m),) + tiling[1:]
+        return gmm(rows, weights, sizes, rows.dtype, tiling)
+    return jax.lax.ragged_dot(rows, weights, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(rows.dtype)
+
+
+def grouped_dot_impl(impl: "str | None" = None) -> str:
+    """``gmm`` (megablox, the TPU) or ``ragged`` (``jax.lax.ragged_dot``,
+    everywhere else), from what the code can observe."""
+    if impl is not None:
+        return impl
+    return "gmm" if jax.devices()[0].platform == "tpu" else "ragged"
+
+
+def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
+                     valid=None, impl: "str | None" = None):
+    """What the held experts add: ``sum_{e chosen, held} w_e E_e(h)``
+    with ``E(h) = down (silu(gate h) * up h)``.
+
+    ``h`` [T, d] in the compute dtype; ``idx``, ``w`` [T, k] from
+    :func:`sigmoid_topk`; ``gate_w``, ``up_w`` [held, d, F], ``down_w``
+    [held, F, d]: experts ``offset .. offset + held`` of the published
+    ones; ``valid`` [T] bool: tokens that exist (a bucket's padding
+    routes nowhere).  Returns ``(y [T, d] float32, pairs, experts_hit)``:
+    the token-expert pairs computed here and the held experts with at
+    least one, int32 scalars.
+
+    The pairs that land here are sorted by expert (absent ones last),
+    their tokens' rows gathered, and the three products run as grouped
+    products over the sorted rows (scope ``moe_experts``); the result
+    goes back by the inverse permutation and is summed per token under
+    its weights (scope ``moe_route``: everything that is not a
+    product)."""
+    T, d = h.shape
+    k = idx.shape[1]
+    held = gate_w.shape[0]
+    impl = grouped_dot_impl(impl)
+    with jax.named_scope("moe_route"):
+        local = idx - offset
+        here = (local >= 0) & (local < held)
+        if valid is not None:
+            here = here & valid[:, None]
+        expert = jnp.where(here, local, held).reshape(T * k)
+        order = jnp.argsort(expert, stable=True)
+        sizes = jnp.sum(expert[:, None] == jnp.arange(held)[None, :],
+                        axis=0, dtype=jnp.int32)
+        rows = jnp.take(h, order // k, axis=0)              # [T*k, d]
+    with jax.named_scope("moe_experts"):
+        a = jax.nn.silu(_grouped_dot(rows, gate_w, sizes, impl)) \
+            * _grouped_dot(rows, up_w, sizes, impl)
+        y = _grouped_dot(a, down_w, sizes, impl)
+    with jax.named_scope("moe_route"):
+        pairs = jnp.sum(sizes)
+        y = jnp.where((jnp.arange(T * k) < pairs)[:, None], y, 0)
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        y = jnp.take(y, back, axis=0).reshape(T, k, d)
+        out = jnp.einsum("tk,tkd->td", jnp.where(here, w, 0.0), y,
+                         preferred_element_type=jnp.float32)
+    return out, pairs, jnp.sum(sizes > 0, dtype=jnp.int32)

@@ -22,6 +22,13 @@ At setup it:
    zero-retrace-after-warmup acceptance evidence, alongside the
    compile-cache hit counters.
 
+The cache is the two arrays ``[n_layer, S, R, C]`` for a model whose
+layers are of one kind, and a small pytree each (a tuple an array a kind,
+an int32 accumulator behind the keys' where the model asked for one:
+``module.serve_counters``) for one whose layers keep caches of their own
+(serve/kvcache.py ``KVCacheSpec.kinds`` / ``state``): one description,
+and the same programs, donation and warm-up either way.
+
 After setup the engine is a pure executor: ``prefill``/``decode`` calls
 carry no Python branching on request state, so the decode loop shape
 never changes (scheduler.py keeps insertion/eviction host-side).
@@ -192,9 +199,10 @@ class ServeEngine:
                 abstract_params, dummy)
             k_avals = [k for k, _ in kv_layer_pairs(cap["kv_cache"])]
             self.kv_spec = KVCacheSpec.from_capture(
-                k_avals, self.slots, self.max_seq_len)
+                k_avals, self.slots, self.max_seq_len,
+                counters=len(getattr(module, "serve_counters", ())))
             kv_dtype = self._k_dtype = k_avals[0].dtype
-            if self.kv_spec.rows is not None:
+            if self.kv_spec.own_state:
                 self._check_own_state(module)
 
             param_sh = self.strategy._shardings_with(
@@ -242,11 +250,12 @@ class ServeEngine:
 
             # -- programs ------------------------------------------------------
             import jax.numpy as jnp
-            shape = self.kv_spec.shape
+            kv_spec = self.kv_spec
 
             def kv_init():
-                z = jnp.zeros(shape, kv_dtype)
-                return z, z
+                # (k, v): the two arrays, or a small pytree each where
+                # the layers are of more than one kind (serve/kvcache.py)
+                return kv_spec.state(jnp.zeros, kv_dtype)
 
             kkw = {"out_shardings": (kv_sh, kv_sh)} if multi else {}
             self._kv_init = jax.jit(self._counted("kv_init", kv_init), **kkw)
@@ -431,13 +440,14 @@ class ServeEngine:
             # compiled-once story is built on)
             param_avals = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.params)
-            pre = self._submit_precompiles(jax, param_avals, shape,
-                                           kv_dtype)
+            pre = self._submit_precompiles(
+                jax, param_avals,
+                kv_spec.state(jax.ShapeDtypeStruct, kv_dtype))
         self._warm(jax, pre)
         _log.info(
             "serve engine ready in %.2fs: mesh=%s buckets=%s slots=%d "
             "kv=%s (%.1f MB)", time.monotonic() - t0, dict(mesh.shape),
-            self.buckets, self.slots, shape,
+            self.buckets, self.slots, self.kv_spec.shapes,
             self.kv_spec.nbytes(np.dtype(kv_dtype).itemsize) / 2**20)
         return self
 
@@ -454,18 +464,19 @@ class ServeEngine:
         if self.paged is not None or self.kvship or self.spec is not None:
             raise ValueError(
                 f"{type(module).__name__} keeps its own kind of cache "
-                f"rows ({self.kv_spec.rows} a slot, not a row per "
-                f"position): paged=, kvship= and spec= copy or replay "
-                f"rows by position and are refused")
+                f"rows ({[s[2] for s in self.kv_spec.shapes]} a slot, not "
+                f"a row per position): paged=, kvship= and spec= copy or "
+                f"replay rows by position and are refused")
 
-    def _submit_precompiles(self, jax, abstract_params, kv_shape,
-                            kv_dtype) -> AotPrecompiler:
+    def _submit_precompiles(self, jax, abstract_params,
+                            kv_avals) -> AotPrecompiler:
         """Background-compile every program through the persistent cache
         (no-op when the cache is inactive, compile/aot.py): the AOT
         thread lowers and compiles (or loads) them while this thread
         goes on."""
         pre = AotPrecompiler.resolve()
-        kv_aval = jax.ShapeDtypeStruct(kv_shape, kv_dtype)
+        k_aval, v_aval = kv_avals
+        kv_dtype = self._k_dtype
         i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)  # noqa: E731
         # the newest-token vector is a device array wherever a program
         # is fed it (_put_tokens): the aval says where it lies
@@ -473,17 +484,17 @@ class ServeEngine:
                                       sharding=self._rep)
         for b, jitted in self._prefills.items():
             pre.submit(f"prefill_{b}", jitted,
-                       (abstract_params, kv_aval, kv_aval,
+                       (abstract_params, k_aval, v_aval,
                         i32(1, b), i32(), i32(), newest))
         pre.submit("decode", self._decode,
-                   (abstract_params, kv_aval, kv_aval,
+                   (abstract_params, k_aval, v_aval,
                     newest, i32(self.slots)))
         if self.paged is not None:
             pre.submit("suffix", self._suffix,
-                       (abstract_params, kv_aval, kv_aval,
+                       (abstract_params, k_aval, v_aval,
                         i32(), i32(), i32()))
             pre.submit("kv_copy", self._kv_copy,
-                       (kv_aval, kv_aval, i32(), i32(), i32()))
+                       (k_aval, v_aval, i32(), i32(), i32()))
         if self.spec is not None:
             dp_avals = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
@@ -498,7 +509,7 @@ class ServeEngine:
                        (dp_avals, dkv_aval, dkv_aval,
                         i32(self.slots), i32(self.slots)))
             pre.submit("verify", self._verify,
-                       (abstract_params, kv_aval, kv_aval,
+                       (abstract_params, k_aval, v_aval,
                         i32(self.slots, self.spec.k + 1),
                         i32(self.slots, self.spec.k + 1)))
         if self.kvship:
@@ -506,7 +517,7 @@ class ServeEngine:
             for b, jitted in self._kv_imports.items():
                 rows = jax.ShapeDtypeStruct((nl, 1, b, width), kv_dtype)
                 pre.submit(f"kv_import_{b}", jitted,
-                           (kv_aval, kv_aval, rows, rows, i32()))
+                           (k_aval, v_aval, rows, rows, i32()))
         return pre
 
     def _warm(self, jax, pre: AotPrecompiler) -> None:
@@ -797,6 +808,17 @@ class ServeEngine:
             self._k, self._v, np.asarray(k_rows).astype(dt),
             np.asarray(v_rows).astype(dt), np.int32(slot))
 
+    def _counters(self, jax) -> dict:
+        """What the model's steps counted on the device
+        (``module.serve_counters`` names the accumulator's entries; it
+        rides behind the keys' arrays, serve/kvcache.py): read here,
+        where the stats are asked, after whatever is queued."""
+        names = getattr(self.module, "serve_counters", ())
+        if not names or self._k is None:
+            return {}
+        got = np.asarray(jax.device_get(self._k[-1]))
+        return {"counters": {n: int(x) for n, x in zip(names, got)}}
+
     @staticmethod
     def _charge(name: str, seconds: float) -> None:
         reg = _metrics.get_registry()
@@ -820,6 +842,7 @@ class ServeEngine:
                        "count": jax.device_count()},
             "memory_stats": dev.memory_stats(),
             "decode_kernel": self.decode_kernel,
+            **self._counters(jax),
             "traces": dict(self.trace_counts),
             # traces since the warmup snapshot: 0 everywhere = the
             # decode loop never re-traced while serving

@@ -23,6 +23,19 @@ from_capture`` reads it off the model's prefill capture):
   are the same; the model's own ``prefill`` and ``decode`` methods write
   and read them.  A prefix of a prompt is NOT a prefix of this state:
   prefix reuse, paging and KV shipping refuse such a model.
+- **two kinds of layer, a cache of its own each** (models/command.py,
+  ops/window_attention.py): a sliding layer keeps a ring of ``window``
+  rows a slot (position ``t`` in row ``t % window``), a full layer a row
+  per position.  Layers of one kind share a pair of arrays, so the state
+  is a SHORT TUPLE of pairs, ``[n_sliding, S, window, C]`` and ``[n_full,
+  S, positions, C]``: ``KVCacheSpec.kinds``.  The keys' and the values'
+  arrays travel as two small pytrees (a tuple an array a kind) through
+  the same programs, donated like the one pair; a model with one kind is
+  the one-element case and its programs see the two bare arrays they
+  always saw.  A model may ask for a small int32 accumulator beside them
+  (``counters``: what its steps count on the device, read with the
+  server's stats and never inside a step); it rides behind the keys'
+  arrays.
 
 There is ONE layout, the one the decode kernels read: a row is a
 token's heads side by side on the lane axis, which is how the qkv
@@ -66,30 +79,65 @@ class KVCacheSpec:
     ``max_seq_len`` is the positions a slot's sequence may reach;
     ``rows`` the rows a slot holds per layer: None for a model that
     keeps a row per position (``max_seq_len`` rows), the model's own
-    count otherwise (module docstring)."""
+    count otherwise (module docstring).  ``kinds``: ``((n_layer, rows),
+    ...)`` where layers of more than one kind keep a cache of their own
+    each (empty: the one kind ``n_layer`` and ``rows`` describe);
+    ``counters``: the length of the int32 accumulator a model asked
+    for beside the cache (0: none)."""
 
     n_layer: int
     slots: int
     max_seq_len: int
     width: int
     rows: "int | None" = None
+    kinds: "tuple[tuple[int, int], ...]" = ()
+    counters: int = 0
+
+    @property
+    def own_state(self) -> bool:
+        """Whether a slot's rows are the model's own kind and not a row
+        per position (module docstring)."""
+        return self.rows is not None or bool(self.kinds)
+
+    @property
+    def shapes(self) -> "tuple[tuple[int, int, int, int], ...]":
+        """``[n_layer, S, R, C]`` of each kind's pair of arrays."""
+        kinds = self.kinds or ((
+            self.n_layer,
+            self.max_seq_len if self.rows is None else self.rows),)
+        return tuple((n, self.slots, rows, self.width)
+                     for n, rows in kinds)
 
     @property
     def shape(self) -> tuple[int, int, int, int]:
         """``[n_layer, S, R, C]`` — THE shape every serve program and
-        the decode kernels share."""
-        return (self.n_layer, self.slots,
-                self.max_seq_len if self.rows is None else self.rows,
-                self.width)
+        the decode kernels share, where the layers are of one kind."""
+        if len(self.kinds) > 1:
+            raise ValueError(
+                f"the cache holds {len(self.kinds)} kinds of layer, "
+                f"{self.shapes}: it has no one shape")
+        return self.shapes[0]
 
     def nbytes(self, itemsize: int = 2) -> int:
-        """Device residency of BOTH cache arrays (k and v) at the given
-        element size (bf16 default)."""
-        return 2 * int(np.prod(self.shape, dtype=np.int64)) * itemsize
+        """Device residency of BOTH cache arrays (k and v) of every kind
+        at the given element size (bf16 default)."""
+        return sum(2 * int(np.prod(shape, dtype=np.int64)) * itemsize
+                   for shape in self.shapes)
+
+    def state(self, make, dtype):
+        """``(k, v)`` as every serve program takes and returns them,
+        each leaf ``make(shape, dtype)`` (zeros, or an aval).  One kind
+        and no accumulator: the two bare arrays.  Otherwise a tuple an
+        array a kind, the int32 accumulator behind the keys'."""
+        kinds = tuple(make(shape, dtype) for shape in self.shapes)
+        if len(kinds) == 1 and not self.counters:
+            return kinds[0], kinds[0]
+        extra = (make((self.counters,), np.int32),) if self.counters else ()
+        return kinds + extra, kinds
 
     @classmethod
-    def from_capture(cls, kv_shapes, slots: int,
-                     max_seq_len: int) -> "KVCacheSpec":
+    def from_capture(cls, kv_shapes, slots: int, max_seq_len: int,
+                     counters: int = 0) -> "KVCacheSpec":
         """Derive the cache geometry from a prefill ``eval_shape``
         capture: ``kv_shapes`` is any per-layer K aval list (core/steps.py
         _stacked_kv order).  An entry shaped ``[B, T, C]`` is a row per
@@ -97,16 +145,25 @@ class KVCacheSpec:
         An entry shaped ``[B, 1, R, C]`` is a model's own state block,
         as its ``prefill`` method writes it at a slot: ``R`` rows a
         slot, whatever ``max_seq_len`` (the model sized it from its own
-        configuration's positions, which the engine checks)."""
+        configuration's positions, which the engine checks).  Blocks of
+        differing ``R`` are layers of differing kinds: one kind a
+        distinct ``R``, in the order of each kind's first layer, which
+        is the order the model finds its arrays in."""
         n_layer = len(kv_shapes)
         if n_layer == 0:
             raise ValueError("model captured no kv_cache entries; does "
                              "its attention sow the 'kv_cache' "
                              "collection? (ops/attention.py)")
-        shape = tuple(kv_shapes[0].shape)
-        rows = int(shape[2]) if len(shape) == 4 else None
+        per_layer = [int(k.shape[2]) if len(k.shape) == 4 else None
+                     for k in kv_shapes]
+        distinct = tuple(dict.fromkeys(per_layer))
+        one_kind = len(distinct) == 1
         return cls(n_layer=n_layer, slots=slots, max_seq_len=max_seq_len,
-                   width=int(shape[-1]), rows=rows)
+                   width=int(kv_shapes[0].shape[-1]),
+                   rows=distinct[0] if one_kind else None,
+                   kinds=() if one_kind else tuple(
+                       (per_layer.count(r), r) for r in distinct),
+                   counters=counters)
 
 
 class SlotAllocator:

@@ -747,6 +747,12 @@ class Server:
                 out["workers"] = self._wait_all(
                     [w.call("serve_stats") for w in self._workers],
                     timeout=60)
+                # what the model's steps counted on the device
+                # (serve/engine.py ``_counters``), beside the scheduler's
+                # own counts of the same steps
+                counted = out["workers"][0].get("counters")
+                if counted is not None:
+                    out["scheduler"]["device_counters"] = counted
             except Exception:
                 _log.warning("serve_stats failed", exc_info=True)
         return out

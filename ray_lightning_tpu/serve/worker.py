@@ -124,12 +124,15 @@ class ServeWorker(RLTExecutor):
             spec.max_seq_len, seed=spec.seed, weights=weights,
             paged=getattr(spec, "paged", None),
             spec=sp, kvship=bool(kvship)).setup()
+        shapes = self._engine.kv_spec.shapes
         return {
             "rank": rank,
             "mesh": dict(self._engine._mesh.shape),
             "buckets": list(self._engine.buckets),
             "slots": self._engine.slots,
-            "kv_shape": list(self._engine.kv_spec.shape),
+            # one kind of layer: its [n_layer, S, R, C]; more: a list of them
+            "kv_shape": list(shapes[0]) if len(shapes) == 1
+            else [list(s) for s in shapes],
             "stats": self._engine.stats(),
         }
 

@@ -48,11 +48,15 @@ SCOPES = ("embed", "attn", "mlp", "ln", "lm_head", "loss", "optimizer",
           "kv_cache", "sample")
 
 #: finer names inside a scope, for the parts of a layer that a reader
-#: wants apart (ops/eva_attention.py: a chunk's pooling, the attention).
+#: wants apart (ops/eva_attention.py: a chunk's pooling, the attention;
+#: ops/moe.py: an expert layer's routing, products and shared experts).
 #: They are NOT scopes of the table above: readers of that table know
 #: its short list and refuse another name, so a second table, ``"fine"``
 #: in the file, places the operations that lie under one of these
-FINE_SCOPES = ("eva_summary", "eva_attn")
+FINE_SCOPES = ("eva_summary", "eva_attn",
+               # ops/moe.py dropless_experts, models/command.py: what is
+               # not a product, the grouped products, the shared experts
+               "moe_route", "moe_experts", "moe_shared")
 
 #: the file of tables written beside a captured trace
 TABLE_FILE = "op_scopes.json"

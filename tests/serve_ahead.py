@@ -63,11 +63,28 @@ def worker_on(eng) -> ServeWorker:
     return worker
 
 
+def _kinds(state) -> list:
+    """The cache's arrays a kind (serve/kvcache.py): the one array, or a
+    tuple's (an accumulator behind them is no rows)."""
+    if isinstance(state, tuple):
+        return [np.asarray(a) for a in state if a.ndim == 4]
+    return [np.asarray(state)]
+
+
+def _live(arrays: list, slot: int, at) -> np.ndarray:
+    """A slot's live rows of every kind, flat: ``at`` is the rows of the
+    one kind, or a list of them a kind."""
+    at = at if isinstance(at, (list, tuple)) else [at]
+    return np.concatenate([a[:, slot, rows].reshape(-1)
+                           for a, rows in zip(arrays, at)])
+
+
 def drive(eng: ServeEngine, prompts, waves, live_rows, *, ahead: bool,
           **sched_kw) -> dict:
     """One run from a zeroed cache.  ``live_rows(position)`` lists the
     cache rows of a slot that a decode at ``position`` may read and that
-    were written before it (the rows to compare).  Returns ``{"tokens":
+    were written before it (the rows to compare; a list of them a kind
+    where the cache holds more than one kind of layer).  Returns ``{"tokens":
     [per request], "ahead": [per step], "decoded": [per step: did the
     plan decode], "rows": {(step, slot): (k rows, v rows)}}``."""
     eng._k, eng._v = eng._kv_init()
@@ -89,11 +106,11 @@ def drive(eng: ServeEngine, prompts, waves, live_rows, *, ahead: bool,
                 sched.apply(plan, result)
                 aheads.append(result["timing"].get("ahead"))
                 decoded.append(plan["decode"] is not None)
-                k, v = np.asarray(eng._k), np.asarray(eng._v)
+                k, v = _kinds(eng._k), _kinds(eng._v)
                 for slot, r in sched._by_slot.items():
                     at = live_rows(r.pos)
-                    rows[len(aheads) - 1, slot] = (k[:, slot, at],
-                                                   v[:, slot, at])
+                    rows[len(aheads) - 1, slot] = (_live(k, slot, at),
+                                                   _live(v, slot, at))
             assert sched.idle()
     assert all(r.done() for r in reqs)
     return {"tokens": [r.result(1).tolist() for r in reqs],
