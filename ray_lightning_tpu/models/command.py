@@ -36,9 +36,11 @@ G*D]``.  The model brings the two methods that touch it:
   attends (ops/window_attention.py).
 
 Both also add to a small int32 accumulator that rides the donated state
-(``SERVE_COUNTERS``): runs, token-expert pairs computed here and experts
-hit, apart for decode runs and prefills, read where the server's stats
-are asked and never inside a step.
+(``SERVE_COUNTERS``): runs, token-expert pairs computed here, experts
+hit and the rows pushed through the grouped products (over the pairs:
+how many pieces ``dropless_experts`` ran), apart for decode runs and
+prefills, read where the server's stats are asked and never inside a
+step.
 
 A prefix of a prompt is a prefix of a full layer's rows but not of a
 wrapped ring, so prefix reuse, the paged kernel, KV shipping and the
@@ -63,8 +65,9 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 
 #: the accumulator's entries (serve/engine.py ``stats()['counters']``)
 SERVE_COUNTERS = ("decode_runs", "decode_moe_pairs",
-                  "decode_moe_experts_hit", "prefill_runs",
-                  "prefill_moe_pairs", "prefill_moe_experts_hit")
+                  "decode_moe_experts_hit", "decode_moe_rows",
+                  "prefill_runs", "prefill_moe_pairs",
+                  "prefill_moe_experts_hit", "prefill_moe_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,7 +264,7 @@ def _with_kind(caches: tuple, kind: int, one) -> tuple:
 class ExpertLayer(nn.Module):
     """The routed experts held here and the shared experts.  ``h`` [T,
     d] float32 (the norm's output: the router reads it so).  Returns
-    ``(y [T, d] float32, pairs, experts_hit)``."""
+    ``(y [T, d] float32, (pairs, experts_hit, rows))``."""
 
     config: CommandConfig
 
@@ -278,7 +281,7 @@ class ExpertLayer(nn.Module):
         down = self.param("down", init, (held, F, d))
         idx, w = moe.sigmoid_topk(h, router, cfg.num_experts_per_tok)
         hc = h.astype(cfg.dtype)
-        y, pairs, hit = moe.dropless_experts(
+        y, *counts = moe.dropless_experts(
             hc, idx, w, gate.astype(cfg.dtype), up.astype(cfg.dtype),
             down.astype(cfg.dtype), offset=cfg.expert_offset, valid=valid)
         with jax.named_scope("moe_shared"):
@@ -289,7 +292,7 @@ class ExpertLayer(nn.Module):
             a = nn.silu(dense(n * F, "shared_gate")(hc)) \
                 * dense(n * F, "shared_up")(hc)
             y = y + dense(d, "shared_down")(a).astype(jnp.float32) / n
-        return y, pairs, hit
+        return y, tuple(counts)
 
 
 class CommandBlock(nn.Module):
@@ -300,7 +303,8 @@ class CommandBlock(nn.Module):
     def __call__(self, x, *, cache=None, valid=None, **where):
         """``x`` [B, T, d] float32.  ``where``: ``positions`` (decode) or
         ``slot`` and ``length`` (prefill), with ``cache=(k_caches,
-        v_caches)``.  Returns ``(x', cache, (pairs, experts_hit))``."""
+        v_caches)``.  Returns ``(x', cache, (pairs, experts_hit,
+        rows))``."""
         cfg = self.config
         B, T, d = x.shape
         with jax.named_scope("ln"):
@@ -310,14 +314,14 @@ class CommandBlock(nn.Module):
         if cache is not None:
             a, cache = a
         with jax.named_scope("mlp"):
-            m, pairs, hit = ExpertLayer(cfg, name="moe")(
+            m, counts = ExpertLayer(cfg, name="moe")(
                 h.reshape(B * T, d),
                 None if valid is None else valid.reshape(B * T))
         with jax.named_scope("attn"):
             x = x + a.astype(jnp.float32)
         with jax.named_scope("mlp"):
             x = x + m.reshape(B, T, d)
-        return x, cache, (pairs, hit)
+        return x, cache, counts
 
 
 def _split_state(k_caches):
@@ -328,11 +332,11 @@ def _split_state(k_caches):
     return tuple(k_caches), None
 
 
-def _count(counters, first: int, pairs, hit):
+def _count(counters, first: int, pairs, hit, rows):
     if counters is None:
         return ()
-    add = jnp.stack([jnp.ones((), jnp.int32), pairs, hit])
-    return (counters.at[first:first + 3].add(add.astype(counters.dtype)),)
+    add = jnp.stack([jnp.ones((), jnp.int32), pairs, hit, rows])
+    return (counters.at[first:first + 4].add(add.astype(counters.dtype)),)
 
 
 class Command(nn.Module):
@@ -382,13 +386,14 @@ class Command(nn.Module):
         valid = jnp.arange(tokens.shape[1])[None, :] < length
         x = self._embed(tokens)
         pairs = hit = jnp.zeros((), jnp.int32)
+        rows = 0
         for blk in self.blocks:
-            x, state, (p, e) = blk(x, cache=state, valid=valid, slot=slot,
-                                   length=length)
-            pairs, hit = pairs + p, hit + e
+            x, state, (p, e, r) = blk(x, cache=state, valid=valid,
+                                      slot=slot, length=length)
+            pairs, hit, rows = pairs + p, hit + e, rows + r
         last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
         return (self._head(last)[0, 0],
-                state[0] + _count(counters, 3, pairs, hit), state[1])
+                state[0] + _count(counters, 4, pairs, hit, rows), state[1])
 
     def decode(self, tokens, positions, k_caches, v_caches,
                page_table=None, slots=None):
@@ -404,11 +409,12 @@ class Command(nn.Module):
         state = (kinds, tuple(v_caches))
         x = self._embed(tokens[:, None])
         pairs = hit = jnp.zeros((), jnp.int32)
+        rows = 0
         for blk in self.blocks:
-            x, state, (p, e) = blk(x, cache=state, positions=positions)
-            pairs, hit = pairs + p, hit + e
+            x, state, (p, e, r) = blk(x, cache=state, positions=positions)
+            pairs, hit, rows = pairs + p, hit + e, rows + r
         return (self._head(x)[:, 0],
-                state[0] + _count(counters, 0, pairs, hit), state[1])
+                state[0] + _count(counters, 0, pairs, hit, rows), state[1])
 
 
 class CommandLightningModule(LightningModule):

@@ -177,6 +177,15 @@ def total_aux_loss(model_state) -> "jax.Array | None":
 # result its own experts give; a pair routed to an absent expert adds
 # nothing.  On one chip there is no exchange, and nothing stands in for
 # the absent chips.
+#
+# "Dropless" has no smaller static bound than ``T * k`` rows: every token
+# may put all its ``k`` pairs on held experts.  A decode batch pays that
+# bound (256 rows); a prompt does not.  Its ``T * k`` pairs are sorted,
+# the held ones first and by expert, and the sorted order is cut into
+# PIECES of ``C`` rows (``_piece_rows``); piece ``j`` holds sorted places
+# ``[jC, (j + 1)C)`` and runs only when ``pairs > jC``, so a chip that
+# holds an eighth of the experts moves about an eighth of the rows and a
+# chip that holds them all still computes every pair.
 
 #: tiles (rows, contraction, columns) of the megablox grouped product on
 #: the TPU, by whether a call is a decode batch's few rows or a prompt's.
@@ -191,6 +200,24 @@ def total_aux_loss(model_state) -> "jax.Array | None":
 #: product of as many rows 1.580.
 _GMM_TILING_FEW = (128, 1024, 2048)
 _GMM_TILING_MANY = (256, 1024, 1024)
+#: sorted rows up to which the layer is one piece (where ``_grouped_dot``
+#: changes tilings), and the share of the sorted rows a piece holds
+#: beyond.  The layer alone by hand on the v5e (builder's chip runs, PR
+#: 34; PERF.md section 6), 7168 tokens of which 6,900 exist, 6,773 pairs
+#: on 16 of 128 experts, ms a call: all 57,344 rows moved (PR 33's layer)
+#: 17.90; pieces of a quarter (14,336 rows, one runs) 11.52, an eighth
+#: (7,168, one) 9.64, a sixteenth (3,584, two) 9.54, a thirty-second
+#: (1,792, four) 10.16.  A sixteenth never moves more rows than an eighth
+#: and moves fewer where the pairs pass an eighth by a little: 8192
+#: tokens, 8,257 pairs: 20.47 as it was, an eighth 13.76 (two pieces), a
+#: sixteenth 12.42 (three); 6144 tokens, 5,897 pairs: 15.94, 8.53, 8.49.
+#: Past a sixteenth the pieces' own cost shows.  Every pair held (7168
+#: tokens on 16 of 16 experts, 57,344 pairs, all sixteen pieces): 53.79
+#: as it was, 59.03 in pieces (an eighth 58.64): what the shape's choice
+#: costs a chip that holds all its experts; no cell measures it.  A
+#: decode batch (32 tokens, one piece) 1.959 and 1.962.
+_ONE_PIECE_ROWS = 1024
+_PIECE_SHARE = 16
 
 
 def sigmoid_topk(h, router_w, top_k: int):
@@ -208,14 +235,15 @@ def sigmoid_topk(h, router_w, top_k: int):
 
 def _grouped_dot(rows, weights, sizes, impl: str):
     """``rows[group g's rows] @ weights[g]``, accumulated in float32 and
-    returned in ``rows``' type (the T * k rows of a prompt are eight
-    times the pairs that land here, and three float32 copies of them
-    were 3.2 GB of a prefill's scratch); rows past the groups' total are
-    undefined."""
+    returned in ``rows``' type (the next product reads it so, and a
+    float32 copy would double the scratch of a call's rows: a decode
+    batch's ``T * k``, or one piece of a prompt's, about as many as the
+    pairs that land here); rows past the groups' total are undefined."""
     if impl == "gmm":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
         m = rows.shape[0]
-        tiling = _GMM_TILING_FEW if m <= 1024 else _GMM_TILING_MANY
+        tiling = _GMM_TILING_FEW if m <= _ONE_PIECE_ROWS \
+            else _GMM_TILING_MANY
         tiling = (min(tiling[0], m),) + tiling[1:]
         return gmm(rows, weights, sizes, rows.dtype, tiling)
     return jax.lax.ragged_dot(rows, weights, sizes,
@@ -231,6 +259,26 @@ def grouped_dot_impl(impl: "str | None" = None) -> str:
     return "gmm" if jax.devices()[0].platform == "tpu" else "ragged"
 
 
+def _gated_products(rows, weights, sizes, impl: str):
+    """``down (silu(gate rows) * up rows)``, each product grouped by
+    ``sizes``: what of the layer lies under ``moe_experts``."""
+    gate_w, up_w, down_w = weights
+    with jax.named_scope("moe_experts"):
+        a = jax.nn.silu(_grouped_dot(rows, gate_w, sizes, impl)) \
+            * _grouped_dot(rows, up_w, sizes, impl)
+        return _grouped_dot(a, down_w, sizes, impl)
+
+
+def _piece_rows(rows: int) -> int:
+    """Rows of one piece for ``rows`` sorted pairs: all of them up to
+    ``_GMM_TILING_FEW``'s bound, else the share ``_PIECE_SHARE`` rounded
+    up to whole row tiles."""
+    if rows <= _ONE_PIECE_ROWS:
+        return rows
+    tile = _GMM_TILING_MANY[0]
+    return -(-rows // (_PIECE_SHARE * tile)) * tile
+
+
 def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
                      valid=None, impl: "str | None" = None):
     """What the held experts add: ``sum_{e chosen, held} w_e E_e(h)``
@@ -240,16 +288,24 @@ def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
     :func:`sigmoid_topk`; ``gate_w``, ``up_w`` [held, d, F], ``down_w``
     [held, F, d]: experts ``offset .. offset + held`` of the published
     ones; ``valid`` [T] bool: tokens that exist (a bucket's padding
-    routes nowhere).  Returns ``(y [T, d] float32, pairs, experts_hit)``:
-    the token-expert pairs computed here and the held experts with at
-    least one, int32 scalars.
+    routes nowhere).  Returns ``(y [T, d] float32, pairs, experts_hit,
+    rows)``: the token-expert pairs computed here, the held experts with
+    at least one, and the rows pushed through the grouped products
+    (int32 scalars; ``rows`` a Python int where it is the shape's).
 
-    The pairs that land here are sorted by expert (absent ones last),
-    their tokens' rows gathered, and the three products run as grouped
-    products over the sorted rows (scope ``moe_experts``); the result
-    goes back by the inverse permutation and is summed per token under
-    its weights (scope ``moe_route``: everything that is not a
-    product)."""
+    The ``T * k`` pairs are sorted by expert, the ones that land here
+    first.  Up to ``_ONE_PIECE_ROWS`` of them (a decode batch) are ONE
+    piece: every row gathered, the three products run as grouped products
+    over the sorted rows (scope ``moe_experts``), the result sent back by
+    the inverse permutation and summed per token under its weights (scope
+    ``moe_route``: everything that is not a product).  A prompt's rows
+    are cut into pieces of ``_piece_rows`` sorted positions, and piece
+    ``j`` runs only when ``pairs > j * C`` (a loop of ``ceil(pairs / C)``
+    trips around one set of three grouped products): it gathers its own
+    ``C`` rows, its group sizes are the parts of ``sizes`` inside it, and
+    its weighted rows are added into their tokens' float32 rows.  Every
+    pair that lands here is computed whatever their number: nothing has
+    a capacity and nothing is dropped."""
     T, d = h.shape
     k = idx.shape[1]
     held = gate_w.shape[0]
@@ -263,11 +319,14 @@ def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
         order = jnp.argsort(expert, stable=True)
         sizes = jnp.sum(expert[:, None] == jnp.arange(held)[None, :],
                         axis=0, dtype=jnp.int32)
+    weights = (gate_w, up_w, down_w)
+    C = _piece_rows(T * k)
+    if C < T * k:
+        return _in_pieces(h, order, sizes, jnp.where(here, w, 0.0), C,
+                          weights, impl)
+    with jax.named_scope("moe_route"):
         rows = jnp.take(h, order // k, axis=0)              # [T*k, d]
-    with jax.named_scope("moe_experts"):
-        a = jax.nn.silu(_grouped_dot(rows, gate_w, sizes, impl)) \
-            * _grouped_dot(rows, up_w, sizes, impl)
-        y = _grouped_dot(a, down_w, sizes, impl)
+    y = _gated_products(rows, weights, sizes, impl)
     with jax.named_scope("moe_route"):
         pairs = jnp.sum(sizes)
         y = jnp.where((jnp.arange(T * k) < pairs)[:, None], y, 0)
@@ -276,4 +335,40 @@ def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
         y = jnp.take(y, back, axis=0).reshape(T, k, d)
         out = jnp.einsum("tk,tkd->td", jnp.where(here, w, 0.0), y,
                          preferred_element_type=jnp.float32)
-    return out, pairs, jnp.sum(sizes > 0, dtype=jnp.int32)
+    return out, pairs, jnp.sum(sizes > 0, dtype=jnp.int32), T * k
+
+
+def _in_pieces(h, order, sizes, w, C: int, weights, impl: str):
+    """:func:`dropless_experts` past one piece.  ``order`` [T * k] the
+    sorted pairs' places in ``w`` [T, k] float32 (0 where a pair is not
+    computed here), ``sizes`` [held].  The loop itself lies under neither
+    finer scope, so that a trace's event for it, if it has one, is counted
+    under none; its body's operations lie under theirs."""
+    T, d = h.shape
+    k = w.shape[1]
+    with jax.named_scope("moe_route"):
+        pairs = jnp.sum(sizes)
+        ends = jnp.cumsum(sizes)
+        starts = ends - sizes
+        # (a slice that ran past the end would be moved back inside)
+        order = jnp.pad(order, (0, -order.shape[0] % C))
+        w = w.reshape(T * k)
+
+    def piece(j, out):
+        lo = j * C
+        with jax.named_scope("moe_route"):
+            at = jax.lax.dynamic_slice(order, (lo,), (C,))
+            token = at // k
+            rows = jnp.take(h, token, axis=0, mode="clip")
+            part = jnp.clip(jnp.minimum(ends, lo + C)
+                            - jnp.maximum(starts, lo), 0)
+        y = _gated_products(rows, weights, part, impl)
+        with jax.named_scope("moe_route"):
+            live = (lo + jnp.arange(C) < pairs)[:, None]
+            y = jnp.where(live, y, 0).astype(jnp.float32) \
+                * jnp.take(w, at, mode="clip")[:, None]
+            return out.at[token].add(y)
+
+    n = (pairs + C - 1) // C
+    out = jax.lax.fori_loop(0, n, piece, jnp.zeros((T, d), jnp.float32))
+    return out, pairs, jnp.sum(sizes > 0, dtype=jnp.int32), n * C

@@ -192,8 +192,8 @@ def test_every_share_of_the_experts_adds_up_to_the_uncut_layer():
         assert share["num_experts"] == 4 \
             and share["expert_offset"] == 4 * part
         ref_sum = ref_sum + ref.moe_parts(h, share, KEY, 2)[0]
-        y, _, _ = moe.dropless_experts(h, idx, w, *_held(share, 2),
-                                       offset=4 * part)
+        y, *_ = moe.dropless_experts(h, idx, w, *_held(share, 2),
+                                     offset=4 * part)
         prog_sum = prog_sum + y
     # (the layer's output is small at this size: the tolerance is a
     # share of its largest entry, not of a logit's spread)
@@ -211,20 +211,83 @@ def test_every_share_of_the_experts_adds_up_to_the_uncut_layer():
 @pytest.mark.limit(60)
 def test_pairs_and_experts_hit_against_a_count_by_hand():
     """``pairs`` are the token-expert pairs whose expert is held here
-    and whose token exists; ``experts_hit`` the held experts with one."""
+    and whose token exists; ``experts_hit`` the held experts with one;
+    ``rows`` what went through the grouped products: all ``T * k`` where
+    the layer is one piece, the pieces that ran beyond."""
     h = _layer_inputs()
     idx, w = moe.sigmoid_topk(h, ref.leaf(MODEL, KEY, "router_w", 2), 4)
     valid = jnp.arange(24) < 19
-    _, pairs, hit = moe.dropless_experts(h, idx, w, *_held(MODEL, 2),
-                                         offset=0, valid=valid)
+    _, pairs, hit, rows = moe.dropless_experts(
+        h, idx, w, *_held(MODEL, 2), offset=0, valid=valid)
     chosen = np.asarray(idx)[:19]
     assert int(pairs) == int((chosen < 4).sum()) > 0
     assert int(hit) == len(set(chosen[chosen < 4].tolist()))
+    assert rows == 24 * 4
     np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, atol=1e-6)
     # a share that holds experts nobody chose computes nothing
     none = jnp.full_like(idx, 9)
-    y, pairs, hit = moe.dropless_experts(h, none, w, *_held(MODEL, 2))
+    y, pairs, hit, _ = moe.dropless_experts(h, none, w, *_held(MODEL, 2))
     assert int(pairs) == 0 and int(hit) == 0 and not np.asarray(y).any()
+    # 300 tokens' 1200 sorted rows are past one piece (1024): pieces of
+    # 256 rows (a sixteenth, in whole tiles of 256), as many as the pairs
+    # of the first 280 tokens on experts 4-11 need
+    h = _layer_inputs(300)
+    idx, w = moe.sigmoid_topk(h, ref.leaf(MODEL, KEY, "router_w", 2), 4)
+    half = {**MODEL, "num_experts": 8, "expert_offset": 4}
+    _, pairs, hit, rows = moe.dropless_experts(
+        h, idx, w, *_held(half, 2), offset=4, valid=jnp.arange(300) < 280)
+    chosen = np.asarray(idx)[:280]
+    mine = chosen[(chosen >= 4) & (chosen < 12)]
+    assert int(pairs) == len(mine) and 2 * 256 < len(mine) <= 3 * 256
+    assert int(hit) == len(set(mine.tolist())) == 8
+    assert int(rows) == 3 * 256
+
+
+#: the cases the pieces create, at 4 choices a token of 16 experts: tokens
+#: (so 4 x as many sorted rows: past one piece, in pieces of 256), experts
+#: held and the first of them, tokens that exist, pieces that run
+PIECES = {
+    "pairs_under_one_piece": (320, 3, 0, None, 1),
+    "pairs_past_the_first_piece": (320, 8, 4, None, 3),
+    "every_pair_on_a_held_expert": (320, 16, 0, None, 5),
+    "no_pair_at_all": (320, 8, 0, 0, 0),
+    "valid_cuts_the_buckets_tail": (320, 8, 0, 100, 1),
+    "an_edge_inside_an_experts_group": (384, 6, 10, None, 3),
+}
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("case", list(PIECES))
+def test_the_layer_in_pieces_against_the_references_expert_loop(case):
+    """A prompt's sorted rows are cut into pieces and as many run as the
+    pairs need: whatever their number, the layer is the reference's loop
+    over the held experts (``moe_parts``), zero at a token that does not
+    exist."""
+    T, held, offset, length, pieces = PIECES[case]
+    model = {**MODEL, "num_experts": held, "expert_offset": offset}
+    h = _layer_inputs(T)
+    C = moe._piece_rows(T * 4)
+    assert C == 256 < T * 4
+    valid = None if length is None else jnp.arange(T) < length
+    routed = ref.moe_parts(h, model, KEY, 2)[0]
+    want = routed if valid is None else jnp.where(valid[:, None], routed, 0)
+    idx, w = moe.sigmoid_topk(h, ref.leaf(MODEL, KEY, "router_w", 2), 4)
+    y, pairs, hit, rows = jax.jit(
+        lambda h, idx, w: moe.dropless_experts(
+            h, idx, w, *_held(model, 2), offset=offset, valid=valid))(
+                h, idx, w)
+    chosen = np.asarray(idx)[:length] - offset
+    mine = chosen[(chosen >= 0) & (chosen < held)]
+    assert int(pairs) == len(mine) and int(rows) == pieces * C
+    assert (pieces - 1) * C < len(mine) <= pieces * C
+    assert int(hit) == len(set(mine.tolist()))
+    if case == "every_pair_on_a_held_expert":
+        assert len(mine) == T * 4
+    if case == "an_edge_inside_an_experts_group":
+        ends = np.cumsum(np.bincount(mine, minlength=held))
+        assert C not in ends and ends[0] < C < ends[-1]
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               atol=1e-4 * float(jnp.abs(routed).max()))
 
 
 @pytest.mark.limit(120)
@@ -374,6 +437,12 @@ def test_engine_serves_the_reference_tokens_and_counts_on_the_device(engine):
     pairs = after["decode_moe_pairs"] - before["decode_moe_pairs"]
     hit = after["decode_moe_experts_hit"] - before["decode_moe_experts_hit"]
     assert 0 < hit <= 26 * 4 * 4 and hit <= pairs <= 26 * 4 * 4 * SLOTS
+    # one piece at these sizes: every sorted row goes through the
+    # products, 4 a token, four layers a run
+    assert after["decode_moe_rows"] - before["decode_moe_rows"] \
+        == 26 * 4 * 4 * SLOTS
+    assert after["prefill_moe_rows"] - before["prefill_moe_rows"] \
+        == 4 * 4 * 32
     assert sum(engine.stats()["retraces"].values()) == 0
 
 
@@ -512,14 +581,18 @@ def v5e():
 
 
 @pytest.mark.limit(240)
-@pytest.mark.parametrize("kernel", ["gqa_decode", "splash", "gmm"])
+@pytest.mark.parametrize("kernel", ["gqa_decode", "splash", "gmm",
+                                    "gmm_in_pieces"])
 def test_kernels_compile_for_v5e_at_the_published_widths(monkeypatch, v5e,
                                                          kernel):
     """128 query heads on 8 K/V heads of 128, experts of 4096 x 4096,
     bf16: the grouped decode call over a ring of 4096 rows at 32 slots,
     jax's splash attention under the band (a shorter prompt than the
     cell's: the tables of an 8192 mask are the slow part), and megablox'
-    grouped product inside the dropless layer at a decode batch."""
+    grouped product inside the dropless layer at a decode batch (one
+    piece) and at a prompt's rows (4096 tokens: the three products with
+    a prompt's tiles in the body of a loop over pieces of 2048 rows, and
+    under half the temporaries of the layer that moved all 32,768)."""
     from ray_lightning_tpu.ops import flash_decode
 
     def on_chip(shape, dtype=jnp.bfloat16):
@@ -539,16 +612,24 @@ def test_kernels_compile_for_v5e_at_the_published_widths(monkeypatch, v5e,
         args = (on_chip((1, 2048, 128, 128)), on_chip((1, 2048, 8, 128)),
                 on_chip((1, 2048, 8, 128)))
     else:
-        fn = lambda h, i, w, g, u, d: moe.dropless_experts(  # noqa: E731
-            h, i, w, g, u, d, impl="gmm")[0]
-        args = (on_chip((32, 4096)), on_chip((32, 8), jnp.int32),
-                on_chip((32, 8), jnp.float32), on_chip((16, 4096, 4096)),
-                on_chip((16, 4096, 4096)), on_chip((16, 4096, 4096)))
+        T = 32 if kernel == "gmm" else 4096
+        fn = lambda h, i, w, g, u, d, ok: moe.dropless_experts(  # noqa: E731
+            h, i, w, g, u, d, valid=ok, impl="gmm")[0]
+        args = (on_chip((T, 4096)), on_chip((T, 8), jnp.int32),
+                on_chip((T, 8), jnp.float32), on_chip((16, 4096, 4096)),
+                on_chip((16, 4096, 4096)), on_chip((16, 4096, 4096)),
+                on_chip((T,), jnp.bool_))
     with jax.default_matmul_precision("default"):
-        text = jax.jit(fn).lower(*args).compile().as_text()
+        compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert {"gqa_decode": "gqa_decode", "splash": "splash_mqa_fwd",
-            "gmm": "gmm"}[kernel] in text
+            "gmm": "gmm", "gmm_in_pieces": "gmm"}[kernel] in text
+    if kernel == "gmm_in_pieces":
+        assert moe._piece_rows(T * 8) == 2048 > moe._ONE_PIECE_ROWS
+        assert "while/body/moe_experts/jit(gmm)/pallas_call" in text
+        # PR 33's layer at this size, compiled the same way: 805,951,488
+        assert compiled.memory_analysis().temp_size_in_bytes < 805_951_488 / 2
 
 
 @pytest.mark.limit(60)
