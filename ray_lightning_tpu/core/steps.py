@@ -275,7 +275,8 @@ def build_prefill_step(module, bucket_len: int, model=None,
 
 
 def kv_layer_pairs(kv_tree) -> "list[tuple]":
-    """Per-layer ``(k, v)`` pairs from the sown ``kv_cache`` collection,
+    """Per-layer ``(k, v)`` pairs from the sown ``kv_cache`` collection
+    (``(block,)`` where a model's row holds key and value at once),
     in layer order (sorted on the numeric suffix of the flax block
     names h0, h1, ...).  Works on concrete arrays AND on ``eval_shape``
     avals (serve/engine.py derives the cache geometry from the latter).

@@ -14,6 +14,11 @@ from ray_lightning_tpu.models.evabyte import (
     EvaByteLightningModule,
 )
 from ray_lightning_tpu.models.gpt import GPT, GPTConfig, GPTLightningModule
+from ray_lightning_tpu.models.xing import (
+    Xing,
+    XingConfig,
+    XingLightningModule,
+)
 from ray_lightning_tpu.models.pipeline_gpt import PipelinedGPT
 from ray_lightning_tpu.models.resnet import (
     ResNet,
@@ -52,4 +57,7 @@ __all__ = [
     "BertLightningModule",
     "BertForMaskedLM",
     "BertMLMModule",
+    "Xing",
+    "XingConfig",
+    "XingLightningModule",
 ]

@@ -64,10 +64,7 @@ from ray_lightning_tpu.ops import window_attention as wa
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 #: the accumulator's entries (serve/engine.py ``stats()['counters']``)
-SERVE_COUNTERS = ("decode_runs", "decode_moe_pairs",
-                  "decode_moe_experts_hit", "decode_moe_rows",
-                  "prefill_runs", "prefill_moe_pairs",
-                  "prefill_moe_experts_hit", "prefill_moe_rows")
+SERVE_COUNTERS = moe.SERVE_COUNTERS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,38 +258,15 @@ def _with_kind(caches: tuple, kind: int, one) -> tuple:
     return caches[:kind] + (one,) + caches[kind + 1:]
 
 
-class ExpertLayer(nn.Module):
-    """The routed experts held here and the shared experts.  ``h`` [T,
-    d] float32 (the norm's output: the router reads it so).  Returns
-    ``(y [T, d] float32, (pairs, experts_hit, rows))``."""
-
-    config: CommandConfig
-
-    @nn.compact
-    def __call__(self, h, valid=None):
-        cfg = self.config
-        d, F = cfg.hidden_size, cfg.intermediate_size
-        held, n = cfg.num_experts, cfg.num_shared_experts
-        init = nn.initializers.normal(cfg.init_std)
-        router = self.param("router", init,
-                            (d, cfg.num_experts_published), jnp.float32)
-        gate = self.param("gate", init, (held, d, F))
-        up = self.param("up", init, (held, d, F))
-        down = self.param("down", init, (held, F, d))
-        idx, w = moe.sigmoid_topk(h, router, cfg.num_experts_per_tok)
-        hc = h.astype(cfg.dtype)
-        y, *counts = moe.dropless_experts(
-            hc, idx, w, gate.astype(cfg.dtype), up.astype(cfg.dtype),
-            down.astype(cfg.dtype), offset=cfg.expert_offset, valid=valid)
-        with jax.named_scope("moe_shared"):
-            def dense(width, name):
-                return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
-                                name=name, kernel_init=init)
-
-            a = nn.silu(dense(n * F, "shared_gate")(hc)) \
-                * dense(n * F, "shared_up")(hc)
-            y = y + dense(d, "shared_down")(a).astype(jnp.float32) / n
-        return y, tuple(counts)
+def expert_layer(cfg: CommandConfig, name: str) -> moe.ExpertLayer:
+    """ops/moe.py's layer at this configuration's sizes: the routed
+    experts held here beside the averaged shared experts."""
+    return moe.ExpertLayer(
+        d=cfg.hidden_size, width=cfg.intermediate_size,
+        held=cfg.num_experts, published=cfg.num_experts_published,
+        top_k=cfg.num_experts_per_tok, n_shared=cfg.num_shared_experts,
+        offset=cfg.expert_offset, init_std=cfg.init_std, dtype=cfg.dtype,
+        name=name)
 
 
 class CommandBlock(nn.Module):
@@ -314,7 +288,7 @@ class CommandBlock(nn.Module):
         if cache is not None:
             a, cache = a
         with jax.named_scope("mlp"):
-            m, counts = ExpertLayer(cfg, name="moe")(
+            m, counts = expert_layer(cfg, "moe")(
                 h.reshape(B * T, d),
                 None if valid is None else valid.reshape(B * T))
         with jax.named_scope("attn"):
@@ -322,21 +296,6 @@ class CommandBlock(nn.Module):
         with jax.named_scope("mlp"):
             x = x + m.reshape(B, T, d)
         return x, cache, counts
-
-
-def _split_state(k_caches):
-    """``(kinds, counters)``: the state's arrays a kind, and the
-    accumulator that rides behind them where the engine made one."""
-    if len(k_caches) and k_caches[-1].ndim == 1:
-        return tuple(k_caches[:-1]), k_caches[-1]
-    return tuple(k_caches), None
-
-
-def _count(counters, first: int, pairs, hit, rows):
-    if counters is None:
-        return ()
-    add = jnp.stack([jnp.ones((), jnp.int32), pairs, hit, rows])
-    return (counters.at[first:first + 4].add(add.astype(counters.dtype)),)
 
 
 class Command(nn.Module):
@@ -381,7 +340,7 @@ class Command(nn.Module):
         accumulator behind them, where there is one).  Writes the slot's
         state and returns ``(next-token logits [vocab] float32 at
         position length - 1, k_caches, v_caches)``."""
-        kinds, counters = _split_state(k_caches)
+        kinds, counters = moe.split_counters(k_caches)
         state = (kinds, tuple(v_caches))
         valid = jnp.arange(tokens.shape[1])[None, :] < length
         x = self._embed(tokens)
@@ -393,7 +352,7 @@ class Command(nn.Module):
             pairs, hit, rows = pairs + p, hit + e, rows + r
         last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
         return (self._head(last)[0, 0],
-                state[0] + _count(counters, 4, pairs, hit, rows), state[1])
+                state[0] + moe.count_run(counters, 4, pairs, hit, rows), state[1])
 
     def decode(self, tokens, positions, k_caches, v_caches,
                page_table=None, slots=None):
@@ -405,7 +364,7 @@ class Command(nn.Module):
                 "Command's serve state is a ring of window rows beside a "
                 "row per position: it has no paged fetch and no one-slot "
                 "suffix program (prefix reuse)")
-        kinds, counters = _split_state(k_caches)
+        kinds, counters = moe.split_counters(k_caches)
         state = (kinds, tuple(v_caches))
         x = self._embed(tokens[:, None])
         pairs = hit = jnp.zeros((), jnp.int32)
@@ -414,7 +373,7 @@ class Command(nn.Module):
             x, state, (p, e, r) = blk(x, cache=state, positions=positions)
             pairs, hit, rows = pairs + p, hit + e, rows + r
         return (self._head(x)[:, 0],
-                state[0] + _count(counters, 0, pairs, hit, rows), state[1])
+                state[0] + moe.count_run(counters, 0, pairs, hit, rows), state[1])
 
 
 class CommandLightningModule(LightningModule):

@@ -104,7 +104,10 @@ def select_decode_kernel(L: int, H: int, D: int, *, dtype, impl=None,
                          n_pages=None) -> str:
     """The kernel :func:`~ray_lightning_tpu.ops.attention.cached_attention`
     lowers for this cache geometry: ``dense``, ``flash_decode`` or
-    ``paged``.
+    ``paged``.  (The calls of the models that bring their own serve state
+    choose beside theirs: ops/eva_attention.py ``eva_decode``,
+    ops/window_attention.py ``gqa_decode``, models/xing.py's
+    ``mla_decode`` through :func:`latent_kernel_supported`.)
 
     ``auto`` follows what the code can observe: the Pallas kernel on TPU
     when the geometry lowers (:func:`decode_kernel_supported`), the
@@ -182,7 +185,10 @@ def decode_kernel_supported(L: int, H: int, D: int, *,
                             block_k: int, dtype) -> bool:
     """Whether the kernel path can lower for this cache geometry.  The
     interpreter (non-TPU) takes anything; on TPU the packed lane axis
-    ``C = H*D`` must be a 128-lane multiple and blocks must tile L."""
+    ``C = H*D`` must be a 128-lane multiple and blocks must tile L.
+    (The flat, paged, ``eva_decode`` and ``gqa_decode`` calls ask this;
+    the latent call, ``mla_decode``, whose row is one head of 576 lanes
+    with the value inside it, asks :func:`latent_kernel_supported`.)"""
     C = H * D
     if L % block_k:
         return False
@@ -209,7 +215,8 @@ def _pick_block_k(L: int, most: int = _BLOCK_K) -> int:
 
 def _decode_body(pos, kb, nk, logical_base,
                  q_ref, k_ref, v_ref, o_ref, qd_ref, m_ref, l_ref, acc_ref,
-                 *, sm_scale, block_k, head_dim, rows=None, group=None):
+                 *, sm_scale, block_k, head_dim, rows=None, group=None,
+                 value_dim=None):
     """Online-softmax update for one ``block_k``-row KV block of one
     slot, every packed head at once.  ``logical_base`` is the block's
     first LOGICAL cache row (page-table indirection moves only the
@@ -232,7 +239,13 @@ def _decode_body(pos, kb, nk, logical_base,
     heads of ``head_dim`` lanes in a row.  Row ``h`` of the query then
     owns the columns of K/V head ``h // group``; ``q_ref`` and ``o_ref``
     are ``[1, Hp, head_dim]``, and the block-diagonal query is filled,
-    and the output taken back, by ``Hp / group`` static tile copies."""
+    and the output taken back, by ``Hp / group`` static tile copies.
+
+    ``value_dim`` (:func:`latent_decode_attention`, with ``group``): a
+    row's value is its key's first ``value_dim`` lanes, so ``v_ref`` is
+    the block that ``k_ref`` is (one fetch serves both), ``p @`` reads
+    those lanes of it, and ``acc_ref`` and ``o_ref`` are ``value_dim``
+    wide a K/V head."""
     live = kb * block_k <= pos if rows is None else rows[0]
     hp, width = qd_ref.shape
 
@@ -244,10 +257,10 @@ def _decode_body(pos, kb, nk, logical_base,
         col = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
         return (col >= first) & (col < first + head_dim)
 
-    def tiles():
+    def tiles(dim=head_dim):
         # (rows of a group's query heads, columns of its K/V head)
         return [(slice(g * group, (g + 1) * group),
-                 slice(g * head_dim, (g + 1) * head_dim))
+                 slice(g * dim, (g + 1) * dim))
                 for g in range(width // head_dim)]
 
     @pl.when(kb == 0)
@@ -271,7 +284,7 @@ def _decode_body(pos, kb, nk, logical_base,
     @pl.when(live)
     def _compute():
         k = k_ref[0]                                        # [block_k, C]
-        v = v_ref[0]
+        v = v_ref[0] if value_dim is None else v_ref[0][:, :value_dim]
         cols = (jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
                 + logical_base)
         valid = cols <= pos if rows is None else rows[1](cols)
@@ -294,7 +307,7 @@ def _decode_body(pos, kb, nk, logical_base,
     if group is not None:
         @pl.when(kb == nk - 1)
         def _final_grouped():
-            for heads, cols in tiles():
+            for heads, cols in tiles(value_dim or head_dim):
                 o_ref[0, heads, :] = (acc_ref[heads, cols]
                                       / l_ref[heads, :1]).astype(o_ref.dtype)
         return
@@ -313,17 +326,20 @@ def _decode_body(pos, kb, nk, logical_base,
         o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-def decode_scratch(n_head: int, width: int, dtype) -> list:
+def decode_scratch(n_head: int, width: int, dtype,
+                   value_width: "int | None" = None) -> list:
     """The VMEM a call around :func:`_decode_body` keeps across a slot's
     blocks: the block-diagonal query in the cache's dtype and the
-    float32 running max, running sum and rescaled accumulator, heads on
-    sublanes (``Hp``: ``n_head`` rounded up to ``dtype``'s tile)."""
+    float32 running max, running sum and rescaled accumulator
+    (``value_width`` wide where a row's value is narrower than its key),
+    heads on sublanes (``Hp``: ``n_head`` rounded up to ``dtype``'s
+    tile)."""
     sub = 8 * 4 // jnp.dtype(dtype).itemsize
     hp = -(-n_head // sub) * sub
     return [pltpu.VMEM((hp, width), dtype),
             pltpu.VMEM((hp, 128), jnp.float32),
             pltpu.VMEM((hp, 128), jnp.float32),
-            pltpu.VMEM((hp, width), jnp.float32)]
+            pltpu.VMEM((hp, value_width or width), jnp.float32)]
 
 
 def flash_decode_kernel(positions_ref, *refs, **kw):
@@ -522,6 +538,98 @@ def grouped_decode_attention(q, k_cache, v_cache, bound, *, layer,
     return out.reshape(S, 1, H, D)
 
 
+#: Cache rows a grid step of the LATENT call reads, halved until it tiles
+#: the cache.  Its row is one K/V head of 640 lanes (576 values, 1,152 B
+#: in bf16, and 64 lanes of zeros), its scores [32, block_k].  The kernel
+#: alone at 64 slots, ms a call at 128 / 256 / 512 / 1024 rows (builder's
+#: chip run, PR 36; PERF.md section 6): 6,400-9,900 of 10,240 rows a slot
+#: 3.305 / 2.363 / 1.879 / 1.634 (its 1,152 B rows' time 0.734, the 1,280 B
+#: it reads 0.815); of 9,984 rows, which 512 does not tile, 3.258 / 2.319;
+#: 101 rows 1.275 / 0.996 / 0.937 / 0.933.  32 query rows are a quarter of
+#: the MXU's height: a block's two products take about as long as its
+#: bytes, and the two do not overlap.
+_LATENT_BLOCK_K = 1024
+#: the latent call's name in the compiled program and the trace
+LATENT_KERNEL_NAME = "mla_decode"
+
+
+def latent_block_k(L: int) -> int:
+    return _pick_block_k(L, _LATENT_BLOCK_K)
+
+
+def latent_kernel_supported(L: int, C: int, value_dim: int, *,
+                            dtype) -> bool:
+    """Whether :func:`latent_decode_attention` can lower: the interpreter
+    takes anything; Mosaic wants the blocks to tile the cache in whole
+    sublane tiles and the value's lanes to end on a lane tile (the key's
+    width is the array's whole minor dimension, whatever it is)."""
+    bk = latent_block_k(L)
+    if _use_interpret():
+        return True
+    sub = 16 if dtype == jnp.bfloat16 else 8
+    return bk % sub == 0 and value_dim % 128 == 0 and value_dim <= C
+
+
+def latent_decode_attention(q, cache, positions, *, layer: int,
+                            value_dim: int, sm_scale: float,
+                            dtype=jnp.bfloat16):
+    """Flash decode over one layer of a LATENT cache (models/xing.py):
+    every query head reads the ONE row a position keeps, whose value is
+    the row's first ``value_dim`` lanes.
+
+    ``q`` [S, H, C] (the absorbed query beside the rotated one, as a row
+    lies); ``cache`` [n_layer, S, L, C], whole: ONE array, keys and
+    values at once; ``positions`` [S] int32: slot ``s`` sees the rows
+    ``<= positions[s]``.  Returns [S, H, value_dim] in ``dtype``.  The
+    body is :func:`flash_decode_attention`'s (``_decode_body`` with
+    ``group = H`` and ``value_dim``): a block of rows is fetched once,
+    scored against all ``C`` lanes, and ``p @`` its first ``value_dim``;
+    the same length-aware index_map."""
+    S, H, C = q.shape
+    n_layer, slots, L, width = cache.shape
+    if width != C or slots != S or not 0 <= layer < n_layer \
+            or value_dim > C:
+        raise ValueError(
+            f"cache {cache.shape} does not hold layer {layer} of {S} "
+            f"slots x rows of {C} whose first {value_dim} are the value")
+    bk = latent_block_k(L)
+    nk = L // bk
+    base = layer * S
+
+    def kv_map(s, kb, pos_ref):
+        return (base + s, kv_block_bound(kb, pos_ref[s], bk), 0)
+
+    def sq_map(s, kb, pos_ref):
+        return (s, 0, 0)
+
+    def kernel(pos_ref, q_ref, k_ref, o_ref, *scratch, **kw):
+        s, kb = pl.program_id(0), pl.program_id(1)
+        _decode_body(pos_ref[s], kb, pl.num_programs(1), kb * bk,
+                     q_ref, k_ref, k_ref, o_ref, *scratch, **kw)
+
+    body = functools.partial(
+        kernel, sm_scale=float(sm_scale), block_k=bk, head_dim=C, group=H,
+        value_dim=value_dim)
+    body.__name__ = LATENT_KERNEL_NAME + "_kernel"
+    return pl.pallas_call(
+        body,
+        name=LATENT_KERNEL_NAME,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S, nk),
+            in_specs=[pl.BlockSpec((1, H, C), sq_map),
+                      pl.BlockSpec((1, bk, C), kv_map)],
+            out_specs=pl.BlockSpec((1, H, value_dim), sq_map),
+            scratch_shapes=decode_scratch(H, C, cache.dtype, value_dim)),
+        out_shape=jax.ShapeDtypeStruct((S, H, value_dim), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )(jnp.asarray(positions, jnp.int32), q,
+      # a merge of leading dimensions: a bitcast, not a copy
+      cache.reshape(n_layer * S, L, C))
+
+
 __all__ = [
     "NEG_INF",
     "VALID_DECODE_IMPLS",
@@ -529,6 +637,8 @@ __all__ = [
     "flash_decode_attention",
     "grouped_decode_attention",
     "kv_block_bound",
+    "latent_decode_attention",
+    "latent_kernel_supported",
     "note_decode_kernel",
     "record_decode_kernels",
     "resolve_decode_impl",

@@ -167,7 +167,7 @@ def total_aux_loss(model_state) -> "jax.Array | None":
     return total
 
 
-# -- dropless serving layer (models/command.py) -------------------------------
+# -- dropless serving layer (models/command.py, models/xing.py) ---------------
 #
 # The capacity router above drops tokens and computes every expert for
 # ``capacity`` slots.  Serving wants neither: every pair a token chose is
@@ -176,7 +176,11 @@ def total_aux_loss(model_state) -> "jax.Array | None":
 # routes over all the published experts and computes the part of the
 # result its own experts give; a pair routed to an absent expert adds
 # nothing.  On one chip there is no exchange, and nothing stands in for
-# the absent chips.
+# the absent chips.  The router is a sigmoid over all the published
+# experts (``sigmoid_topk``), with a family's optional selection bias
+# (added to the scores to CHOOSE, never to weigh) and scaling factor;
+# ``ExpertLayer`` is the flax layer both families build: router, routed
+# experts, shared experts, and what it counts for ``SERVE_COUNTERS``.
 #
 # "Dropless" has no smaller static bound than ``T * k`` rows: every token
 # may put all its ``k`` pairs on held experts.  A decode batch pays that
@@ -200,6 +204,24 @@ def total_aux_loss(model_state) -> "jax.Array | None":
 #: product of as many rows 1.580.
 _GMM_TILING_FEW = (128, 1024, 2048)
 _GMM_TILING_MANY = (256, 1024, 1024)
+#: models/xing.py's experts are 64 of [3584, 1024] / [1024, 3584] (3584 =
+#: 3.5 x 1024: a contraction tile of 1024 leaves a masked half tile).  One
+#: product by hand on the v5e (builder's chip run, PR 36; PERF.md section
+#: 6), ms a call, ~0.76 of each dispatch; a tile of a whole 3584 side does
+#: not fit VMEM.  256 rows (64 tokens) on 63 of 64 experts, their bytes'
+#: time 0.565: in (128, 512, 1024) 1.329, (128, 1792, 1024) 1.343, (128,
+#: 1024, 1024) 1.382, (128, 1792, 512) 1.476; out (128, 1024, 1792) 1.345,
+#: (128, 1024, 2048) 1.361, (128, 1024, 512) 1.387; ``ragged_dot`` 1.70 /
+#: 1.72.  2,048 rows (a piece of an 8192-token prompt) on all 64: in (128,
+#: 1792, 1024) 1.555, (128, 512, 1024) 1.566, (256, 1792, 1024) 1.569,
+#: (128, 1024, 1024) 1.640, (256, 1024, 1024) 1.705, (512, 512, 1024)
+#: 2.114; out (128, 1024, 2048) 1.382, (256, 1024, 1792) 1.384, (128, 1024,
+#: 1792) 1.443, (256, 1024, 2048) 1.475, (512, 1024, 1792) 1.968;
+#: ``ragged_dot`` 2.33 / 2.31.  The way out is within noise of the two
+#: constants above and takes them; the way in has the one gap that was
+#: read (a piece's rows: 1.555 against ``_GMM_TILING_MANY``'s 1.705), and
+#: ``_GMM_TILING_FEW``'s 2048 columns are wider than its 1024.
+_GMM_TILING_3584_IN = (128, 1792, 1024)
 #: sorted rows up to which the layer is one piece (where ``_grouped_dot``
 #: changes tilings), and the share of the sorted rows a piece holds
 #: beyond.  The layer alone by hand on the v5e (builder's chip runs, PR
@@ -214,23 +236,38 @@ _GMM_TILING_MANY = (256, 1024, 1024)
 #: Past a sixteenth the pieces' own cost shows.  Every pair held (7168
 #: tokens on 16 of 16 experts, 57,344 pairs, all sixteen pieces): 53.79
 #: as it was, 59.03 in pieces (an eighth 58.64): what the shape's choice
-#: costs a chip that holds all its experts; no cell measures it.  A
-#: decode batch (32 tokens, one piece) 1.959 and 1.962.
+#: costs a chip that holds all its experts.  ``xing4-serve-doc8k`` is the
+#: cell that does (PR 36, 64 of 64 experts of [3584, 1024], 4 a token; by
+#: hand, ms a call): 7168 tokens (27,600 pairs, sixteen pieces of 1,792
+#: rows) 35.31, 8192 (31,600; 2,048) 39.97, 9216 (35,600; 2,304) 44.69,
+#: where the products' operations take 3.1-4.0 and the weights' bytes
+#: 1.72: ~2.5 ms a piece whatever it holds; 64 tokens (one piece) 2.61.
+#: A decode batch (32 tokens, one piece) 1.959 and 1.962.
 _ONE_PIECE_ROWS = 1024
 _PIECE_SHARE = 16
 
 
-def sigmoid_topk(h, router_w, top_k: int):
+def sigmoid_topk(h, router_w, top_k: int, bias=None, scale: float = 1.0):
     """``(idx [T, k] int32, w [T, k] float32)``: the ``k`` largest of
     ``sigmoid(h @ router_w)`` over ALL the router's outputs, and their
-    weights normalised to sum to 1 (``norm_topk_prob``).  float32
-    throughout, the product at ``highest``: a rounding that swaps the
-    k-th and the (k+1)-th score swaps an expert."""
+    weights normalised to sum to 1 (``norm_topk_prob``), times ``scale``
+    (``routed_scaling_factor``).  ``bias`` [E] float32 (``noaux_tc``'s
+    ``e_score_correction_bias``) is added to the scores to CHOOSE the
+    ``k``; the weights are the chosen experts' scores without it.  With
+    neither, the operations are what they were without the arguments.
+    float32 throughout, the product at ``highest``: a rounding that swaps
+    the k-th and the (k+1)-th score swaps an expert."""
     with jax.named_scope("moe_route"):
         r = jnp.einsum("td,de->te", h.astype(jnp.float32),
                        router_w.astype(jnp.float32), precision="highest")
-        top, idx = jax.lax.top_k(jax.nn.sigmoid(r), top_k)
-        return idx, top / jnp.sum(top, axis=-1, keepdims=True)
+        s = jax.nn.sigmoid(r)
+        if bias is None:
+            top, idx = jax.lax.top_k(s, top_k)
+        else:
+            _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+            top = jnp.take_along_axis(s, idx, axis=-1)
+        w = top / jnp.sum(top, axis=-1, keepdims=True)
+        return idx, w if scale == 1.0 else w * scale
 
 
 def _grouped_dot(rows, weights, sizes, impl: str):
@@ -241,14 +278,23 @@ def _grouped_dot(rows, weights, sizes, impl: str):
     pairs that land here); rows past the groups' total are undefined."""
     if impl == "gmm":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
-        m = rows.shape[0]
-        tiling = _GMM_TILING_FEW if m <= _ONE_PIECE_ROWS \
-            else _GMM_TILING_MANY
-        tiling = (min(tiling[0], m),) + tiling[1:]
-        return gmm(rows, weights, sizes, rows.dtype, tiling)
+        return gmm(rows, weights, sizes, rows.dtype,
+                   gmm_tiling(*rows.shape))
     return jax.lax.ragged_dot(rows, weights, sizes,
                               preferred_element_type=jnp.float32
                               ).astype(rows.dtype)
+
+
+def gmm_tiling(m: int, k: int) -> tuple:
+    """The megablox tiles (rows, contraction, columns) for ``[m, k] x
+    [groups, k, n]``, from the shapes handed in: few rows or many
+    (``_ONE_PIECE_ROWS``), as the two constants say, the 4096-wide
+    products among them; the measured choice where the contraction is
+    3584 wide."""
+    tiling = _GMM_TILING_FEW if m <= _ONE_PIECE_ROWS else _GMM_TILING_MANY
+    if k == 3584:
+        tiling = _GMM_TILING_3584_IN
+    return (min(tiling[0], m),) + tiling[1:]
 
 
 def grouped_dot_impl(impl: "str | None" = None) -> str:
@@ -372,3 +418,85 @@ def _in_pieces(h, order, sizes, w, C: int, weights, impl: str):
     n = (pairs + C - 1) // C
     out = jax.lax.fori_loop(0, n, piece, jnp.zeros((T, d), jnp.float32))
     return out, pairs, jnp.sum(sizes > 0, dtype=jnp.int32), n * C
+
+
+# -- the layer both served families build -------------------------------------
+
+#: the int32 accumulator a model with an ``ExpertLayer`` keeps beside its
+#: serve state (serve/engine.py ``stats()['counters']``,
+#: ``module.serve_counters``): runs, pairs computed here, experts hit and
+#: rows pushed through the grouped products, for decode runs and prefills
+SERVE_COUNTERS = ("decode_runs", "decode_moe_pairs",
+                  "decode_moe_experts_hit", "decode_moe_rows",
+                  "prefill_runs", "prefill_moe_pairs",
+                  "prefill_moe_experts_hit", "prefill_moe_rows")
+
+
+def split_counters(k_caches):
+    """``(arrays, counters)``: a serve state's arrays, and the accumulator
+    that rides behind them where the engine made one."""
+    if len(k_caches) and k_caches[-1].ndim == 1:
+        return tuple(k_caches[:-1]), k_caches[-1]
+    return tuple(k_caches), None
+
+
+def count_run(counters, first: int, pairs, hit, rows) -> tuple:
+    """``(counters',)`` with one run and its three counts added at
+    entries ``first .. first + 4`` (0: a decode run; 4: a prefill);
+    ``()`` where there is no accumulator."""
+    if counters is None:
+        return ()
+    add = jnp.stack([jnp.ones((), jnp.int32), pairs, hit, rows])
+    return (counters.at[first:first + 4].add(add.astype(counters.dtype)),)
+
+
+class ExpertLayer(nn.Module):
+    """The routed experts held here and the shared experts.  ``h`` [T,
+    d] float32 (the norm's output: the router reads it so).  Returns
+    ``(y [T, d] float32, (pairs, experts_hit, rows))``.
+
+    ``width``: one expert's; ``held`` of ``published`` experts from
+    ``offset``; ``top_k`` a token; ``n_shared`` shared experts as ONE
+    gated product of width ``n_shared * width``, whose output is divided
+    by ``n_shared``.  ``select_bias``: a float32 parameter ``bias``
+    [published] chooses with the scores (:func:`sigmoid_topk`);
+    ``scale``: the routed weights' factor."""
+
+    d: int
+    width: int
+    held: int
+    published: int
+    top_k: int
+    n_shared: int
+    offset: int = 0
+    select_bias: bool = False
+    scale: float = 1.0
+    init_std: float = 0.02
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h, valid=None):
+        d, F, held, n = self.d, self.width, self.held, self.n_shared
+        init = nn.initializers.normal(self.init_std)
+        router = self.param("router", init, (d, self.published),
+                            jnp.float32)
+        gate = self.param("gate", init, (held, d, F))
+        up = self.param("up", init, (held, d, F))
+        down = self.param("down", init, (held, F, d))
+        bias = self.param("bias", nn.initializers.zeros, (self.published,),
+                          jnp.float32) if self.select_bias else None
+        idx, w = sigmoid_topk(h, router, self.top_k, bias, self.scale)
+        hc = h.astype(self.dtype)
+        y, *counts = dropless_experts(
+            hc, idx, w, gate.astype(self.dtype), up.astype(self.dtype),
+            down.astype(self.dtype), offset=self.offset, valid=valid)
+        with jax.named_scope("moe_shared"):
+            def dense(width, name):
+                return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                                name=name, kernel_init=init)
+
+            a = nn.silu(dense(n * F, "shared_gate")(hc)) \
+                * dense(n * F, "shared_up")(hc)
+            shared = dense(d, "shared_down")(a).astype(jnp.float32)
+            y = y + shared / n
+        return y, tuple(counts)

@@ -32,6 +32,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_lightning_tpu.ops import flash_decode as _fd
 from ray_lightning_tpu.ops.flash_attention import NEG_INF
@@ -49,15 +50,19 @@ _SPLASH_BLOCK_KV = 1024
 _SPLASH_BLOCK_KV_COMPUTE = 512
 
 
-def rotary_interleaved(x, positions, theta: float):
+def rotary_interleaved(x, positions, theta: float, inv_freq=None):
     """Rotary embedding over every dimension of the head with the pairs
     ``(2j, 2j + 1)`` interleaved (``rope_gptj``).  ``x`` [..., T, H, D];
-    ``positions`` [..., T] (or [T]).  Computed in float32, returned in
-    ``x``'s type.  The partner of each dimension comes from a product
-    with a signed permutation matrix, as in ops/eva_attention.py
-    ``rotary``: exact, and a pass of the MXU instead of lane shuffles."""
+    ``positions`` [..., T] (or [T]).  Pair ``j`` turns by ``theta ** (-2j
+    / D)`` a position, or by ``inv_freq[j]`` ([D / 2] float32) where a
+    family scales its frequencies (:func:`yarn_inv_freq`).  Computed in
+    float32, returned in ``x``'s type.  The partner of each dimension
+    comes from a product with a signed permutation matrix, as in
+    ops/eva_attention.py ``rotary``: exact, and a pass of the MXU instead
+    of lane shuffles."""
     D = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
     angle = positions.astype(jnp.float32)[..., None] * inv_freq
     angle = jnp.repeat(angle, 2, axis=-1)[..., None, :]
     i = jnp.arange(D)
@@ -70,6 +75,39 @@ def rotary_interleaved(x, positions, theta: float):
                         preferred_element_type=jnp.float32)
     return (x.astype(jnp.float32) * jnp.cos(angle)
             + turned * jnp.sin(angle)).astype(x.dtype)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """[dim / 2] float32 (a constant): YaRN's frequencies (arXiv:
+    2309.00071, as the ``deepseek_v3`` family computes them).  Pair ``j``
+    of a head of ``dim`` turns ``original * theta ** (-2j / dim) / 2 pi``
+    times over the ``original`` positions it was trained on; the pairs
+    that turn more than ``beta_fast`` times keep their frequency, those
+    that turn fewer than ``beta_slow`` are slowed by ``factor``, and a
+    linear ramp between the two (over whole pair numbers, the lower
+    rounded down and the upper up) blends the rest."""
+    j = np.arange(0, dim, 2, dtype=np.float64) / dim
+    plain = theta ** -j
+
+    def pair_that_turns(n):
+        return dim * math.log(original / (n * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                   0.0, 1.0)
+    return jnp.asarray(plain / factor * ramp + plain * (1.0 - ramp),
+                       jnp.float32)
+
+
+def yarn_softmax_scale(width: int, factor: float,
+                       mscale_all_dim: float) -> float:
+    """The scale of the scores under YaRN: ``width ** -0.5`` times the
+    square of ``0.1 mscale_all_dim ln(factor) + 1``."""
+    m = 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return width ** -0.5 * m * m
 
 
 def visible_scores(length: int, window: "int | None") -> int:
@@ -221,4 +259,5 @@ def ring_rows(length, window: int, bucket: int):
 
 __all__ = ["KERNEL_NAME", "banded_attention", "cached_attention",
            "ring_rows", "rotary_interleaved", "select_decode_kernel",
-           "select_prefill_kernel", "visible_scores"]
+           "select_prefill_kernel", "visible_scores", "yarn_inv_freq",
+           "yarn_softmax_scale"]

@@ -27,7 +27,10 @@ layers are of one kind, and a small pytree each (a tuple an array a kind,
 an int32 accumulator behind the keys' where the model asked for one:
 ``module.serve_counters``) for one whose layers keep caches of their own
 (serve/kvcache.py ``KVCacheSpec.kinds`` / ``state``): one description,
-and the same programs, donation and warm-up either way.
+and the same programs, donation and warm-up either way.  A model whose
+row holds key and value at once (models/xing.py's latent rows) has ONE
+array a kind: it travels on the keys' side, and the values' side of
+every program is the empty tuple.
 
 After setup the engine is a pure executor: ``prefill``/``decode`` calls
 carry no Python branching on request state, so the decode loop shape
@@ -197,11 +200,13 @@ class ServeEngine:
                 lambda p, t: model.apply({"params": p}, t, True,
                                          mutable=["kv_cache"]),
                 abstract_params, dummy)
-            k_avals = [k for k, _ in kv_layer_pairs(cap["kv_cache"])]
+            # (a layer's captured tuple: keys and values, or the one
+            # block of a model whose row holds both)
+            captured = kv_layer_pairs(cap["kv_cache"])
             self.kv_spec = KVCacheSpec.from_capture(
-                k_avals, self.slots, self.max_seq_len,
+                captured, self.slots, self.max_seq_len,
                 counters=len(getattr(module, "serve_counters", ())))
-            kv_dtype = self._k_dtype = k_avals[0].dtype
+            kv_dtype = self._k_dtype = captured[0][0].dtype
             if self.kv_spec.own_state:
                 self._check_own_state(module)
 

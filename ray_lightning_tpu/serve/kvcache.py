@@ -36,6 +36,17 @@ from_capture`` reads it off the model's prefill capture):
   (``counters``: what its steps count on the device, read with the
   server's stats and never inside a step); it rides behind the keys'
   arrays.
+- **one latent row a position** (models/xing.py, ops/flash_decode.py
+  ``mla_decode``): a position keeps ONE row, the normed latent ``c_kv``
+  beside the rotated shared key (512 + 64 values), which every query
+  head reads as its key and whose first 512 lanes are its value.  There
+  is no values' array: the state is ONE array a kind, ``[n_layer, S,
+  positions, 640]`` (the 576 values and 64 lanes of zeros: whole lane
+  tiles; ``KVCacheSpec.paired`` False, read off a capture
+  that sows one block a layer and not two).  It travels where the keys'
+  arrays travel, the accumulator behind it, and the values' side of
+  every program is the empty tuple: the programs, their donation and
+  their warm-up are the pairs'.
 
 There is ONE layout, the one the decode kernels read: a row is a
 token's heads side by side on the lane axis, which is how the qkv
@@ -83,7 +94,9 @@ class KVCacheSpec:
     ...)`` where layers of more than one kind keep a cache of their own
     each (empty: the one kind ``n_layer`` and ``rows`` describe);
     ``counters``: the length of the int32 accumulator a model asked
-    for beside the cache (0: none)."""
+    for beside the cache (0: none); ``paired``: a keys' and a values'
+    array a kind, or (False) the one array of a model whose row holds
+    both (module docstring)."""
 
     n_layer: int
     slots: int
@@ -92,6 +105,7 @@ class KVCacheSpec:
     rows: "int | None" = None
     kinds: "tuple[tuple[int, int], ...]" = ()
     counters: int = 0
+    paired: bool = True
 
     @property
     def own_state(self) -> bool:
@@ -101,7 +115,8 @@ class KVCacheSpec:
 
     @property
     def shapes(self) -> "tuple[tuple[int, int, int, int], ...]":
-        """``[n_layer, S, R, C]`` of each kind's pair of arrays."""
+        """``[n_layer, S, R, C]`` of each kind's pair of arrays (or its
+        one array)."""
         kinds = self.kinds or ((
             self.n_layer,
             self.max_seq_len if self.rows is None else self.rows),)
@@ -119,29 +134,33 @@ class KVCacheSpec:
         return self.shapes[0]
 
     def nbytes(self, itemsize: int = 2) -> int:
-        """Device residency of BOTH cache arrays (k and v) of every kind
-        at the given element size (bf16 default)."""
-        return sum(2 * int(np.prod(shape, dtype=np.int64)) * itemsize
-                   for shape in self.shapes)
+        """Device residency of BOTH cache arrays (k and v) of every kind,
+        or of its one array, at the given element size (bf16 default)."""
+        return sum((1 + self.paired) * int(np.prod(shape, dtype=np.int64))
+                   * itemsize for shape in self.shapes)
 
     def state(self, make, dtype):
         """``(k, v)`` as every serve program takes and returns them,
         each leaf ``make(shape, dtype)`` (zeros, or an aval).  One kind
         and no accumulator: the two bare arrays.  Otherwise a tuple an
-        array a kind, the int32 accumulator behind the keys'."""
+        array a kind, the int32 accumulator behind the keys'; the
+        values' side empty where a kind is one array."""
         kinds = tuple(make(shape, dtype) for shape in self.shapes)
-        if len(kinds) == 1 and not self.counters:
+        if len(kinds) == 1 and not self.counters and self.paired:
             return kinds[0], kinds[0]
         extra = (make((self.counters,), np.int32),) if self.counters else ()
-        return kinds + extra, kinds
+        return kinds + extra, kinds if self.paired else ()
 
     @classmethod
     def from_capture(cls, kv_shapes, slots: int, max_seq_len: int,
                      counters: int = 0) -> "KVCacheSpec":
         """Derive the cache geometry from a prefill ``eval_shape``
         capture: ``kv_shapes`` is any per-layer K aval list (core/steps.py
-        _stacked_kv order).  An entry shaped ``[B, T, C]`` is a row per
-        captured position: the cache holds ``max_seq_len`` rows a slot.
+        _stacked_kv order), or the layers' captured tuples themselves
+        (``kv_layer_pairs``), where a tuple of ONE block says that the
+        layer's row holds key and value at once.  An entry shaped ``[B,
+        T, C]`` is a row per captured position: the cache holds
+        ``max_seq_len`` rows a slot.
         An entry shaped ``[B, 1, R, C]`` is a model's own state block,
         as its ``prefill`` method writes it at a slot: ``R`` rows a
         slot, whatever ``max_seq_len`` (the model sized it from its own
@@ -149,6 +168,9 @@ class KVCacheSpec:
         differing ``R`` are layers of differing kinds: one kind a
         distinct ``R``, in the order of each kind's first layer, which
         is the order the model finds its arrays in."""
+        paired = not any(isinstance(k, tuple) and len(k) == 1
+                         for k in kv_shapes)
+        kv_shapes = [k[0] if isinstance(k, tuple) else k for k in kv_shapes]
         n_layer = len(kv_shapes)
         if n_layer == 0:
             raise ValueError("model captured no kv_cache entries; does "
@@ -163,7 +185,7 @@ class KVCacheSpec:
                    rows=distinct[0] if one_kind else None,
                    kinds=() if one_kind else tuple(
                        (per_layer.count(r), r) for r in distinct),
-                   counters=counters)
+                   counters=counters, paired=paired)
 
 
 class SlotAllocator:

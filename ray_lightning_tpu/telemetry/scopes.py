@@ -49,14 +49,21 @@ SCOPES = ("embed", "attn", "mlp", "ln", "lm_head", "loss", "optimizer",
 
 #: finer names inside a scope, for the parts of a layer that a reader
 #: wants apart (ops/eva_attention.py: a chunk's pooling, the attention;
-#: ops/moe.py: an expert layer's routing, products and shared experts).
+#: ops/moe.py: an expert layer's routing, products and shared experts;
+#: models/xing.py: the hyper-connections and latent attention's
+#: projections).
 #: They are NOT scopes of the table above: readers of that table know
 #: its short list and refuse another name, so a second table, ``"fine"``
 #: in the file, places the operations that lie under one of these
 FINE_SCOPES = ("eva_summary", "eva_attn",
                # ops/moe.py dropless_experts, models/command.py: what is
                # not a product, the grouped products, the shared experts
-               "moe_route", "moe_experts", "moe_shared")
+               "moe_route", "moe_experts", "moe_shared",
+               # models/xing.py: a sublayer's hyper-connection
+               # coefficients (the streams' norm, the n d x n (n + 2)
+               # product, sigmoids, Sinkhorn), the streams mixed with
+               # them, and what of latent attention is not its kernel
+               "mhc_mix", "mhc_apply", "mla_proj")
 
 #: the file of tables written beside a captured trace
 TABLE_FILE = "op_scopes.json"

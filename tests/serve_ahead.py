@@ -75,8 +75,9 @@ def _live(arrays: list, slot: int, at) -> np.ndarray:
     """A slot's live rows of every kind, flat: ``at`` is the rows of the
     one kind, or a list of them a kind."""
     at = at if isinstance(at, (list, tuple)) else [at]
-    return np.concatenate([a[:, slot, rows].reshape(-1)
-                           for a, rows in zip(arrays, at)])
+    # (no array at all: the values' side of a state of one array)
+    return np.concatenate([np.zeros(0)] + [a[:, slot, rows].reshape(-1)
+                                           for a, rows in zip(arrays, at)])
 
 
 def drive(eng: ServeEngine, prompts, waves, live_rows, *, ahead: bool,

@@ -151,6 +151,50 @@ def test_decode_kernels_compile_for_v5e(monkeypatch, v5e, dtype, geometry,
     assert not _layer_movers(text, layer)
 
 
+@pytest.mark.parametrize("kernel", ["mla_decode", "splash_two_widths"])
+def test_latent_attention_kernels_compile_for_v5e(monkeypatch, v5e, kernel):
+    """models/xing.py at the published widths, bfloat16: the latent decode
+    call (32 query heads against ONE row of 640 lanes, 576 of them values
+    and the first 512 the value, 64 slots x 10,240 rows in blocks of 1024;
+    the cache enters whole and nothing copies it: with 576 as the array's
+    minor dimension the compiler copied all of it), and jax's splash
+    attention with a query / key width of 192 and a value width of 128 (a
+    shorter prompt than the cell's: the tables of an 8192 mask are the
+    slow part)."""
+    from ray_lightning_tpu.ops import flash_decode
+    from ray_lightning_tpu.ops import latent_attention as la
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    monkeypatch.setattr(flash_decode, "_use_interpret", lambda: False)
+    if kernel == "mla_decode":
+        slots, rows, width = 64, 10240, 640
+        fn = lambda q, cache, at: la.cached_attention(  # noqa: E731
+            q, cache, at, layer=3, value_dim=512, sm_scale=0.1447,
+            impl="flash_decode")
+        args = (sds((slots, 32, width)), sds((5, slots, rows, width)),
+                sds((slots,), jnp.int32))
+    else:
+        monkeypatch.setattr(la, "select_prefill_kernel",
+                            lambda T, dv: "splash")
+        fn = lambda q, k, v: la.causal_attention(  # noqa: E731
+            q, k, v, sm_scale=0.1447)
+        args = (sds((1, 2048, 32, 192)), sds((1, 2048, 32, 192)),
+                sds((1, 2048, 32, 128)))
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert {"mla_decode": "mla_decode",
+            "splash_two_widths": "splash_mha_fwd"}[kernel] in text
+    if kernel == "mla_decode":
+        assert flash_decode.latent_block_k(rows) == 1024
+        layer = slots * rows * width
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 0.05 * layer * 2
+        assert not _layer_movers(text, layer)
+
+
 # -- the cache's trip through the serve programs ----------------------------
 #
 # The K/V cache is resident as [n_layer, S, L, H*D] and every program
