@@ -188,8 +188,8 @@ def total_aux_loss(model_state) -> "jax.Array | None":
 # the held ones first and by expert, and the sorted order is cut into
 # PIECES of ``C`` rows (``_piece_rows``); piece ``j`` holds sorted places
 # ``[jC, (j + 1)C)`` and runs only when ``pairs > jC``, so a chip that
-# holds an eighth of the experts moves about an eighth of the rows and a
-# chip that holds them all still computes every pair.
+# holds an eighth of the experts moves about an eighth of the rows; one
+# that holds them all owes the whole bound and pays it in ONE pass.
 
 #: tiles (rows, contraction, columns) of the megablox grouped product on
 #: the TPU, by whether a call is a decode batch's few rows or a prompt's.
@@ -237,12 +237,12 @@ _GMM_TILING_3584_IN = (128, 1792, 1024)
 #: tokens on 16 of 16 experts, 57,344 pairs, all sixteen pieces): 53.79
 #: as it was, 59.03 in pieces (an eighth 58.64): what the shape's choice
 #: costs a chip that holds all its experts.  ``xing4-serve-doc8k`` is the
-#: cell that does (PR 36, 64 of 64 experts of [3584, 1024], 4 a token; by
-#: hand, ms a call): 7168 tokens (27,600 pairs, sixteen pieces of 1,792
-#: rows) 35.31, 8192 (31,600; 2,048) 39.97, 9216 (35,600; 2,304) 44.69,
-#: where the products' operations take 3.1-4.0 and the weights' bytes
-#: 1.72: ~2.5 ms a piece whatever it holds; 64 tokens (one piece) 2.61.
-#: A decode batch (32 tokens, one piece) 1.959 and 1.962.
+#: cell that does (64 of 64 experts of [3584, 1024], 4 a token; a piece is
+#: a run of the by-expert order, so it meets 4-5 experts' groups of ~450-
+#: 560 rows and not all 64): in pieces, by hand, ms a call (PR 36 | PR 37,
+#: a trace): 7168 tokens 35.31 | 36.97, 8192 39.97 | 41.60, 9216 44.69 |
+#: 46.48, the scatter-add alone 27.5 / 31.2 / 35.2: such a chip takes ONE
+#: pass (``_in_one_pass`` has its numbers).  A decode batch 1.959, 1.962.
 _ONE_PIECE_ROWS = 1024
 _PIECE_SHARE = 16
 
@@ -285,15 +285,42 @@ def _grouped_dot(rows, weights, sizes, impl: str):
                               ).astype(rows.dtype)
 
 
+#: tiles by contraction width and by whether a call's rows pass a decode
+#: batch's (``_ONE_PIECE_ROWS``), where the two 4096-wide constants were
+#: read behind.  3584 or 1024 wide and past it is a prompt of
+#: models/xing.py in ONE pass (``_in_one_pass``): 28,672 / 32,768 / 36,864
+#: rows in 64 whole groups of ~450-560.  One product by hand on the v5e
+#: (builder's chip run, PR 37; a trace's kernel events; PERF.md section
+#: 6), ms a call at 7168 / 8192 / 9216 tokens.  In, [rows, 3584] x 64 x
+#: [3584, 1024] (its operations' time 1.07 / 1.22 / 1.37): (256, 3584, 512)
+#: 1.852 / 1.997 / 2.152, (128, 3584, 512) 1.861 / 2.017 / 2.198, (256,
+#: 3584, 256) 2.016 / 2.196 / 2.373, (256, 1792, 1024) 2.200 / 2.397 /
+#: 2.592, (256, 3584, 640) 2.213 / 2.404 / 2.590, (512, 3584, 256) 2.452 /
+#: 2.620 / 2.768, ``_GMM_TILING_3584_IN`` (128, 1792, 1024) 3.179 / 3.520
+#: / 3.873 (an expert's 7.3 MB read again for each of ~350 row tiles); at
+#: 8192 (256, 1024, 1024) 2.740, (256, 512, 1024) 2.636, (512, 1024, 1024)
+#: 2.953; a whole 3584 with 768 or 1024 columns does not fit VMEM.  Out,
+#: [rows, 1024] x 64 x [1024, 3584]: (64, 1024, 3584) 1.867 / 2.027 /
+#: 2.184, (128, 1024, 1792) 1.937 / 2.094 / 2.271, (256, 1024, 1792)
+#: 1.993 / 2.147 / 2.299, (64, 1024, 1792) 1.988 / 2.159 / 2.327, (128,
+#: 1024, 1280) 2.092 / 2.265 / 2.462, (512, 1024, 1792) 2.326 / 2.463 /
+#: 2.601, ``_GMM_TILING_MANY`` (256, 1024, 1024) 2.300 / 2.485 / 2.663;
+#: (128 / 256, 1024, 3584) do not fit.  The way in changes a 9216 layer
+#: by 3.4 ms of 14.0, the way out by 0.5.
+_GMM_TILING_BY_WIDTH = {(3584, False): _GMM_TILING_3584_IN,
+                        (3584, True): (256, 3584, 512),
+                        (1024, True): (64, 1024, 3584)}
+
+
 def gmm_tiling(m: int, k: int) -> tuple:
     """The megablox tiles (rows, contraction, columns) for ``[m, k] x
     [groups, k, n]``, from the shapes handed in: few rows or many
     (``_ONE_PIECE_ROWS``), as the two constants say, the 4096-wide
-    products among them; the measured choice where the contraction is
-    3584 wide."""
-    tiling = _GMM_TILING_FEW if m <= _ONE_PIECE_ROWS else _GMM_TILING_MANY
-    if k == 3584:
-        tiling = _GMM_TILING_3584_IN
+    products among them; the measured choices where the contraction is
+    3584 or, past a decode batch's rows, 1024 wide."""
+    many = m > _ONE_PIECE_ROWS
+    tiling = _GMM_TILING_BY_WIDTH.get(
+        (k, many), _GMM_TILING_MANY if many else _GMM_TILING_FEW)
     return (min(tiling[0], m),) + tiling[1:]
 
 
@@ -326,18 +353,20 @@ def _piece_rows(rows: int) -> int:
 
 
 def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
-                     valid=None, impl: "str | None" = None):
+                     valid=None, impl: "str | None" = None,
+                     published: "int | None" = None):
     """What the held experts add: ``sum_{e chosen, held} w_e E_e(h)``
     with ``E(h) = down (silu(gate h) * up h)``.
 
     ``h`` [T, d] in the compute dtype; ``idx``, ``w`` [T, k] from
     :func:`sigmoid_topk`; ``gate_w``, ``up_w`` [held, d, F], ``down_w``
-    [held, F, d]: experts ``offset .. offset + held`` of the published
-    ones; ``valid`` [T] bool: tokens that exist (a bucket's padding
-    routes nowhere).  Returns ``(y [T, d] float32, pairs, experts_hit,
-    rows)``: the token-expert pairs computed here, the held experts with
-    at least one, and the rows pushed through the grouped products
-    (int32 scalars; ``rows`` a Python int where it is the shape's).
+    [held, F, d]: experts ``offset .. offset + held`` of the ``published``
+    ones (None: not said, and never taken for all of them); ``valid`` [T]
+    bool: tokens that exist (a bucket's padding routes nowhere).  Returns
+    ``(y [T, d] float32, pairs, experts_hit, rows)``: the token-expert
+    pairs computed here, the held experts with at least one, and the rows
+    pushed through the grouped products (int32 scalars; ``rows`` a Python
+    int where it is the shape's).
 
     The ``T * k`` pairs are sorted by expert, the ones that land here
     first.  Up to ``_ONE_PIECE_ROWS`` of them (a decode batch) are ONE
@@ -349,9 +378,12 @@ def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
     ``j`` runs only when ``pairs > j * C`` (a loop of ``ceil(pairs / C)``
     trips around one set of three grouped products): it gathers its own
     ``C`` rows, its group sizes are the parts of ``sizes`` inside it, and
-    its weighted rows are added into their tokens' float32 rows.  Every
-    pair that lands here is computed whatever their number: nothing has
-    a capacity and nothing is dropped."""
+    its weighted rows are added into their tokens' float32 rows.  Where
+    every published expert is held (``held == published``) every pair of
+    every token that exists lands here, pieces save no row, and a prompt
+    too is ONE pass (:func:`_in_one_pass`).  Every pair that lands here
+    is computed whatever their number: nothing has a capacity and
+    nothing is dropped."""
     T, d = h.shape
     k = idx.shape[1]
     held = gate_w.shape[0]
@@ -366,6 +398,8 @@ def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
         sizes = jnp.sum(expert[:, None] == jnp.arange(held)[None, :],
                         axis=0, dtype=jnp.int32)
     weights = (gate_w, up_w, down_w)
+    if held == published and T * k > _ONE_PIECE_ROWS:
+        return _in_one_pass(h, order, sizes, here, w, weights, impl)
     C = _piece_rows(T * k)
     if C < T * k:
         return _in_pieces(h, order, sizes, jnp.where(here, w, 0.0), C,
@@ -382,6 +416,49 @@ def dropless_experts(h, idx, w, gate_w, up_w, down_w, *, offset: int = 0,
         out = jnp.einsum("tk,tkd->td", jnp.where(here, w, 0.0), y,
                          preferred_element_type=jnp.float32)
     return out, pairs, jnp.sum(sizes > 0, dtype=jnp.int32), T * k
+
+
+#: the layer with every expert held (64 of 64 of [3584, 1024], 4 a token,
+#: 3 % of the bucket padding), by hand on the v5e, device ms a call from
+#: a trace at 7168 / 8192 / 9216 tokens (builder's chip runs, PR 37;
+#: PERF.md section 6): in pieces 34.77 / 39.15 / 43.74 (36.97 / 41.60 /
+#: 46.48 on another machine), ONE pass 8.14 / 9.09 / 10.01 (11.32 / 12.57 /
+#: 14.04 before ``_GMM_TILING_BY_WIDTH``).  The pass at 9216: the three
+#: products 6.5 and ``silu * up`` 0.23; the gather in 0.40 (the HBM's
+#: rate), the four gathers back 2.03, the select, weigh and sum 0.58, the
+#: inverse permutation 0.17, the sort 0.04, sizes and the rest ~0.3.
+def _in_one_pass(h, order, sizes, here, w, weights, impl: str):
+    """:func:`dropless_experts` past one piece's rows where every
+    published expert is held: a decode batch's path at a prompt's size.
+    ONE gather into the by-expert order, the three grouped products over
+    whole expert groups, the way back by the inverse permutation as
+    gathers (a token's ``k`` rows, choice by choice: ``[T, d]`` each,
+    where a ``[T, k, d]`` view would pad ``k`` to a tile's sublanes); no
+    loop and no scatter-add.  ``here`` [T, k] the pairs computed (all of
+    a token that exists); the sum over a token's ``k`` rows is float32
+    arithmetic on the float32 ``w`` (a multiply and a sum: an einsum
+    would round ``w`` to bfloat16 on the MXU), and a row past the pairs
+    (undefined, :func:`_grouped_dot`) is selected away there and never
+    multiplied.  Every index is a permutation's: ``mode="clip"`` saves
+    the fill's select, a pass over all the rows.  The products take whole
+    row tiles, as a piece is: a bucket that is not (the cells' are) runs
+    a last tile's rows of token 0 past the pairs."""
+    T, d = h.shape
+    k = w.shape[1]
+    tile = _GMM_TILING_MANY[0]
+    with jax.named_scope("moe_route"):
+        rows = jnp.take(h, jnp.pad(order, (0, -(T * k) % tile)) // k,
+                        axis=0, mode="clip")        # [T*k in tiles, d]
+    y = _gated_products(rows, weights, sizes, impl)
+    with jax.named_scope("moe_route"):
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32)).reshape(T, k)
+        out = sum(jnp.where(
+            here[:, j, None],
+            jnp.take(y, back[:, j], axis=0, mode="clip").astype(jnp.float32)
+            * w[:, j, None], 0.0) for j in range(k))
+    return (out, jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32),
+            T * k)
 
 
 def _in_pieces(h, order, sizes, w, C: int, weights, impl: str):
@@ -489,7 +566,8 @@ class ExpertLayer(nn.Module):
         hc = h.astype(self.dtype)
         y, *counts = dropless_experts(
             hc, idx, w, gate.astype(self.dtype), up.astype(self.dtype),
-            down.astype(self.dtype), offset=self.offset, valid=valid)
+            down.astype(self.dtype), offset=self.offset, valid=valid,
+            published=self.published)
         with jax.named_scope("moe_shared"):
             def dense(width, name):
                 return nn.Dense(width, use_bias=False, dtype=self.dtype,

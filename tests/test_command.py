@@ -290,6 +290,114 @@ def test_the_layer_in_pieces_against_the_references_expert_loop(case):
                                atol=1e-4 * float(jnp.abs(routed).max()))
 
 
+#: the cases of ONE pass (every published expert held, ``T * 4`` sorted
+#: rows past one piece): tokens, tokens that exist, an expert that the
+#: router's bias keeps every token from choosing
+ONE_PASS = {
+    "every_token_exists": (320, None, None),
+    "valid_cuts_the_buckets_tail": (320, 290, None),
+    "fewer_pairs_than_one_piece": (320, 100, None),
+    "no_token_exists": (320, 0, None),
+    "an_expert_gets_no_pair": (384, 350, 5),
+    "a_bucket_that_is_not_whole_row_tiles": (333, 300, None),
+}
+
+
+def _jaxpr_of_the_layer(T, model, **kw):
+    z = ref.sizes(model)
+    return str(jax.make_jaxpr(
+        lambda h, idx, w: moe.dropless_experts(
+            h, idx, w, *_held(model, 2), offset=z["offset"], impl="ragged",
+            **kw))(
+                _layer_inputs(T), jnp.zeros((T, 4), jnp.int32),
+                jnp.ones((T, 4), jnp.float32)))
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("case", list(ONE_PASS))
+def test_the_layer_in_one_pass_against_the_loop_and_the_pieces(case):
+    """Told that it holds every published expert, the layer takes a
+    prompt's rows in ONE pass (one gather in, whole expert groups, one
+    gather back): the reference's loop over the experts (``moe_parts``),
+    a dense sum over the experts from the pairs themselves, and the
+    in-pieces result on the same inputs, to float32 rounding; zero at a
+    token that does not exist; ``rows`` is ``T * k``."""
+    T, length, unchosen = ONE_PASS[case]
+    model = {**MODEL, "num_experts": 16, "expert_offset": 0}
+    h = _layer_inputs(T)
+    assert moe._ONE_PIECE_ROWS < T * 4
+    valid = None if length is None else jnp.arange(T) < length
+    bias = None if unchosen is None else \
+        jnp.zeros((16,)).at[unchosen].set(-10.0)
+    idx, w = moe.sigmoid_topk(h, ref.leaf(MODEL, KEY, "router_w", 2), 4,
+                              bias)
+    held = _held(model, 2)
+
+    def layer(**kw):
+        return jax.jit(lambda h, idx, w: moe.dropless_experts(
+            h, idx, w, *held, valid=valid, impl="ragged", **kw))(h, idx, w)
+
+    y, pairs, hit, rows = layer(published=16)
+    pieces, p_pairs, p_hit, p_rows = layer()
+    exists = T if length is None else length
+    chosen = np.asarray(idx)[:exists]
+    # every pair of every token that exists lands here
+    assert int(pairs) == int(p_pairs) == 4 * exists and rows == T * 4
+    assert int(hit) == int(p_hit) == len(set(chosen.reshape(-1).tolist()))
+    assert int(p_rows) == -(-4 * exists // 256) * 256
+    if unchosen is not None:
+        assert int(hit) == 15 and unchosen not in chosen
+    # the dense form: each expert over every token under its weight
+    gated = lambda e: (jax.nn.silu(h @ held[0][e])  # noqa: E731
+                       * (h @ held[1][e])) @ held[2][e]
+    dense = sum(jnp.sum(jnp.where(idx == e, w, 0.0), -1)[:, None] * gated(e)
+                for e in range(16))
+    if valid is not None:
+        dense = jnp.where(valid[:, None], dense, 0)
+    tol = 3e-4 * float(jnp.abs(dense).max()) if exists else 0.0
+    np.testing.assert_allclose(np.asarray(y), np.asarray(dense), atol=tol)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(pieces), atol=tol)
+    if unchosen is None:
+        routed = ref.moe_parts(h, model, KEY, 2)[0]
+        if valid is not None:
+            routed = jnp.where(valid[:, None], routed, 0)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(routed),
+                                   atol=tol)
+    if not exists:
+        assert not np.asarray(y).any()
+
+
+@pytest.mark.limit(60)
+@pytest.mark.parametrize("held,published,loop", [
+    (16, 16, False), (16, None, True), (8, 16, True), (4, 16, True)])
+def test_one_pass_only_where_every_published_expert_is_held(held, published,
+                                                            loop):
+    """The piece count follows from ``held`` and ``published``: one pass
+    (no ``while``, no ``scatter-add``, a multiply and a sum where the
+    decode batch has its ``dot_general``) only where they are equal;
+    fewer held, or ``published`` not said, and a prompt's rows run in
+    pieces as they did; a decode batch's jaxpr is the same whatever is
+    said."""
+    model = {**MODEL, "num_experts": held, "expert_offset": 0}
+    kw = {} if published is None else {"published": published}
+    text = _jaxpr_of_the_layer(320, model, **kw)
+    assert ("while" in text) == loop and ("scatter-add" in text) == loop
+    assert text.count("ragged_dot_general") == 3
+    if not loop:
+        assert " dot_general" not in text
+    assert _jaxpr_of_the_layer(64, model, **kw) \
+        == _jaxpr_of_the_layer(64, model)
+    # the layer both families build hands the fact down
+    layer = moe.ExpertLayer(d=64, width=48, held=held, published=16,
+                            top_k=4, n_shared=1, dtype=jnp.float32)
+    h = _layer_inputs(320)
+    params = layer.init(KEY, h)
+    text = str(jax.make_jaxpr(lambda p, h: layer.apply(p, h))(params, h))
+    assert ("while" in text) == (held < 16)
+    _, (_, _, rows) = layer.apply(params, h)
+    assert (int(rows) == 320 * 4) == (held == 16)
+
+
 @pytest.mark.limit(120)
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
                                         (jnp.bfloat16, 2e-2)])
@@ -582,7 +690,7 @@ def v5e():
 
 @pytest.mark.limit(240)
 @pytest.mark.parametrize("kernel", ["gqa_decode", "splash", "gmm",
-                                    "gmm_in_pieces"])
+                                    "gmm_in_pieces", "gmm_in_one_pass"])
 def test_kernels_compile_for_v5e_at_the_published_widths(monkeypatch, v5e,
                                                          kernel):
     """128 query heads on 8 K/V heads of 128, experts of 4096 x 4096,
@@ -592,7 +700,10 @@ def test_kernels_compile_for_v5e_at_the_published_widths(monkeypatch, v5e,
     grouped product inside the dropless layer at a decode batch (one
     piece) and at a prompt's rows (4096 tokens: the three products with
     a prompt's tiles in the body of a loop over pieces of 2048 rows, and
-    under half the temporaries of the layer that moved all 32,768)."""
+    under half the temporaries of the layer that moved all 32,768); and
+    models/xing.py's layer, 64 of 64 experts of 3584 x 1024 held, 4 a
+    token, at its cell's 9216 bucket: one pass, so one ``gmm`` call a
+    product and none in the body of a loop."""
     from ray_lightning_tpu.ops import flash_decode
 
     def on_chip(shape, dtype=jnp.bfloat16):
@@ -611,6 +722,14 @@ def test_kernels_compile_for_v5e_at_the_published_widths(monkeypatch, v5e,
             q, k, v, window=1024)
         args = (on_chip((1, 2048, 128, 128)), on_chip((1, 2048, 8, 128)),
                 on_chip((1, 2048, 8, 128)))
+    elif kernel == "gmm_in_one_pass":
+        T = 9216
+        fn = lambda h, i, w, g, u, d, ok: moe.dropless_experts(  # noqa: E731
+            h, i, w, g, u, d, valid=ok, impl="gmm", published=64)[0]
+        args = (on_chip((T, 3584)), on_chip((T, 4), jnp.int32),
+                on_chip((T, 4), jnp.float32), on_chip((64, 3584, 1024)),
+                on_chip((64, 3584, 1024)), on_chip((64, 1024, 3584)),
+                on_chip((T,), jnp.bool_))
     else:
         T = 32 if kernel == "gmm" else 4096
         fn = lambda h, i, w, g, u, d, ok: moe.dropless_experts(  # noqa: E731
@@ -624,12 +743,22 @@ def test_kernels_compile_for_v5e_at_the_published_widths(monkeypatch, v5e,
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert {"gqa_decode": "gqa_decode", "splash": "splash_mqa_fwd",
-            "gmm": "gmm", "gmm_in_pieces": "gmm"}[kernel] in text
+            "gmm": "gmm", "gmm_in_pieces": "gmm",
+            "gmm_in_one_pass": "gmm"}[kernel] in text
     if kernel == "gmm_in_pieces":
         assert moe._piece_rows(T * 8) == 2048 > moe._ONE_PIECE_ROWS
         assert "while/body/moe_experts/jit(gmm)/pallas_call" in text
         # PR 33's layer at this size, compiled the same way: 805,951,488
         assert compiled.memory_analysis().temp_size_in_bytes < 805_951_488 / 2
+    if kernel == "gmm_in_one_pass":
+        assert T * 4 > moe._ONE_PIECE_ROWS
+        assert text.count('custom_call_target="tpu_custom_call"') == 3
+        assert "moe_experts/jit(gmm)/pallas_call" in text \
+            and "while/body/moe_experts" not in text \
+            and "while/body/moe_route" not in text
+        # this tree's own described compile (PR 37): 529,611,264, the
+        # products' output beside its four gathered parts
+        assert compiled.memory_analysis().temp_size_in_bytes < 560_000_000
 
 
 @pytest.mark.limit(60)

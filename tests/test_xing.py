@@ -135,6 +135,35 @@ def test_forward_matches_reference(params, T):
     np.testing.assert_allclose(np.asarray(got)[0], _full(seq), atol=ATOL)
 
 
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("T", [29, 50])
+def test_a_prompt_past_one_pieces_rows_is_one_pass_through_the_model(
+        params, monkeypatch, T):
+    """Every expert is held (8 of 8), so ``ExpertLayer`` hands
+    ``dropless_experts`` the fact and a prompt whose ``T x 2`` sorted rows
+    pass one piece's (put at 32 here: this size has no 1024) runs ONE
+    pass, no scatter-add in the whole forward: the reference's logits;
+    told nothing, the same layer runs its pieces and reads the same."""
+    monkeypatch.setattr(moe, "_ONE_PIECE_ROWS", 32)
+    monkeypatch.setattr(moe, "_GMM_TILING_MANY", (2, 1024, 1024))
+    assert moe._piece_rows(T * 2) < T * 2
+    seq = jnp.asarray(_tokens(T, T))[None]
+    net = Xing(CFG)
+    forward = lambda p, t: net.apply({"params": p}, t)  # noqa: E731
+    got = forward(params, seq)
+    np.testing.assert_allclose(np.asarray(got)[0], _full(np.asarray(seq)[0]),
+                               atol=ATOL)
+    assert "scatter-add" not in str(jax.make_jaxpr(forward)(params, seq))
+    told = moe.dropless_experts
+    monkeypatch.setattr(moe, "dropless_experts", lambda *a, published, **kw:
+                        told(*a, **kw))
+    # (a function of its own: a trace is cached by the function traced)
+    pieces = lambda p, t: net.apply({"params": p}, t)  # noqa: E731
+    assert "scatter-add" in str(jax.make_jaxpr(pieces)(params, seq))
+    np.testing.assert_allclose(np.asarray(pieces(params, seq)),
+                               np.asarray(got), atol=ATOL)
+
+
 @pytest.mark.limit(240)
 @pytest.mark.parametrize("impl", ["flash_decode", "dense"])
 def test_prefill_then_decode_is_the_absorbed_path_against_the_expanded(
@@ -348,9 +377,13 @@ def test_without_bias_and_factor_the_router_is_the_program_it_was():
     (65536, 4096, (256, 1024, 1024)),
     (64, 4096, (64, 1024, 2048)),
     (256, 3584, (128, 1792, 1024)),
-    (4096, 3584, (128, 1792, 1024)),
+    (1024, 3584, (128, 1792, 1024)),
+    (4096, 3584, (256, 3584, 512)),
+    (36864, 3584, (256, 3584, 512)),
     (256, 1024, (128, 1024, 2048)),
-    (4096, 1024, (256, 1024, 1024))])
+    (1024, 1024, (128, 1024, 2048)),
+    (4096, 1024, (64, 1024, 3584)),
+    (36864, 1024, (64, 1024, 3584))])
 def test_the_grouped_products_tiles_follow_the_shapes(m, k, want):
     assert moe.gmm_tiling(m, k) == want
 
