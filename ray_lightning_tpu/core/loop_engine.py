@@ -157,7 +157,10 @@ class StreamSource:
                                     payload=batch)
             return None
         finally:
-            waited = time.monotonic() - t0
+            # one pair of reads for the loop's clock, the metrics plane
+            # and the goodput ledger
+            waited = t._loop_clock.add("data_wait", t0,
+                                       step=t.global_step) - t0
             _metrics.on_data_wait(waited)
             _goodput.on_data_wait(waited)
 

@@ -58,11 +58,20 @@ from ray_lightning_tpu.parallel.gather import fetch_tree
 from ray_lightning_tpu.parallel.mesh import set_current_mesh
 from ray_lightning_tpu.parallel.strategy import resolve_strategy
 from ray_lightning_tpu.telemetry import TelemetryConfig, span
+from ray_lightning_tpu.telemetry import clocks as _clocks
+from ray_lightning_tpu.telemetry import scopes as _scopes
 from ray_lightning_tpu.telemetry import spans as _spans
 from ray_lightning_tpu.telemetry import metrics as _metrics
 from ray_lightning_tpu.utils.seed import reset_seed, seed_everything
 
 _log = logging.getLogger(__name__)
+
+#: what the fit loop's clock is charged with (``Trainer.loop_stats()``):
+#: the loader (``data_wait``), user hooks around a dispatch
+#: (``callbacks``), the body of the ``step`` span (``dispatch``:
+#: ``run_one`` / ``run_chunk``) and fetches that block on the device
+#: (``device_wait``); the span sites of the same names
+LOOP_PHASES = ("data_wait", "callbacks", "dispatch", "device_wait")
 
 _RUNTIME_FIELDS = (
     "state", "_mesh", "_train_step", "_eval_steps", "_predict_step",
@@ -268,6 +277,9 @@ class Trainer:
         #: harness reads (plugins set it in their teardown)
         self._goodput_local: Optional[dict] = None
         self._goodput_report: Optional[dict] = None
+        #: where the fit loop's wall time goes (telemetry/clocks.py): a
+        #: new one each stage, read by ``loop_stats()``
+        self._loop_clock = _clocks.PhaseClock(LOOP_PHASES)
 
     # ------------------------------------------------------------------
     # pickling across the driver→worker boundary (ray_ddp.py:164-172
@@ -352,6 +364,9 @@ class Trainer:
         self._stage = stage
         self._stage_t0 = time.monotonic()
         self.time_to_first_step = None
+        # where the loop's wall time goes, always on: starts at the first
+        # step's result (telemetry/clocks.py; ``loop_stats()``)
+        self._loop_clock = _clocks.PhaseClock(LOOP_PHASES)
         # set-up is kept whatever the telemetry flag says (dozens of
         # spans, none in a loop): one root from here to the end of the
         # first step, read afterwards from telemetry.spans.kept(<root>)
@@ -570,7 +585,29 @@ class Trainer:
             for cb in self.callbacks:
                 cb.teardown(self, module, stage)
             self._close_goodput_ledger()
+            self._close_loop_clock(stage)
         return result
+
+    def loop_stats(self) -> dict:
+        """Where this stage's loop spent its wall time since its first
+        step's result (``PhaseClock.snapshot()``): per phase of
+        ``LOOP_PHASES`` the ``seconds``, the intervals ``n`` and the
+        ``longest`` one with its ``step`` and ``ts``; ``steps``;
+        ``wall_s``, whose rest is ``seconds["other"]`` (transfers, hook
+        plans, evaluation, epoch ends).  After the stage the same is
+        ``telemetry.clocks.last(<stage>)``."""
+        return self._loop_clock.snapshot()
+
+    def _close_loop_clock(self, stage: str) -> None:
+        """The stage is over: stop its clock and keep the snapshot for a
+        reader that holds no trainer; and where a profiler session was
+        open over this stage's programs (a span site saw one), keep
+        their scope tables before anything lets go of the programs
+        (telemetry/scopes.py).  No session: one boolean."""
+        self._loop_clock.stop()
+        _clocks.keep(stage, self._loop_clock.snapshot())
+        if _spans.session_seen():
+            _scopes.remember()
 
     def _close_goodput_ledger(self) -> None:
         """Finalize this stage's goodput ledger: fold the snapshotter's
@@ -1461,6 +1498,8 @@ class Trainer:
 
     def _engine_one(self, module, source, item) -> None:
         invoke, want_batch = self._batch_hook_plan()
+        clock, step = self._loop_clock, self.global_step
+        t0 = time.monotonic()
         if invoke:
             # user code between two dispatches
             with span("callbacks", hook="on_train_batch_start"):
@@ -1468,15 +1507,11 @@ class Trainer:
                 for cb in self.callbacks:
                     cb.on_train_batch_start(self, module, batch,
                                             item.batch_idx)
-        t0 = time.monotonic()
-        with span("step", step=self.global_step):
+            t0 = clock.add("callbacks", t0, step=step)
+        with span("step", step=step):
             metrics = source.run_one(self, item)
-        self.global_step += 1
-        first = self._note_first_step(metrics)
-        step_s = time.monotonic() - t0
+        step_s = self._note_dispatch(metrics, t0, 1)
         _metrics.on_step(step_s, step=self.global_step)
-        if self._goodput_ledger is not None:
-            self._goodput_ledger.note_step(step_s, first=first)
         if self._redundancy is not None:
             # parity BEFORE the snapshot: a rank that dies inside the
             # save (snapkill) has already escrowed this step
@@ -1487,16 +1522,20 @@ class Trainer:
         if self.global_step % self.log_every_n_steps == 0:
             self._publish_metrics(metrics)
         if invoke:
+            t0 = time.monotonic()
             with span("callbacks", hook="on_train_batch_end"):
                 for cb in self.callbacks:
                     cb.on_train_batch_end(self, module, metrics, batch,
                                           item.batch_idx)
+            clock.add("callbacks", t0, step=step)
 
     def _engine_chunk(self, module, source, items) -> None:
         """k steps in ONE dispatch; batch-granular callbacks coarsen to
         once per chunk (starts for every batch, one end with the chunk's
         stacked metrics and its last batch)."""
         invoke, want_batch = self._batch_hook_plan()
+        clock, before = self._loop_clock, self.global_step
+        t0 = time.monotonic()
         if invoke:
             with span("callbacks", hook="on_train_batch_start"):
                 for it in items:
@@ -1505,19 +1544,13 @@ class Trainer:
                             self, module,
                             it.batch() if want_batch else None,
                             it.batch_idx)
-        before = self.global_step
+            t0 = clock.add("callbacks", t0, step=before)
         # k steps ride one span; the aggregator normalizes per-step time
         # by the "k" attribute when computing percentiles
-        t0 = time.monotonic()
         with span("step", step=before, k=len(items)):
             metrics = source.run_chunk(self, items)
-        self.global_step += len(items)
-        first = self._note_first_step(metrics)
-        step_s = time.monotonic() - t0
+        step_s = self._note_dispatch(metrics, t0, len(items))
         _metrics.on_step(step_s, k=len(items), step=self.global_step)
-        if self._goodput_ledger is not None:
-            self._goodput_ledger.note_step(step_s, k=len(items),
-                                           first=first)
         if self._redundancy is not None:
             # chunked dispatch coarsens the parity cadence to chunk
             # boundaries, exactly like the snapshot cadence below
@@ -1530,12 +1563,34 @@ class Trainer:
         self._publish_if_crossed(before, jax.tree_util.tree_map(
             lambda a: a[-1], metrics))
         if invoke:
+            t0 = time.monotonic()
             with span("callbacks", hook="on_train_batch_end"):
                 for cb in self.callbacks:
                     cb.on_train_batch_end(
                         self, module, metrics,
                         items[-1].batch() if want_batch else None,
                         items[-1].batch_idx)
+            clock.add("callbacks", t0, step=before)
+
+    def _note_dispatch(self, metrics, t0: float, k: int) -> float:
+        """The ``step`` span just closed on a dispatch of ``k`` steps
+        that began at ``t0``: count the steps, charge the loop's clock
+        and the goodput ledger, and return the seconds.  One pair of
+        clock reads serves all three.  The first dispatch of a stage is
+        timed to its RESULT (``_note_first_step`` waits for it, and the
+        loop's clock starts there): the ledger books it as compile."""
+        clock, step = self._loop_clock, self.global_step
+        t1 = time.monotonic()
+        self.global_step += k
+        first = self._note_first_step(metrics)
+        if first:
+            t1 = clock.t_start
+        elif clock.t_start is not None:
+            clock.add("dispatch", t0, t1, step=step)
+            clock.steps += k
+        if self._goodput_ledger is not None:
+            self._goodput_ledger.note_step(t1 - t0, k=k, first=first)
+        return t1 - t0
 
     def _note_first_step(self, metrics) -> bool:
         """Record time-to-first-step once per stage: the startup cost
@@ -1548,7 +1603,9 @@ class Trainer:
             return False
         with span("device_wait", what="first_step"):
             jax.block_until_ready(metrics)
-        self.time_to_first_step = time.monotonic() - self._stage_t0
+        # compile and the first step lie before the loop's clock
+        self.time_to_first_step = \
+            self._loop_clock.start() - self._stage_t0
         compile_cache.note_first_step(self.time_to_first_step)
         self._close_setup_spans()
         return True
@@ -1567,8 +1624,9 @@ class Trainer:
         t0 = time.monotonic()
         with span("device_wait", what=what):
             out = jax.device_get(tree)
+        t1 = self._loop_clock.add("device_wait", t0, step=self.global_step)
         if self._goodput_ledger is not None:
-            self._goodput_ledger.add("step", time.monotonic() - t0)
+            self._goodput_ledger.add("step", t1 - t0)
         return out
 
     def _publish_metrics(self, metrics: dict) -> None:

@@ -54,6 +54,7 @@ from ray_lightning_tpu.serve.buckets import bucket_for, pad_to_bucket
 from ray_lightning_tpu.serve.kvcache import SlotAllocator
 from ray_lightning_tpu.telemetry import metrics as _metrics
 from ray_lightning_tpu.telemetry import tracing as _tracing
+from ray_lightning_tpu.telemetry.clocks import PhaseClock
 
 #: histogram bounds for TTFT/TPOT (seconds): sub-ms CPU-mesh decodes up
 #: to multi-second cold paths
@@ -156,13 +157,14 @@ class ServeRequest:
         self._event.set()
 
 
-class PumpClock:
+class PumpClock(PhaseClock):
     """Where the driver pump's wall time goes, always on: one clock read
     at each boundary of a step (before ``plan``, after it, after the
     ``serve_step`` calls went out, after they all came back, after
-    ``apply``), summed per phase.  ``Server``'s pump owns the reads; the
-    sums come back under ``Scheduler.stats()["pump"]`` so that whoever
-    reads the scheduler's stats reads them too.
+    ``apply``), summed per phase (telemetry/clocks.py).  ``Server``'s
+    pump owns the reads; the sums come back under
+    ``Scheduler.stats()["pump"]`` so that whoever reads the scheduler's
+    stats reads them too.
 
     ``loop_s`` is the pump's own bookkeeping between two steps (queue
     drain, watchdog, goodput peek); ``worker_s`` the workers' own
@@ -175,46 +177,91 @@ class PumpClock:
     ``ahead_hits`` / ``ahead_misses`` count the steps whose decode the
     worker had queued before the plan arrived, and those where it had
     to drop one or queue the plan's own (worker.py ``_run_ahead``), as
-    the step results report them."""
+    the step results report them.
+
+    ``kinds`` splits the steps by what their plan carried
+    (``kind_of``): per kind the steps ``n``, their ``wall_s`` (a step's
+    ``loop + plan + call + wait + apply``), the ``prompt_tokens`` of
+    their prefills and the ``longest`` step, ``{"seconds", "step",
+    "ts", "phase"}`` with ``phase`` the largest of that step's five.
+    The worker fetches a plan's own prefills' first tokens before it
+    returns (worker.py ``_run_ahead``), so a prefill's time lies in its
+    own step's ``wait`` and the decode queued ahead behind it in the
+    next step's: a kind's wall less ``n`` times a ``decode`` step's
+    mean is what its prefills cost.  The steps of an on-demand profile
+    window are a kind of their own, ``profiled``, whatever their plans
+    carried: the profiler starts in the first and, in the last, stops,
+    writes its trace and reads the scope tables, seconds that are no
+    prefill's and no decode's.  ``longest`` is the longest over all
+    kinds, with its ``kind``."""
 
     PHASES = ("loop", "plan", "call", "wait", "apply", "idle")
+    STEP_PHASES = PHASES[:5]
 
-    def __init__(self, clock=time.monotonic):
-        self._clock = clock
-        self.steps = 0
+    def __init__(self, clock=time.monotonic,
+                 wall_offset: Optional[float] = None):
+        super().__init__(self.PHASES, clock=clock, wall_offset=wall_offset)
         self.worker_s = 0.0
         self.ahead_hits = 0
         self.ahead_misses = 0
-        self.seconds = dict.fromkeys(self.PHASES, 0.0)
-        self._t_start: Optional[float] = None
-        self._t_stop: Optional[float] = None
+        #: kind -> [n, wall_s, prompt_tokens, longest seconds, its step,
+        #: its edges]; a new kind is a new key, read by ``snapshot``
+        #: from another thread through ``list()``
+        self._kinds: dict = {}
 
-    def start(self) -> float:
-        self._t_start = self._clock()
-        return self._t_start
+    @staticmethod
+    def kind_of(plan: dict) -> str:
+        """``decode`` for a plan that carried no prefill, else
+        ``prefill_<bucket>``, several prefills' buckets sorted and
+        joined by ``+``."""
+        prefills = plan["prefills"]
+        if not prefills:
+            return "decode"
+        return "prefill_" + "+".join(
+            map(str, sorted(p["bucket"] for p in prefills)))
 
-    def stop(self) -> None:
-        self._t_stop = self._clock()
+    def note_step(self, plan: dict, edges: tuple,
+                  profiled: bool = False) -> None:
+        """Count one finished step under its kind (``profiled``: it ran
+        under a profile window).  ``edges`` are the step's six clock
+        reads: the last iteration's end, then the end of ``loop``,
+        ``plan``, ``call``, ``wait`` and ``apply``."""
+        kind = "profiled" if profiled else self.kind_of(plan)
+        k = self._kinds.get(kind)
+        if k is None:
+            k = self._kinds[kind] = [0, 0.0, 0, 0.0, None, None]
+        wall = edges[5] - edges[0]
+        k[0] += 1
+        k[1] += wall
+        if plan["prefills"]:
+            k[2] += sum(p["length"] for p in plan["prefills"])
+        if wall > k[3]:
+            k[3], k[4], k[5] = wall, self.steps, edges
+        self.steps += 1
 
-    def now(self) -> float:
-        return self._clock()
-
-    def add(self, phase: str, t0: float,
-            t1: Optional[float] = None) -> float:
-        """Charge ``phase`` with the time from ``t0`` to ``t1`` (now when
-        left out); returns ``t1``."""
-        if t1 is None:
-            t1 = self._clock()
-        self.seconds[phase] += t1 - t0
-        return t1
+    def _step_doc(self, seconds: float, step: int, edges: tuple) -> dict:
+        spent = [b - a for a, b in zip(edges, edges[1:])]
+        return {"seconds": seconds, "step": step,
+                "ts": edges[0] + self._wall,
+                "phase": self.STEP_PHASES[spent.index(max(spent))]}
 
     def snapshot(self) -> dict:
         out = {f"{k}_s": v for k, v in self.seconds.items()}
         out.update(steps=self.steps, worker_s=self.worker_s,
                    ahead_hits=self.ahead_hits,
                    ahead_misses=self.ahead_misses)
-        if self._t_start is not None:
-            out["wall_s"] = (self._t_stop or self._clock()) - self._t_start
+        wall = self.wall_s()
+        if wall is not None:
+            out["wall_s"] = wall
+        kinds, longest = {}, None
+        for kind, (n, wall_s, prompt, seconds, step, edges) in list(
+                self._kinds.items()):
+            doc = self._step_doc(seconds, step, edges)
+            kinds[kind] = {"n": n, "wall_s": wall_s,
+                           "prompt_tokens": prompt, "longest": doc}
+            if longest is None or seconds > longest["seconds"]:
+                longest = {**doc, "kind": kind}
+        out.update(kinds=kinds, longest=longest)
         return out
 
 

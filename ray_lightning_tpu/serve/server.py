@@ -626,8 +626,11 @@ class Server:
             sched.apply(plan, result)
         if self._profile_ctl is not None:
             self._profile_ctl.note_step()
-        pump.steps += 1
-        return pump.add("apply", t_wait), "step"
+        t_apply = pump.add("apply", t_wait)
+        # (``_kept_steps``: this step rode an on-demand profile window)
+        pump.note_step(plan, (t, t_loop, t_plan, t_call, t_wait, t_apply),
+                       profiled=self._kept_steps > 0)
+        return t_apply, "step"
 
     # -- goodput (telemetry/goodput.py) ------------------------------------
 

@@ -11,7 +11,11 @@ every executable live in this process (the ones that loaded and ran,
 whatever tree compiled them into the cache) into a table from operation
 name to scope per program; each profiler window the program captures
 gets them beside it (``op_scopes.json``, telemetry/tracing.py
-``WorkerProfiler``, utils/profiling.py).  No window, no parse.
+``WorkerProfiler``, utils/profiling.py).  No window, no parse.  An
+executable lives as long as its jitted function does, which is nobody's
+contract with a reader: whoever had a session open over its programs
+calls ``remember()`` before it lets go of them, and ``tables()`` gives
+what was remembered under what is live.
 
 The scopes are a short fixed list.  ``models/gpt.py``, ``core/steps.py``,
 ``ops/attention.py`` and ``ops/losses.py`` enter them with
@@ -127,13 +131,11 @@ def table_from_text(hlo_text: str, names=SCOPES) -> "tuple[str, dict]":
     return module, table
 
 
-def tables(names=SCOPES) -> "dict[str, dict[str, Optional[str]]]":
+def _live(names) -> "dict[str, dict[str, Optional[str]]]":
     """``{program: table}`` of every executable live in this process,
-    read now (``names``: the list a path is searched for, ``SCOPES`` or
-    ``FINE_SCOPES``).  Two live programs of one name (a step retraced for
+    read now.  Two live programs of one name (a step retraced for
     another shape) share a table; an operation name they place
-    differently is left out, so a reader that meets it fails.  Never
-    raises: the tables are evidence, not a dependency."""
+    differently is left out, so a reader that meets it fails."""
     jax = sys.modules.get("jax")
     out: dict = {}
     clash: set = set()
@@ -156,13 +158,45 @@ def tables(names=SCOPES) -> "dict[str, dict[str, Optional[str]]]":
     return out
 
 
+#: what ``remember()`` read, per list of names and program: a program's
+#: table is replaced whole by a later reading, never added to, so the
+#: store is bounded by the number of program names
+_remembered: "dict[tuple, dict[str, dict]]" = {}
+
+
+def remember() -> None:
+    """Read the live executables now, under both lists of names, and
+    keep their tables: an executable goes when the last reference to
+    its jitted function does (a trainer let go of, an engine rebuilt),
+    and a reader that asks afterwards still finds what ran.  Whoever
+    had a profiler session open over its programs calls this before it
+    lets go of them (``Trainer`` at the end of a stage; ``write_tables``
+    for a window the program captured itself): no session, no parse."""
+    for names in (SCOPES, FINE_SCOPES):
+        _remembered.setdefault(tuple(names), {}).update(_live(names))
+
+
+def tables(names=SCOPES) -> "dict[str, dict[str, Optional[str]]]":
+    """``{program: table}`` (``names``: the list a path is searched for,
+    ``SCOPES`` or ``FINE_SCOPES``): the tables ``remember()`` kept,
+    overlaid by those of every executable live in this process, read
+    now.  A live program's table wins whole over a remembered one of
+    its name.  Never raises: the tables are evidence, not a
+    dependency."""
+    return {**_remembered.get(tuple(names), {}), **_live(names)}
+
+
 def write_tables(trace_dir: str) -> Optional[str]:
-    """Write this process's tables beside a trace it captured."""
-    snap = tables()
+    """Write this process's tables beside a trace it captured, and keep
+    them (``remember()``: one reading serves both, and what was just
+    remembered IS ``tables()``)."""
+    remember()
+    snap = _remembered[tuple(SCOPES)]
     if not snap:
         return None
     path = os.path.join(trace_dir, TABLE_FILE)
-    fine = {program: placed for program, table in tables(FINE_SCOPES).items()
+    fine = {program: placed
+            for program, table in _remembered[tuple(FINE_SCOPES)].items()
             if (placed := {op: s for op, s in table.items() if s})}
     with open(path, "w") as f:
         json.dump({"scopes": list(SCOPES), "programs": snap,
@@ -171,4 +205,4 @@ def write_tables(trace_dir: str) -> Optional[str]:
 
 
 __all__ = ["SCOPES", "FINE_SCOPES", "TABLE_FILE", "INHERITED", "scope_of",
-           "table_from_text", "tables", "write_tables"]
+           "table_from_text", "remember", "tables", "write_tables"]

@@ -87,12 +87,30 @@ def _annotation():
     return _annotation_cls
 
 
+#: a span site found a profiler session open since the flag was last read
+_session_seen = False
+
+
+def session_seen() -> bool:
+    """Whether any span site of this process has found a profiler
+    session open since this was last asked; asking clears it.  The
+    sites ask ``is_enabled()`` anyway, so whoever owns the programs a
+    session may have captured (``Trainer`` at the end of a stage) learns
+    for one boolean whether their scope tables are worth keeping
+    (telemetry/scopes.py ``remember``)."""
+    global _session_seen
+    seen, _session_seen = _session_seen, False
+    return seen
+
+
 def _annotate(name: str, attrs: Optional[dict]):
     """The span's profiler annotation, or None when no profiler session
     is open (one static call to find out) or jax is not imported."""
+    global _session_seen
     ann = _annotation_cls or _annotation()
     if ann is None or not ann.is_enabled():
         return None
+    _session_seen = True
     if not attrs:
         return ann(ANNOTATION_PREFIX + name)
     if "traces" in attrs:

@@ -252,7 +252,9 @@ class WorkerProfiler:
             import jax
             jax.profiler.stop_trace()
             # operation names in the trace are fusion.N: the table that
-            # says which part of the model each belongs to goes beside it
+            # says which part of the model each belongs to goes beside
+            # it, and stays in this process for a reader that asks after
+            # the programs went (a serve engine rebuilt)
             scopes.write_tables(self._dir)
         except Exception as e:
             _log.warning("profile: stop_trace failed: %s", e)

@@ -360,6 +360,147 @@ def test_scope_tables_are_read_from_the_live_executables_when_asked(
                if op in table)
 
 
+def _run_once(name: str, n: int = 8):
+    """Jit and run a small scoped function named ``name``: ``(the jitted
+    function, its program, its table)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.telemetry import scopes
+
+    def fn(x, w):
+        with jax.named_scope("mlp"):
+            h = jnp.tanh(x @ w)
+        with jax.named_scope("loss"):
+            return jnp.sum(h * h)
+
+    fn.__name__ = fn.__qualname__ = name
+    jitted = jax.jit(fn)
+    jitted(jnp.ones((n, 8)), jnp.ones((8, 8))).block_until_ready()
+    program = "jit_" + name
+    return jitted, program, scopes._live(scopes.SCOPES)[program]
+
+
+def test_remembered_scope_tables_outlive_their_executable():
+    import gc
+
+    from ray_lightning_tpu.telemetry import scopes
+    jitted, program, table = _run_once("outlives_fn")
+    assert {"mlp", "loss"} <= set(table.values())
+    scopes.remember()
+    del jitted
+    gc.collect()
+    # the executable went with its jitted function ...
+    assert program not in scopes._live(scopes.SCOPES)
+    # ... and a reader that asks now still finds what ran, under both
+    # lists of names
+    assert scopes.tables()[program] == table
+    assert program in scopes.tables(scopes.FINE_SCOPES)
+    assert set(scopes.tables(scopes.FINE_SCOPES)[program].values()) == {None}
+
+
+def test_a_live_program_wins_whole_over_a_remembered_one():
+    from ray_lightning_tpu.telemetry import scopes
+    jitted, program, table = _run_once("live_wins_fn")
+    # what was remembered under this name is another tree's program:
+    # nothing of it may show beside the live one's operations
+    scopes._remembered.setdefault(tuple(scopes.SCOPES), {})[program] = {
+        "fusion.stale": "attn", **dict.fromkeys(table, "embed")}
+    assert scopes.tables()[program] == table
+    # and a program that is NOT live is served from the store untouched
+    scopes._remembered[tuple(scopes.SCOPES)]["jit_only_remembered"] = {
+        "fusion.1": "mlp"}
+    assert scopes.tables()["jit_only_remembered"] == {"fusion.1": "mlp"}
+    del scopes._remembered[tuple(scopes.SCOPES)]["jit_only_remembered"]
+    del jitted
+
+
+def test_the_remembered_store_does_not_grow_over_fifty_rounds():
+    """A program's remembered table is replaced, never added to: fifty
+    programs of one name (fifty fits' ``jit_step_fn``), each with
+    operations of its own shape, leave one table of the newest."""
+    import gc
+
+    from ray_lightning_tpu.telemetry import scopes
+    sizes, programs = [], []
+    for i in range(50):
+        jitted, program, table = _run_once("round_fn", n=8 + 8 * (i % 5))
+        scopes.remember()
+        kept = scopes._remembered[tuple(scopes.SCOPES)]
+        assert kept[program] == table          # the newest, whole
+        sizes.append(len(kept[program]))
+        programs.append(len(kept))
+        del jitted
+        gc.collect()
+    assert set(scopes._remembered) == {tuple(scopes.SCOPES),
+                                       tuple(scopes.FINE_SCOPES)}
+    # as many programs kept at the end as after the first round, and the
+    # table no longer than one program's
+    assert programs[-1] == programs[0]
+    assert max(sizes) <= 2 * min(sizes)
+
+
+def test_session_seen_is_set_inside_a_profiler_session_and_cleared_by_asking(
+        tmp_path):
+    import jax
+
+    from ray_lightning_tpu.telemetry import spans
+    spans.session_seen()
+    with telemetry.span("outside"):
+        pass
+    assert spans.session_seen() is False
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("inside", step=1):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert spans.session_seen() is True
+    assert spans.session_seen() is False       # asking cleared it
+
+
+def test_trainer_remembers_its_programs_only_after_a_profiler_session(
+        tmp_path, monkeypatch):
+    """A fit that a profiler session lay over keeps its programs' tables
+    at the end of the stage (one reading under each list of names); the
+    next fit, with no session, parses nothing."""
+    import jax
+
+    from ray_lightning_tpu.core.callbacks import Callback
+    from ray_lightning_tpu.telemetry import scopes, spans
+
+    class Session(Callback):
+        needs_batch = False
+
+        def on_train_batch_end(self, trainer, module, metrics, batch, idx):
+            if trainer.global_step == 2:
+                jax.profiler.start_trace(str(tmp_path / "trace"))
+            elif trainer.global_step == 4:
+                jax.profiler.stop_trace()
+
+    readings = []
+    live = scopes._live
+    monkeypatch.setattr(scopes, "_live",
+                        lambda names: readings.append(names) or live(names))
+    scopes._remembered.clear()
+    spans.session_seen()
+
+    def fit(callbacks):
+        Trainer(max_epochs=1, limit_train_batches=6, callbacks=callbacks,
+                enable_checkpointing=False, num_sanity_val_steps=0,
+                limit_val_batches=0, telemetry=False,
+                default_root_dir=str(tmp_path / "run")).fit(BoringModel())
+
+    fit([Session()])
+    assert readings == [scopes.SCOPES, scopes.FINE_SCOPES]
+    assert "jit_step_fn" in scopes._remembered[tuple(scopes.SCOPES)]
+    assert spans.session_seen() is False       # the trainer asked
+    fit([])
+    assert readings == [scopes.SCOPES, scopes.FINE_SCOPES]
+    import gc
+    gc.collect()        # the two fits' programs go with their trainers
+
+
 def test_gpt_train_step_operations_fall_under_the_fixed_scopes():
     """Every instruction of the tiny GPT train step that carries a path
     at all carries a listed scope: models/gpt.py, ops/ and core/steps.py
