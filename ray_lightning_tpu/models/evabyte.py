@@ -143,7 +143,14 @@ class EvaAttention(nn.Module):
     whole sequence (the training forward: no ``cache``); a prompt at a
     slot (``cache=(k_cache, v_cache, layer)`` with ``slot`` and
     ``length``); one token a slot (``cache`` with ``positions`` [S]).
-    With a cache it returns ``(y, (k_cache, v_cache))``."""
+    With a cache it returns ``(y, (k_cache, v_cache))``.
+
+    A whole sequence and a prompt are ONE path, and on it q, k, v and the
+    attention's output stay the packed ``[B, T, H*D]`` rows the products
+    write and the ``o`` product reads (ops/eva_attention.py: a head is a
+    block of columns): no ``[T, H, D]`` view of anything of a prompt's
+    size, so no product carries a relayout inside it.  One token a slot
+    rotates its row on the ``[S, 1, H, D]`` view."""
 
     config: EvaByteConfig
 
@@ -162,33 +169,36 @@ class EvaAttention(nn.Module):
         s = 1.0 / D ** 0.5
         phi = self.param("phi", _phi_mu_init(s), (H, D))
         mu = self.param("mu", _phi_mu_init(s), (H, D))
-        at = jnp.arange(T) if positions is None else positions[:, None]
-        q = eva.rotary(dense("q")(x).reshape(B, T, H, D), at,
-                       cfg.rope_theta)
-        k = eva.rotary(dense("k")(x).reshape(B, T, H, D), at,
-                       cfg.rope_theta).reshape(B, T, C)
-        v = dense("v")(x)
         if positions is not None:
+            # one token a slot: the [S, 1, H, D] view is a row's own
+            at = positions[:, None]
+            q = eva.rotary(dense("q")(x).reshape(B, T, H, D), at,
+                           cfg.rope_theta)
+            k = eva.rotary(dense("k")(x).reshape(B, T, H, D), at,
+                           cfg.rope_theta).reshape(B, T, C)
+            v = dense("v")(x)
             y, cache = self._decode(q, k, v, phi, mu, positions, cache)
             return dense("o")(y.reshape(B, T, C)), cache
 
+        # a whole sequence: packed [B, T, C] rows from the three products
+        # to the fourth, a head a block of D columns all the way
+        at = jnp.arange(T)
+        q, k = eva.rotary_rows((dense("q")(x), dense("k")(x)), at,
+                               cfg.rope_theta, H)
+        v = dense("v")(x)
         # whole chunks, and whole windows beyond the first: zero rows
         # that causality hides from every row before them
         pad = -T % (window if T > window else chunk)
-        kp, vp = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (k, v))
+        qp, kp, vp = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                      for a in (q, k, v))
         Tp = T + pad
         member = jnp.arange(Tp) < (T if length is None else length)
-        k_sum, v_sum = eva.chunk_summaries(
-            kp.reshape(B, Tp // chunk, chunk, C),
-            vp.reshape(B, Tp // chunk, chunk, C), phi, mu,
-            jnp.broadcast_to(member.reshape(1, Tp // chunk, chunk),
-                             (B, Tp // chunk, chunk)))
-        qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        y = eva.eva_attention(
-            qp, kp.reshape(B, Tp, H, D), vp.reshape(B, Tp, H, D),
-            k_sum.reshape(B, -1, H, D), v_sum.reshape(B, -1, H, D),
-            window=window, chunk=chunk, dtype=cfg.dtype)[:, :T]
-        y = dense("o")(y.reshape(B, T, C))
+        k_sum, v_sum = eva.chunk_summaries_rows(
+            kp, vp, phi, mu, jnp.broadcast_to(member, (B, Tp)), chunk)
+        y = eva.eva_attention(qp, kp, vp, k_sum, v_sum, n_head=H,
+                              window=window, chunk=chunk,
+                              dtype=cfg.dtype)[:, :T]
+        y = dense("o")(y)
         if cache is None:
             if not self.is_initializing():
                 # the shape of a slot's state, for the engine to size the

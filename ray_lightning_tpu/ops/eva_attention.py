@@ -22,16 +22,32 @@ beyond is the previous window's, or a former tenant's) and the first
 ``(t // window) * (window // chunk)`` rows of the second (the current
 window's summaries are being built and are not seen until it is over).
 
-Here: the rotary embedding, the pooling (:func:`chunk_summaries`, scope
-``eva_summary``), prefill attention over a whole prompt
-(:func:`eva_attention`, scope ``eva_attn``) and decode attention of one
-query a slot against the resident cache (:func:`eva_cached_attention`):
-the dense ``jax.numpy`` path, and on the TPU the Pallas kernel
-``eva_decode``, which is ops/flash_decode.py's online-softmax body
-(every head of a block in one product against the block-diagonal query,
-heads on sublanes; nothing of it is this file's) under the two-range
-bound: its index_map clamps dead blocks of either part to the last live
-one, so a slot reads only the blocks that hold rows it may see.
+Here: the rotary embedding, the pooling (scope ``eva_summary``), prefill
+attention over a whole prompt (:func:`eva_attention`, scope ``eva_attn``)
+and decode attention of one query a slot against the resident cache
+(:func:`eva_cached_attention`).
+
+The layouts' contract.  Everything of a PROMPT's size is packed rows
+``[B, T, H*D]`` from the q/k/v products to the output projection, and a
+head is a block of ``D`` columns (128 lanes at the published size),
+never an axis of its own: :func:`rotary_rows`, :func:`chunk_summaries_rows`
+and :func:`eva_attention` take packed rows and return packed rows, and a
+head's softmax statistics are one column, ``[.., H, T, 1]``.  On one TPU
+chip with heads of 128 each is a Pallas kernel that addresses a head as a
+column block (``eva_rotary``, ``eva_pool``, the flash kernel ``flash_fwd``
+of ops/flash_attention.py, ``eva_far``); elsewhere the same mathematics
+in ``jax.numpy`` on a ``[.., H, D]`` view.  (A ``[T, H, D]`` view of a
+prompt puts the heads on sublanes, and the TPU compiler answers it with
+relayout copies of the whole tensor, fused into whatever reads it: PR 39
+found 35 ms of a 117 ms prefill in four output projections whose
+operand arrived as such a view.)  DECODE keeps one row a slot, ``[S, 1,
+H, D]`` (:func:`rotary`, :func:`chunk_summaries`), and on the TPU the
+Pallas kernel ``eva_decode``, which is ops/flash_decode.py's
+online-softmax body (every head of a block in one product against the
+block-diagonal query, heads on sublanes; nothing of it is this file's)
+under the two-range bound: its index_map clamps dead blocks of either
+part to the last live one, so a slot reads only the blocks that hold
+rows it may see.
 """
 
 from __future__ import annotations
@@ -46,10 +62,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_lightning_tpu.ops import flash_decode as _fd
 from ray_lightning_tpu.ops.flash_attention import (
-    NEG_INF, flash_attention_lse)
+    NEG_INF, _pick_block, flash_attention_lse)
 
 #: the decode kernel's name in the compiled program and the trace
 KERNEL_NAME = "eva_decode"
+#: the prompt's kernels: rotary on packed rows, and the summaries' part
+#: of a prompt's attention with the merge
+ROTARY_KERNEL_NAME = "eva_rotary"
+FAR_KERNEL_NAME = "eva_far"
+POOL_KERNEL_NAME = "eva_pool"
 
 
 def cache_rows(window: int, chunk: int, max_positions: int) -> int:
@@ -64,6 +85,14 @@ def visible_rows(position, window: int, chunk: int):
     return position % window + 1, (position // window) * (window // chunk)
 
 
+def _rotary_angle(positions, theta: float, D: int):
+    """``positions`` [..., T] -> the angles ``[..., T, D]`` of a head's
+    ``D`` values, float32: the two halves turn alike."""
+    inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    angle = positions.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.concatenate([angle, angle], axis=-1)
+
+
 def rotary(x, positions, theta: float):
     """Rotate-half rotary embedding over every dimension of the head.
     ``x`` [..., T, H, D]; ``positions`` [..., T] (or [T]).  Computed in
@@ -75,9 +104,7 @@ def rotary(x, positions, theta: float):
     slices and a concatenate on the lane axis, which the compiler
     lowered to relayout copies of the whole ``[T, H, D]`` tensor."""
     D = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    angle = positions.astype(jnp.float32)[..., None] * inv_freq
-    angle = jnp.concatenate([angle, angle], axis=-1)[..., None, :]
+    angle = _rotary_angle(positions, theta, D)[..., None, :]
     i = jnp.arange(D)
     turn = (jnp.where(i[:, None] == i[None, :] + D // 2, -1, 0)
             + jnp.where(i[:, None] + D // 2 == i[None, :], 1, 0)
@@ -86,6 +113,61 @@ def rotary(x, positions, theta: float):
                         preferred_element_type=jnp.float32)
     return (x.astype(jnp.float32) * jnp.cos(angle)
             + turned * jnp.sin(angle)).astype(x.dtype)
+
+
+def _one_tpu_chip() -> bool:
+    """Where a prompt's kernels run: one process driving one TPU chip (a
+    serve worker).  Elsewhere (the CPU; a mesh, whose programs would need
+    the kernels under ``shard_map``) the same mathematics in ``jax.numpy``
+    (a test that wants the kernels under the interpreter says so here)."""
+    return not _fd._use_interpret() and jax.device_count() == 1
+
+
+def rotary_rows(xs, positions, theta: float, n_head: int):
+    """:func:`rotary` on packed rows, to the bit.  ``xs``: arrays [B, T,
+    H*D] of one shape and type, as the projections leave them (q and k
+    turn by the same angles: one call, one reading of the tables);
+    ``positions`` [T], shared by the batch.  Returns them rotated, packed.
+
+    A head of 128 values is one block of lanes, so ``rotate_half`` is a
+    roll of its lanes by 64 under a sign, and the sign lives in the
+    sine's table: ``x * cos + roll(x, 64) * (-sin | +sin)``.  The Pallas
+    kernel ``eva_rotary`` reads and writes each row once and nothing
+    ever views the rows as ``[T, H, D]`` (the compiler answers that view
+    with layouts that put positions on the lanes, and copies back).
+    Heads of another size, rows that are not whole tiles, or no single
+    TPU chip, take :func:`rotary` on the view."""
+    B, T, C = xs[0].shape
+    D = C // n_head
+    if D != 128 or T % 16 or not _one_tpu_chip():
+        return tuple(rotary(x.reshape(B, T, n_head, D), positions,
+                            theta).reshape(B, T, C) for x in xs)
+    angle = _rotary_angle(positions, theta, D)                  # [T, D]
+    sin = jnp.sin(angle) * jnp.where(jnp.arange(D) < D // 2, -1.0, 1.0)
+    # a step's rows and heads: the body is traced a head at a time, and
+    # a program holds a call a layer (what a start pays to lower)
+    rows, G = _pick_block(T, 256), _pick_block(n_head, 8)
+
+    def kernel(cos_ref, sin_ref, *refs):
+        cos, sin = cos_ref[...], sin_ref[...]
+        for x_ref, o_ref in zip(refs[:len(xs)], refs[len(xs):]):
+            for h in range(G):
+                head = slice(h * D, (h + 1) * D)
+                xh = x_ref[0, :, head].astype(jnp.float32)
+                o_ref[0, :, head] = (
+                    xh * cos + pltpu.roll(xh, D // 2, 1) * sin
+                ).astype(o_ref.dtype)
+
+    kernel.__name__ = ROTARY_KERNEL_NAME + "_kernel"
+    heads = pl.BlockSpec((1, rows, G * D), lambda b, i, g: (b, i, g))
+    table = pl.BlockSpec((rows, D), lambda b, i, g: (i, 0))
+    return tuple(pl.pallas_call(
+        kernel, name=ROTARY_KERNEL_NAME, grid=(B, T // rows, n_head // G),
+        in_specs=[table, table] + [heads] * len(xs),
+        out_specs=[heads] * len(xs),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in xs],
+        interpret=_fd._use_interpret(),
+    )(jnp.cos(angle), sin, *xs))
 
 
 def chunk_summaries(k, v, phi, mu, member):
@@ -115,75 +197,236 @@ def chunk_summaries(k, v, phi, mu, member):
                 v_sum.reshape(B, N, C).astype(v.dtype))
 
 
+def _summary_rows_kernel(k_ref, v_ref, member_ref, phi_ref, mu_ref,
+                         ks_ref, vs_ref, *, heads, chunk, sm_scale):
+    """``R`` chunks of ``heads`` heads' columns of packed rows, a head at
+    a time: its 128 lanes of the ``R * chunk`` rows as ``[R, chunk, 128]``
+    (whole sublane tiles), the pooling weights one column a row."""
+    R = k_ref.shape[1] // chunk
+    keep = member_ref[0].reshape(R, chunk, 1) > 0
+    for h in range(heads):
+        head = slice(h * 128, (h + 1) * 128)
+        kh = k_ref[0, :, head].astype(jnp.float32).reshape(R, chunk, 128)
+        vh = v_ref[0, :, head].astype(jnp.float32).reshape(R, chunk, 128)
+        logits = jnp.sum(kh * phi_ref[:, head], axis=-1,
+                         keepdims=True) * sm_scale
+        logits = jnp.where(keep, logits, NEG_INF)
+        e = jnp.where(keep, jnp.exp(
+            logits - jnp.max(logits, axis=1, keepdims=True)), 0.0)
+        a = e / jnp.maximum(jnp.sum(e, axis=1, keepdims=True), 1e-30)
+        ks_ref[0, :, head] = (jnp.sum(a * kh, axis=1)
+                              + mu_ref[:, head]).astype(ks_ref.dtype)
+        vs_ref[0, :, head] = jnp.sum(a * vh, axis=1).astype(vs_ref.dtype)
+
+
+def chunk_summaries_rows(k, v, phi, mu, member, chunk: int):
+    """:func:`chunk_summaries` of a whole sequence's packed rows: ``k``,
+    ``v`` [B, T, C] with ``T`` whole chunks, ``member`` [B, T] bool.
+    Returns ``(K~, V~)`` [B, T // chunk, C].
+
+    With heads of 128 on one TPU chip the Pallas kernel ``eva_pool``
+    reads each row once, a head a block of lanes (the ``[.., H, D]``
+    view of :func:`chunk_summaries` costs a float32 copy of all of ``k``
+    and of ``v`` that moves heads from lanes to sublanes); elsewhere,
+    and for chunks that are not whole sublane tiles, that function."""
+    B, T, C = k.shape
+    H, D = phi.shape
+    N = T // chunk
+    if D != 128 or chunk % 8 or N % 16 or not _one_tpu_chip():
+        return chunk_summaries(
+            k.reshape(B, N, chunk, C), v.reshape(B, N, chunk, C), phi, mu,
+            member.reshape(B, N, chunk))
+    R, G = 16, _pick_block(H, 4)       # chunks and heads a grid step
+    body = functools.partial(_summary_rows_kernel, heads=G, chunk=chunk,
+                             sm_scale=1.0 / math.sqrt(D))
+    body.__name__ = POOL_KERNEL_NAME + "_kernel"
+    rows = pl.BlockSpec((1, R * chunk, G * D), lambda b, i, g: (b, i, g))
+    one = pl.BlockSpec((1, G * D), lambda b, i, g: (0, g))
+    pooled = pl.BlockSpec((1, R, G * D), lambda b, i, g: (b, i, g))
+    with jax.named_scope("eva_summary"):
+        return tuple(pl.pallas_call(
+            body, name=POOL_KERNEL_NAME, grid=(B, N // R, H // G),
+            in_specs=[rows, rows,
+                      pl.BlockSpec((1, R * chunk, 1),
+                                   lambda b, i, g: (b, i, 0)),
+                      one, one],
+            out_specs=[pooled, pooled],
+            out_shape=[jax.ShapeDtypeStruct((B, N, C), k.dtype),
+                       jax.ShapeDtypeStruct((B, N, C), v.dtype)],
+            interpret=_fd._use_interpret(),
+        )(k, v, member.astype(jnp.float32)[..., None],
+          phi.astype(jnp.float32).reshape(1, C),
+          mu.astype(jnp.float32).reshape(1, C)))
+
+
 # -- a whole sequence (training forward, prefill) --------------------------------
 
-def _causal_with_lse(q, k, v, dtype):
-    """Plain causal attention of ``[N, T, H, D]`` with its log-sum-exp
-    ``[N, T, H]``: the flash kernel on one TPU chip, dense elsewhere."""
-    if not _fd._use_interpret() and jax.device_count() == 1:
-        return flash_attention_lse(q, k, v, causal=True, interpret=False)
-    T, D = q.shape[1], q.shape[-1]
-    s = jnp.einsum("nqhd,nkhd->nqhk", q, k,
+def _causal_with_lse(q, k, v, n_head: int, dtype):
+    """Plain causal attention of packed ``[N, T, C]`` rows with its
+    log-sum-exp ``[N, H, T, 1]``: the flash kernel on one TPU chip,
+    dense elsewhere."""
+    if _one_tpu_chip():
+        return flash_attention_lse(q, k, v, n_head=n_head, causal=True,
+                                   interpret=_fd._use_interpret())
+    N, T, C = q.shape
+    D = C // n_head
+    q, k, v = (a.reshape(N, T, n_head, D) for a in (q, k, v))
+    s = jnp.einsum("nqhd,nkhd->nhqk", q, k,
                    preferred_element_type=jnp.float32) / math.sqrt(D)
-    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, :, None, :], s,
-                  NEG_INF)
-    lse = jax.nn.logsumexp(s, axis=-1)
-    p = jnp.exp(s - lse[..., None]).astype(dtype)
-    return jnp.einsum("nqhk,nkhd->nqhd", p, v).astype(dtype), lse
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, NEG_INF)
+    lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - lse).astype(dtype)
+    return jnp.einsum("nhqk,nkhd->nqhd", p, v).astype(dtype).reshape(
+        N, T, C), lse
 
 
-def eva_attention(q, k, v, k_sum, v_sum, *, window: int, chunk: int,
-                  dtype=jnp.bfloat16):
-    """EVA over a whole sequence.  ``q``, ``k``, ``v`` [B, T, H, D]
-    (rotary applied), ``T`` at most one window or a multiple of it;
-    ``k_sum``, ``v_sum`` [B, T // chunk, H, D] (:func:`chunk_summaries`).
-    Returns [B, T, H, D] in ``dtype``.
+def _far_merge_kernel(q_ref, near_ref, lse_ref, k_ref, v_ref, o_ref, *,
+                      sm_scale, block_q, block_k, window, per):
+    """One head's column block of ``block_q`` queries of one window:
+    an online softmax over the summary rows of earlier windows, block of
+    rows by block (as many as hold a row the window may see, and no
+    more), and the merge with the near part by the two log-sum-exps.
+    The first window sees no summary: its rows are the near part's."""
+    w = pl.program_id(2) * block_q // window
+    seen = w * per
+
+    @pl.when(w == 0)
+    def _near_only():
+        o_ref[0] = near_ref[0].astype(o_ref.dtype)
+
+    @pl.when(w > 0)
+    def _merge():
+        q = q_ref[0]
+        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+
+        def rows(j, carry):
+            m, l, acc = carry
+            at = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+            s = jax.lax.dot_general(
+                q, k_ref[0, at, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(cols + j * block_k < seen, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, at, :],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        m, l, acc = jax.lax.fori_loop(
+            0, (seen + block_k - 1) // block_k, rows,
+            (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, q.shape[1]), jnp.float32)))
+        near_lse, far_lse = lse_ref[0, 0], m + jnp.log(l)
+        top = jnp.maximum(near_lse, far_lse)
+        lse = top + jnp.log(jnp.exp(near_lse - top)
+                            + jnp.exp(far_lse - top))
+        # acc / l is the far part and l * exp(m - lse) its weight
+        o_ref[0] = (near_ref[0].astype(jnp.float32)
+                    * jnp.exp(near_lse - lse)
+                    + acc * jnp.exp(m - lse)).astype(o_ref.dtype)
+
+
+def _far_merge(q, near, near_lse, k_sum, v_sum, *, n_head, window, chunk,
+               dtype):
+    """The far part of :func:`eva_attention` and the merge.  ``q``,
+    ``near`` [B, T, C] packed; ``near_lse`` [B * T/window, H, window, 1];
+    ``k_sum``, ``v_sum`` [B, T/chunk, C].  Returns packed [B, T, C].
+
+    With heads of 128 on one TPU chip the Pallas kernel ``eva_far`` over
+    ``(batch, head, query block)``: a head is addressed as a column block
+    of the packed rows on the way in and on the way out, so no relayout
+    exists to be placed anywhere.  Elsewhere ``jax.numpy``, head-major."""
+    B, T, C = q.shape
+    H, D = n_head, C // n_head
+    nw, per = T // window, window // chunk
+    sm_scale = 1.0 / math.sqrt(D)
+    if D != 128 or window % 16 or not _one_tpu_chip():
+        f32 = {"preferred_element_type": jnp.float32}
+        qh = q.reshape(B, nw, window, H, D)
+        ks, vs = (a.reshape(B, nw * per, H, D) for a in (k_sum, v_sum))
+        s = jnp.einsum("bwqhd,bjhd->bwhqj", qh, ks, **f32) * sm_scale
+        seen = jnp.arange(nw * per) < (jnp.arange(nw) * per)[:, None]
+        s = jnp.where(seen[:, None, None, :], s, NEG_INF)
+        far_lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+        far = jnp.einsum("bwhqj,bjhd->bwhqd",
+                         jnp.exp(s - far_lse).astype(dtype), vs, **f32)
+        near_lse = near_lse.reshape(B, nw, H, window, 1)
+        lse = jnp.logaddexp(near_lse, far_lse)
+        near = jnp.moveaxis(near.reshape(B, nw, window, H, D), 3, 2)
+        out = near.astype(jnp.float32) * jnp.exp(near_lse - lse) \
+            + far * jnp.exp(far_lse - lse)
+        return jnp.moveaxis(out, 2, 3).astype(dtype).reshape(B, T, C)
+    bq, bk = _pick_block(window, 512), 128
+    per_w = window // bq
+    # whole blocks of summary rows; the rows added are never seen
+    J = -(-nw * per // bk) * bk
+    grow = ((0, 0), (0, J - nw * per), (0, 0))
+    k_sum, v_sum = jnp.pad(k_sum, grow), jnp.pad(v_sum, grow)
+
+    def rows(b, h, i):
+        return (b, i, h)
+
+    def stats(b, h, i):
+        return (b * nw + i // per_w, h, i % per_w, 0)
+
+    def summaries(b, h, i):
+        return (b, 0, h)
+
+    body = functools.partial(_far_merge_kernel, sm_scale=sm_scale,
+                             block_q=bq, block_k=bk, window=window, per=per)
+    body.__name__ = FAR_KERNEL_NAME + "_kernel"
+    return pl.pallas_call(
+        body, name=FAR_KERNEL_NAME, grid=(B, H, T // bq),
+        in_specs=[pl.BlockSpec((1, bq, D), rows),
+                  pl.BlockSpec((1, bq, D), rows),
+                  pl.BlockSpec((1, 1, bq, 1), stats),
+                  pl.BlockSpec((1, J, D), summaries),
+                  pl.BlockSpec((1, J, D), summaries)],
+        out_specs=pl.BlockSpec((1, bq, D), rows),
+        out_shape=jax.ShapeDtypeStruct((B, T, C), dtype),
+        interpret=_fd._use_interpret(),
+    )(q, near, near_lse, k_sum, v_sum)
+
+
+def eva_attention(q, k, v, k_sum, v_sum, *, n_head: int, window: int,
+                  chunk: int, dtype=jnp.bfloat16):
+    """EVA over a whole sequence, packed rows in and packed rows out.
+    ``q``, ``k``, ``v`` [B, T, H*D] (rotary applied), ``T`` at most one
+    window or a multiple of it; ``k_sum``, ``v_sum`` [B, T // chunk, H*D]
+    (:func:`chunk_summaries`).  Returns [B, T, H*D] in ``dtype``: what
+    the output projection multiplies, as it is.
 
     A sequence within one window is plain causal attention and takes the
     repo's attention dispatch (the flash kernel on the TPU).  A longer
     one is two attentions under one softmax: every window's causal
     attention over its own exact rows (all windows as one batch of the
-    flash kernel, with its log-sum-exp), and, window by window under one
-    ``lax.map``, a dense attention over the summaries of earlier windows
-    (at most ``T / chunk`` keys); ``logaddexp`` of the two log-sum-exps
-    weighs the two outputs."""
+    flash kernel, with its log-sum-exp), and a dense attention over the
+    summaries of earlier windows (at most ``T / chunk`` keys), which the
+    two log-sum-exps weigh against the first (:func:`_far_merge`)."""
     from ray_lightning_tpu.ops.attention import auto_attention
-    B, T, H, D = q.shape
+    B, T, C = q.shape
     with jax.named_scope("eva_attn"):
         if T <= window:
-            return auto_attention(q, k, v, causal=True, dtype=dtype)
+            # the dispatch's [B, T, H, D] views are bitcasts of packed
+            # rows on both sides of the flash kernel
+            return auto_attention(
+                *(a.reshape(B, T, n_head, C // n_head) for a in (q, k, v)),
+                causal=True, dtype=dtype).reshape(B, T, C)
         if T % window:
             raise ValueError(f"{T} positions are not whole windows of "
                              f"{window}")
-        nw, per = T // window, window // chunk
-        far_rows = (nw - 1) * per      # the last window's are never seen
-        k_far, v_far = k_sum[:, :far_rows], v_sum[:, :far_rows]
-        chunk_no = jnp.arange(far_rows)
-        f32 = {"preferred_element_type": jnp.float32}
+        nw = T // window
         near, near_lse = _causal_with_lse(
-            *(a.reshape(B * nw, window, H, D) for a in (q, k, v)), dtype)
-
-        def one_window(args):
-            w, qw, near, near_lse = args
-            s = jnp.einsum("bqhd,bjhd->bqhj", qw, k_far, **f32) \
-                / math.sqrt(D)
-            s = jnp.where(chunk_no < w * per, s, NEG_INF)
-            far_lse = jax.nn.logsumexp(s, axis=-1)
-            far = jnp.einsum(
-                "bqhj,bjhd->bqhd",
-                jnp.exp(s - far_lse[..., None]).astype(dtype), v_far, **f32)
-            lse = jnp.logaddexp(near_lse, far_lse)
-            return (near.astype(jnp.float32)
-                    * jnp.exp(near_lse - lse)[..., None]
-                    + far * jnp.exp(far_lse - lse)[..., None]).astype(dtype)
-
-        def windows(a):
-            return jnp.moveaxis(a.reshape((B, nw) + a.shape[1:]), 1, 0)
-
-        out = jax.lax.map(one_window, (jnp.arange(nw), windows(
-            q.reshape(B * nw, window, H, D)), windows(near),
-            windows(near_lse)))
-        return jnp.moveaxis(out, 0, 1).reshape(B, T, H, D)
+            *(a.reshape(B * nw, window, C) for a in (q, k, v)), n_head,
+            dtype)
+        return _far_merge(q, near.reshape(B, T, C), near_lse, k_sum, v_sum,
+                          n_head=n_head, window=window, chunk=chunk,
+                          dtype=dtype)
 
 
 # -- one query a slot against the resident cache ---------------------------------
@@ -323,5 +566,5 @@ def _eva_decode_kernel_call(q, k_cache, v_cache, positions, *, layer,
 
 
 __all__ = ["KERNEL_NAME", "cache_rows", "visible_rows", "rotary",
-           "chunk_summaries", "eva_attention", "eva_cached_attention",
-           "select_eva_kernel"]
+           "rotary_rows", "chunk_summaries", "chunk_summaries_rows",
+           "eva_attention", "eva_cached_attention", "select_eva_kernel"]

@@ -1570,7 +1570,8 @@ def flash_attention(q, k, v, *, causal: bool = True, dtype=jnp.bfloat16,
     return o.reshape(b, t, h, d).astype(dtype)
 
 
-def flash_attention_lse(q, k, v, *, causal: bool = True,
+def flash_attention_lse(q, k, v, *, n_head: int | None = None,
+                        causal: bool = True,
                         sm_scale: float | None = None,
                         interpret: bool | None = None):
     """The forward kernels of :func:`flash_attention` with the softmax's
@@ -1579,8 +1580,22 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
     merges this attention with another over further keys under ONE
     softmax (ops/eva_attention.py: a window's exact rows here, the
     summaries of earlier windows there): ``logaddexp`` of the two lse
-    weighs the two outputs."""
-    b, t, h, d = q.shape
+    weighs the two outputs.
+
+    Packed rows ``[B, T, H*D]`` with ``n_head`` beside them go to the
+    kernels as they are (it is the layout the kernels address: a head is
+    a block of columns) and come back packed: ``(o [B, T, H*D], lse
+    [B, H, T, 1])``, a head's statistics one column of its own, as the
+    kernels write them.  Nothing of the call's size is reshaped to a
+    ``[.., H, D]`` view on either side."""
+    packed = q.ndim == 3
+    if packed:
+        if n_head is None:
+            raise ValueError("packed [B, T, H*D] rows need n_head")
+        (b, t, c), h = q.shape, n_head
+        d = c // h
+    else:
+        b, t, h, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if interpret is None:
@@ -1588,7 +1603,12 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
     o, lse = _fwd(q.reshape(b, t, h * d), k.reshape(b, t, h * d),
                   v.reshape(b, t, h * d), h, causal, sm_scale, None, None,
                   interpret)
-    if _select_family(t, h, d, causal).lse == "packed":
+    heads_last = _select_family(t, h, d, causal).lse == "packed"
+    if packed:
+        if heads_last and lse.shape[-1] > 1:      # [B, H/pack, T, pack]
+            lse = lse.transpose(0, 1, 3, 2)
+        return o, lse.reshape(b, h, t, 1)         # (folded: [B·H, T, 1])
+    if heads_last:
         lse = lse.transpose(0, 2, 1, 3)          # [B, H/pack, T, pack]
     else:
         lse = lse.reshape(b, h, t).transpose(0, 2, 1)       # [B·H, T, 1]

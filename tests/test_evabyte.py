@@ -5,7 +5,7 @@ width 64, 2 heads of 32, window 32, chunk 4, 2 layers, 2 output heads,
 
 Tolerance: the two sides are the same mathematics written twice in
 float32 (the reference window by window with its own masks and pooling,
-the program over padded chunks, one ``lax.map`` and, served, through the
+the program over padded chunks on packed rows and, served, through the
 cache), so they differ by summation order only.  Logits here have a
 spread of about 0.8; float32 rounding through 2 layers reaches a few
 1e-6 of that, and ``ATOL = 1e-5`` leaves it room while a wrong row, mask,
@@ -150,10 +150,148 @@ def test_one_window_is_plain_causal_attention(weights):
     k_sum, v_sum = eva.chunk_summaries(
         k.reshape(2, 8, 4, 64), v.reshape(2, 8, 4, 64), phi, mu,
         jnp.ones((2, 8, 4), bool))
-    got = eva.eva_attention(q, k, v, k_sum.reshape(2, 8, 2, 32),
-                            v_sum.reshape(2, 8, 2, 32), window=32, chunk=4,
-                            dtype=jnp.float32)
-    np.testing.assert_allclose(got, plain, atol=ATOL, rtol=0)
+    got = eva.eva_attention(
+        *(a.reshape(2, 32, 64) for a in (q, k, v)), k_sum, v_sum, n_head=2,
+        window=32, chunk=4, dtype=jnp.float32)
+    np.testing.assert_allclose(got, plain.reshape(2, 32, 64), atol=ATOL,
+                               rtol=0)
+
+
+# -- a prompt's attention on packed rows ---------------------------------------
+
+_PACKED = {"f32": (jnp.float32, 2e-5), "bf16": (jnp.bfloat16, 2e-2)}
+_W, _CH, _H, _D = 32, 4, 2, 128       # heads of the published size
+
+
+def _prompt_on_the_view(q, k, v, phi, mu, length):
+    """The ``[T, H, D]`` formulation, as plain as it gets: one softmax a
+    query over the exact rows of its own window up to itself and the
+    pooled rows of every chunk of every earlier window; float32."""
+    q, k, v = (np.asarray(a, np.float32) for a in (q, k, v))
+    phi, mu = np.asarray(phi, np.float32), np.asarray(mu, np.float32)
+    B, T, H, D = q.shape
+    out = np.zeros_like(q)
+    for b in range(B):
+        for h in range(H):
+            ks, vs = [], []
+            for c in range(0, T - T % _W, _CH):   # chunks of whole windows
+                rows = slice(c, min(c + _CH, length))
+                if c >= length:                 # a pad chunk: seen by none
+                    continue
+                a = k[b, rows, h] @ phi[h] / np.sqrt(D)
+                a = np.exp(a - a.max())
+                a /= a.sum()
+                ks.append(a @ k[b, rows, h] + mu[h])
+                vs.append(a @ v[b, rows, h])
+            for t in range(min(T, length)):
+                w = t // _W
+                far = w * (_W // _CH)
+                keys = np.concatenate(
+                    [np.reshape(ks[:far], (far, D)), k[b, w * _W:t + 1, h]])
+                vals = np.concatenate(
+                    [np.reshape(vs[:far], (far, D)), v[b, w * _W:t + 1, h]])
+                s = keys @ q[b, t, h] / np.sqrt(D)
+                p = np.exp(s - s.max())
+                out[b, t, h] = p / p.sum() @ vals
+    return out
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("dtype", list(_PACKED))
+@pytest.mark.parametrize("path", ["numpy", "kernels"])
+@pytest.mark.parametrize("T,length", [(32, 32), (64, 64), (96, 96),
+                                      (128, 128), (128, 101), (576, 576)],
+                         ids=["one_window", "edge", "three", "four",
+                              "padded", "eighteen"])
+def test_packed_prompt_attention_is_the_views(monkeypatch, T, length, path,
+                                              dtype):
+    """Packed ``[B, T, H*D]`` rows in, packed rows out, against the plain
+    mathematics on ``[T, H, D]``: within one window, at a window's edge
+    (row 32 sees the first window's summaries and itself), over three
+    and four windows, and in a padded bucket whose ``length`` lies
+    inside the last window (rows below it are what the unpadded prompt
+    gives); over eighteen, whose last sees 136 summary rows: two blocks
+    of the kernel's online softmax, the second partly seen.  ``numpy``
+    is the path the CPU takes; ``kernels`` the chip's (``flash_fwd``,
+    ``eva_far``) under the interpreter."""
+    if path == "kernels":
+        monkeypatch.setattr(eva, "_one_tpu_chip", lambda: True)
+    dt, bar = _PACKED[dtype]
+    rng = np.random.default_rng(T + length)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, T, _H, _D)), dt)
+               for _ in range(3))
+    phi, mu = (jnp.asarray(rng.normal(size=(_H, _D)), dt) for _ in range(2))
+    C = _H * _D
+    member = jnp.broadcast_to(
+        (jnp.arange(T) < length).reshape(1, T // _CH, _CH), (2, T // _CH, _CH))
+    k_sum, v_sum = eva.chunk_summaries(
+        k.reshape(2, T // _CH, _CH, C), v.reshape(2, T // _CH, _CH, C),
+        phi, mu, member)
+    got = eva.eva_attention(
+        *(a.reshape(2, T, C) for a in (q, k, v)), k_sum, v_sum, n_head=_H,
+        window=_W, chunk=_CH, dtype=dt)
+    assert got.shape == (2, T, C) and got.dtype == dt
+    want = _prompt_on_the_view(q, k, v, phi, mu, length)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32).reshape(2, T, _H, _D)[:, :length],
+        want[:, :length], atol=bar, rtol=bar)
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("dtype", list(_PACKED))
+@pytest.mark.parametrize("path", ["view", "kernel"])
+def test_rotary_on_packed_rows_is_rotary_on_the_view_to_the_bit(
+        monkeypatch, path, dtype):
+    """``rotary_rows`` (a roll of a head's 128 lanes by 64 under the
+    sine's sign: ``eva_rotary`` under the interpreter) against ``rotary``
+    (the signed-permutation product) on the ``[T, H, D]`` view, both
+    compiled: the same two products and one sum an element, so the same
+    bits.  (This CPU's compiler may contract ``a*b + c*d`` to one fused
+    multiply-add, and does so alike in float32; from bfloat16 operands
+    it leaves the view's alone, and one element in 1e5 then rounds to
+    the neighbouring bfloat16: allowed there, and nowhere else.)"""
+    if path == "kernel":
+        monkeypatch.setattr(eva, "_one_tpu_chip", lambda: True)
+    dt = _PACKED[dtype][0]
+    x = jnp.asarray(np.random.default_rng(7).normal(size=(2, 256, 3, _D)), dt)
+    at = jnp.arange(256)
+    got, = jax.jit(lambda x: eva.rotary_rows((x,), at, 100000.0, 3))(
+        x.reshape(2, 256, 3 * _D))
+    want = jax.jit(lambda x: eva.rotary(x, at, 100000.0))(x)
+    assert got.dtype == dt and got.shape == (2, 256, 3 * _D)
+    got, want = (np.asarray(a, np.float32).reshape(x.shape)
+                 for a in (got, want))
+    if (path, dtype) == ("kernel", "bf16"):
+        off = got != want
+        assert off.mean() < 1e-4
+        np.testing.assert_allclose(got[off], want[off], rtol=2.0 ** -7)
+    else:
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("dtype", list(_PACKED))
+@pytest.mark.parametrize("length", [512, 300], ids=["whole", "padded"])
+def test_pooling_packed_rows_is_pooling_the_view(monkeypatch, length, dtype):
+    """``chunk_summaries_rows`` (``eva_pool`` under the interpreter: a
+    head a block of lanes, 16 chunks of 16 rows a step) against
+    ``chunk_summaries`` on the ``[.., H, D]`` view; rows beyond
+    ``length`` enter no summary, and a chunk of none pools to zeros and
+    ``mu``."""
+    dt, bar = _PACKED[dtype]
+    rng = np.random.default_rng(length)
+    k, v = (jnp.asarray(rng.normal(size=(2, 512, 3 * _D)), dt)
+            for _ in range(2))
+    phi, mu = (jnp.asarray(rng.normal(size=(3, _D)), dt) for _ in range(2))
+    member = jnp.broadcast_to(jnp.arange(512) < length, (2, 512))
+    want = eva.chunk_summaries_rows(k, v, phi, mu, member, 16)
+    monkeypatch.setattr(eva, "_one_tpu_chip", lambda: True)
+    got = eva.chunk_summaries_rows(k, v, phi, mu, member, 16)
+    for g, w in zip(got, want):
+        assert g.shape == (2, 32, 3 * _D) and g.dtype == dt
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   atol=bar, rtol=bar)
 
 
 def _serve(engine, programs, weights, lengths, buckets, steps, k=None,
@@ -395,8 +533,100 @@ def v5e():
     compilation_cache.reset_cache()
 
 
+def _computations(text):
+    """A compiled program's text as ``{computation: [(name, result,
+    operation, operands, called, line)]}``."""
+    import re
+    comps, at = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if m:
+            at = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            at = None
+        elif at is not None:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                         r"([\w\-]+)\((.*?)\)(?:, |$)", line)
+            if m:
+                at.append((m.group(1), m.group(2), m.group(3),
+                           re.findall(r"%([\w.\-]+)", m.group(4)),
+                           re.findall(r"(?:calls|to_apply)=%([\w.\-]+)",
+                                      line), line))
+    return comps
+
+
+def _holds(comps, comp, kinds):
+    """The operations of ``kinds`` in ``comp`` and in what it calls."""
+    found = []
+    for name, _, op, _, called, line in comps.get(comp, ()):
+        if op in kinds:
+            found.append(line.strip()[:200])
+        for c in called:
+            found += _holds(comps, c, kinds)
+    return found
+
+
+def _packed_rows_all_the_way(text, T):
+    """ISSUE 39's two structural criteria on a compiled prefill.
+    (1) No fused computation that holds an ``attn/o/dot_general``
+    convolution holds a ``copy`` or a ``transpose``: the attention's
+    output reaches the product as the packed rows it was written in.
+    (2) From each operand of a flash call back to the q / k / v product
+    it came from, nothing copies or transposes anything of a prompt's
+    size.  (3) Nor does anything else of the program, the embedding's
+    gather apart (the pooling of a chunk's rows reads packed rows too).
+    Returns what offends."""
+    import re
+    comps = _computations(text)
+    entry = next(c for c in comps if c.startswith("main"))
+    movers = ("copy", "transpose")
+    offenders, o_products = [], 0
+    for comp, ops in comps.items():
+        if any(op == "convolution" and "attn/o/dot_general" in line
+               for _, _, op, _, _, line in ops):
+            o_products += 1
+            offenders += _holds(comps, comp, movers)
+    assert o_products == 2, o_products            # one a layer
+    by_name = {name: row for row in comps[entry] for name in [row[0]]}
+
+    def size(result):
+        return max((int(np.prod([int(d) for d in dims.split(",")]))
+                    for dims in re.findall(r"\[([0-9,]+)\]", result)),
+                   default=0)
+
+    def back(name, seen):
+        row = by_name.get(name)
+        if row is None or name in seen or size(row[1]) < T * 512:
+            return
+        seen.add(name)
+        _, _, op, operands, called, line = row
+        if op in movers:
+            offenders.append(line.strip()[:200])
+        held = [h for c in called for h in _holds(comps, c, movers)]
+        offenders.extend(held)
+        if any(_holds(comps, c, ("convolution",)) for c in called):
+            return                                # the product: the end
+        for o in operands:
+            back(o, seen)
+
+    flash = [row for row in comps[entry] if row[2] == "custom-call"
+             and "flash_fwd" in row[5]]
+    assert len(flash) == 2, len(flash)
+    for row in flash:
+        for o in row[3]:
+            back(o, set())
+    # (3) and nowhere else either, the embedding's gather apart
+    for ops in comps.values():
+        for _, result, op, _, _, line in ops:
+            if op in movers and size(result) >= T * 512 \
+                    and "/embed/" not in line:
+                offenders.append(line.strip()[:200])
+    return offenders
+
+
 @pytest.mark.limit(240)
-@pytest.mark.parametrize("program", ["decode", "prefill_1024"])
+@pytest.mark.parametrize("program", ["decode", "prefill_1024",
+                                     "prefill_1024_one_chip"])
 def test_serve_programs_compile_for_v5e_and_leave_the_cache_where_it_lies(
         monkeypatch, v5e, program):
     """Heads of the published size (128) at a width of 512, windows of
@@ -404,7 +634,12 @@ def test_serve_programs_compile_for_v5e_and_leave_the_cache_where_it_lies(
     where this process has one device as a serve worker has, the flash
     kernel under the prefill's windows), the decode program needs
     no scratch worth the name beside the donated cache, and no program
-    copies, slices or transposes anything of a layer's size."""
+    copies, slices or transposes anything of a layer's size.
+    ``prefill_1024_one_chip`` is the prefill as a serve worker compiles
+    it (this process told it has one device): the prompt's kernels
+    (``eva_rotary``, ``eva_pool``, ``flash_fwd``, ``eva_far``) lower, and a prompt's
+    attention is packed ``[T, H*128]`` rows from the q / k / v products
+    to the ``o`` product (:func:`_packed_rows_all_the_way`)."""
     import re
     from ray_lightning_tpu.core import steps
     from ray_lightning_tpu.ops import flash_decode
@@ -414,6 +649,8 @@ def test_serve_programs_compile_for_v5e_and_leave_the_cache_where_it_lies(
                         max_position_embeddings=4096)
     monkeypatch.setenv("RLT_DECODE_IMPL", "flash_decode")
     monkeypatch.setattr(flash_decode, "_use_interpret", lambda: False)
+    if program.endswith("one_chip"):
+        monkeypatch.setattr(jax, "device_count", lambda backend=None: 1)
     module = EvaByteLightningModule(cfg)
     module.setup_model()
     net = module.configure_decode_model()
@@ -457,21 +694,36 @@ def test_serve_programs_compile_for_v5e_and_leave_the_cache_where_it_lies(
         r"dynamic-slice|concatenate|transpose)\(", text)
         if np.prod([int(d) for d in m.group(1).split(",")]) >= layer]
     assert not movers, movers[:5]
+    if program.endswith("one_chip"):
+        for kernel in ("eva_rotary", "eva_pool", "flash_fwd", "eva_far"):
+            assert kernel in text, kernel
+        offenders = _packed_rows_all_the_way(text, 1024)
+        assert not offenders, offenders[:5]
 
 
 @pytest.mark.limit(120)
+@pytest.mark.parametrize("entry", ["view", "rows"])
 @pytest.mark.parametrize("T,H,D", [(64, 2, 32), (64, 2, 128), (1024, 2, 128)],
                          ids=["folded", "packed", "tiled"])
-def test_flash_attention_lse_in_both_of_the_kernels_layouts(T, H, D):
+def test_flash_attention_lse_in_both_of_the_kernels_layouts(T, H, D, entry):
     """The prefill merges the flash kernel's causal attention over a
     window with a dense one over the summaries by their log-sum-exps:
     ``flash_attention_lse`` returns ``[B, T, H]`` whichever layout the
-    forward kernel keeps it in (the kernel under the interpreter)."""
+    forward kernel keeps it in (the kernel under the interpreter), and to
+    packed ``[B, T, H*D]`` rows it answers with packed rows and a column
+    a head, ``[B, H, T, 1]``."""
     from ray_lightning_tpu.ops.flash_attention import flash_attention_lse
     rng = np.random.default_rng(T + D)
     q, k, v = (jnp.asarray(rng.normal(size=(2, T, H, D)), jnp.float32)
                for _ in range(3))
-    o, lse = flash_attention_lse(q, k, v, interpret=True)
+    if entry == "rows":
+        o, lse = flash_attention_lse(
+            *(a.reshape(2, T, H * D) for a in (q, k, v)), n_head=H,
+            interpret=True)
+        assert o.shape == (2, T, H * D) and lse.shape == (2, H, T, 1)
+        o, lse = o.reshape(2, T, H, D), lse[..., 0].transpose(0, 2, 1)
+    else:
+        o, lse = flash_attention_lse(q, k, v, interpret=True)
     s = jnp.einsum("bqhd,bkhd->bqhk", q, k) / np.sqrt(D)
     s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, :, None, :], s,
                   -jnp.inf)
