@@ -19,6 +19,11 @@ from ray_lightning_tpu.models.xing import (
     XingConfig,
     XingLightningModule,
 )
+from ray_lightning_tpu.models.zaya import (
+    Zaya,
+    ZayaConfig,
+    ZayaLightningModule,
+)
 from ray_lightning_tpu.models.pipeline_gpt import PipelinedGPT
 from ray_lightning_tpu.models.resnet import (
     ResNet,
@@ -60,4 +65,7 @@ __all__ = [
     "Xing",
     "XingConfig",
     "XingLightningModule",
+    "Zaya",
+    "ZayaConfig",
+    "ZayaLightningModule",
 ]

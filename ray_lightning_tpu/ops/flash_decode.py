@@ -470,6 +470,15 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
 #: run, PR 33; PERF.md section 6): a ring of 4096 rows read whole 1.147 /
 #: 0.883 / 0.780 (its bytes' time 0.656); 6,901 of 8,960 rows (which 512
 #: does not tile) 1.997 / 1.498 (1.104); 101 rows 0.511 / 0.337.
+#: models/zaya.py's row is 2 K/V heads (512 B) under 8 query heads, 128
+#: slots (builder's chip run, PR 41; ~0.2 ms of each is the host's floor a
+#: call), ms a call at 1,500 / 3,000 live rows a slot, bytes' time 0.240 /
+#: 0.480: blocks of 128 rows 1.113 / 1.620, of 256 (which tile 3,328)
+#: 0.696 / 1.035, of 512 (3,584) 0.513 / 0.753, and over 4,096 rows 512:
+#: 0.515 / 0.766, 1024: 0.519 / 0.684, 2048: 0.539 / 0.725.  The narrow row
+#: wants 512 as the wide one does.  Its benchmark cell holds 3,328 rows a
+#: slot, which 512 does not tile: it reads blocks of 256 and pays the
+#: difference until a ragged last block is masked in the body.
 _GROUPED_BLOCK_K = 512
 #: the grouped call's name in the compiled program and the trace
 GROUPED_KERNEL_NAME = "gqa_decode"

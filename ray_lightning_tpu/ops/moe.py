@@ -180,7 +180,12 @@ def total_aux_loss(model_state) -> "jax.Array | None":
 # experts (``sigmoid_topk``), with a family's optional selection bias
 # (added to the scores to CHOOSE, never to weigh) and scaling factor;
 # ``ExpertLayer`` is the flax layer both families build: router, routed
-# experts, shared experts, and what it counts for ``SERVE_COUNTERS``.
+# experts, shared experts, and what it counts for ``SERVE_COUNTERS``.  A
+# family whose router is not that (models/zaya.py: an MLP that reads the
+# previous layer's router, a softmax, ONE choice a token, which may be "no
+# expert": ``mlp_softmax_top1``) owns its expert sublayer and calls
+# ``dropless_experts`` itself; an index past the held experts is a pair
+# that lands nowhere, the case ``offset`` / ``held`` already make.
 #
 # "Dropless" has no smaller static bound than ``T * k`` rows: every token
 # may put all its ``k`` pairs on held experts.  A decode batch pays that
@@ -270,6 +275,43 @@ def sigmoid_topk(h, router_w, top_k: int, bias=None, scale: float = 1.0):
         return idx, w if scale == 1.0 else w * scale
 
 
+def mlp_softmax_top1(h, prev, down_w, gamma, norm_g, w1, w2, w3, bias,
+                     eps: float):
+    """``(idx [T, 1] int32, w [T, 1] float32, s [T, R] float32)``: an MLP
+    router that reads the router of the layer before it (the ZAYA1
+    router, arXiv:2511.17127).  ``r = h down_w`` ([d, R]); ``s = r +
+    gamma * prev`` (``prev`` [T, R]: the SAME tokens' ``s`` of the
+    previous layer's router, None before the first; ``gamma`` a scalar);
+    ``p = softmax(w3 gelu(w2 gelu(w1 n(s))))`` with ``n`` an RMSNorm of
+    gain ``norm_g``, ``w1``, ``w2`` [R, R] and ``w3`` [R, E + 1], the
+    gelu exact.  The LAST output is "no expert": ``idx`` is the largest
+    of ``p + bias`` (``bias`` [E + 1] chooses and never weighs), in
+    ``[0, E]``, where ``E`` lands on no held expert
+    (:func:`dropless_experts`' ``held``), and ``w`` the chosen output's
+    ``p`` as it is (one a token: nothing to normalise).  ``s`` goes to the
+    next layer's router.  float32 throughout, every product at
+    ``highest``: the best two of 17 may lie a rounding apart, and a swap
+    replaces a token's whole expert."""
+    f32 = jnp.float32
+    with jax.named_scope("moe_route"):
+        def dot(a, b):
+            return jnp.einsum("tr,re->te", a, b.astype(f32),
+                              precision="highest")
+
+        s = dot(h.astype(f32), down_w)
+        if prev is not None:
+            s = s + gamma.astype(f32) * prev
+        n = s * jax.lax.rsqrt(
+            jnp.mean(jnp.square(s), axis=-1, keepdims=True) + eps) \
+            * norm_g.astype(f32)
+        a = jax.nn.gelu(dot(n, w1), approximate=False)
+        a = jax.nn.gelu(dot(a, w2), approximate=False)
+        p = jax.nn.softmax(dot(a, w3), axis=-1)
+        idx = jnp.argmax(p + bias.astype(f32), axis=-1)[:, None]
+        return (idx.astype(jnp.int32),
+                jnp.take_along_axis(p, idx, axis=-1), s)
+
+
 def _grouped_dot(rows, weights, sizes, impl: str):
     """``rows[group g's rows] @ weights[g]``, accumulated in float32 and
     returned in ``rows``' type (the next product reads it so, and a
@@ -307,9 +349,21 @@ def _grouped_dot(rows, weights, sizes, impl: str):
 #: 2.601, ``_GMM_TILING_MANY`` (256, 1024, 1024) 2.300 / 2.485 / 2.663;
 #: (128 / 256, 1024, 3584) do not fit.  The way in changes a 9216 layer
 #: by 3.4 ms of 14.0, the way out by 0.5.
+#: models/zaya.py's experts are 16 of [2048, 2048] both ways, ONE a token:
+#: a decode batch's 128 rows in groups of ~8 and a prompt's up to 1024 in
+#: groups of ~60, neither past ``_ONE_PIECE_ROWS``.  One product by hand on
+#: the v5e (builder's chip run, PR 41; PERF.md section 6), ms a call, calls
+#: queued back to back (~0.2 ms is the host's floor a call; 16 experts'
+#: bytes' time 0.164).  128 rows: (128, 2048, 1024) 0.213, (128, 512, 2048)
+#: 0.215, ``_GMM_TILING_FEW`` (128, 1024, 2048) 0.216, (128, 1024, 1024)
+#: 0.231, (64, 1024, 2048) 0.231, ``ragged_dot`` 0.260; (128, 2048, 2048)
+#: does not fit VMEM.  1024 rows: (128, 2048, 1024) 0.264, (256, 1024,
+#: 2048) 0.278, (256, 1024, 1024) 0.304, ``_GMM_TILING_FEW`` 0.305, (128,
+#: 1024, 1024) 0.325, (512, 1024, 1024) 0.434, ``ragged_dot`` 0.495.
 _GMM_TILING_BY_WIDTH = {(3584, False): _GMM_TILING_3584_IN,
                         (3584, True): (256, 3584, 512),
-                        (1024, True): (64, 1024, 3584)}
+                        (1024, True): (64, 1024, 3584),
+                        (2048, False): (128, 2048, 1024)}
 
 
 def gmm_tiling(m: int, k: int) -> tuple:
@@ -317,7 +371,7 @@ def gmm_tiling(m: int, k: int) -> tuple:
     [groups, k, n]``, from the shapes handed in: few rows or many
     (``_ONE_PIECE_ROWS``), as the two constants say, the 4096-wide
     products among them; the measured choices where the contraction is
-    3584 or, past a decode batch's rows, 1024 wide."""
+    3584, 2048 up to a decode batch's rows, or 1024 past them."""
     many = m > _ONE_PIECE_ROWS
     tiling = _GMM_TILING_BY_WIDTH.get(
         (k, many), _GMM_TILING_MANY if many else _GMM_TILING_FEW)

@@ -139,14 +139,16 @@ def _splash_kernel(T: int, per: int, window: "int | None"):
     one = sm.CausalMask((T, T)) if window is None \
         else sm.LocalMask((T, T), (window - 1, 0), 0)
     bq, bkv = min(_SPLASH_BLOCK_Q, T), min(_SPLASH_BLOCK_KV, T)
+    # keys to a product: a whole divisor of the block (a prompt of 768
+    # positions is one block of 768 keys, three products of 256)
+    compute = math.gcd(bkv, _SPLASH_BLOCK_KV_COMPUTE)
     # made under no trace: the mask's block tables are constants of
     # whatever program calls the kernel
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mqa_single_device(
             sm.MultiHeadMask([one] * per),
             block_sizes=sk.BlockSizes(
-                block_q=bq, block_kv=bkv,
-                block_kv_compute=min(bkv, _SPLASH_BLOCK_KV_COMPUTE)))
+                block_q=bq, block_kv=bkv, block_kv_compute=compute))
 
 
 def banded_attention(q, k, v, *, window: "int | None",

@@ -177,7 +177,9 @@ class ShardingStrategy:
         """Sharding of the serve plane's slot-indexed KV cache
         ``[n_layer, slot, pos, head*dim]`` (serve/kvcache.py): slots
         shard exactly like the batch's leading dim — each data shard
-        decodes its own slots with no cross-device attention traffic.
+        decodes its own slots with no cross-device attention traffic
+        (a slot's tail, ``[n_layer, slot, r, c]``, has its slots where
+        the cache has them and shards the same way).
         Requires ``max_batch_slots`` divisible by the data-axis size
         (the serve engine builds its mesh with ``batch_hint=slots`` so
         single-process meshes clamp instead of erroring)."""

@@ -30,7 +30,12 @@ an int32 accumulator behind the keys' where the model asked for one:
 and the same programs, donation and warm-up either way.  A model whose
 row holds key and value at once (models/xing.py's latent rows) has ONE
 array a kind: it travels on the keys' side, and the values' side of
-every program is the empty tuple.
+every program is the empty tuple.  A model whose decode step needs more
+of the position before it than its rows hold (models/zaya.py: two
+convolutions' inputs and a shifted value) keeps a small block a slot a
+layer, its TAIL (``KVCacheSpec.tail``, read off the same capture): one
+more array on the keys' side, before the accumulator, made by
+``kv_init`` in the model's own dtype and donated with the rest.
 
 After setup the engine is a pure executor: ``prefill``/``decode`` calls
 carry no Python branching on request state, so the decode loop shape

@@ -47,6 +47,24 @@ from_capture`` reads it off the model's prefill capture):
   arrays travel, the accumulator behind it, and the values' side of
   every program is the empty tuple: the programs, their donation and
   their warm-up are the pairs'.
+- **a row per position and a TAIL a slot** (models/zaya.py): the keys
+  and values of compressed convolutional attention are a row per
+  position (256 lanes each, 1,024 B in bfloat16 a layer), but a
+  position's key is made from the position BEFORE it too: two
+  convolutions of kernel 2 and a value shifted by one.  What a decode
+  step at position ``t`` needs of ``t - 1`` is neither a row nor a
+  position: a small block a slot a layer, ``[n_layer, S, r, c]`` of the
+  model's own ``r`` and ``c`` (``KVCacheSpec.tail``, read off a capture
+  that sows a THIRD block a layer; 2 x 2,688 values there), float32
+  (``TAIL_DTYPE``: what the convolutions read).  It is one more array
+  on the keys' side, behind the kinds' arrays and before the
+  accumulator, donated with them.  A step may run
+  twice at one position (serve/worker.py: a decode queued ahead and
+  dropped is queued again from the plan), so a tail a step overwrote in
+  place would be read as its own predecessor: the model keeps TWO
+  generations, position ``t``'s in row ``t % 2``, and a step at ``t``
+  reads row ``(t - 1) % 2`` and writes row ``t % 2``: run again, it
+  reads and writes the same values.
 
 There is ONE layout, the one the decode kernels read: a row is a
 token's heads side by side on the lane axis, which is how the qkv
@@ -60,15 +78,19 @@ SLOT INDEX operations:
 
 - insert  = the bucket prefill program ``dynamic_update_slice``-writes a
   prompt's K/V block at its slot (core/steps.py build_prefill_step; the
-  window-and-summary kind: its last window and its summaries);
+  window-and-summary kind: its last window and its summaries; a tail:
+  the generation of the prompt's LAST position, ``length - 1``, whatever
+  the bucket pads to);
 - advance = the decode program scatter-writes one row per slot and
   layer at ``[layer, slot, position]`` (ops/attention.py
   MultiHeadAttention; the window-and-summary kind: at ``position %
-  window``, and the current chunk's summary row again);
+  window``, and the current chunk's summary row again; a tail: the
+  other generation read, this position's written);
 - evict   = the driver frees the slot index — NO device work.  Stale
   rows beyond what a slot's position lets it see are unreachable by
   construction (the per-slot mask), so a freed slot is reusable the
-  moment the next prefill overwrites its rows.
+  moment the next prefill overwrites its rows (and the one generation
+  of its tail that the first decode reads).
 
 Shapes are static whatever the live-request mix, so the decode loop
 never re-traces — the property the serve acceptance pins with trace
@@ -80,6 +102,9 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import numpy as np
+
+#: a slot's tail is kept as what the model's convolutions read
+TAIL_DTYPE = np.dtype(np.float32)
 
 
 @dataclass(frozen=True)
@@ -96,7 +121,8 @@ class KVCacheSpec:
     ``counters``: the length of the int32 accumulator a model asked
     for beside the cache (0: none); ``paired``: a keys' and a values'
     array a kind, or (False) the one array of a model whose row holds
-    both (module docstring)."""
+    both (module docstring); ``tail``: ``(r, c)`` of the ``TAIL_DTYPE``
+    block a slot keeps a layer beside its rows (empty: none)."""
 
     n_layer: int
     slots: int
@@ -106,12 +132,14 @@ class KVCacheSpec:
     kinds: "tuple[tuple[int, int], ...]" = ()
     counters: int = 0
     paired: bool = True
+    tail: "tuple[int, ...]" = ()
 
     @property
     def own_state(self) -> bool:
         """Whether a slot's rows are the model's own kind and not a row
         per position (module docstring)."""
-        return self.rows is not None or bool(self.kinds)
+        return self.rows is not None or bool(self.kinds) \
+            or bool(self.tail)
 
     @property
     def shapes(self) -> "tuple[tuple[int, int, int, int], ...]":
@@ -133,22 +161,36 @@ class KVCacheSpec:
                 f"{self.shapes}: it has no one shape")
         return self.shapes[0]
 
+    @property
+    def tail_shape(self) -> "tuple[int, int, int, int] | None":
+        """``[n_layer, S, r, c]`` of the slots' tails, None without."""
+        if not self.tail:
+            return None
+        return (self.n_layer, self.slots) + tuple(self.tail)
+
     def nbytes(self, itemsize: int = 2) -> int:
         """Device residency of BOTH cache arrays (k and v) of every kind,
-        or of its one array, at the given element size (bf16 default)."""
-        return sum((1 + self.paired) * int(np.prod(shape, dtype=np.int64))
-                   * itemsize for shape in self.shapes)
+        or of its one array, at the given element size (bf16 default),
+        and of the tails in theirs."""
+        tail = int(np.prod(self.tail_shape, dtype=np.int64)) \
+            * TAIL_DTYPE.itemsize if self.tail else 0
+        return tail + sum(
+            (1 + self.paired) * int(np.prod(shape, dtype=np.int64))
+            * itemsize for shape in self.shapes)
 
     def state(self, make, dtype):
         """``(k, v)`` as every serve program takes and returns them,
         each leaf ``make(shape, dtype)`` (zeros, or an aval).  One kind
         and no accumulator: the two bare arrays.  Otherwise a tuple an
-        array a kind, the int32 accumulator behind the keys'; the
-        values' side empty where a kind is one array."""
+        array a kind, the tails and then the int32 accumulator behind
+        the keys'; the values' side empty where a kind is one array."""
         kinds = tuple(make(shape, dtype) for shape in self.shapes)
-        if len(kinds) == 1 and not self.counters and self.paired:
+        if len(kinds) == 1 and not self.counters and self.paired \
+                and not self.tail:
             return kinds[0], kinds[0]
         extra = (make((self.counters,), np.int32),) if self.counters else ()
+        if self.tail:
+            extra = (make(self.tail_shape, TAIL_DTYPE),) + extra
         return kinds + extra, kinds if self.paired else ()
 
     @classmethod
@@ -158,9 +200,11 @@ class KVCacheSpec:
         capture: ``kv_shapes`` is any per-layer K aval list (core/steps.py
         _stacked_kv order), or the layers' captured tuples themselves
         (``kv_layer_pairs``), where a tuple of ONE block says that the
-        layer's row holds key and value at once.  An entry shaped ``[B,
-        T, C]`` is a row per captured position: the cache holds
-        ``max_seq_len`` rows a slot.
+        layer's row holds key and value at once and a THIRD block
+        ``[B, 1, r, c]`` is the tail a slot keeps in that layer beside
+        its rows (every layer's alike).  An entry shaped ``[B, T, C]`` is
+        a row per captured position: the cache holds ``max_seq_len`` rows
+        a slot.
         An entry shaped ``[B, 1, R, C]`` is a model's own state block,
         as its ``prefill`` method writes it at a slot: ``R`` rows a
         slot, whatever ``max_seq_len`` (the model sized it from its own
@@ -170,6 +214,13 @@ class KVCacheSpec:
         is the order the model finds its arrays in."""
         paired = not any(isinstance(k, tuple) and len(k) == 1
                          for k in kv_shapes)
+        tails = {(tuple(int(n) for n in k[2].shape[2:]),
+                  np.dtype(k[2].dtype).name)
+                 for k in kv_shapes if isinstance(k, tuple) and len(k) == 3}
+        if len(tails) > 1 or any(t != TAIL_DTYPE.name for _, t in tails):
+            raise ValueError(f"layers keep tails of differing shapes, or "
+                             f"not {TAIL_DTYPE.name}: {sorted(tails)}")
+        tail = next(iter(tails), ((),))[0]
         kv_shapes = [k[0] if isinstance(k, tuple) else k for k in kv_shapes]
         n_layer = len(kv_shapes)
         if n_layer == 0:
@@ -185,7 +236,7 @@ class KVCacheSpec:
                    rows=distinct[0] if one_kind else None,
                    kinds=() if one_kind else tuple(
                        (per_layer.count(r), r) for r in distinct),
-                   counters=counters, paired=paired)
+                   counters=counters, paired=paired, tail=tail)
 
 
 class SlotAllocator:

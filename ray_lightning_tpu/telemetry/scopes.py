@@ -67,7 +67,13 @@ FINE_SCOPES = ("eva_summary", "eva_attn",
                # coefficients (the streams' norm, the n d x n (n + 2)
                # product, sigmoids, Sinkhorn), the streams mixed with
                # them, and what of latent attention is not its kernel
-               "mhc_mix", "mhc_apply", "mla_proj")
+               "mhc_mix", "mhc_apply", "mla_proj",
+               # models/zaya.py: what of compressed convolutional
+               # attention is neither a projection nor the kernel (the q-k
+               # mean, both convolutions, the norm and temperature, the
+               # shifted value, the tail's read and write), and its
+               # projections with rotary
+               "cca_mix", "cca_proj")
 
 #: the file of tables written beside a captured trace
 TABLE_FILE = "op_scopes.json"

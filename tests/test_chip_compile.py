@@ -195,6 +195,72 @@ def test_latent_attention_kernels_compile_for_v5e(monkeypatch, v5e, kernel):
         assert not _layer_movers(text, layer)
 
 
+@pytest.mark.parametrize("T", [512, 768, 1024])
+def test_a_short_prompts_splash_attention_compiles_for_v5e(monkeypatch, v5e,
+                                                           T):
+    """models/zaya.py's prompt: 8 query heads over 2 K/V heads of 128 at
+    the cell's three buckets.  768 is one block of 768 keys, which 512 keys
+    a product does not divide (the chip's first run of the cell refused it,
+    PR 41): the product takes a whole divisor, 256."""
+    from ray_lightning_tpu.ops import window_attention as wa
+    monkeypatch.setattr(wa, "select_prefill_kernel", lambda T, D: "splash")
+    q = jax.ShapeDtypeStruct((1, T, 8, 128), jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((1, T, 2, 128), jnp.bfloat16, sharding=v5e)
+    text = jax.jit(lambda q, k, v: wa.banded_attention(
+        q, k, v, window=None)).lower(q, kv, kv).compile().as_text()
+    assert "tpu_custom_call" in text and "splash_mqa_fwd" in text
+
+
+@pytest.mark.parametrize("what", ["gqa_decode", "sublayer"])
+def test_compressed_attention_decode_compiles_for_v5e(monkeypatch, v5e, what):
+    """models/zaya.py at the published widths, bfloat16, 128 slots x 3,328
+    rows: the shared grouped call at 4 query heads a K/V head over a row
+    of 2 x 128 lanes (13 blocks of 256 rows), and the whole attention
+    sublayer of a decode step: the projections, the tail's read and write
+    (two generations of 2,688 float32 values a slot), both convolutions,
+    the kernel.  The cache enters whole and nothing copies or slices a
+    layer of it; the tails are 27.5 MB and may be copied."""
+    from ray_lightning_tpu.models import zaya
+    from ray_lightning_tpu.ops import flash_decode
+    from ray_lightning_tpu.ops import window_attention as wa
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    monkeypatch.setattr(flash_decode, "_use_interpret", lambda: False)
+    monkeypatch.setenv("RLT_DECODE_IMPL", "flash_decode")
+    slots, rows, width, layers = 128, 3328, 256, 10
+    cache = sds((layers, slots, rows, width))
+    at = sds((slots,), jnp.int32)
+    if what == "gqa_decode":
+        fn = lambda q, k, v, at: wa.cached_attention(  # noqa: E731
+            q, k, v, at, layer=7, ring=False)
+        args = (sds((slots, 1, 8, 128)), cache, cache, at)
+    else:
+        cfg = zaya.ZayaConfig(num_hidden_layers=layers,
+                              served_positions=rows)
+        attn = zaya.CompressedAttention(cfg, 7)
+        u = sds((slots, 1, cfg.hidden_size))
+        tail = sds((layers, slots, 2, cfg.tail_width), jnp.float32)
+        made = jax.eval_shape(lambda: zaya.resident({"attn": attn.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size),
+                                             jnp.bfloat16))["params"]}))
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), made)
+        fn = lambda p, u, k, v, tail, at: attn.apply(  # noqa: E731
+            {"params": p["attn"]}, u, cache=(k, v, tail), positions=at)
+        args = (params, u, cache, cache, tail, at)
+    compiled = jax.jit(fn, donate_argnums=(2, 3, 4) if what == "sublayer"
+                       else ()).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(r"%gqa_decode(\.\d+)? = [^\n]* custom-call\([^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text), text
+    assert flash_decode.grouped_block_k(rows) == 256
+    layer = slots * rows * width
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05 * layer * 2
+    assert not _layer_movers(text, layer)
+
+
 # -- the cache's trip through the serve programs ----------------------------
 #
 # The K/V cache is resident as [n_layer, S, L, H*D] and every program
