@@ -177,7 +177,8 @@ def cached_attention(q, k_cache, v_cache, positions, *, layer,
     L = k_cache.shape[2]
     kernel = select_decode_kernel(
         L, H, D, dtype=q.dtype, impl=impl,
-        n_pages=None if page_table is None else page_table.shape[1])
+        n_pages=None if page_table is None else page_table.shape[1],
+        by_slot=slots is not None)
     note_decode_kernel(kernel)
     if kernel != "dense":
         return flash_decode_attention(

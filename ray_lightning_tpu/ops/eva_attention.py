@@ -521,6 +521,7 @@ def _eva_decode_kernel_call(q, k_cache, v_cache, positions, *, layer,
                          f"{layer} of {S} slots x {H} heads x {D}")
     bk = decode_block_k(window, rows - window)
     nk, wb, per = rows // bk, window // bk, window // chunk
+    _fd.note_decode_kernel(KERNEL_NAME, bk, rows)
     base = layer * S
 
     def kv_map(s, kb, pos_ref):

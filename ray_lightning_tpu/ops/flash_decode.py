@@ -18,6 +18,14 @@ the win is not FLOPs, it is *bytes not read*.  This kernel:
   dead blocks to the last live block — Pallas skips the DMA for a block
   whose mapped index is unchanged from the previous grid step, so a slot
   at position p reads ``ceil((p+1)/block_k)`` KV blocks, not ``L/block_k``;
+- reads blocks of the CALL's length, not the cache's: the flat, grouped
+  and latent calls take ``ceil(L / block_k)`` blocks a slot whatever
+  ``L`` is, and where ``block_k`` does not tile ``L`` the last block ends
+  past the array.  Its rows there hold whatever VMEM held; the body
+  zeroes those VALUE rows (their scores are out by the bound already,
+  but ``0 x NaN`` is NaN), and emits nothing where the blocks tile
+  (``_decode_body``'s ``cache_rows``; PR 42: a block halved until it
+  tiled cost ZAYA1's 3,328-row slots a third of the kernel's time);
 - has a **paged** variant whose KV index_map walks a page table
   (``serve/fleet/pages.py identity_page_table``): a layer of the cache
   is viewed as ``[S*pages_per_slot, page_size, C]`` physical pages and
@@ -101,7 +109,7 @@ def resolve_decode_impl(value=None) -> str:
 
 
 def select_decode_kernel(L: int, H: int, D: int, *, dtype, impl=None,
-                         n_pages=None) -> str:
+                         n_pages=None, by_slot: bool = False) -> str:
     """The kernel :func:`~ray_lightning_tpu.ops.attention.cached_attention`
     lowers for this cache geometry: ``dense``, ``flash_decode`` or
     ``paged``.  (The calls of the models that bring their own serve state
@@ -115,7 +123,10 @@ def select_decode_kernel(L: int, H: int, D: int, *, dtype, impl=None,
     opts in).  An EXPLICIT ``flash_decode``/``paged`` request that the
     geometry cannot lower raises — it never silently becomes the dense
     einsum.  ``paged`` without a page table (``n_pages=None``) is the
-    slot-contiguous kernel: same Pallas body, identity fetch."""
+    slot-contiguous kernel: same Pallas body, identity fetch.
+    ``by_slot``: the rows name their slots (``cached_attention``'s
+    ``slots``), so without a page table they walk their slots' own
+    pages, which have to tile the cache (:func:`_pick_block_k`)."""
     req = resolve_decode_impl(impl)
     if req == "dense":
         return "dense"
@@ -127,8 +138,12 @@ def select_decode_kernel(L: int, H: int, D: int, *, dtype, impl=None,
         want = req
     if want == "paged" and n_pages is None:
         want = "flash_decode"
-    bk = L // n_pages if want == "paged" else _pick_block_k(L)
-    if decode_kernel_supported(L, H, D, block_k=bk, dtype=dtype):
+    if want == "paged":
+        bk = L // n_pages
+    else:
+        bk = _pick_block_k(L) if by_slot else flat_block_k(L)
+    if decode_kernel_supported(L, H, D, block_k=bk, dtype=dtype,
+                               ragged=want != "paged" and not by_slot):
         return want
     if req == "auto":
         return "dense"
@@ -136,27 +151,31 @@ def select_decode_kernel(L: int, H: int, D: int, *, dtype, impl=None,
         f"decode impl {req!r} was requested explicitly but the cache "
         f"geometry L={L}, H={H}, D={D}, block_k={bk}, "
         f"dtype={jnp.dtype(dtype).name} cannot lower on this platform "
-        f"(needs H*D % 128 == 0 and block_k a sublane multiple that "
-        f"tiles L); use impl='auto' or 'dense'")
+        f"(needs H*D % 128 == 0 and block_k a sublane multiple, and "
+        f"pages that tile L); use impl='auto' or 'dense'")
 
 
 # -- which kernel a program actually lowered --------------------------------
 #
 # The serve engine reports the kernel its decode program LOWERED, not the
 # one requested: cached_attention notes its choice here while a program
-# traces, and the engine collects the notes around each trace
-# (serve/engine.py ``_counted``).  Thread-local because the AOT
-# precompiler traces on its own thread.
+# traces, each kernel call notes the blocks it reads a slot's rows in,
+# and the engine collects the notes around each trace (serve/engine.py
+# ``_counted``).  Thread-local because the AOT precompiler traces on its
+# own thread.
 
 _trace_notes = threading.local()
 
 
 @contextlib.contextmanager
 def record_decode_kernels():
-    """Collect the kernel names ``cached_attention`` lowers while
-    tracing on this thread inside the block (yields the set)."""
+    """Collect what the decode attentions lower while tracing on this
+    thread inside the block.  Yields a dict: each kernel's name (``dense``
+    among them) to the distinct ``[block rows, blocks a slot, rows in the
+    last block]`` its calls read a slot in, in the order they traced
+    (none for ``dense``)."""
     prev = getattr(_trace_notes, "seen", None)
-    seen: set = set()
+    seen: dict = {}
     _trace_notes.seen = seen
     try:
         yield seen
@@ -164,10 +183,19 @@ def record_decode_kernels():
         _trace_notes.seen = prev
 
 
-def note_decode_kernel(kernel: str) -> None:
+def note_decode_kernel(kernel: str, block_k: "int | None" = None,
+                       cache_rows: "int | None" = None) -> None:
+    """``kernel`` lowered on this thread; with ``block_k``, a call of it
+    that reads a slot's ``cache_rows`` rows in blocks of ``block_k``."""
     seen = getattr(_trace_notes, "seen", None)
-    if seen is not None:
-        seen.add(kernel)
+    if seen is None:
+        return
+    blocks = seen.setdefault(kernel, [])
+    if block_k is not None:
+        nk = pl.cdiv(cache_rows, block_k)
+        triple = [block_k, nk, cache_rows - (nk - 1) * block_k]
+        if triple not in blocks:
+            blocks.append(triple)
 
 
 def kv_block_bound(kb: int, pos, block_k: int):
@@ -181,32 +209,55 @@ def kv_block_bound(kb: int, pos, block_k: int):
     return jnp.minimum(kb, pos // block_k)
 
 
+#: A cache's rows a slot are whole tiles of 8 on the chip: the compiler
+#: copies an array of other lengths whole before a kernel reads it (24
+#: slots x 1,001 rows x 1,280 lanes: 248 MB of temporaries a call where
+#: 1,000 rows leave none; described v5e, PR 42).  Blocks that tile the
+#: cache imply it; a ragged last block does not.
+_ROW_TILE = 8
+
+
 def decode_kernel_supported(L: int, H: int, D: int, *,
-                            block_k: int, dtype) -> bool:
+                            block_k: int, dtype,
+                            ragged: bool = False) -> bool:
     """Whether the kernel path can lower for this cache geometry.  The
-    interpreter (non-TPU) takes anything; on TPU the packed lane axis
-    ``C = H*D`` must be a 128-lane multiple and blocks must tile L.
-    (The flat, paged, ``eva_decode`` and ``gqa_decode`` calls ask this;
-    the latent call, ``mla_decode``, whose row is one head of 576 lanes
-    with the value inside it, asks :func:`latent_kernel_supported`.)"""
+    packed lane axis ``C = H*D`` must be a 128-lane multiple and a block
+    whole sublane tiles on the TPU (the interpreter takes anything), and
+    the blocks must tile ``L`` unless the call takes a ``ragged`` last
+    block, one that ends past the cache, whose rows :func:`_decode_body`
+    masks: the flat call and ``gqa_decode`` do (and ``mla_decode``, whose
+    row is one head of 576 lanes with the value inside it and which asks
+    :func:`latent_kernel_supported`); the paged call's pages and
+    ``eva_decode``'s two ranges of rows do not.  Either way the cache
+    holds whole tiles of rows (``_ROW_TILE``)."""
     C = H * D
-    if L % block_k:
+    if L % block_k and not ragged:
         return False
     if _use_interpret():
         return True
     sub = 16 if dtype == jnp.bfloat16 else 8
-    return C % 128 == 0 and block_k % sub == 0
+    return C % 128 == 0 and block_k % sub == 0 and L % _ROW_TILE == 0
 
 
-#: Cache rows a grid step reads, halved until it tiles the cache.  The
-#: kernel alone, ms a call at 128 / 256 / 512 rows (builder's chip run,
-#: PR 29; PERF.md section 6): 0.1453 / 0.1437 / 0.1536 at gpt2-large's
-#: geometry; at EvaByte's 256 is +5 % and 512 does not fit Mosaic's
-#: 16 MB of scoped VMEM.
+#: Cache rows a grid step reads.  The kernel alone, ms a call at 128 /
+#: 256 / 512 rows (builder's chip run, PR 29; PERF.md section 6): 0.1453
+#: / 0.1437 / 0.1536 at gpt2-large's geometry; at EvaByte's 256 is +5 %
+#: and 512 does not fit Mosaic's 16 MB of scoped VMEM.
 _BLOCK_K = 128
 
 
+def flat_block_k(L: int) -> int:
+    return min(_BLOCK_K, L)
+
+
 def _pick_block_k(L: int, most: int = _BLOCK_K) -> int:
+    """The largest block of at most ``most`` rows, by halving, that TILES
+    ``L``: for the two calls that cannot take a ragged last block.  The
+    paged call views a layer as ``[pages, page_size, C]``, a reshape, and
+    ``eva_decode``'s block tiles two ranges of rows
+    (ops/eva_attention.py ``decode_block_k``): a block that does not tile
+    is there not a tail to mask but a wrong address.  The flat, grouped
+    and latent calls read blocks of their constant whatever ``L`` is."""
     b = min(most, L)
     while L % b:
         b //= 2
@@ -216,7 +267,7 @@ def _pick_block_k(L: int, most: int = _BLOCK_K) -> int:
 def _decode_body(pos, kb, nk, logical_base,
                  q_ref, k_ref, v_ref, o_ref, qd_ref, m_ref, l_ref, acc_ref,
                  *, sm_scale, block_k, head_dim, rows=None, group=None,
-                 value_dim=None):
+                 value_dim=None, cache_rows=None):
     """Online-softmax update for one ``block_k``-row KV block of one
     slot, every packed head at once.  ``logical_base`` is the block's
     first LOGICAL cache row (page-table indirection moves only the
@@ -245,7 +296,16 @@ def _decode_body(pos, kb, nk, logical_base,
     row's value is its key's first ``value_dim`` lanes, so ``v_ref`` is
     the block that ``k_ref`` is (one fetch serves both), ``p @`` reads
     those lanes of it, and ``acc_ref`` and ``o_ref`` are ``value_dim``
-    wide a K/V head."""
+    wide a K/V head.
+
+    ``cache_rows``: the rows the cache array holds a slot.  Where
+    ``block_k`` does not tile them the last block ends past the array
+    and its tail is whatever VMEM held (the interpreter: NaN).  Those
+    rows are out of the scores by the bound (``cols <= pos``, here with
+    ``cols < cache_rows`` for a bound past the cache; ``where`` selects,
+    so a NaN score does not spread), but ``p = 0`` times a NaN value is
+    NaN: the VALUE rows past the array are zeroed.  Where the blocks tile
+    the cache nothing is emitted."""
     live = kb * block_k <= pos if rows is None else rows[0]
     hp, width = qd_ref.shape
 
@@ -281,13 +341,18 @@ def _decode_body(pos, kb, nk, logical_base,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(live)
-    def _compute():
+    def update(tail: bool):
         k = k_ref[0]                                        # [block_k, C]
         v = v_ref[0] if value_dim is None else v_ref[0][:, :value_dim]
         cols = (jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
                 + logical_base)
         valid = cols <= pos if rows is None else rows[1](cols)
+        if tail:
+            # (a bound past the cache, a slot speculating past its end,
+            # sees every row there is and no more)
+            valid &= cols < cache_rows
+            row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row < cache_rows % block_k, v, jnp.zeros_like(v))
         # q . k^T, the flash forward kernel's own form
         s = jax.lax.dot_general(
             qd_ref[:], k, (((1,), (1,)), ((), ())),
@@ -303,6 +368,14 @@ def _decode_body(pos, kb, nk, logical_base,
             preferred_element_type=jnp.float32)             # [Hp, C]
         acc_ref[:] = alpha * acc_ref[:] + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    if cache_rows is None or cache_rows % block_k == 0:
+        pl.when(live)(lambda: update(False))
+    else:
+        # the mask in the last block's step alone (the readings:
+        # _GROUPED_BLOCK_K): every other step runs the tiling shape's code
+        pl.when(live & (kb < nk - 1))(lambda: update(False))
+        pl.when(live & (kb == nk - 1))(lambda: update(True))
 
     if group is not None:
         @pl.when(kb == nk - 1)
@@ -373,7 +446,10 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
     slot only through a scalar-prefetch table, so without a
     ``page_table`` (whose rows are the batch rows' then) the rows walk
     their slots' own ``block_k``-row pages through the paged kernel.
-    """
+
+    Unpaged, a slot's rows are read in blocks of ``_BLOCK_K`` whatever
+    ``L`` is: the last block may end past the array
+    (``_decode_body``'s ``cache_rows``).  Pages tile ``L``."""
     B, _, H, D = q.shape
     n_layer, S, L, C = k_cache.shape
     if C != H * D or not 0 <= layer < n_layer \
@@ -381,6 +457,7 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
         raise ValueError(
             f"cache {k_cache.shape} does not hold layer {layer} of "
             f"{B} rows x {H} heads x {D}")
+    noted = "flash_decode" if page_table is None else "paged"
     if slots is not None and page_table is None:
         nk = L // (block_k or _pick_block_k(L))
         page_table = slots[:, None] * nk + jnp.arange(nk)[None, :]
@@ -392,8 +469,9 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
                 f"page table with {n_pages} pages cannot tile L={L}")
         bk = L // n_pages
     else:
-        bk = block_k or _pick_block_k(L)
-    nk = L // bk
+        bk = block_k or flat_block_k(L)
+    nk = pl.cdiv(L, bk)
+    note_decode_kernel(noted, bk, L)
     if interpret is None:
         interpret = _use_interpret()
 
@@ -444,7 +522,8 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
         scratch_shapes=decode_scratch(H, C, k_cache.dtype),
     )
     body = functools.partial(
-        kernel, sm_scale=1.0 / float(np.sqrt(D)), block_k=bk, head_dim=D)
+        kernel, sm_scale=1.0 / float(np.sqrt(D)), block_k=bk, head_dim=D,
+        cache_rows=L)
     # both names keep the "flash" stem: the anatomy category table and
     # the collective classifier key on it (telemetry/anatomy.py
     # bucket_of, comm/audit.py collective_kind)
@@ -463,8 +542,8 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
     return out.reshape(B, 1, H, D)
 
 
-#: Cache rows a grid step of the GROUPED call reads, halved until it
-#: tiles the cache.  Its row is 8 K/V heads of 128 lanes (2 KB in bf16)
+#: Cache rows a grid step of the GROUPED call reads, whatever the cache's
+#: length (the last block may end past it).  Its row is 8 K/V heads of 128 lanes (2 KB in bf16)
 #: and its scores are [128, block_k]: larger blocks pay.  The kernel
 #: alone at 32 slots, ms a call at 128 / 256 / 512 rows (builder's chip
 #: run, PR 33; PERF.md section 6): a ring of 4096 rows read whole 1.147 /
@@ -477,15 +556,29 @@ def flash_decode_attention(q, k_cache, v_cache, positions, *, layer,
 #: 0.696 / 1.035, of 512 (3,584) 0.513 / 0.753, and over 4,096 rows 512:
 #: 0.515 / 0.766, 1024: 0.519 / 0.684, 2048: 0.539 / 0.725.  The narrow row
 #: wants 512 as the wide one does.  Its benchmark cell holds 3,328 rows a
-#: slot, which 512 does not tile: it reads blocks of 256 and pays the
-#: difference until a ragged last block is masked in the body.
+#: slot, which 512 does not tile: six blocks of 512 and a seventh whose
+#: last 256 rows lie past the array, their values masked in the body.
+#: The kernel alone (builder's chip run, PR 42, one call; three timings of
+#: 50 calls each agree to 0.3 %, their medians here), ms a call at 1,500 / 3,000 / 3,328 live
+#: rows over 3,328: blocks of 256 0.695 / 1.034 / 1.044; ragged blocks of
+#: 512 with the values masked in the LAST block's step alone 0.513 / 0.763
+#: / 0.764, by a ``where`` in every live step 0.512 / 0.764 / 0.763, the
+#: block's tail zeroed in VMEM in place 0.513 / 0.763 / 0.764, no mask at
+#: all (wrong: the floor) 0.513 / 0.763 / 0.763; 3,584 rows, which tile,
+#: 0.515 / 0.752 / 0.766.  The wide row's full layer, 32 slots x 8,960
+#: rows, at 6,901 / 8,960 live (bytes' time 1.104 / 1.434): 256 1.439 /
+#: 1.755; ragged 512 last-only 1.349 / 1.602, every step 1.346 / 1.603, in
+#: place 1.347 / 1.602, none 1.346 / 1.602.  The mask costs nothing that
+#: shows wherever it stands; it stands in the last block's step, where no
+#: other step can pay for it (the latent call's every-step mask read
+#: +1.2 % at a full cache).
 _GROUPED_BLOCK_K = 512
 #: the grouped call's name in the compiled program and the trace
 GROUPED_KERNEL_NAME = "gqa_decode"
 
 
 def grouped_block_k(L: int) -> int:
-    return _pick_block_k(L, _GROUPED_BLOCK_K)
+    return min(_GROUPED_BLOCK_K, L)
 
 
 def grouped_decode_attention(q, k_cache, v_cache, bound, *, layer,
@@ -507,7 +600,8 @@ def grouped_decode_attention(q, k_cache, v_cache, bound, *, layer,
             f"cache {k_cache.shape} does not hold layer {layer} of {S} "
             f"slots x K/V heads of {D} that divide {H} query heads")
     bk = grouped_block_k(L)
-    nk = L // bk
+    nk = pl.cdiv(L, bk)
+    note_decode_kernel(GROUPED_KERNEL_NAME, bk, L)
     base = layer * S
 
     def kv_map(s, kb, pos_ref):
@@ -523,7 +617,7 @@ def grouped_decode_attention(q, k_cache, v_cache, bound, *, layer,
 
     body = functools.partial(
         kernel, sm_scale=1.0 / float(np.sqrt(D)), block_k=bk, head_dim=D,
-        group=H // (C // D))
+        group=H // (C // D), cache_rows=L)
     body.__name__ = GROUPED_KERNEL_NAME + "_kernel"
     out = pl.pallas_call(
         body,
@@ -547,13 +641,17 @@ def grouped_decode_attention(q, k_cache, v_cache, bound, *, layer,
     return out.reshape(S, 1, H, D)
 
 
-#: Cache rows a grid step of the LATENT call reads, halved until it tiles
-#: the cache.  Its row is one K/V head of 640 lanes (576 values, 1,152 B
+#: Cache rows a grid step of the LATENT call reads, whatever the cache's
+#: length.  Its row is one K/V head of 640 lanes (576 values, 1,152 B
 #: in bf16, and 64 lanes of zeros), its scores [32, block_k].  The kernel
 #: alone at 64 slots, ms a call at 128 / 256 / 512 / 1024 rows (builder's
 #: chip run, PR 36; PERF.md section 6): 6,400-9,900 of 10,240 rows a slot
 #: 3.305 / 2.363 / 1.879 / 1.634 (its 1,152 B rows' time 0.734, the 1,280 B
-#: it reads 0.815); of 9,984 rows, which 512 does not tile, 3.258 / 2.319;
+#: it reads 0.815); of 9,984 rows, which 512 does not tile, 3.258 / 2.319
+#: (builder's chip run, PR 42, at 8,000 / 9,984 live rows: the 256 that
+#: tile 9,984 1.613 / 1.856, ragged blocks of 1,024 1.013 / 1.114, with the
+#: mask in every live step 1.013 / 1.127, none 1.011 / 1.112; 10,240 rows
+#: 1.012 / 1.130);
 #: 101 rows 1.275 / 0.996 / 0.937 / 0.933.  32 query rows are a quarter of
 #: the MXU's height: a block's two products take about as long as its
 #: bytes, and the two do not overlap.
@@ -563,20 +661,22 @@ LATENT_KERNEL_NAME = "mla_decode"
 
 
 def latent_block_k(L: int) -> int:
-    return _pick_block_k(L, _LATENT_BLOCK_K)
+    return min(_LATENT_BLOCK_K, L)
 
 
 def latent_kernel_supported(L: int, C: int, value_dim: int, *,
                             dtype) -> bool:
     """Whether :func:`latent_decode_attention` can lower: the interpreter
-    takes anything; Mosaic wants the blocks to tile the cache in whole
-    sublane tiles and the value's lanes to end on a lane tile (the key's
-    width is the array's whole minor dimension, whatever it is)."""
+    takes anything; Mosaic wants a block to be whole sublane tiles (the
+    last may end past the cache) and the value's lanes to end on a lane
+    tile (the key's width is the array's whole minor dimension, whatever
+    it is)."""
     bk = latent_block_k(L)
     if _use_interpret():
         return True
     sub = 16 if dtype == jnp.bfloat16 else 8
-    return bk % sub == 0 and value_dim % 128 == 0 and value_dim <= C
+    return bk % sub == 0 and L % _ROW_TILE == 0 \
+        and value_dim % 128 == 0 and value_dim <= C
 
 
 def latent_decode_attention(q, cache, positions, *, layer: int,
@@ -602,7 +702,8 @@ def latent_decode_attention(q, cache, positions, *, layer: int,
             f"cache {cache.shape} does not hold layer {layer} of {S} "
             f"slots x rows of {C} whose first {value_dim} are the value")
     bk = latent_block_k(L)
-    nk = L // bk
+    nk = pl.cdiv(L, bk)
+    note_decode_kernel(LATENT_KERNEL_NAME, bk, L)
     base = layer * S
 
     def kv_map(s, kb, pos_ref):
@@ -618,7 +719,7 @@ def latent_decode_attention(q, cache, positions, *, layer: int,
 
     body = functools.partial(
         kernel, sm_scale=float(sm_scale), block_k=bk, head_dim=C, group=H,
-        value_dim=value_dim)
+        value_dim=value_dim, cache_rows=L)
     body.__name__ = LATENT_KERNEL_NAME + "_kernel"
     return pl.pallas_call(
         body,

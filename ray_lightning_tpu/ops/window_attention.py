@@ -205,7 +205,8 @@ def select_decode_kernel(rows: int, G: int, D: int, *, dtype,
     if req == "auto" and jax.devices()[0].platform != "tpu":
         return "dense"
     bk = _fd.grouped_block_k(rows)
-    if _fd.decode_kernel_supported(rows, G, D, block_k=bk, dtype=dtype) \
+    if _fd.decode_kernel_supported(rows, G, D, block_k=bk, dtype=dtype,
+                                   ragged=True) \
             and (_fd._use_interpret() or D % 128 == 0):
         return KERNEL_NAME
     if req == "auto":

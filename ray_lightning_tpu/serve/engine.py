@@ -134,6 +134,13 @@ class ServeEngine:
         #: benches emit it so a kernel regression is visible in the
         #: JSON ledger.  None until the decode program has traced.
         self.decode_kernel: Optional[str] = None
+        #: for each kernel of that program, the distinct [block rows,
+        #: blocks a slot, rows in the last block] its calls read a slot
+        #: in (a last block of fewer rows than a block ends past the
+        #: cache and is masked, ops/flash_decode.py ``_decode_body``):
+        #: {"gqa_decode": [[512, 8, 512], [512, 18, 256]]} for a ring of
+        #: 4,096 rows and a full layer of 8,960
+        self.decode_blocks: dict[str, list] = {}
         self.trace_counts: dict[str, int] = {}
         self.kv_spec: Optional[KVCacheSpec] = None
         self.params = None
@@ -618,6 +625,8 @@ class ServeEngine:
                 out = fn(*args)
             # one kernel per program: every layer has the same geometry
             self.decode_kernel = "+".join(sorted(lowered)) or None
+            self.decode_blocks = {name: blocks for name, blocks
+                                  in lowered.items() if blocks}
             return out
         # the program's name: the profiler trace and the compile cache
         # read jit_serve_decode, jit_serve_prefill_512, ...
@@ -852,6 +861,7 @@ class ServeEngine:
                        "count": jax.device_count()},
             "memory_stats": dev.memory_stats(),
             "decode_kernel": self.decode_kernel,
+            "decode_blocks": self.decode_blocks,
             **self._counters(jax),
             "traces": dict(self.trace_counts),
             # traces since the warmup snapshot: 0 everywhere = the

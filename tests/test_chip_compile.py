@@ -195,6 +195,61 @@ def test_latent_attention_kernels_compile_for_v5e(monkeypatch, v5e, kernel):
         assert not _layer_movers(text, layer)
 
 
+@pytest.mark.parametrize("case", ["command_full", "latent_9984",
+                                  "flat_1000"])
+def test_decode_calls_with_a_ragged_last_block_compile_for_v5e(
+        monkeypatch, v5e, case):
+    """A cache whose rows the call's block does not tile (PR 42), bfloat16:
+    Command A+'s full layer (32 slots x 8,960 rows x 8 K/V heads of 128
+    under 128 query heads: 17 blocks of 512 and one of 256), a latent
+    cache of 9,984 rows (64 slots x 640 lanes: nine blocks of 1,024 and one
+    of 768) and gpt2-large's row over 1,000 rows (seven blocks of 128 and
+    one of 104, which ends inside a sublane tile).  Each is a
+    ``tpu_custom_call`` under its name; the cache enters whole and nothing
+    copies or slices a layer of it."""
+    from ray_lightning_tpu.ops import attention, flash_decode
+    from ray_lightning_tpu.ops import latent_attention as la
+    from ray_lightning_tpu.ops import window_attention as wa
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    monkeypatch.setattr(flash_decode, "_use_interpret", lambda: False)
+    if case == "command_full":
+        slots, rows, width = 32, 8960, 1024
+        fn = lambda q, k, v, at: wa.cached_attention(  # noqa: E731
+            q, k, v, at, layer=1, ring=False, impl="flash_decode")
+        cache = sds((2, slots, rows, width))
+        args = (sds((slots, 1, 128, 128)), cache, cache,
+                sds((slots,), jnp.int32))
+        name, blocks = "gqa_decode", [512, 18, 256]
+    elif case == "latent_9984":
+        slots, rows, width = 64, 9984, 640
+        fn = lambda q, cache, at: la.cached_attention(  # noqa: E731
+            q, cache, at, layer=1, value_dim=512, sm_scale=0.1447,
+            impl="flash_decode")
+        args = (sds((slots, 32, width)), sds((2, slots, rows, width)),
+                sds((slots,), jnp.int32))
+        name, blocks = "mla_decode", [1024, 10, 768]
+    else:
+        slots, rows, width = 24, 1000, 1280
+        fn = lambda q, k, v, at: attention.cached_attention(  # noqa: E731
+            q, k, v, at, layer=1, impl="flash_decode")
+        cache = sds((2, slots, rows, width))
+        args = (sds((slots, 1, 20, 64)), cache, cache,
+                sds((slots,), jnp.int32))
+        name, blocks = "flash_decode", [128, 8, 104]
+    with flash_decode.record_decode_kernels() as lowered:
+        compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]* custom-call\([^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text), text
+    assert lowered == {name: [blocks]}
+    layer = slots * rows * width
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05 * layer * 2
+    assert not _layer_movers(text, layer)
+
+
 @pytest.mark.parametrize("T", [512, 768, 1024])
 def test_a_short_prompts_splash_attention_compiles_for_v5e(monkeypatch, v5e,
                                                            T):
@@ -215,7 +270,8 @@ def test_a_short_prompts_splash_attention_compiles_for_v5e(monkeypatch, v5e,
 def test_compressed_attention_decode_compiles_for_v5e(monkeypatch, v5e, what):
     """models/zaya.py at the published widths, bfloat16, 128 slots x 3,328
     rows: the shared grouped call at 4 query heads a K/V head over a row
-    of 2 x 128 lanes (13 blocks of 256 rows), and the whole attention
+    of 2 x 128 lanes (six blocks of 512 rows and a seventh whose last 256
+    lie past the array: PR 42), and the whole attention
     sublayer of a decode step: the projections, the tail's read and write
     (two generations of 2,688 float32 values a slot), both convolutions,
     the kernel.  The cache enters whole and nothing copies or slices a
@@ -250,12 +306,13 @@ def test_compressed_attention_decode_compiles_for_v5e(monkeypatch, v5e, what):
         fn = lambda p, u, k, v, tail, at: attn.apply(  # noqa: E731
             {"params": p["attn"]}, u, cache=(k, v, tail), positions=at)
         args = (params, u, cache, cache, tail, at)
-    compiled = jax.jit(fn, donate_argnums=(2, 3, 4) if what == "sublayer"
-                       else ()).lower(*args).compile()
+    with flash_decode.record_decode_kernels() as lowered:
+        compiled = jax.jit(fn, donate_argnums=(2, 3, 4) if what == "sublayer"
+                           else ()).lower(*args).compile()
     text = compiled.as_text()
     assert re.search(r"%gqa_decode(\.\d+)? = [^\n]* custom-call\([^\n]*"
                      r'custom_call_target="tpu_custom_call"', text), text
-    assert flash_decode.grouped_block_k(rows) == 256
+    assert lowered == {"gqa_decode": [[512, 7, 256]]}
     layer = slots * rows * width
     assert compiled.memory_analysis().temp_size_in_bytes < 0.05 * layer * 2
     assert not _layer_movers(text, layer)
@@ -372,6 +429,45 @@ def test_select_decode_kernel(monkeypatch, impl, n_pages, want):
                                 n_pages=n_pages) == want
 
 
+@pytest.mark.parametrize("rows,want", [
+    (3328, "gqa_decode"),       # 512 does not tile it: before PR 42 the
+    (8960, "gqa_decode"),       # block was halved to 256; now ragged
+    (1000, "gqa_decode"),       # halved to 8, no bfloat16 tile: was dense
+    (4096, "gqa_decode"),
+    (1001, "dense"),            # no whole tiles of 8 rows: the compiler
+                                # would copy the cache before the call
+    (24, "dense"),              # one block of 24 rows: no bfloat16 tile
+])
+def test_a_cache_the_block_does_not_tile_takes_the_kernel_under_auto(
+        monkeypatch, rows, want):
+    """``auto`` on the chip (steered here: the backend is the CPU): the
+    grouped call is chosen whatever the cache's length, the flat call
+    likewise, and the page walks keep asking for pages that tile."""
+    from ray_lightning_tpu.ops import flash_decode as fd
+    from ray_lightning_tpu.ops import window_attention as wa
+    monkeypatch.delenv("RLT_DECODE_IMPL", raising=False)
+    monkeypatch.setattr(fd, "_use_interpret", lambda: False)
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeDevice()])
+    assert wa.select_decode_kernel(rows, 2, 128, dtype=jnp.bfloat16) == want
+    assert fd.decode_kernel_supported(
+        rows, 2, 128, block_k=fd.grouped_block_k(rows), dtype=jnp.bfloat16,
+        ragged=True) == (want != "dense")
+    flat = fd.select_decode_kernel(rows, 20, 64, dtype=jnp.bfloat16)
+    assert flat == ("flash_decode" if want != "dense" else "dense")
+    # rows that name their slots walk pages of _pick_block_k rows, and a
+    # page table's pages have to tile
+    by_slot = fd.select_decode_kernel(rows, 20, 64, dtype=jnp.bfloat16,
+                                      by_slot=True)
+    assert by_slot == ("flash_decode" if fd._pick_block_k(rows) % 16 == 0
+                       else "dense")
+    assert not fd.decode_kernel_supported(rows, 20, 64, block_k=48,
+                                          dtype=jnp.bfloat16) or rows % 48 == 0
+    if rows % 7:
+        with pytest.raises(ValueError, match="requested explicitly"):
+            fd.select_decode_kernel(rows, 20, 64, dtype=jnp.bfloat16,
+                                    impl="paged", n_pages=7)
+
+
 @pytest.mark.parametrize("impl", ["flash_decode", "paged"])
 def test_explicit_decode_kernel_never_falls_back(monkeypatch, impl):
     """A geometry the kernel cannot lower on the chip (H*D not a lane
@@ -389,7 +485,7 @@ def test_explicit_decode_kernel_never_falls_back(monkeypatch, impl):
                          page_table=table)
     with fd.record_decode_kernels() as lowered:
         cached_attention(q, kv, kv, pos, layer=0, impl="dense")
-    assert lowered == {"dense"}
+    assert lowered == {"dense": []}
 
 
 # -- one process for each chip ----------------------------------------------

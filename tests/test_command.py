@@ -31,6 +31,7 @@ from chipbench import command_reference as ref
 from chipbench.adapters import command as adapter
 from ray_lightning_tpu.models.command import (
     SERVE_COUNTERS, Command, CommandLightningModule)
+from ray_lightning_tpu.ops import flash_decode as _fd
 from ray_lightning_tpu.ops import moe
 from ray_lightning_tpu.ops import window_attention as wa
 from ray_lightning_tpu.parallel.strategy import DataParallelStrategy
@@ -402,19 +403,30 @@ def test_one_pass_only_where_every_published_expert_is_held(held, published,
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
                                         (jnp.bfloat16, 2e-2)])
 @pytest.mark.parametrize("ring", [True, False])
-def test_grouped_decode_call_against_plain_attention(dtype, atol, ring):
+@pytest.mark.parametrize("rows,block", [(32, None), (40, 16)],
+                         ids=["one_block", "ragged"])
+def test_grouped_decode_call_against_plain_attention(monkeypatch, dtype,
+                                                     atol, ring, rows, block):
     """``gqa_decode`` (ops/flash_decode.py's shared body with ``group``)
     under the interpreter against attention written out head by head:
     query head i reads K/V head i // 4; a ring is read whole once it has
-    wrapped."""
-    S, H, G, D, rows = 3, 8, 2, 16, 32
+    wrapped.  ``ragged``: 40 rows in blocks of 16, the third block's last
+    8 rows past the array (the full layer's 8,960 rows in blocks of 512,
+    PR 42), a slot at the last whole block's last row and one at the
+    cache's last (a ring: wrapped, so every row of the ragged block is
+    read)."""
+    if block:
+        monkeypatch.setattr(_fd, "_GROUPED_BLOCK_K", block)
+    S, H, G, D = 3, 8, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (S, 1, H, D), dtype)
     kc = jax.random.normal(ks[1], (2, S, rows, G * D), dtype)
     vc = jax.random.normal(ks[2], (2, S, rows, G * D), dtype)
-    pos = jnp.asarray([0, 13, 45 if ring else 31])
-    got = wa.cached_attention(q, kc, vc, pos, layer=1, ring=ring,
-                              dtype=dtype, impl="flash_decode")
+    pos = jnp.asarray([0, 31 if block else 13, 45 if ring else rows - 1])
+    with _fd.record_decode_kernels() as lowered:
+        got = wa.cached_attention(q, kc, vc, pos, layer=1, ring=ring,
+                                  dtype=dtype, impl="flash_decode")
+    assert lowered == {"gqa_decode": [[16, 3, 8] if block else [32, 1, 32]]}
     seen = np.minimum(np.asarray(pos), rows - 1) + 1
     for s in range(S):
         for h in range(H):
@@ -552,6 +564,30 @@ def test_engine_serves_the_reference_tokens_and_counts_on_the_device(engine):
     assert after["prefill_moe_rows"] - before["prefill_moe_rows"] \
         == 4 * 4 * 32
     assert sum(engine.stats()["retraces"].values()) == 0
+
+
+@pytest.mark.limit(120)
+def test_the_engine_records_the_blocks_of_the_ring_and_of_the_full_layer(
+        monkeypatch):
+    """``stats()["decode_blocks"]`` (PR 42): the ring's 8 rows are one
+    block, the full layer's 56 rows three blocks of 16 and a ragged one
+    of 8; ``decode_kernel`` names the kernel once, as it did.  Greedy
+    tokens past row 48 (the ragged block) are the reference's."""
+    monkeypatch.setenv("RLT_DECODE_IMPL", "flash_decode")
+    monkeypatch.setattr(_fd, "_GROUPED_BLOCK_K", 16)
+    eng = ServeEngine(_Module(), DataParallelStrategy(), buckets=(32,),
+                      slots=SLOTS, max_seq_len=POSITIONS, seed=0).setup()
+    stats = eng.stats()
+    assert stats["decode_kernel"] == "gqa_decode"
+    assert stats["decode_blocks"] == {"gqa_decode": [[8, 1, 8], [16, 4, 8]]}
+    seq = _tokens(11, 56)
+    want = _full(seq, MODEL4).argmax(-1)
+    got = [eng.prefill(1, pad_to_bucket(seq[:30], 32), 30, 32)]
+    toks, at = np.zeros(SLOTS, np.int32), np.zeros(SLOTS, np.int32)
+    for t in range(30, 55):
+        toks[1], at[1] = seq[t], t
+        got.append(int(eng.decode(toks, at)[1]))
+    assert got == [int(x) for x in want[29:55]]
 
 
 @pytest.mark.limit(240)

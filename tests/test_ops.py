@@ -604,6 +604,35 @@ def test_decode_parity(monkeypatch, caller, geometry, positions, dtype):
         atol=_BARS[dtype], rtol=_BARS[dtype])
 
 
+@pytest.mark.parametrize("dtype", list(_BARS), ids=["f32", "bf16"])
+@pytest.mark.parametrize("caller", ["flat", "slots"])
+def test_decode_over_a_cache_the_block_does_not_tile(monkeypatch, caller,
+                                                     dtype):
+    """232 rows a slot under blocks of 64 (PR 42).  ``flat`` reads three
+    whole blocks and a fourth whose last 24 rows lie past the array (the
+    interpreter fills them with NaN: the body masks the value rows);
+    slots at row 0, the last whole block's last row, the ragged block's
+    first and the cache's last.  ``slots`` walks pages, which have to
+    tile: 29 pages of 8 rows, as before."""
+    monkeypatch.setattr(flash_decode, "_BLOCK_K", _BK)
+    L = 232
+    q, kc, vc = _rand_decode(s=_SLOTS, L=L, dtype=dtype)
+    pos = np.asarray([0, 3 * _BK - 1, 3 * _BK, L - 1], np.int32)
+    ref = _einsum_ref(q, kc, vc, pos, dtype)
+    with flash_decode.record_decode_kernels() as lowered:
+        if caller == "flat":
+            out = _decode("flash_decode", q, kc, vc, pos, dtype=dtype)
+        else:
+            ref = ref[_PICK]
+            out = _decode("flash_decode", q[_PICK], kc, vc, pos[_PICK],
+                          dtype=dtype, slots=jnp.asarray(_PICK, jnp.int32))
+    assert lowered == {"flash_decode": [[_BK, 4, 40] if caller == "flat"
+                                        else [8, 29, 8]]}
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        atol=_BARS[dtype], rtol=_BARS[dtype])
+
+
 @pytest.mark.parametrize("impl", ["dense", "flash_decode", "paged"])
 def test_decode_rows_in_named_slots(impl):
     """``slots``: a batch of rows that live in cache slots of their own

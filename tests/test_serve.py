@@ -566,6 +566,36 @@ def test_a_profile_window_holds_its_plans_programs_and_nothing_else(
     assert aheads == [None, "hit", "miss", "hit", "hit", "hit", "hit"]
 
 
+@pytest.mark.parametrize("impl,block,blocks", [
+    ("flash_decode", 8, {"flash_decode": [[8, 4, 8]]}),
+    ("flash_decode", 12, {"flash_decode": [[12, 3, 8]]}),
+    ("dense", 8, {}),
+])
+def test_engine_records_the_blocks_its_decode_program_reads(
+        impl, block, blocks, monkeypatch):
+    """``stats()["decode_blocks"]``: [block rows, blocks a slot, rows in
+    the last block] of each kernel the decode program lowered (PR 42).
+    32 rows a slot in blocks of 12 are two whole blocks and a ragged one
+    of 8; ``decode_kernel`` is the string it was."""
+    from ray_lightning_tpu.ops import flash_decode
+    monkeypatch.setenv("RLT_DECODE_IMPL", impl)
+    monkeypatch.setattr(flash_decode, "_BLOCK_K", block)
+    eng = ServeEngine(GPTLightningModule(TINY), DataParallelStrategy(),
+                      buckets=(8,), slots=2, max_seq_len=TINY.block_size,
+                      seed=0).setup()
+    assert eng.stats()["decode_kernel"] == impl
+    assert eng.stats()["decode_blocks"] == blocks
+    if impl == "dense":
+        return
+    prompt = np.array([5, 9, 2, 7, 11, 3, 1], np.int32)
+    got = _generate(eng, 1, prompt, 20)      # into the ragged block
+    monkeypatch.setenv("RLT_DECODE_IMPL", "dense")
+    dense = ServeEngine(GPTLightningModule(TINY), DataParallelStrategy(),
+                        buckets=(8,), slots=2, max_seq_len=TINY.block_size,
+                        seed=0).setup()
+    assert got == _generate(dense, 1, prompt, 20)
+
+
 @pytest.mark.parametrize("impl", ["flash_decode", "paged"])
 def test_engine_kernel_decode_parity_and_zero_retrace(impl, monkeypatch):
     """RLT_DECODE_IMPL forces the Pallas decode kernel (interpret mode

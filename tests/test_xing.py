@@ -438,19 +438,31 @@ def test_a_state_of_one_array_and_the_pairs_as_they_were():
 @pytest.mark.limit(120)
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
                                         (jnp.bfloat16, 2e-2)])
-def test_latent_decode_call_against_a_dense_einsum(monkeypatch, dtype, atol):
+@pytest.mark.parametrize("L", [64, 72], ids=["tiled", "ragged"])
+def test_latent_decode_call_against_a_dense_einsum(monkeypatch, dtype, atol,
+                                                   L):
     """``mla_decode`` under the interpreter: 4 heads read ONE row of 40
     whose first 32 lanes are the value; slots at position 0, at a block's
-    last row and its first, mid-cache and at the last row."""
+    last row and its first, mid-cache and at the last row.  ``ragged``: 72
+    rows in blocks of 16, so the fifth block's last 8 rows lie past the
+    array (9,984 rows in blocks of 1,024, PR 42): the slots at the last
+    whole block's last row, the ragged block's first and the cache's
+    last; one fetch serves keys and values, so the mask is on the block's
+    value lanes."""
     monkeypatch.setattr(flash_decode, "_LATENT_BLOCK_K", 16)
-    S, H, C, r, L = 5, 4, 40, 32, 64
+    S, H, C, r = 5, 4, 40, 32
     q, rows = (jax.random.normal(jax.random.PRNGKey(i), shape, jnp.float32)
                for i, shape in enumerate([(S, H, C), (2, S, L, C)]))
-    at = jnp.asarray([0, 15, 16, 37, 63], jnp.int32)
+    at = jnp.asarray([0, 15, 16, 37, 63] if L == 64
+                     else [0, 63, 64, 37, 71], jnp.int32)
     cache = rows.astype(dtype)
-    got = la.cached_attention(q.astype(dtype), cache, at, layer=1,
-                              value_dim=r, sm_scale=0.31, dtype=dtype,
-                              impl="flash_decode")
+    with flash_decode.record_decode_kernels() as lowered:
+        got = la.cached_attention(q.astype(dtype), cache, at, layer=1,
+                                  value_dim=r, sm_scale=0.31, dtype=dtype,
+                                  impl="flash_decode")
+    assert lowered == {"mla_decode": [[16, 4, 16] if L == 64
+                                      else [16, 5, 8]]}
+    assert np.isfinite(np.asarray(got, np.float32)).all()
     assert got.shape == (S, H, r) and got.dtype == dtype
     own = cache[1].astype(jnp.float32)
     s = jnp.einsum("shc,slc->shl", q.astype(dtype).astype(jnp.float32),

@@ -468,6 +468,96 @@ def test_gqa_decode_at_4_query_heads_a_kv_head_against_a_dense_einsum(
                                np.asarray(want), atol=2e-5)
 
 
+def _narrow_row_case(L, dtype, S=5, H=8, G=2, D=16):
+    keys = jax.random.split(jax.random.PRNGKey(L), 3)
+    q = jax.random.normal(keys[0], (S, 1, H, D), dtype)
+    k, v = (jax.random.normal(kk, (2, S, L, G * D), dtype)
+            for kk in keys[1:])
+    return q, k, v
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("ring", [False, True])
+def test_gqa_decode_over_a_cache_its_block_does_not_tile(monkeypatch, dtype,
+                                                         atol, ring):
+    """72 rows a slot in blocks of 16: four whole blocks and a fifth of
+    which 8 rows lie past the array (the cell's 3,328 rows in blocks of
+    512, PR 42).  Slots at row 0, the last whole block's last row, the
+    ragged block's first row, the cache's last row and mid-cache; a ring
+    that has wrapped reads all 72 rows at every slot."""
+    monkeypatch.setattr(flash_decode, "_GROUPED_BLOCK_K", 16)
+    L = 72
+    q, k, v = _narrow_row_case(L, dtype)
+    positions = jnp.asarray([0, 63, 64, 71, 30], jnp.int32) \
+        + (100 if ring else 0)
+    with flash_decode.record_decode_kernels() as lowered:
+        got = wa.cached_attention(q, k, v, positions, layer=1, ring=ring,
+                                  dtype=dtype, impl="flash_decode")
+    assert lowered == {"gqa_decode": [[16, 5, 8]]}
+    want = wa.cached_attention(q, k, v, positions, layer=1, ring=ring,
+                               dtype=dtype, impl="dense")
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol)
+
+
+@pytest.mark.limit(60)
+def test_what_lies_past_the_array_is_nan_here_and_the_result_is_finite(
+        monkeypatch):
+    """The interpreter fills what a block reads past its array with NaN
+    (a 13-row array in 8-row blocks: rows 13-15), so a value row left
+    unmasked fails loudly on the CPU: 0 x NaN is NaN.  Every slot at the
+    cache's last row reads the ragged block whole."""
+    from jax.experimental import pallas as pl
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    seen = pl.pallas_call(
+        copy, grid=(2,), in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((16, 128), jnp.float32),
+        interpret=True)(jnp.ones((13, 128), jnp.float32))
+    assert np.isnan(np.asarray(seen[13:])).all() \
+        and (np.asarray(seen[:13]) == 1).all()
+    monkeypatch.setattr(flash_decode, "_GROUPED_BLOCK_K", 16)
+    q, k, v = _narrow_row_case(72, jnp.float32)
+    got = wa.cached_attention(q, k, v, jnp.full((5,), 71, jnp.int32),
+                              layer=1, ring=False, dtype=jnp.float32,
+                              impl="flash_decode")
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def _selects_by_shape(jaxpr, found=None):
+    """Output shapes of every ``select_n`` in a jaxpr and the jaxprs
+    under it (a kernel's body, the branches of its ``cond``s)."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "select_n":
+            found.append(eqn.outvars[0].aval.shape)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _selects_by_shape(sub, found)
+    return found
+
+
+@pytest.mark.limit(60)
+def test_a_cache_the_blocks_tile_emits_no_mask_on_the_value_block(
+        monkeypatch):
+    """``cache_rows % block_k == 0`` is a Python branch: the kernel of a
+    shape that tiles holds no select over a ``[block, lanes]`` value
+    block; the ragged one holds exactly one."""
+    monkeypatch.setattr(flash_decode, "_GROUPED_BLOCK_K", 16)
+
+    def value_selects(L):
+        q, k, v = _narrow_row_case(L, jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda *a: flash_decode.grouped_decode_attention(
+            *a, layer=1))(q, k, v, jnp.zeros((5,), jnp.int32))
+        return _selects_by_shape(jaxpr.jaxpr).count((16, 32))
+
+    assert value_selects(64) == 0 and value_selects(72) == 1
+
+
 # -- the expert sublayer, handed its routing ---------------------------------------
 
 @pytest.mark.limit(120)
@@ -594,6 +684,29 @@ def test_engine_serves_the_reference_tokens_through_cache_and_tail(engine):
     assert after["decode_runs"] - before["decode_runs"] == 26
     assert after["decode_moe_rows"] - before["decode_moe_rows"] == 26 * 3 * 3
     assert sum(engine.stats()["retraces"].values()) == 0
+
+
+@pytest.mark.limit(120)
+def test_the_engine_records_the_blocks_its_decode_program_reads(monkeypatch):
+    """``stats()["decode_blocks"]`` (PR 42): 64 rows a slot in blocks of
+    24 are two whole blocks and a ragged one of 16, the same at all three
+    layers, so one triple; ``decode_kernel`` is the string it was.  Greedy
+    tokens past row 48 (the ragged block) are the reference's."""
+    monkeypatch.setenv("RLT_DECODE_IMPL", "flash_decode")
+    monkeypatch.setattr(flash_decode, "_GROUPED_BLOCK_K", 24)
+    eng = ServeEngine(_Module(), DataParallelStrategy(), buckets=(32,),
+                      slots=3, max_seq_len=POSITIONS, seed=0).setup()
+    stats = eng.stats()
+    assert stats["decode_kernel"] == "gqa_decode"
+    assert stats["decode_blocks"] == {"gqa_decode": [[24, 3, 16]]}
+    seq = _tokens(11, 64)
+    want = _full(seq).argmax(-1)
+    got = [eng.prefill(1, pad_to_bucket(seq[:30], 32), 30, 32)]
+    toks, at = np.zeros(3, np.int32), np.zeros(3, np.int32)
+    for t in range(30, 63):
+        toks[1], at[1] = seq[t], t
+        got.append(int(eng.decode(toks, at)[1]))
+    assert got == [int(x) for x in want[29:63]]
 
 
 @pytest.mark.limit(240)
