@@ -14,6 +14,11 @@ from ray_lightning_tpu.models.evabyte import (
     EvaByteLightningModule,
 )
 from ray_lightning_tpu.models.gpt import GPT, GPTConfig, GPTLightningModule
+from ray_lightning_tpu.models.kimi_linear import (
+    KimiLinear,
+    KimiLinearConfig,
+    KimiLinearLightningModule,
+)
 from ray_lightning_tpu.models.xing import (
     Xing,
     XingConfig,
@@ -68,4 +73,7 @@ __all__ = [
     "Zaya",
     "ZayaConfig",
     "ZayaLightningModule",
+    "KimiLinear",
+    "KimiLinearConfig",
+    "KimiLinearLightningModule",
 ]

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_lightning_tpu.ops import flash_decode as _fd
-from ray_lightning_tpu.ops.flash_attention import NEG_INF
+from ray_lightning_tpu.ops.flash_attention import NEG_INF, _pick_block
 
 KERNEL_NAME = _fd.LATENT_KERNEL_NAME
 
@@ -62,7 +62,13 @@ def select_prefill_kernel(T: int, dv: int) -> str:
 def _splash_kernel(T: int, heads: int):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
-    bq, bkv = min(_SPLASH_BLOCK_Q, T), min(_SPLASH_BLOCK_KV, T)
+    # the preferred rows, halved until the block divides T: the kernel
+    # refuses one that does not ("q_block_size=1024 should divide
+    # q_seq_len=2560": a described-v5e compile, PR 43).  1024 wherever it
+    # divides (every bucket the numbers above were read at); 512 at 2,560,
+    # 256 at 3,328
+    bq, bkv = (_pick_block(T, _SPLASH_BLOCK_Q),
+               _pick_block(T, _SPLASH_BLOCK_KV))
     # made under no trace: the mask's block tables are constants of
     # whatever program calls the kernel
     with jax.ensure_compile_time_eval():

@@ -178,8 +178,11 @@ class ShardingStrategy:
         ``[n_layer, slot, pos, head*dim]`` (serve/kvcache.py): slots
         shard exactly like the batch's leading dim — each data shard
         decodes its own slots with no cross-device attention traffic
-        (a slot's tail, ``[n_layer, slot, r, c]``, has its slots where
-        the cache has them and shards the same way).
+        (a slot's tail, ``[n_layer, slot, r, c]``, and the blocks of
+        layers that keep a state and no rows, ``[layers, slot, *block]``
+        of whatever rank from 2, have their slots where the cache has
+        them and shard the same way: the engine asks with each array's
+        ``ndim``).
         Requires ``max_batch_slots`` divisible by the data-axis size
         (the serve engine builds its mesh with ``batch_hint=slots`` so
         single-process meshes clamp instead of erroring)."""

@@ -35,7 +35,14 @@ of the position before it than its rows hold (models/zaya.py: two
 convolutions' inputs and a shifted value) keeps a small block a slot a
 layer, its TAIL (``KVCacheSpec.tail``, read off the same capture): one
 more array on the keys' side, before the accumulator, made by
-``kv_init`` in the model's own dtype and donated with the rest.
+``kv_init`` in the model's own dtype and donated with the rest.  A layer
+that keeps NO rows (models/kimi_linear.py: a float32 matrix a head that
+every step multiplies, a ring of convolution inputs and the int32
+position the matrix stands at) sows a ``SlotState`` in their place; its
+blocks are arrays of their own shapes and types on the keys' side too
+(``KVCacheSpec.states``), behind the arrays of the layers that do keep
+rows, and each array is sharded by its own rank (slots on the data axes
+wherever it has them).
 
 After setup the engine is a pure executor: ``prefill``/``decode`` calls
 carry no Python branching on request state, so the decode loop shape
@@ -73,7 +80,7 @@ from ray_lightning_tpu.core.steps import (
     build_verify_step,
     kv_layer_pairs,
 )
-from ray_lightning_tpu.serve.kvcache import KVCacheSpec
+from ray_lightning_tpu.serve.kvcache import KVCacheSpec, SlotState
 from ray_lightning_tpu.telemetry import metrics as _metrics
 from ray_lightning_tpu.telemetry import span
 
@@ -218,13 +225,20 @@ class ServeEngine:
             self.kv_spec = KVCacheSpec.from_capture(
                 captured, self.slots, self.max_seq_len,
                 counters=len(getattr(module, "serve_counters", ())))
-            kv_dtype = self._k_dtype = captured[0][0].dtype
+            # (the rows' type: of the first layer that keeps rows)
+            kv_dtype = self._k_dtype = next(
+                k[0].dtype for k in captured
+                if not isinstance(k, SlotState))
             if self.kv_spec.own_state:
                 self._check_own_state(module)
 
             param_sh = self.strategy._shardings_with(
                 mesh, abstract_params, self.strategy.param_spec)
-            kv_sh = NamedSharding(mesh, self.strategy.kv_cache_spec(mesh))
+            # one sharding an array of the state, by the array's rank
+            k_sh, v_sh = jax.tree_util.tree_map(
+                lambda a: NamedSharding(mesh, self.strategy.kv_cache_spec(
+                    mesh, len(a.shape))),
+                self.kv_spec.state(jax.ShapeDtypeStruct, kv_dtype))
             rep = NamedSharding(mesh, P())
             multi = mesh.devices.size > 1
             self._rep = rep if multi else None
@@ -274,15 +288,15 @@ class ServeEngine:
                 # the layers are of more than one kind (serve/kvcache.py)
                 return kv_spec.state(jnp.zeros, kv_dtype)
 
-            kkw = {"out_shardings": (kv_sh, kv_sh)} if multi else {}
+            kkw = {"out_shardings": (k_sh, v_sh)} if multi else {}
             self._kv_init = jax.jit(self._counted("kv_init", kv_init), **kkw)
 
             def jit_step(name, fn, n_scalars, n_out=1):
                 kw: dict = {"donate_argnums": (1, 2)}
                 if multi:
                     kw["in_shardings"] = (
-                        (param_sh, kv_sh, kv_sh) + (rep,) * n_scalars)
-                    kw["out_shardings"] = (kv_sh, kv_sh) + (rep,) * n_out
+                        (param_sh, k_sh, v_sh) + (rep,) * n_scalars)
+                    kw["out_shardings"] = (k_sh, v_sh) + (rep,) * n_out
                 return jax.jit(self._counted(name, fn), **kw)
 
             for b in self.buckets:
@@ -314,8 +328,8 @@ class ServeEngine:
                     build_suffix_step(module, page_table=page_table), 3)
                 ckw: dict = {"donate_argnums": (0, 1)}
                 if multi:
-                    ckw["in_shardings"] = (kv_sh, kv_sh, rep, rep, rep)
-                    ckw["out_shardings"] = (kv_sh, kv_sh)
+                    ckw["in_shardings"] = (k_sh, v_sh, rep, rep, rep)
+                    ckw["out_shardings"] = (k_sh, v_sh)
                 self._kv_copy = jax.jit(
                     self._counted("kv_copy", build_kv_copy()), **ckw)
 
@@ -410,7 +424,7 @@ class ServeEngine:
                     # reads the resident shardings of the shared views
                     kw: dict = {"donate_argnums": (1, 2)}
                     if multi:
-                        kw["out_shardings"] = (kv_sh, kv_sh, rep)
+                        kw["out_shardings"] = (k_sh, v_sh, rep)
                     return jax.jit(self._counted(name, fn), **kw)
 
                 for b in self.buckets:
@@ -445,8 +459,8 @@ class ServeEngine:
                 for b in self.buckets:
                     ikw2: dict = {"donate_argnums": (0, 1)}
                     if multi:
-                        ikw2["in_shardings"] = (kv_sh, kv_sh, rep, rep, rep)
-                        ikw2["out_shardings"] = (kv_sh, kv_sh)
+                        ikw2["in_shardings"] = (k_sh, v_sh, rep, rep, rep)
+                        ikw2["out_shardings"] = (k_sh, v_sh)
                     self._kv_imports[b] = jax.jit(
                         self._counted(f"kv_import_{b}", import_fn), **ikw2)
 
@@ -862,6 +876,9 @@ class ServeEngine:
             "memory_stats": dev.memory_stats(),
             "decode_kernel": self.decode_kernel,
             "decode_blocks": self.decode_blocks,
+            # bytes of KVCacheSpec.states a slot holds (0: rows only)
+            "state_bytes_per_slot": self.kv_spec.state_bytes_per_slot
+            if self.kv_spec else 0,
             **self._counters(jax),
             "traces": dict(self.trace_counts),
             # traces since the warmup snapshot: 0 everywhere = the

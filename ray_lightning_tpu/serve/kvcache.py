@@ -65,6 +65,29 @@ from_capture`` reads it off the model's prefill capture):
   generations, position ``t``'s in row ``t % 2``, and a step at ``t``
   reads row ``(t - 1) % 2`` and writes row ``t % 2``: run again, it
   reads and writes the same values.
+- **a matrix a head and NO rows** (models/kimi_linear.py, ops/kda.py):
+  a Kimi Delta Attention layer keeps no row at all.  A slot's state in
+  such a layer is what the layer's capture sows as a :class:`SlotState`:
+  blocks of the model's own shapes and types, here a float32 matrix a
+  head (``[H V, K]``: 2 MB published) that every step MULTIPLIES, a ring
+  of the last four positions' convolution inputs (position ``p`` in row
+  ``p % 4``: written by position like a cache row, so idempotent), and an
+  int32 STAMP: the position the matrix stands at.  Layers that sow alike
+  share one array a block, ``[layers, S, *block]``
+  (``KVCacheSpec.states``), on the keys' side behind the kinds' arrays
+  and before a tail; the layers between them that keep rows (one latent
+  row a position there) are the kinds, as ever, fewer than ``n_layer``.
+  A matrix that a step multiplies cannot be rewritten by position, and a
+  step may run twice (above).  The model keeps ONE generation and reads
+  the stamp: a step at ``t`` updates a state that stands at ``t - 1``
+  (and stamps it ``t``), reads out of one that already stands at ``t``
+  unchanged, and leaves any other alone (a dead slot's dummy step), so a
+  second run computes from the state the first run left and returns the
+  first run's values.  A prefill writes state, ring and stamp (``length
+  - 1``) whole at its slot, so a freed slot needs no clearing.  Two
+  generations, as the tail has them, would have doubled the largest
+  array of such a cell (3.0 GB at 192 slots) for the same traffic; the
+  stamps are 4 B a slot a layer.
 
 There is ONE layout, the one the decode kernels read: a row is a
 token's heads side by side on the lane axis, which is how the qkv
@@ -100,11 +123,20 @@ counters (serve/engine.py).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import numpy as np
 
 #: a slot's tail is kept as what the model's convolutions read
 TAIL_DTYPE = np.dtype(np.float32)
+
+
+class SlotState(NamedTuple):
+    """What a layer that keeps NO rows sows in their place: ``blocks``, a
+    tuple of ``[B, 1, *block]`` arrays of the model's own shapes and
+    types, each one block a slot (module docstring)."""
+
+    blocks: tuple
 
 
 @dataclass(frozen=True)
@@ -122,7 +154,11 @@ class KVCacheSpec:
     for beside the cache (0: none); ``paired``: a keys' and a values'
     array a kind, or (False) the one array of a model whose row holds
     both (module docstring); ``tail``: ``(r, c)`` of the ``TAIL_DTYPE``
-    block a slot keeps a layer beside its rows (empty: none)."""
+    block a slot keeps a layer beside its rows (empty: none);
+    ``states``: ``((layers, block, dtype name), ...)``, one array
+    ``[layers, S, *block]`` each, of the layers that keep no rows and a
+    :class:`SlotState` instead (empty: none; ``kinds`` then counts only
+    the layers that keep rows)."""
 
     n_layer: int
     slots: int
@@ -133,13 +169,14 @@ class KVCacheSpec:
     counters: int = 0
     paired: bool = True
     tail: "tuple[int, ...]" = ()
+    states: "tuple[tuple[int, tuple[int, ...], str], ...]" = ()
 
     @property
     def own_state(self) -> bool:
         """Whether a slot's rows are the model's own kind and not a row
         per position (module docstring)."""
         return self.rows is not None or bool(self.kinds) \
-            or bool(self.tail)
+            or bool(self.tail) or bool(self.states)
 
     @property
     def shapes(self) -> "tuple[tuple[int, int, int, int], ...]":
@@ -168,13 +205,20 @@ class KVCacheSpec:
             return None
         return (self.n_layer, self.slots) + tuple(self.tail)
 
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of ``states`` one slot holds, all their layers."""
+        return sum(n * int(np.prod(block, dtype=np.int64))
+                   * np.dtype(dtype).itemsize
+                   for n, block, dtype in self.states)
+
     def nbytes(self, itemsize: int = 2) -> int:
         """Device residency of BOTH cache arrays (k and v) of every kind,
         or of its one array, at the given element size (bf16 default),
-        and of the tails in theirs."""
+        and of the tails and the states in theirs."""
         tail = int(np.prod(self.tail_shape, dtype=np.int64)) \
             * TAIL_DTYPE.itemsize if self.tail else 0
-        return tail + sum(
+        return tail + self.slots * self.state_bytes_per_slot + sum(
             (1 + self.paired) * int(np.prod(shape, dtype=np.int64))
             * itemsize for shape in self.shapes)
 
@@ -182,16 +226,19 @@ class KVCacheSpec:
         """``(k, v)`` as every serve program takes and returns them,
         each leaf ``make(shape, dtype)`` (zeros, or an aval).  One kind
         and no accumulator: the two bare arrays.  Otherwise a tuple an
-        array a kind, the tails and then the int32 accumulator behind
-        the keys'; the values' side empty where a kind is one array."""
+        array a kind, the states, the tails and then the int32
+        accumulator behind the keys'; the values' side empty where a
+        kind is one array."""
         kinds = tuple(make(shape, dtype) for shape in self.shapes)
         if len(kinds) == 1 and not self.counters and self.paired \
-                and not self.tail:
+                and not self.tail and not self.states:
             return kinds[0], kinds[0]
         extra = (make((self.counters,), np.int32),) if self.counters else ()
         if self.tail:
             extra = (make(self.tail_shape, TAIL_DTYPE),) + extra
-        return kinds + extra, kinds if self.paired else ()
+        states = tuple(make((n, self.slots) + tuple(block), np.dtype(t))
+                       for n, block, t in self.states)
+        return kinds + states + extra, kinds if self.paired else ()
 
     @classmethod
     def from_capture(cls, kv_shapes, slots: int, max_seq_len: int,
@@ -202,9 +249,12 @@ class KVCacheSpec:
         (``kv_layer_pairs``), where a tuple of ONE block says that the
         layer's row holds key and value at once and a THIRD block
         ``[B, 1, r, c]`` is the tail a slot keeps in that layer beside
-        its rows (every layer's alike).  An entry shaped ``[B, T, C]`` is
-        a row per captured position: the cache holds ``max_seq_len`` rows
-        a slot.
+        its rows (every layer's alike).  A :class:`SlotState` is a layer
+        that keeps no rows: its blocks are ``states``, one array for all
+        the layers that sow a block alike, and the layers that do keep
+        rows are ``kinds`` even where they are of one kind.  An entry
+        shaped ``[B, T, C]`` is a row per captured position: the cache
+        holds ``max_seq_len`` rows a slot.
         An entry shaped ``[B, 1, R, C]`` is a model's own state block,
         as its ``prefill`` method writes it at a slot: ``R`` rows a
         slot, whatever ``max_seq_len`` (the model sized it from its own
@@ -212,6 +262,18 @@ class KVCacheSpec:
         differing ``R`` are layers of differing kinds: one kind a
         distinct ``R``, in the order of each kind's first layer, which
         is the order the model finds its arrays in."""
+        n_layer = len(kv_shapes)
+        stateful = [k for k in kv_shapes if isinstance(k, SlotState)]
+        kv_shapes = [k for k in kv_shapes if not isinstance(k, SlotState)]
+        blocks = {tuple((tuple(int(n) for n in b.shape[2:]),
+                         np.dtype(b.dtype).name) for b in k.blocks)
+                  for k in stateful}
+        if len(blocks) > 1 or (stateful and not kv_shapes):
+            raise ValueError(
+                f"layers without rows keep states of differing blocks, or "
+                f"no layer keeps rows: {sorted(blocks)}")
+        states = tuple((len(stateful),) + b
+                       for b in next(iter(blocks), ()))
         paired = not any(isinstance(k, tuple) and len(k) == 1
                          for k in kv_shapes)
         tails = {(tuple(int(n) for n in k[2].shape[2:]),
@@ -222,7 +284,6 @@ class KVCacheSpec:
                              f"not {TAIL_DTYPE.name}: {sorted(tails)}")
         tail = next(iter(tails), ((),))[0]
         kv_shapes = [k[0] if isinstance(k, tuple) else k for k in kv_shapes]
-        n_layer = len(kv_shapes)
         if n_layer == 0:
             raise ValueError("model captured no kv_cache entries; does "
                              "its attention sow the 'kv_cache' "
@@ -230,13 +291,15 @@ class KVCacheSpec:
         per_layer = [int(k.shape[2]) if len(k.shape) == 4 else None
                      for k in kv_shapes]
         distinct = tuple(dict.fromkeys(per_layer))
-        one_kind = len(distinct) == 1
+        one_kind = len(distinct) == 1 and not stateful
         return cls(n_layer=n_layer, slots=slots, max_seq_len=max_seq_len,
                    width=int(kv_shapes[0].shape[-1]),
                    rows=distinct[0] if one_kind else None,
                    kinds=() if one_kind else tuple(
-                       (per_layer.count(r), r) for r in distinct),
-                   counters=counters, paired=paired, tail=tail)
+                       (per_layer.count(r), r or max_seq_len)
+                       for r in distinct),
+                   counters=counters, paired=paired, tail=tail,
+                   states=states)
 
 
 class SlotAllocator:
@@ -275,4 +338,4 @@ class SlotAllocator:
         return tuple(sorted(self._used))
 
 
-__all__ = ["KVCacheSpec", "SlotAllocator"]
+__all__ = ["KVCacheSpec", "SlotAllocator", "SlotState"]

@@ -868,6 +868,8 @@ class Scheduler:
             "decode_steps": self._decode_steps,
             # means over decode steps, summed over the occupied slots:
             # context positions live, and the cache rows their slots read
+            # (the mean over ALL the layers: a layer that keeps no rows
+            # counts none)
             "live_positions": self._live_positions_sum / steps,
             "live_rows": self._live_rows_sum / steps,
             "pump": self.pump.snapshot(),

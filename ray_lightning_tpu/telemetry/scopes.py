@@ -73,7 +73,14 @@ FINE_SCOPES = ("eva_summary", "eva_attn",
                # mean, both convolutions, the norm and temperature, the
                # shifted value, the tail's read and write), and its
                # projections with rotary
-               "cca_mix", "cca_proj")
+               "cca_mix", "cca_proj",
+               # models/kimi_linear.py: what of Kimi Delta Attention reads
+               # or writes a slot's matrix state (the decay, the delta
+               # update and read-out of a step; a prompt's chunkwise scan
+               # and the state's write), and everything else of it
+               # (projections, short convolutions and their ring, norms,
+               # gates)
+               "kda_state", "kda_proj")
 
 #: the file of tables written beside a captured trace
 TABLE_FILE = "op_scopes.json"

@@ -318,6 +318,81 @@ def test_compressed_attention_decode_compiles_for_v5e(monkeypatch, v5e, what):
     assert not _layer_movers(text, layer)
 
 
+def test_the_kda_decode_call_compiles_for_v5e_in_place(monkeypatch, v5e):
+    """ops/kda.py ``kda_decode`` at the published sizes: 192 slots x 32
+    matrices of 128 x 128 float32 in one layer of the resident ``[7, 192,
+    4096, 128]`` array (3.08 GB), a slot a grid step.  The array enters
+    whole, donated, and comes back aliased: no temporary of a layer's
+    size (0.40 GB), let alone of the array's."""
+    from ray_lightning_tpu.ops import flash_decode, kda
+    monkeypatch.setattr(flash_decode, "_use_interpret", lambda: False)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e)
+
+    row = sds(192, 32, 128)
+    compiled = jax.jit(
+        lambda q, k, v, g, b, st: kda.kda_decode(q, k, v, g, b, st, layer=5),
+        donate_argnums=(5,)).lower(
+            row, row, row, row, sds(192, 32), sds(7, 192, 4096, 128)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%kda_decode(\.\d+)? = [^\n]* custom-call\([^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text), text
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6
+
+
+#: one v5e chip's memory as the runtime reports it (PERF.md section 3)
+BYTES_LIMIT = 16_909_336_064
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_4096"])
+def test_the_kimi_linear_cells_programs_fit_the_described_v5e(
+        monkeypatch, v5e, program):
+    """``kimi-linear-serve-turns4k``'s decode program and its largest
+    prefill at the cell's slots and the published widths, as
+    ``chipbench/describe_latent.py`` compiles them by hand: 9 layers, 192
+    slots x 5,248 latent rows in two of them, 2 MB of float32 matrix a
+    slot in each of the other seven.  Arguments (weights 4.75 GB, rows
+    2.58, state 3.08) and temporaries together lie under the chip's
+    ``bytes_limit`` with the room a deployment wants, and the state is
+    not held twice: the temporaries stay under one layer's matrices of
+    every slot (0.40 GB) twice over for the decode run, and under 2 GB
+    for the prompt."""
+    from chipbench import describe_state, run
+    from ray_lightning_tpu.core import steps
+    from ray_lightning_tpu.ops import flash_decode, moe
+    from ray_lightning_tpu.ops import latent_attention as la
+
+    # describe_state steers these for the described chip: put them back
+    for mod, name in ((flash_decode, "_use_interpret"),
+                      (moe, "grouped_dot_impl")):
+        monkeypatch.setattr(mod, name, getattr(mod, name))
+    monkeypatch.setenv("RLT_DECODE_IMPL", "flash_decode")
+    # (this process has eight CPU devices: the prompt would go dense)
+    monkeypatch.setattr(la, "select_prefill_kernel", lambda T, dv: "splash")
+    captured = steps.kv_layer_pairs
+    monkeypatch.setattr(steps, "kv_layer_pairs", lambda tree: [
+        (entry, None) for entry in captured(tree)])
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(REPO, "chipbench", "traffic",
+                           "turns4k-saturated.json")) as f:
+        slots = int(json.load(f)["slots"])
+    adapter = run.load_adapter(config, REPO)
+    rows = describe_state.programs(
+        adapter, config["model"], slots,
+        [4096] if program != "decode" else [], False)
+    got = next(r for r in rows if r["program"] == program)
+    assert got["fits"], got
+    assert got["cache_shapes"] == [[2, slots, 5248, 640]]
+    total = got["arguments_gb"] + got["temporaries_gb"]
+    assert slots == 192 and 10.3 < got["arguments_gb"] < 10.5
+    assert total * 1e9 < 0.8 * BYTES_LIMIT, got
+    assert got["temporaries_gb"] < (0.81 if program == "decode" else 3.0)
+    assert "gmm" in got["kernels"]
+
+
 # -- the cache's trip through the serve programs ----------------------------
 #
 # The K/V cache is resident as [n_layer, S, L, H*D] and every program
